@@ -14,7 +14,8 @@ Here: factorize a low-rank matrix M ≈ U Vᵀ where V lives in a MatrixTable
 batch, takes a gradient step, and scatters the update back — the classic
 PS access pattern (cf. WordEmbedding's embedding rows), entirely on device.
 
-Run (any backend; forces an 8-device CPU mesh when no TPU is present):
+Run (on whatever backend JAX selects; a CPU-only machine gets 8 host
+devices from the XLA_FLAGS default below, so the table is still sharded):
     python device_plane.py
 """
 
@@ -25,9 +26,6 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 import numpy as np
 
 import jax
-
-if jax.default_backend() != "tpu":
-    jax.config.update("jax_platforms", "cpu")
 
 import jax.numpy as jnp
 from jax import lax

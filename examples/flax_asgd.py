@@ -23,9 +23,6 @@ import numpy as np
 
 import jax
 
-if jax.default_backend() != "tpu":
-    jax.config.update("jax_platforms", "cpu")
-
 import flax.linen as nn
 import jax.numpy as jnp
 import optax
@@ -53,6 +50,8 @@ def init_params():
 
 
 def main():
+    dev = jax.devices()
+    print(f"running on {len(dev)} x {dev[0].platform} ({dev[0].device_kind})")
     rng = np.random.default_rng(0)
     centers = rng.standard_normal((CLASSES, FEATURES)).astype(np.float32) * 2
     y = rng.integers(0, CLASSES, N)

@@ -20,8 +20,8 @@ every top-level def into edges. Resolution, strongest first:
 Lambdas and nested defs merge into their enclosing top-level def, so
 ``bounded(lambda: capped_exchange(...))`` correctly charges the caller.
 Defs under module/class-level ``if``/``try``/``with`` scaffolding (the
-version-shim idiom — parallel/mesh.py's ``shard_map``) are top-level
-definitions too (:func:`flat_body`), not module code.
+optional-dependency-fallback idiom) are top-level definitions too
+(:func:`flat_body`), not module code.
 Non-call references to resolvable functions (callbacks passed by name)
 also produce edges. What the graph cannot see: getattr-by-string,
 property getters that do work, and calls that cross an actor mailbox
@@ -117,9 +117,8 @@ def iter_top_defs(tree: ast.AST):
 def flat_body(body) -> "list":
     """Module/class-body statements with conditional/guard scaffolding
     flattened: a def under a module-level ``if``/``try``/``with`` (the
-    version-shim and optional-dependency-fallback idioms —
-    parallel/mesh.py's ``shard_map`` shim is the in-package example) is
-    still a top-level definition for graph purposes. The guard's own
+    optional-dependency-fallback idiom) is still a top-level definition
+    for graph purposes. The guard's own
     expressions (``if`` tests, ``except`` types, ``with`` context
     expressions) are yielded too, so module-level guard code keeps its
     edges. Does NOT descend into defs/lambdas — nested defs stay merged

@@ -44,8 +44,6 @@ def _aux_leaves(table):
     state = getattr(table, "state", None)
     if not isinstance(state, dict) or "aux" not in state:
         return []
-    # tree_util spelling: jax.tree.leaves_with_path is newer than some
-    # supported jax releases; the tree_util alias exists on all of them
     leaves = jax.tree_util.tree_leaves_with_path(state["aux"])
     return [(jax.tree_util.keystr(path), leaf) for path, leaf in leaves]
 

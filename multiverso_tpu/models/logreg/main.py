@@ -6,6 +6,7 @@ from __future__ import annotations
 import sys
 
 from multiverso_tpu.models.logreg.logreg import LogReg
+from multiverso_tpu.utils import compile_cache
 from multiverso_tpu.utils.log import Log
 
 
@@ -15,6 +16,7 @@ def main(argv=None) -> int:
         Log.Error("usage: python -m multiverso_tpu.models.logreg.main "
                   "<config_file>")
         return 1
+    compile_cache.enable()
     lr = LogReg(argv[0])
     lr.Train()
     if lr.config.test_file:

@@ -341,19 +341,27 @@ class BlockQueue:
     def __init__(self, capacity: int = 2):
         self._q: MtQueue[DataBlock] = MtQueue()
         self._space = threading.Semaphore(capacity)
+        self._error: Optional[Exception] = None
 
     def push(self, block: DataBlock) -> None:
         self._space.acquire()
         self._q.Push(block)
 
     def pop(self) -> Optional[DataBlock]:
+        """The next block; None at the end of the stream. A loader that
+        died raises its exception here, after the blocks it had queued."""
         ok, block = self._q.Pop()
         if not ok:
+            if self._error is not None:
+                raise self._error
             return None
         self._space.release()
         return block
 
-    def close(self) -> None:
+    def close(self, error: Optional[Exception] = None) -> None:
+        """End of the stream. ``error``: the loader failed — a short
+        stream must fail the trainer, not look like a short corpus."""
+        self._error = error
         self._q.Exit()
 
 
@@ -407,13 +415,16 @@ def start_loader(option, dictionary: Dictionary, generator: PairGenerator,
                 queue.push(pending.popleft().result())
 
     def run():
+        error = None
         try:
             if workers == 1:
                 run_sequential()
             else:
                 run_pooled()
+        except Exception as exc:    # re-raised in the trainer by pop()
+            error = exc
         finally:
-            queue.close()
+            queue.close(error)
 
     t = threading.Thread(target=run, daemon=True)
     t.start()

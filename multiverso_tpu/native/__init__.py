@@ -2,9 +2,12 @@
 
 The C++ runtime mirrors the reference's native core (actors, store,
 updaters, BSP sync, c_api — see native/) and additionally exports fast
-text parsers used by the python data pipelines. The library is built on
-demand with ``make`` and loaded via ctypes; everything degrades gracefully
-to pure python when no toolchain is available.
+text parsers used by the python data pipelines. A source checkout builds
+the library with ``make`` (a no-op when it is up to date) every time a
+process first asks for it, so what loads is never older than
+``native/src``; an installed wheel loads the copy built into it. Without
+a library the callers fall back to pure python, and :func:`lib` says so
+once.
 """
 
 from __future__ import annotations
@@ -17,6 +20,8 @@ from typing import Optional, Tuple
 
 import numpy as np
 
+from multiverso_tpu.utils.log import Log
+
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 _NATIVE_DIR = os.path.join(_REPO_ROOT, "native")
@@ -26,51 +31,56 @@ _PKG_LIB_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                              "libmultiverso_tpu.so")
 _REPO_LIB_PATH = os.path.join(_NATIVE_DIR, "libmultiverso_tpu.so")
 
+_WITHOUT = ("running without it: python libsvm parser and tokenizer, no "
+            "native host store, kv index or crc32c")
+
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
-def _build() -> bool:
+def _build() -> Optional[str]:
+    """Run the Makefile. None on success, else why it failed."""
+    import fcntl
     try:
-        result = subprocess.run(["make", "-C", _NATIVE_DIR, "-j4",
-                                 "libmultiverso_tpu.so"],
-                                capture_output=True, text=True, timeout=300)
-        return result.returncode == 0
-    except Exception:
-        return False
-
-
-def _try_load(path: str) -> Optional[ctypes.CDLL]:
-    """Load + signature-check one candidate; None on any failure
-    (AttributeError = stale .so missing a newer symbol)."""
-    try:
-        handle = ctypes.CDLL(path)
-        _configure_signatures(handle)
-        return handle
-    except (OSError, AttributeError):
-        return None
+        # two processes of one job can both be first to ask (2-process
+        # worlds start together): serialize their makes on a file lock
+        with open(os.path.join(_NATIVE_DIR, ".build.lock"), "w") as lock:
+            fcntl.flock(lock, fcntl.LOCK_EX)
+            result = subprocess.run(
+                ["make", "-C", _NATIVE_DIR, "-j4", "libmultiverso_tpu.so"],
+                capture_output=True, text=True, timeout=300)
+    except (OSError, subprocess.TimeoutExpired) as exc:
+        return repr(exc)
+    if result.returncode != 0:
+        return result.stderr[-4000:]
+    return None
 
 
 def lib() -> Optional[ctypes.CDLL]:
     """The loaded native library, or None when unavailable."""
     global _lib, _tried
     with _lock:
-        if _lib is not None or _tried:
+        if _tried:
             return _lib
         _tried = True
-        # wheel package-data first, source-tree build second
-        for path in (_PKG_LIB_PATH, _REPO_LIB_PATH):
-            if os.path.exists(path):
-                _lib = _try_load(path)
-                if _lib is not None:
-                    return _lib
-        # missing everywhere, or every existing candidate was stale:
-        # rebuild the SOURCE-TREE library (the package-data .so is an
-        # immutable wheel artifact — recovery must not retry it) and load
-        # that; otherwise degrade to pure python (module contract)
-        if _build():
-            _lib = _try_load(_REPO_LIB_PATH)
+        if os.path.exists(os.path.join(_NATIVE_DIR, "Makefile")):
+            # source checkout: make decides whether a library lying in
+            # native/ is current — a stale one is rebuilt, never loaded
+            err = _build()
+            if err is not None:
+                Log.Error("native runtime build failed (make -C %s); %s\n%s",
+                          _NATIVE_DIR, _WITHOUT, err)
+                return None
+            path = _REPO_LIB_PATH
+        elif os.path.exists(_PKG_LIB_PATH):
+            path = _PKG_LIB_PATH
+        else:
+            Log.Info("native runtime not installed; %s", _WITHOUT)
+            return None
+        handle = ctypes.CDLL(path)
+        _configure_signatures(handle)
+        _lib = handle
         return _lib
 
 
@@ -108,24 +118,20 @@ def _configure_signatures(h: ctypes.CDLL) -> None:
     h.MV_HostStoreGetRows.argtypes = [ctypes.c_void_p, i32p, i64, f32p]
     h.MV_HostStorePoolStats.argtypes = [
         np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")]
-    # round 19 — the versioned seal's hardware CRC32C (crc32c.cc);
-    # hasattr-guarded like MV_KvIndexCapacity so a stale prebuilt .so
-    # degrades to the pure-python seal paths instead of failing load
-    if hasattr(h, "MV_Crc32c"):
-        u8p = np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS")
-        h.MV_Crc32c.restype = ctypes.c_uint32
-        h.MV_Crc32c.argtypes = [u8p, i64, ctypes.c_uint32]
-        h.MV_Crc32cHw.restype = ctypes.c_int
-        h.MV_Crc32cHw.argtypes = []
+    # the versioned seal's hardware CRC32C (crc32c.cc)
+    u8p = np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS")
+    h.MV_Crc32c.restype = ctypes.c_uint32
+    h.MV_Crc32c.argtypes = [u8p, i64, ctypes.c_uint32]
+    h.MV_Crc32cHw.restype = ctypes.c_int
+    h.MV_Crc32cHw.argtypes = []
     i64p = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")
     h.MV_KvIndexNew.restype = ctypes.c_void_p
     h.MV_KvIndexNew.argtypes = [i64]
     h.MV_KvIndexFree.argtypes = [ctypes.c_void_p]
     h.MV_KvIndexSize.restype = i64
     h.MV_KvIndexSize.argtypes = [ctypes.c_void_p]
-    if hasattr(h, "MV_KvIndexCapacity"):    # older prebuilt .so
-        h.MV_KvIndexCapacity.restype = i64
-        h.MV_KvIndexCapacity.argtypes = [ctypes.c_void_p]
+    h.MV_KvIndexCapacity.restype = i64
+    h.MV_KvIndexCapacity.argtypes = [ctypes.c_void_p]
     h.MV_KvIndexLookup.argtypes = [ctypes.c_void_p, i64p, i64, i32p]
     h.MV_KvIndexInsert.argtypes = [ctypes.c_void_p, i64p, i64, i32p]
     h.MV_KvIndexItems.argtypes = [ctypes.c_void_p, i64p, i32p]
@@ -310,12 +316,8 @@ class KvIndex:
 
     def capacity(self) -> int:
         """Allocated probing-table slots (>= len; the load-factor
-        headroom the accounting ledger must count). Falls back to len
-        on an older .so without the export."""
-        fn = getattr(self._h, "MV_KvIndexCapacity", None)
-        if fn is None:
-            return len(self)
-        return int(fn(self._ptr))
+        headroom the accounting ledger must count)."""
+        return int(self._h.MV_KvIndexCapacity(self._ptr))
 
     def lookup(self, keys: np.ndarray) -> np.ndarray:
         keys = np.ascontiguousarray(keys, np.int64)
@@ -357,14 +359,12 @@ class KvIndex:
 def crc32c_fn():
     """The native CRC32C entry point (``MV_Crc32c(data_u8, n, seed)``
     -> u32, zlib.crc32-style chaining), or None when the native lib is
-    unavailable or predates the export. Returned as the raw callable so
+    unavailable. Returned as the raw callable so
     the seal's hot loop (parallel/seal.py) pays the capability probe
     ONCE, not per frame. This module stays jax-free — the replica
     plane's reader processes verify fan-out seals through it."""
     h = lib()
-    if h is None or not hasattr(h, "MV_Crc32c"):
-        return None
-    return h.MV_Crc32c
+    return None if h is None else h.MV_Crc32c
 
 
 _charp_fn = None
@@ -380,21 +380,13 @@ def crc32c_charp_fn():
     wire's streaming chunks) keeps working. None when unavailable."""
     global _charp_fn
     if _charp_fn is None:
-        if lib() is None or not hasattr(lib(), "MV_Crc32c"):
+        if lib() is None:
             return None
-        for path in (_PKG_LIB_PATH, _REPO_LIB_PATH):
-            if os.path.exists(path):
-                try:
-                    h2 = ctypes.CDLL(path)
-                    fn = h2.MV_Crc32c
-                    fn.restype = ctypes.c_uint32
-                    fn.argtypes = [ctypes.c_char_p, ctypes.c_int64,
-                                   ctypes.c_uint32]
-                    # mv-lint: ok(cross-domain-state): idempotent lazy init — every racing thread binds the same symbol of the same library; a double-store of an equivalent callable is benign
-                    _charp_fn = fn
-                    break
-                except (OSError, AttributeError):
-                    continue
+        fn = ctypes.CDLL(lib()._name).MV_Crc32c
+        fn.restype = ctypes.c_uint32
+        fn.argtypes = [ctypes.c_char_p, ctypes.c_int64, ctypes.c_uint32]
+        # mv-lint: ok(cross-domain-state): idempotent lazy init — every racing thread binds the same symbol of the same library; a double-store of an equivalent callable is benign
+        _charp_fn = fn
     return _charp_fn
 
 

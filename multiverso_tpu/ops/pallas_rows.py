@@ -60,11 +60,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed across jax releases (TPUMemorySpace -> MemorySpace); resolve
-# whichever this jax ships
-_MemorySpace = getattr(pltpu, "MemorySpace", None) \
-    or pltpu.TPUMemorySpace
-
 
 def _contig(vals):
     """Traced predicate: the chunk's ids are strictly consecutive
@@ -77,32 +72,11 @@ def _contig(vals):
         ok = jnp.logical_and(ok, vals[j] - vals[j - 1] == 1)
     return ok
 
+#: ids per grid step. Rows are exactly one 128-lane tile of a 4-byte dtype
+#: (rows._pallas_eligible — the only row shape Mosaic compiles these
+#: kernels for), so the fused kernel's three (CHUNK, 128) f32 VMEM blocks
+#: are 96 KB: nothing to budget.
 CHUNK = 64
-# Conservative slice of the ~16MB/core VMEM for a kernel's blocks.
-# _chunk_for shrinks the chunk for wide rows so the blocks always fit; rows
-# so wide that even MIN_CHUNK overflows make it return 0, which
-# rows._pallas_eligible uses to route those tables to the XLA path.
-VMEM_BUDGET = 4 * 1024 * 1024
-MIN_CHUNK = 8
-#: the fused RMW kernel's VMEM block count (deltas block double-buffered by
-#: Mosaic's pipeline + scratch) — the worst case of the three kernels, and
-#: therefore what eligibility is judged against
-FUSED_BLOCKS = 3
-
-
-def _chunk_for(cols: int, itemsize: int, blocks: int = FUSED_BLOCKS) -> int:
-    """Largest chunk (<= CHUNK, >= MIN_CHUNK, power of two) for which
-    ``blocks`` VMEM blocks of (chunk, cols) fit the budget, or 0 when even
-    MIN_CHUNK does not. ``blocks`` is per kernel: the fused update holds
-    FUSED_BLOCKS, gather/scatter hold 2 (one block, double-buffered).
-    Callers derive chunk from static shapes, so it is a compile-time
-    constant."""
-    c = CHUNK
-    while c > MIN_CHUNK and blocks * c * cols * itemsize > VMEM_BUDGET:
-        c //= 2
-    if blocks * c * cols * itemsize > VMEM_BUDGET:
-        return 0
-    return c
 
 
 def _make_gather_kernel(chunk, coalesce):
@@ -149,8 +123,7 @@ def _make_gather_kernel(chunk, coalesce):
 def pallas_gather_rows(data: jax.Array, ids: jax.Array,
                        interpret: bool = False) -> jax.Array:
     """rows[i] = data[ids[i]] — one row DMA per id, chunk per grid step."""
-    chunk = _chunk_for(data.shape[1], data.dtype.itemsize, blocks=2)
-    assert chunk, "caller must gate on rows._pallas_eligible"
+    chunk = CHUNK
     orig_n = ids.shape[0]
     if orig_n % chunk:
         # tail pad with id 0: a read-only over-fetch, sliced off below
@@ -162,7 +135,7 @@ def pallas_gather_rows(data: jax.Array, ids: jax.Array,
         num_scalar_prefetch=1,
         grid=(n // chunk,),
         in_specs=[
-            pl.BlockSpec(memory_space=_MemorySpace.ANY),  # data: HBM
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),  # data: HBM
         ],
         out_specs=pl.BlockSpec((chunk, cols), lambda i, ids: (i, 0)),
         scratch_shapes=[pltpu.SemaphoreType.DMA((chunk,))],
@@ -221,8 +194,7 @@ def pallas_scatter_set_rows(data: jax.Array, ids: jax.Array,
     Rows the ids never name keep their HBM content — only touched rows
     move, which is the whole point of the PS row protocol.
     """
-    chunk = _chunk_for(data.shape[1], data.dtype.itemsize, blocks=2)
-    assert chunk, "caller must gate on rows._pallas_eligible"
+    chunk = CHUNK
     if ids.shape[0] % chunk:
         # tail pad by replicating the last (id, row) pair: the extra DMAs
         # rewrite the same bytes to the same row — a no-op on memory content
@@ -236,9 +208,9 @@ def pallas_scatter_set_rows(data: jax.Array, ids: jax.Array,
         grid=(n // chunk,),
         in_specs=[
             pl.BlockSpec((chunk, cols), lambda i, ids: (i, 0)),   # rows: VMEM
-            pl.BlockSpec(memory_space=_MemorySpace.ANY),  # data: HBM
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),  # data: HBM
         ],
-        out_specs=pl.BlockSpec(memory_space=_MemorySpace.ANY),
+        out_specs=pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
         scratch_shapes=[pltpu.SemaphoreType.DMA((chunk,))],
     )
     return pl.pallas_call(
@@ -335,8 +307,7 @@ def pallas_update_rows(data: jax.Array, ids: jax.Array, deltas: jax.Array,
     arg: one compile per (shape, combine) pair — combines are per-table
     updater singletons, so this never retraces in steady state.
     """
-    chunk = _chunk_for(data.shape[1], data.dtype.itemsize)
-    assert chunk, "caller must gate on rows._pallas_eligible"
+    chunk = CHUNK
     orig_n = ids.shape[0]
     if orig_n % chunk:
         # tail pad to a chunk multiple; the padded lanes are skipped inside
@@ -352,9 +323,9 @@ def pallas_update_rows(data: jax.Array, ids: jax.Array, deltas: jax.Array,
         grid=(n // chunk,),
         in_specs=[
             pl.BlockSpec((chunk, cols), lambda i, ids: (i, 0)),  # deltas
-            pl.BlockSpec(memory_space=_MemorySpace.ANY),    # data: HBM
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),    # data: HBM
         ],
-        out_specs=pl.BlockSpec(memory_space=_MemorySpace.ANY),
+        out_specs=pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
         scratch_shapes=[pltpu.VMEM((chunk, cols), data.dtype),
                         pltpu.SemaphoreType.DMA((chunk,)),
                         pltpu.SemaphoreType.DMA((chunk,))],

@@ -11,11 +11,12 @@ used by tests); ``off`` — XLA only.
 The XLA fallback relies on jit'd gather + ``.at[].set`` — on a CPU test
 mesh that is both correct and fast enough.
 
-Row DMAs slice HBM along the lane dim, so Pallas needs the row byte-width
-tile-aligned (128 lanes for 4-byte dtypes). The table layer pads its
-storage column dim to ``padded_cols`` so the hot path stays eligible —
-measured ~5.6x on the reference 1Mx50 row-op benchmark even for plain XLA
-(aligned rows vs 200-byte ragged rows), with the fused Pallas update
+Row DMAs slice HBM along the lane dim, and Mosaic compiles them only for
+rows of exactly one 128-lane tile of a 4-byte dtype (``_pallas_eligible``).
+The table layer pads its storage column dim to ``padded_cols``, which keeps
+tables of up to 128 columns on the kernels; wider ones ride XLA. The pad
+alone measured ~5.6x on the reference 1Mx50 row-op benchmark even for plain
+XLA (aligned rows vs 200-byte ragged rows), with the fused Pallas update
 another ~1.6x on top.
 """
 
@@ -49,15 +50,15 @@ SMEM_IDS_BYTES = 512 * 1024
 
 
 def _pallas_eligible(data) -> bool:
-    """Row DMAs slice HBM along the lane dim, so rows must be tile-aligned:
-    128 lanes for 4-byte dtypes (Mosaic: 'slice shape along dimension 1 must
-    be aligned to tiling (128)'). Rows so wide that even the minimum chunk's
-    VMEM blocks overflow the kernel budget take the XLA path instead —
-    pallas_rows._chunk_for owns that budget law and returns 0 when there is
-    nothing left to shrink."""
-    from multiverso_tpu.ops.pallas_rows import _chunk_for
-    return (data.dtype.itemsize == 4 and data.shape[-1] % LANE == 0
-            and _chunk_for(data.shape[-1], data.dtype.itemsize) > 0)
+    """The row shapes Mosaic compiles the kernels for (jax 0.9.0, libtpu
+    0.0.34, v5e): a 4-byte dtype at exactly one 128-lane tile per row.
+    Wider multiples of 128 are refused at compile time — the per-row DMA
+    with 'Slice shape along dimension 0 must be aligned to tiling (8), but
+    is 1', the coalesced one with 'Failed to prove that a tile index in
+    dimension 0 is divisible by the tiling (8)' — so those tables take the
+    XLA path. tests/test_ops.py compiles every width this admits ahead of
+    time against a v5e topology."""
+    return data.dtype.itemsize == 4 and data.shape[-1] == LANE
 
 
 def use_pallas(data=None, ids=None) -> bool:
@@ -67,7 +68,7 @@ def use_pallas(data=None, ids=None) -> bool:
     if mode == "on":
         # forced on (interpreter mode off-TPU; tests): still respect the
         # lowering constraints — an ineligible shape would be a Mosaic
-        # compile error (or a zero chunk) rather than a kernel choice
+        # compile error rather than a kernel choice
         return data is None or _pallas_eligible(data)
     if mode == "off":
         return False

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from multiverso_tpu.parallel import mesh as mesh_lib
 from multiverso_tpu.parallel.mesh import SERVER_AXIS
 
 
@@ -36,7 +35,7 @@ def device_allreduce(x: jax.Array, mesh: Mesh, axis_name: str = SERVER_AXIS) -> 
     The idiomatic form: annotate the desired output sharding and let XLA
     insert the all-reduce over ICI.
     """
-    @partial(mesh_lib.shard_map, mesh=mesh, in_specs=P(axis_name),
+    @partial(jax.shard_map, mesh=mesh, in_specs=P(axis_name),
              out_specs=P())
     def _psum(shard):
         return jax.lax.psum(shard, axis_name)
@@ -133,7 +132,7 @@ class RendezvousAllreduce:
 def jit_mean_across(params: jax.Array, mesh: Mesh, axis_name: str = SERVER_AXIS) -> jax.Array:
     """Model-average helper: mean of per-device replicas along the mesh axis
     (the `model average` training mode, reference -ma flag zoo.cpp:24,49)."""
-    @partial(mesh_lib.shard_map, mesh=mesh, in_specs=P(axis_name),
+    @partial(jax.shard_map, mesh=mesh, in_specs=P(axis_name),
              out_specs=P())
     def _pmean(shard):
         return jax.lax.pmean(shard, axis_name)

@@ -26,21 +26,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 SERVER_AXIS = "server"
 
-# ``jax.shard_map`` graduated from jax.experimental across jax releases
-# (and renamed its replication-check kwarg ``check_rep`` -> ``check_vma``
-# on the way); resolve whichever this jax ships so the table/allreduce
-# programs run on both. Callers use the new spellings.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def shard_map(*args, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _exp_shard_map(*args, **kwargs)
-
-
 def partition_offsets(size: int, num_servers: int) -> List[Tuple[int, int]]:
     """[(offset, count)] per server; last server takes the remainder.
 

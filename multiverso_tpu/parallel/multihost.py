@@ -101,11 +101,9 @@ MV_DEFINE_int("mv_shm_ring_bytes", 4 << 20,
 # service declares a silent member dead after ~100s of missed
 # heartbeats (10s interval x 10 misses) and then tears the survivors
 # down — a long-lived SHRUNK world (elastic plane, the dead member
-# never returns) must outlive that corpse detection. MV_Init plumbs
-# this budget into jax.distributed.initialize's heartbeat knobs when
-# the installed jax exposes them (signature-checked; older/newer jax
-# without the kwargs logs and keeps runtime defaults). 0 = leave the
-# runtime defaults; -mv_elastic worlds default to 600s.
+# never returns) must outlive that corpse detection. MV_Init hands
+# this budget to jax.distributed.initialize(heartbeat_timeout_seconds=).
+# 0 = leave the runtime default; -mv_elastic worlds default to 600s.
 MV_DEFINE_int("mv_pjrt_heartbeat_s", 0,
               "PJRT coordination-service liveness budget in seconds "
               "(missed-heartbeat window before a silent member is "
@@ -766,88 +764,37 @@ def _enable_cpu_collectives() -> None:
     a 2-process CPU world (tests, single-host bring-up, the bench's
     subprocess children) therefore needs gloo. Only applies when the job
     explicitly targets CPU (``jax_platforms``/``JAX_PLATFORMS``): TPU
-    pods keep their platform default. Best-effort — a jax/jaxlib without
-    the knob (or without gloo) just keeps its default behavior."""
+    pods keep their platform default."""
     import jax
-    try:
-        plats = str(jax.config.jax_platforms
-                    or os.environ.get("JAX_PLATFORMS", ""))
-    except AttributeError:  # pragma: no cover - very old jax
-        plats = os.environ.get("JAX_PLATFORMS", "")
-    if "cpu" not in plats.lower().split(","):
-        return
-    try:
+    plats = str(jax.config.jax_platforms
+                or os.environ.get("JAX_PLATFORMS", ""))
+    if "cpu" in plats.lower().split(","):
         jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception as exc:  # pragma: no cover - jaxlib without gloo
-        Log.Debug("multihost: CPU gloo collectives unavailable (%r)", exc)
 
 
-def pjrt_heartbeat_kwargs() -> dict:
-    """The coordination-service heartbeat kwargs MV_Init plumbs into
-    ``jax.distributed.initialize`` (ROADMAP elastic follow-on 4): the
-    ``-mv_pjrt_heartbeat_s`` liveness budget split into an interval and
-    a missed-heartbeat count, for BOTH the service and client sides.
-    Empty when the budget is 0 (runtime defaults); an -mv_elastic world
-    with the flag unset defaults to 600s — a long-lived shrunk world
-    must outlive the runtime's ~100s corpse detection."""
-    try:
-        secs = int(GetFlag("mv_pjrt_heartbeat_s"))
-    except Exception:
-        secs = 0
+def pjrt_heartbeat_timeout_s() -> int:
+    """The coordination-service liveness budget MV_Init hands to
+    ``jax.distributed.initialize(heartbeat_timeout_seconds=...)``:
+    ``-mv_pjrt_heartbeat_s``, or 600 in an ``-mv_elastic`` world that
+    leaves the flag unset — a long-lived shrunk world must outlive the
+    runtime's 100 s corpse detection. 0 = keep the runtime default."""
+    secs = int(GetFlag("mv_pjrt_heartbeat_s"))
     if secs <= 0:
         try:
-            if bool(GetFlag("mv_elastic")):
-                secs = 600
-        except Exception:
-            pass
-    if secs <= 0:
-        return {}
-    interval = max(10, secs // 10)
-    missing = max(2, -(-secs // interval))
-    return {"service_heartbeat_interval_seconds": interval,
-            "service_max_missing_heartbeats": missing,
-            "client_heartbeat_interval_seconds": interval,
-            "client_max_missing_heartbeats": missing}
-
-
-def _supported_heartbeat_kwargs(params) -> dict:
-    """The subset of :func:`pjrt_heartbeat_kwargs` this jax's
-    state-level initializer actually accepts (param-name filtered, so
-    a jax that renamed or dropped the knobs degrades to {})."""
-    return {k: v for k, v in pjrt_heartbeat_kwargs().items()
-            if k in params}
+            elastic = bool(GetFlag("mv_elastic"))
+        except KeyError:    # the elastic plane's module was never imported
+            elastic = False
+        secs = 600 if elastic else 0
+    return secs
 
 
 def _dist_initialize(**kw) -> None:
-    """``jax.distributed.initialize`` with the heartbeat budget plumbed
-    through when this jax exposes the knobs (the public wrapper hides
-    them; the state-level initializer the wrapper delegates to takes
-    them). Any plumbing surprise falls back to the plain public call —
-    heartbeat tuning must never break bring-up."""
     import jax
-    hb = pjrt_heartbeat_kwargs()
-    if hb:
-        try:
-            import inspect
-
-            from jax._src import distributed as _jdist
-            from jax._src import xla_bridge as _xb
-            supported = _supported_heartbeat_kwargs(
-                inspect.signature(_jdist.State.initialize).parameters)
-            if supported and not _xb.backends_are_initialized():
-                _jdist.global_state.initialize(**kw, **supported)
-                Log.Info("multihost: PJRT coordination-service "
-                         "heartbeats raised (%s)",
-                         ", ".join(f"{k}={v}"
-                                   for k, v in sorted(supported.items())))
-                return
-            if not supported:
-                Log.Info("multihost: this jax exposes no heartbeat "
-                         "knobs — -mv_pjrt_heartbeat_s ignored, "
-                         "runtime defaults kept")
-        except Exception as exc:
-            Log.Error("multihost: PJRT heartbeat plumbing failed (%r) "
-                      "— plain initialize", exc)
+    secs = pjrt_heartbeat_timeout_s()
+    if secs:
+        kw["heartbeat_timeout_seconds"] = secs
+        Log.Info("multihost: PJRT coordination-service heartbeat timeout "
+                 "%d s", secs)
     jax.distributed.initialize(**kw)
 
 

@@ -48,12 +48,11 @@ from multiverso_tpu.parallel import multihost, wire
 from multiverso_tpu.parallel.mesh import (SERVER_AXIS, ceil_block_rows,
                                           local_device_count, next_bucket,
                                           parts_bucket, place_parts,
-                                          shard_map,
                                           storage_partition_server)
 from multiverso_tpu.tables.base import ServerTable, TableOption, WorkerTable
 from multiverso_tpu.telemetry import sketch as tsketch
 from multiverso_tpu.updaters.base import AddOption, CreateUpdater, GetOption
-from multiverso_tpu.utils.log import CHECK
+from multiverso_tpu.utils.log import CHECK, Log
 
 
 @functools.partial(jax.jit, static_argnames=("bucket",))
@@ -182,6 +181,13 @@ class MatrixServerTable(ServerTable):
         # slices are what the hot path needs; padded cols hold zeros forever
         # (every updater is identity on a zero delta).
         self.store_cols = ops.padded_cols(num_cols, self.dtype.itemsize)
+        if jax.default_backend() == "tpu" and not ops.use_pallas(
+                jax.ShapeDtypeStruct((self.shard_rows, self.store_cols),
+                                     self.dtype)):
+            Log.Info("matrix table %dx%d %s (%d stored columns): row "
+                     "writes take the XLA scatter, not the Pallas row "
+                     "kernels", num_rows, num_cols, self.dtype.name,
+                     self.store_cols)
         self.updater = CreateUpdater(updater_type)
         self._mesh = ctx.mesh
 
@@ -319,7 +325,7 @@ class MatrixServerTable(ServerTable):
                 data, aux = _update_rows_local(state["data"], state["aux"],
                                                ids, deltas, opt)
                 return {"data": data, "aux": aux}
-            data, aux = shard_map(
+            data, aux = jax.shard_map(
                 _update_rows_local, mesh=self._mesh,
                 in_specs=(P(SERVER_AXIS, None), self._aux_specs, P(), P(),
                           P()),
@@ -411,7 +417,7 @@ class MatrixServerTable(ServerTable):
             if single:
                 # 1-server fast path (see _update_rows)
                 return _gather_rows_local(data, aux, ids)
-            return shard_map(
+            return jax.shard_map(
                 _gather_rows_local, mesh=self._mesh,
                 in_specs=(P(SERVER_AXIS, None), self._aux_specs, P()),
                 out_specs=P(),
@@ -465,7 +471,7 @@ class MatrixServerTable(ServerTable):
                 data, aux, rows = _update_gather_local(
                     state["data"], state["aux"], ids, deltas, opt)
                 return {"data": data, "aux": aux}, rows
-            data, aux, rows = shard_map(
+            data, aux, rows = jax.shard_map(
                 _update_gather_local, mesh=self._mesh,
                 in_specs=(P(SERVER_AXIS, None), self._aux_specs, P(), P(),
                           P()),

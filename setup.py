@@ -3,9 +3,10 @@
 The reference installs via CMake (root CMakeLists.txt -> libmultiverso.so
 + headers); the TPU build's wheel carries the equivalent
 ``libmultiverso_tpu.so`` as package data under ``multiverso_tpu/native/``
-(the ctypes loader checks there first in installed trees, falling back to
-the repo's ``native/`` dir in source checkouts, and degrading to pure
-python when no library exists — multiverso_tpu/native/__init__.py).
+(the ctypes loader loads it from there in installed trees; a source
+checkout builds and loads the repo's ``native/`` copy instead, and a tree
+with no library says once that the python fallbacks run —
+multiverso_tpu/native/__init__.py).
 
 The library is built with the same flags as native/Makefile. A missing
 C++ toolchain degrades gracefully: the wheel ships pure-python and the

@@ -250,10 +250,10 @@ class TestCallGraph:
         assert "m.py:Table.get" not in edges          # container-name bound
 
     def test_defs_under_module_level_guards_are_nodes(self, tmp_path):
-        """The shard_map version-shim idiom (parallel/mesh.py): a def
-        inside a module-level try/except or if/else is a top-level
-        graph node — dropping it would silently break the
-        never-collective guarantee for shimmed collectives."""
+        """The optional-dependency-fallback idiom: a def inside a
+        module-level try/except or if/else is a top-level graph node —
+        dropping it would silently break the never-collective guarantee
+        for shimmed collectives."""
         g = self._graph(tmp_path, {"m.py": """\
             try:
                 import fastpath

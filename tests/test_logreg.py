@@ -293,6 +293,37 @@ class TestDevicePlane:
     the PS tables' HBM storage; must match the host plane exactly (same
     verb order — window-start cache, summed linear deltas)."""
 
+    def test_staging_budget_never_invents_a_chip(self, monkeypatch):
+        """A quarter of what the device reports; only the CPU backend,
+        which reports nothing, gets the 1GB default — a chip whose
+        memory_stats() fails is an error, not a 1GB chip."""
+        import jax
+        from multiverso_tpu.models.logreg.device_plane import (
+            DeviceWindowTrainer)
+
+        class Dev:
+            def __init__(self, stats):
+                self.stats = stats
+
+            def memory_stats(self):
+                if isinstance(self.stats, Exception):
+                    raise self.stats
+                return self.stats
+
+        budget = DeviceWindowTrainer._device_staging_budget
+        assert budget() == 1 << 30                      # this CPU backend
+        monkeypatch.setattr(jax, "local_devices",
+                            lambda: [Dev({"bytes_limit": 16 << 30})])
+        assert budget() == 4 << 30
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(jax, "local_devices", lambda: [Dev(None)])
+        with pytest.raises(TypeError):
+            budget()
+        monkeypatch.setattr(jax, "local_devices",
+                            lambda: [Dev(RuntimeError("chip lost"))])
+        with pytest.raises(RuntimeError, match="chip lost"):
+            budget()
+
     def _final_weights(self, d, **kw):
         kw.setdefault("objective_type", "sigmoid")
         cfg = _config(d, use_ps=True, updater_type="sgd",

@@ -779,70 +779,54 @@ class TestTwoProcessLogRegDevicePlane:
 
 
 class TestPjrtHeartbeatPlumbing:
-    """Round 12 satellite (ROADMAP elastic follow-on 4): MV_Init plumbs
-    -mv_pjrt_heartbeat_s into the coordination-service heartbeat knobs
-    so long-lived shrunk worlds outlive the runtime's ~100s corpse
-    detection. The kwargs computation + signature filtering are the
-    plumbing under regression here (a live multi-host init is
-    environment-bound)."""
+    """MV_Init hands -mv_pjrt_heartbeat_s to the coordination service so
+    long-lived shrunk worlds outlive the runtime's 100 s corpse
+    detection. The value must REACH ``jax.distributed.initialize`` on
+    the installed jax (a live multi-host init is environment-bound, so
+    the public entry point is intercepted)."""
 
     def _set(self, name, value):
+        import multiverso_tpu.zoo  # noqa: F401 — defines both flags
         from multiverso_tpu.utils.configure import SetCMDFlag
         SetCMDFlag(name, value)
 
-    def test_budget_splits_into_interval_and_misses(self):
-        from multiverso_tpu.parallel import multihost as mh
-        self._set("mv_pjrt_heartbeat_s", 600)
-        try:
-            kw = mh.pjrt_heartbeat_kwargs()
-            assert kw["service_heartbeat_interval_seconds"] == 60
-            assert kw["client_heartbeat_interval_seconds"] == 60
-            # interval x misses covers the requested budget
-            assert (kw["service_heartbeat_interval_seconds"]
-                    * kw["service_max_missing_heartbeats"]) >= 600
-            assert kw["client_max_missing_heartbeats"] == \
-                kw["service_max_missing_heartbeats"]
-        finally:
-            self._set("mv_pjrt_heartbeat_s", 0)
+    def _initialize_kwargs(self):
+        """The kwargs one ``_dist_initialize`` hands the public API —
+        bound against its real signature, so a renamed knob fails here
+        instead of being dropped."""
+        import inspect
+        from unittest import mock
 
-    def test_zero_means_runtime_defaults_unless_elastic(self):
-        from multiverso_tpu.parallel import multihost as mh
-        assert mh.pjrt_heartbeat_kwargs() == {}
-        self._set("mv_elastic", True)
-        try:
-            kw = mh.pjrt_heartbeat_kwargs()
-            # elastic worlds default to a 600s budget
-            assert (kw["client_heartbeat_interval_seconds"]
-                    * kw["client_max_missing_heartbeats"]) >= 600
-        finally:
-            self._set("mv_elastic", False)
+        import jax
 
-    def test_small_budget_clamps_to_sane_interval(self):
         from multiverso_tpu.parallel import multihost as mh
-        self._set("mv_pjrt_heartbeat_s", 30)
-        try:
-            kw = mh.pjrt_heartbeat_kwargs()
-            assert kw["service_heartbeat_interval_seconds"] >= 10
-            assert kw["service_max_missing_heartbeats"] >= 2
-        finally:
-            self._set("mv_pjrt_heartbeat_s", 0)
+        sig = inspect.signature(jax.distributed.initialize)
+        seen = {}
 
-    def test_signature_filter_drops_unknown_kwargs(self):
-        from multiverso_tpu.parallel import multihost as mh
+        def fake(*args, **kw):
+            seen.update(sig.bind(*args, **kw).arguments)
+
+        with mock.patch.object(jax.distributed, "initialize", fake):
+            mh._dist_initialize(coordinator_address="127.0.0.1:1",
+                                num_processes=2, process_id=0)
+        return seen
+
+    def test_flag_reaches_initialize(self):
         self._set("mv_pjrt_heartbeat_s", 300)
         try:
-            full = mh.pjrt_heartbeat_kwargs()
-            assert mh._supported_heartbeat_kwargs(full.keys()) == full
-            # a jax that renamed every knob -> nothing passed through
-            assert mh._supported_heartbeat_kwargs(
-                {"coordinator_address": None}) == {}
-            # the INSTALLED jax: whatever its state-level initializer
-            # accepts must be the subset actually plumbed
-            import inspect
-            from jax._src import distributed as _jdist
-            params = inspect.signature(
-                _jdist.State.initialize).parameters
-            sup = mh._supported_heartbeat_kwargs(params)
-            assert set(sup) <= set(full)
+            kw = self._initialize_kwargs()
         finally:
             self._set("mv_pjrt_heartbeat_s", 0)
+        assert kw["heartbeat_timeout_seconds"] == 300
+        assert kw["num_processes"] == 2
+
+    def test_zero_means_runtime_default_unless_elastic(self):
+        assert "heartbeat_timeout_seconds" not in \
+            self._initialize_kwargs()
+        self._set("mv_elastic", True)
+        try:
+            kw = self._initialize_kwargs()
+        finally:
+            self._set("mv_elastic", False)
+        # elastic worlds default to a 600s budget
+        assert kw["heartbeat_timeout_seconds"] == 600

@@ -339,6 +339,38 @@ class TestNativeReader:
             native.parse_libsvm(b"xyz 1:2\n")
 
 
+class TestLoaderBuildFailure:
+    """A source checkout whose build fails must say so once and run
+    without the library — never load whatever ``.so`` is lying in
+    ``native/`` (git-ignored, so possibly older than the sources)."""
+
+    def test_failed_build_logs_once_and_never_loads_stale(
+            self, native_build, tmp_path, monkeypatch, capfd):
+        from multiverso_tpu import native
+        # a checkout with a stale library and a Makefile that cannot
+        # rebuild it
+        shutil.copy(os.path.join(native_build, "libmultiverso_tpu.so"),
+                    tmp_path / "libmultiverso_tpu.so")
+        (tmp_path / "Makefile").write_text(
+            ".PHONY: libmultiverso_tpu.so\n"
+            "libmultiverso_tpu.so:\n"
+            "\t@echo 'mvt-test: compiler exploded' >&2; exit 1\n")
+        monkeypatch.setattr(native, "_NATIVE_DIR", str(tmp_path))
+        monkeypatch.setattr(native, "_REPO_LIB_PATH",
+                            str(tmp_path / "libmultiverso_tpu.so"))
+        monkeypatch.setattr(native, "_lib", None)
+        monkeypatch.setattr(native, "_tried", False)
+        capfd.readouterr()
+        assert native.lib() is None
+        assert native.parse_libsvm(b"1 3:0.5\n") is None
+        assert native.VocabTokenizer.create(["the", "cat"]) is None
+        assert native.crc32c_fn() is None
+        err = capfd.readouterr().err
+        assert err.count("native runtime build failed") == 1
+        assert "mvt-test: compiler exploded" in err
+        assert "running without it" in err
+
+
 class TestKvIndex:
     def _ix(self, cap=1024):
         from multiverso_tpu import native

@@ -135,41 +135,64 @@ class TestDispatch:
         SetCMDFlag("use_pallas", "auto")
         assert ops.use_pallas() == (jax.default_backend() == "tpu")
 
-    def test_chunk_shrinks_for_wide_rows(self):
-        from multiverso_tpu.ops.pallas_rows import (CHUNK, FUSED_BLOCKS,
-                                                    MIN_CHUNK, VMEM_BUDGET,
-                                                    _chunk_for)
-        assert _chunk_for(128, 4) == CHUNK
-        # chunk halves until the kernel's VMEM blocks fit the budget
-        wide = _chunk_for(8 * 1024, 4)
-        assert MIN_CHUNK <= wide < CHUNK
-        assert FUSED_BLOCKS * wide * 8 * 1024 * 4 <= VMEM_BUDGET
-        # gather/scatter hold fewer blocks -> deeper chunk for the same cols
-        assert _chunk_for(8 * 1024, 4, blocks=2) >= wide
-        assert _chunk_for(10 ** 9, 4) == 0  # infeasible even at MIN_CHUNK
-
-    def test_too_wide_rows_fall_back_to_xla(self):
+    def test_only_one_lane_tile_rows_are_eligible(self):
         from multiverso_tpu.ops.rows import _pallas_eligible
-        ok = jnp.zeros((4, 1024), jnp.float32)
-        assert _pallas_eligible(ok)
-        # wider than even MIN_CHUNK's blocks can fit -> XLA path
-        too_wide = jax.ShapeDtypeStruct((4, 1024 * 1024), jnp.float32)
-        assert not _pallas_eligible(too_wide)
+        assert _pallas_eligible(jax.ShapeDtypeStruct((4, 128), jnp.float32))
+        # Mosaic refuses wider rows (rows._pallas_eligible) -> XLA path
+        for cols in (64, 256, 1024):
+            assert not _pallas_eligible(
+                jax.ShapeDtypeStruct((4, cols), jnp.float32))
+        assert not _pallas_eligible(
+            jax.ShapeDtypeStruct((4, 128), jnp.bfloat16))
 
-    def test_wide_rows_kernel_still_correct(self):
-        # cols wide enough to force a shrunken chunk (interpreter mode)
-        from multiverso_tpu.ops.pallas_rows import (_chunk_for,
-                                                    pallas_update_rows)
-        cols = 8 * 1024
-        assert 0 < _chunk_for(cols, 4) < 64
-        data = jnp.zeros((8, cols), jnp.float32)
-        ids = np.array([3, 6], np.int32)
-        deltas = jnp.ones((2, cols), jnp.float32)
-        out = pallas_update_rows(data, jnp.asarray(ids), deltas,
-                                 combine=lambda r, d: r + d, interpret=True)
-        host = np.asarray(out)
-        assert host[3].sum() == cols and host[6].sum() == cols
-        assert host[0].sum() == 0
+
+_AOT_CHILD = r"""
+import sys
+import jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+from multiverso_tpu.ops import pallas_rows as pr
+from multiverso_tpu.ops.rows import _pallas_eligible
+try:
+    dev = topologies.get_topology_desc("v5e:2x2", "tpu").devices[0]
+except Exception as exc:
+    print(f"SKIP no v5e topology description: {exc!r}")
+    sys.exit(0)
+sh = SingleDeviceSharding(dev)
+admitted = []
+for cols in (128, 256, 512, 2048):
+    data = jax.ShapeDtypeStruct((100_001, cols), jnp.float32, sharding=sh)
+    if not _pallas_eligible(data):
+        continue
+    admitted.append(cols)
+    ids = jax.ShapeDtypeStruct((8192,), jnp.int32, sharding=sh)
+    rows = jax.ShapeDtypeStruct((8192, cols), jnp.float32, sharding=sh)
+    pr.pallas_gather_rows.lower(data, ids).compile()
+    pr.pallas_scatter_set_rows.lower(data, ids, rows).compile()
+    pr.pallas_update_rows.lower(data, ids, rows, jnp.add).compile()
+print("ADMITTED", admitted)
+"""
+
+
+class TestKernelsCompileForTpu:
+    def test_every_admitted_width_compiles_for_v5e(self):
+        """Tier-1 runs the kernels in interpreter mode only; this compiles
+        them with Mosaic against a v5e topology description (no chip
+        needed). A width ``_pallas_eligible`` admits but Mosaic refuses
+        would crash a table's first Add on the chip."""
+        import os
+        import subprocess
+        import sys
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=root)
+        res = subprocess.run([sys.executable, "-c", _AOT_CHILD], env=env,
+                             capture_output=True, text=True, timeout=300)
+        assert res.returncode == 0, res.stderr[-3000:]
+        last = res.stdout.strip().splitlines()[-1]
+        if last.startswith("SKIP"):
+            pytest.skip(last)
+        # the widths the repo's own tables use must stay on the kernels
+        assert last.startswith("ADMITTED [128"), res.stdout[-2000:]
 
 
 class TestMatrixTableWithPallas:
@@ -194,6 +217,24 @@ class TestMatrixTableWithPallas:
         np.testing.assert_allclose(got, 2 * deltas)
         # untouched rows stay zero
         np.testing.assert_allclose(table.GetRows([1, 16, 31]), 0.0)
+
+    def test_wider_than_one_tile_takes_the_xla_path(self, pallas_env):
+        """256 f32 columns: Mosaic refuses the row kernels there, so even
+        ``-use_pallas=on`` must route the table to XLA (on the chip the
+        old gate crashed this table's first Add) and stay exact."""
+        from multiverso_tpu import ops
+        from multiverso_tpu.tables.matrix_table import MatrixTableOption
+        table = pallas_env.MV_CreateTable(
+            MatrixTableOption(num_rows=300, num_cols=256))
+        assert not ops.use_pallas(table.server().state["data"])
+        rng = np.random.default_rng(5)
+        ids = rng.choice(300, 40, replace=False).astype(np.int32)
+        deltas = rng.standard_normal((40, 256)).astype(np.float32)
+        table.AddRows(ids, deltas)
+        table.AddRows(ids, deltas)
+        np.testing.assert_array_equal(table.GetRows(ids), deltas + deltas)
+        untouched = np.setdiff1d(np.arange(300), ids).astype(np.int32)
+        assert not table.GetRows(untouched).any()
 
     def test_full_table_roundtrip(self, pallas_env):
         from multiverso_tpu.tables.matrix_table import MatrixTableOption

@@ -135,6 +135,24 @@ def _run(tmp_path, **kw):
     return opt, avg_loss
 
 
+class TestBlockQueue:
+    def test_loader_error_surfaces_after_the_queued_blocks(self):
+        from multiverso_tpu.models.wordembedding.data import (BlockQueue,
+                                                              DataBlock)
+        q = BlockQueue(capacity=2)
+        q.push(DataBlock(word_count=7))
+        q.close(OSError("disk gone"))
+        assert q.pop().word_count == 7
+        with pytest.raises(OSError, match="disk gone"):
+            q.pop()
+
+    def test_clean_close_ends_the_stream(self):
+        from multiverso_tpu.models.wordembedding.data import BlockQueue
+        q = BlockQueue()
+        q.close()
+        assert q.pop() is None
+
+
 class TestEndToEnd:
     def test_skipgram_neg_trains_and_saves(self, tmp_path):
         opt, avg_loss = _run(tmp_path)
@@ -171,6 +189,42 @@ class TestEndToEnd:
     def test_no_pipeline(self, tmp_path):
         _, avg_loss = _run(tmp_path, is_pipeline=False)
         assert avg_loss < 0.69 * 4 * 0.9
+
+    @pytest.mark.parametrize("threads", [1, 2])
+    def test_loader_failure_fails_main(self, tmp_path, monkeypatch,
+                                       threads):
+        """A loader thread that dies mid-corpus must fail the run: the
+        trainer used to see a short stream, save embeddings trained on
+        the first block alone and exit 0."""
+        import multiverso_tpu as mv
+        from multiverso_tpu.models.wordembedding import distributed
+        from multiverso_tpu.models.wordembedding.data import PairGenerator
+        corpus = tmp_path / "corpus.txt"
+        _make_corpus(str(corpus))
+        real = PairGenerator.make_block
+        calls = []
+
+        def flaky(self, *args, **kw):
+            calls.append(1)
+            if len(calls) == 2:
+                raise OSError("corpus went away")
+            return real(self, *args, **kw)
+
+        monkeypatch.setattr(PairGenerator, "make_block", flaky)
+        # main() turns the compile cache on; placed from outside it sets no
+        # path, so this process's later compiles stay off the disk
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / "jax_cache"))
+        out = tmp_path / "vec.txt"
+        with pytest.raises(OSError, match="corpus went away"):
+            distributed.main(["-train_file", str(corpus), "-output",
+                              str(out), "-size", "16", "-min_count", "1",
+                              "-data_block_size", "4000", "-threads",
+                              str(threads)])
+        assert not out.exists()
+        # the failed run shut its world down: the next one can start
+        mv.MV_Init([])
+        mv.MV_ShutDown()
 
     def test_device_pairs_trains_with_topic_structure(self, tmp_path):
         """-device_pairs 1: the fused on-device generate+train program must
