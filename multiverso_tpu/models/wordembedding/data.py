@@ -30,6 +30,7 @@ from multiverso_tpu.models.wordembedding.dictionary import Dictionary
 from multiverso_tpu.models.wordembedding.huffman import HuffmanEncoder
 from multiverso_tpu.models.wordembedding.sampler import Sampler
 from multiverso_tpu.parallel.mesh import next_bucket
+from multiverso_tpu.telemetry import trace as ttrace
 from multiverso_tpu.utils.mt_queue import MtQueue
 
 MAX_SENTENCE_LENGTH = 1000  # reference constant.h kMaxSentenceLength
@@ -57,6 +58,10 @@ class DataBlock:
     # true count as a device scalar.
     tokens: Optional[np.ndarray] = None
     token_sent: Optional[np.ndarray] = None
+    # the SpanContext of the loader's make_block span (None with -trace
+    # off): the train loop's worker.we.block continues that tree, as
+    # Message.trace_ctx does across the mailbox
+    trace_ctx: Optional[ttrace.SpanContext] = None
 
 
 def sentences_from_file(path: str, dictionary: Dictionary) -> Iterator[Tuple[np.ndarray, int]]:
@@ -68,7 +73,8 @@ def sentences_from_file(path: str, dictionary: Dictionary) -> Iterator[Tuple[np.
     foreign call each — ids come back with -2 sentinels at newlines and
     are split into sentences vectorized; pure-python fallback otherwise."""
     from multiverso_tpu.native import VocabTokenizer
-    tok = VocabTokenizer.create(dictionary.words())
+    with ttrace.span("worker.we.load.tokenizer", cat="worker"):
+        tok = VocabTokenizer.create(dictionary.words())
 
     def emit(ids: np.ndarray):
         for start in range(0, len(ids), MAX_SENTENCE_LENGTH):
@@ -316,6 +322,18 @@ class PairGenerator:
 
     def make_block(self, sentences: List[np.ndarray],
                    word_count: int, rng_stream=None) -> DataBlock:
+        """One block from its sentences, on whichever loader thread
+        runs it; the block carries this span's context to the train
+        loop (DataBlock.trace_ctx)."""
+        with ttrace.span("worker.we.load.make_block", cat="worker",
+                         args=({"words": int(word_count)}
+                               if ttrace.enabled() else None)) as ctx:
+            block = self._make_block(sentences, word_count, rng_stream)
+        block.trace_ctx = ctx
+        return block
+
+    def _make_block(self, sentences: List[np.ndarray],
+                    word_count: int, rng_stream=None) -> DataBlock:
         # per-block deterministic randomness: the loader spawns streams in
         # block order (sampler.spawn_stream) so -seed reproduces exactly,
         # independent of -threads and scheduling
@@ -344,7 +362,8 @@ class BlockQueue:
         self._error: Optional[Exception] = None
 
     def push(self, block: DataBlock) -> None:
-        self._space.acquire()
+        with ttrace.span("worker.we.load.push_wait", cat="worker"):
+            self._space.acquire()
         self._q.Push(block)
 
     def pop(self) -> Optional[DataBlock]:
@@ -377,20 +396,28 @@ def start_loader(option, dictionary: Dictionary, generator: PairGenerator,
 
     workers = max(1, int(getattr(option, "thread_cnt", 1)))
 
+    def read_block(reader):
+        """Sentences off ``reader`` until a block is full or the corpus
+        ends. -> (sentences, words); no sentences at the end."""
+        sentences: List[np.ndarray] = []
+        n_words = 0
+        for ids, raw_count in reader:
+            sentences.append(ids)
+            n_words += raw_count
+            if n_words * 8 >= option.data_block_size:
+                break
+        return sentences, n_words
+
     def chunks():
         for _ in range(epochs):
-            sentences: List[np.ndarray] = []
-            n_words = 0
-            n_bytes = 0
-            for ids, raw_count in sentences_from_file(option.train_file,
-                                                      dictionary):
-                sentences.append(ids)
-                n_words += raw_count
-                n_bytes += raw_count * 8
-                if n_bytes >= option.data_block_size:
-                    yield sentences, n_words, generator.sampler.spawn_stream()
-                    sentences, n_words, n_bytes = [], 0, 0
-            if sentences:
+            reader = sentences_from_file(option.train_file, dictionary)
+            while True:
+                # closed before the yield: a span never stays open
+                # across one
+                with ttrace.span("worker.we.load.read", cat="worker"):
+                    sentences, n_words = read_block(reader)
+                if not sentences:
+                    break
                 yield sentences, n_words, generator.sampler.spawn_stream()
 
     def run_sequential():

@@ -57,6 +57,7 @@ from typing import Optional
 import numpy as np
 
 from multiverso_tpu.parallel.mesh import next_bucket
+from multiverso_tpu.telemetry import trace as ttrace
 
 
 class _LazyStats:
@@ -256,6 +257,7 @@ class DevicePairsTrainer:
 
         cbow, hs = opt.cbow, opt.hs
 
+        @jax.named_scope("we.device_pairs_step")
         def program(states, aux, ids, sent, key, lr):
             n = t_pad
             ar = jnp.arange(n, dtype=jnp.int32)
@@ -420,34 +422,36 @@ class DevicePairsTrainer:
             t_pad = next_bucket(T, min_bucket=1024)
         if nproc <= 1 and T == 0:
             return jnp.float32(0.0), jnp.int32(0)
-        ids = np.full(t_pad, -1, np.int32)
-        ids[:T] = token_ids
-        sent = np.full(t_pad, -1, np.int32)
-        rank = multihost.process_index()
-        if nproc > 1:
-            # disjoint per-process sentence ranges: offset by the GLOBAL
-            # max sentence id so shards can never merge across the
-            # process boundary in the concatenated vector
-            sent[:T] = token_sent + rank * sent_span
-            ids_g = place_parts(mesh, ids, nproc)
-            sent_g = place_parts(mesh, sent, nproc)
-            n_total = nproc * t_pad
-        else:
-            sent[:T] = token_sent
-            ids_g, sent_g = jnp.asarray(ids), jnp.asarray(sent)
-            n_total = t_pad
-        P = n_total if self.opt.cbow \
-            else 2 * self.opt.window_size * n_total
-        nb = next_bucket(-(-P // self.opt.pair_batch_size), min_bucket=4)
-        program = self._program(n_total, nb)
-        self._block_counter += 1
-        key = jax.random.fold_in(jax.random.PRNGKey(self.opt.seed),
-                                 self._block_counter)
-        aux = ((self._hs_points, self._hs_labels, self._hs_mask)
-               if self.opt.hs else (self._slots,))
-        states, stats = program(
-            self._take_states(), aux, ids_g, sent_g, key,
-            jnp.float32(lr))
+        with ttrace.span("worker.we.upload", cat="worker"):
+            ids = np.full(t_pad, -1, np.int32)
+            ids[:T] = token_ids
+            sent = np.full(t_pad, -1, np.int32)
+            rank = multihost.process_index()
+            if nproc > 1:
+                # disjoint per-process sentence ranges: offset by the
+                # GLOBAL max sentence id so shards can never merge across
+                # the process boundary in the concatenated vector
+                sent[:T] = token_sent + rank * sent_span
+                ids_g = place_parts(mesh, ids, nproc)
+                sent_g = place_parts(mesh, sent, nproc)
+                n_total = nproc * t_pad
+            else:
+                sent[:T] = token_sent
+                ids_g, sent_g = jnp.asarray(ids), jnp.asarray(sent)
+                n_total = t_pad
+        with ttrace.span("worker.we.dispatch", cat="worker"):
+            P = n_total if self.opt.cbow \
+                else 2 * self.opt.window_size * n_total
+            nb = next_bucket(-(-P // self.opt.pair_batch_size), min_bucket=4)
+            program = self._program(n_total, nb)
+            self._block_counter += 1
+            key = jax.random.fold_in(jax.random.PRNGKey(self.opt.seed),
+                                     self._block_counter)
+            aux = ((self._hs_points, self._hs_labels, self._hs_mask)
+                   if self.opt.hs else (self._slots,))
+            states, stats = program(
+                self._take_states(), aux, ids_g, sent_g, key,
+                jnp.float32(lr))
         self._put_states(states)
         # stats is a (2,) int32 device array; one np.asarray in the
         # harvest fetches both scalars (lane 0 is the bitcast f32 loss)

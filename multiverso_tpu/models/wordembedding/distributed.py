@@ -11,6 +11,7 @@ and rank-0 embedding export in word2vec text/binary format (:263-306).
 
 from __future__ import annotations
 
+import time
 from typing import Optional
 
 import numpy as np
@@ -26,6 +27,8 @@ from multiverso_tpu.models.wordembedding.model import (decayed_lr,
                                                        make_train_step)
 from multiverso_tpu.models.wordembedding.option import Option
 from multiverso_tpu.models.wordembedding.sampler import Sampler
+from multiverso_tpu.telemetry import metrics as tmetrics
+from multiverso_tpu.telemetry import trace as ttrace
 from multiverso_tpu.utils import compile_cache
 from multiverso_tpu.utils.log import Log
 from multiverso_tpu.utils.timer import Timer
@@ -42,11 +45,22 @@ class DistributedWordEmbedding:
         self._world = WorldOwner()
         self.total_loss = 0.0
         self.total_pairs = 0
+        self._blocks_done = 0   # index of the next block, per train()
 
     # -- setup --------------------------------------------------------------
 
     def prepare(self) -> None:
+        """Dictionary, sampler, world, tables, trainer. No trace covers
+        set-up, so each part's seconds go to a ``we.prepare.*_s`` gauge
+        (set once the world is up: ``-telemetry`` is a flag of it)."""
         opt = self.opt
+        laps, at = {}, time.perf_counter()
+
+        def lap(part: str) -> None:
+            nonlocal at
+            now = time.perf_counter()
+            laps[part], at = now - at, now
+
         stop = set()
         if opt.stopwords and opt.sw_file:
             with open(opt.sw_file, encoding="utf-8") as f:
@@ -62,22 +76,29 @@ class DistributedWordEmbedding:
         if opt.total_words <= 0:
             opt.total_words = self.dictionary.WordCount()
         counts = self.dictionary.counts()
+        lap("dictionary")
         self.sampler = Sampler(counts, seed=opt.seed)
         if opt.hs:
             self.huffman = HuffmanEncoder()
             self.huffman.BuildFromTermFrequency(counts)
+        lap("sampler")
         self._world.init_if_needed()
+        lap("world")
         # exception-safe: anything raising after MV_Init (table creation,
         # trainer CHECKs) must not strand a started Zoo the caller can
         # never shut down
         with self._world.guard("wordembedding.prepare"):
             self.comm = Communicator(opt, self.dictionary.Size())
+            lap("tables")
             self._dp_trainer = None
             if opt.device_pairs:
                 from multiverso_tpu.models.wordembedding.device_pairs import (
                     DevicePairsTrainer)
                 self._dp_trainer = DevicePairsTrainer(opt, self.comm, counts,
                                                       huffman=self.huffman)
+            lap("trainer")
+        for part, seconds in laps.items():
+            tmetrics.gauge(f"we.prepare.{part}_s").set(seconds)
 
     # -- training -----------------------------------------------------------
 
@@ -101,14 +122,22 @@ class DistributedWordEmbedding:
         self.total_loss = 0.0
         self.total_pairs = 0
         pending = collections.deque()
+        self._blocks_done = 0
+        m_pop_wait = tmetrics.histogram("we.pop_wait_s")
+        m_blocks = tmetrics.counter("we.blocks")
 
         def harvest(force: bool = False) -> None:
             while pending and (force or len(pending) >= 2):
-                loss, pairs = pending.popleft()
-                self.total_loss += float(loss)
-                # -device_pairs blocks report the pair count as a device
-                # scalar (the program derives the pairs); int() fetches it
-                self.total_pairs += int(pairs)
+                loss, pairs, ctx = pending.popleft()
+                # the block's make_block span is the parent: one tree
+                # from the loader to the harvest
+                with ttrace.span("worker.we.harvest", cat="worker",
+                                 parent=ctx):
+                    self.total_loss += float(loss)
+                    # -device_pairs blocks report the pair count as a
+                    # device scalar (the program derives the pairs);
+                    # int() fetches it
+                    self.total_pairs += int(pairs)
 
         from multiverso_tpu.parallel import multihost
         from multiverso_tpu.utils.log import CHECK
@@ -125,7 +154,10 @@ class DistributedWordEmbedding:
             planes cannot run an empty block through their row verbs, so
             ragged shard streams fail LOUDLY there instead (shard
             corpora evenly, or use -device_pairs)."""
-            block = queue.pop()
+            t0 = time.perf_counter()
+            with ttrace.span("worker.we.pop_wait", cat="worker"):
+                block = queue.pop()
+            m_pop_wait.observe(time.perf_counter() - t0)
             if not multiproc:
                 return block
             T = len(block.tokens) if (block is not None
@@ -172,7 +204,8 @@ class DistributedWordEmbedding:
                     prefetch = self.comm.request_parameter_async(
                         next_block.input_rows, next_block.output_rows)
             loss, pairs = self._train_block(current, step)
-            pending.append((loss, pairs))
+            m_blocks.inc()
+            pending.append((loss, pairs, current.trace_ctx))
             harvest()
             words_done += current.word_count
             self.comm.add_word_count(current.word_count)
@@ -184,8 +217,10 @@ class DistributedWordEmbedding:
             if opt.is_pipeline:
                 if next_block is not None and next_block.pair_count \
                         and prefetch is not None:
-                    next_block._prefetched = self.comm.wait_parameter(
-                        prefetch)
+                    with ttrace.span("worker.we.fetch", cat="worker",
+                                     parent=next_block.trace_ctx):
+                        next_block._prefetched = self.comm.wait_parameter(
+                            prefetch)
                 current, prefetch = next_block, None
             else:
                 current = pop_block()
@@ -214,10 +249,12 @@ class DistributedWordEmbedding:
             def run(state, inputs, imask, outputs, labels, omask, lr):
                 def body(st, x):
                     return step(st, *x, lr)
-                st, losses = lax.scan(body, state,
-                                      (inputs, imask, outputs, labels,
-                                       omask))
-                return st, jnp.sum(losses)
+                # a stable name in the device trace's op metadata
+                with jax.named_scope("we.block_scan"):
+                    st, losses = lax.scan(body, state,
+                                          (inputs, imask, outputs, labels,
+                                           omask))
+                    return st, jnp.sum(losses)
 
             # donate the block state: the fetch path hands this jit its own
             # buffers (jnp.copy in request_parameter_device keeps the
@@ -230,7 +267,18 @@ class DistributedWordEmbedding:
     def _train_block(self, block: DataBlock, step) -> tuple:
         """One block through the scanned program. Returns (loss, pairs)
         where both may be DEVICE scalars (the caller harvests lazily so
-        the dispatch overlaps the next block's prep)."""
+        the dispatch overlaps the next block's prep). The dispatches are
+        asynchronous, so ``worker.we.block`` is the host's part of a
+        block; its children name that part."""
+        index, self._blocks_done = self._blocks_done, self._blocks_done + 1
+        with ttrace.span("worker.we.block", cat="worker",
+                         parent=block.trace_ctx,
+                         args=({"block": index,
+                                "words": int(block.word_count)}
+                               if ttrace.enabled() else None)):
+            return self._train_block_body(block, step)
+
+    def _train_block_body(self, block: DataBlock, step) -> tuple:
         if self.opt.device_pairs and block.tokens is not None:
             # fused generate+train: the tiny token stream is the upload
             return self._dp_trainer.train_block(
@@ -240,28 +288,35 @@ class DistributedWordEmbedding:
             return 0.0, 0
         import jax.numpy as jnp
         pre = getattr(block, "_prefetched", None)
-        if self.opt.device_plane:
-            # rows gathered, trained, and pushed without leaving HBM;
-            # the loader threads prebuilt the remapped stacked tensors, so
-            # the block rides one upload + one scanned dispatch
-            state, fetched = self.comm.request_parameter_device(
-                block.input_rows, block.output_rows)
-        elif pre is not None:
-            state, fetched = pre
+        if pre is not None and not self.opt.device_plane:
+            state, fetched = pre    # train() waited for it, under its span
         else:
-            state, fetched = self.comm.request_parameter(block.input_rows,
-                                                         block.output_rows)
+            with ttrace.span("worker.we.fetch", cat="worker"):
+                if self.opt.device_plane:
+                    # rows gathered, trained, and pushed without leaving
+                    # HBM; the loader threads prebuilt the remapped
+                    # stacked tensors, so the block rides one upload + one
+                    # scanned dispatch
+                    state, fetched = self.comm.request_parameter_device(
+                        block.input_rows, block.output_rows)
+                else:
+                    state, fetched = self.comm.request_parameter(
+                        block.input_rows, block.output_rows)
         st = block.stacked
-        state, loss_dev = self._block_scan_fn(step)(
-            state, jnp.asarray(st["inputs"]), jnp.asarray(st["input_mask"]),
-            jnp.asarray(st["outputs"]), jnp.asarray(st["labels"]),
-            jnp.asarray(st["output_mask"]), jnp.float32(self._current_lr()))
-        if self.opt.device_plane:
-            self.comm.add_delta_parameter_device(
-                state, fetched, block.input_rows, block.output_rows)
-        else:
-            self.comm.add_delta_parameter(state, fetched, block.input_rows,
-                                          block.output_rows)
+        with ttrace.span("worker.we.upload", cat="worker"):
+            tensors = [jnp.asarray(st[k]) for k in (
+                "inputs", "input_mask", "outputs", "labels", "output_mask")]
+        with ttrace.span("worker.we.dispatch", cat="worker"):
+            state, loss_dev = self._block_scan_fn(step)(
+                state, *tensors, jnp.float32(self._current_lr()))
+        with ttrace.span("worker.we.push", cat="worker"):
+            if self.opt.device_plane:
+                self.comm.add_delta_parameter_device(
+                    state, fetched, block.input_rows, block.output_rows)
+            else:
+                self.comm.add_delta_parameter(state, fetched,
+                                              block.input_rows,
+                                              block.output_rows)
         return loss_dev, block.pair_count
 
     # -- export (word2vec format) -------------------------------------------

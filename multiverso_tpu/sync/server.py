@@ -1293,27 +1293,28 @@ class Server(Actor):
         SyncServer overrides both entries with its unbatched clocked
         path: the BSP defer/drain protocol must see messages strictly
         one at a time."""
-        batch = [msg]
-        while len(batch) < self.GET_PIPELINE_WINDOW:
-            ok, nxt = self.mailbox.TryPop()
-            if not ok:
-                break
-            batch.append(nxt)
-        # round 19 — batched verb envelopes flatten here, BEFORE
-        # admission/windowing: each member is an ordinary stream verb
-        # from this point on (dedup slots, chaos draws, window
-        # positions, replies), so one envelope = one admission but N
-        # lockstep stream positions
-        batch = self._expand_multi(batch)
-        for m in batch:
-            # drained members bypass _dispatch — observe their queue
-            # wait here (idempotent; the head was noted there already,
-            # and multi members carry no enqueue stamp)
-            self.note_dequeue(m)
-        # failsafe admission (dedup + chaos) BEFORE windowing: a
-        # duplicate or chaos-rejected verb must never become a stream
-        # position (divergent descriptors across ranks otherwise)
-        batch = [m for m in batch if self._admit(m)]
+        with ttrace.span("server.window.admit", cat="server"):
+            batch = [msg]
+            while len(batch) < self.GET_PIPELINE_WINDOW:
+                ok, nxt = self.mailbox.TryPop()
+                if not ok:
+                    break
+                batch.append(nxt)
+            # round 19 — batched verb envelopes flatten here, BEFORE
+            # admission/windowing: each member is an ordinary stream verb
+            # from this point on (dedup slots, chaos draws, window
+            # positions, replies), so one envelope = one admission but N
+            # lockstep stream positions
+            batch = self._expand_multi(batch)
+            for m in batch:
+                # drained members bypass _dispatch — observe their queue
+                # wait here (idempotent; the head was noted there
+                # already, and multi members carry no enqueue stamp)
+                self.note_dequeue(m)
+            # failsafe admission (dedup + chaos) BEFORE windowing: a
+            # duplicate or chaos-rejected verb must never become a stream
+            # position (divergent descriptors across ranks otherwise)
+            batch = [m for m in batch if self._admit(m)]
         if not batch:
             return
         if multihost.world_size() > 1:
@@ -1331,7 +1332,8 @@ class Server(Actor):
         else:
             self._ph_stamp_this = False
         with ttrace.span("server.window", cat="server",
-                         args={"verbs": len(batch)}):
+                         args=({"verbs": len(batch)}
+                               if ttrace.enabled() else None)):
             self._local_window(batch)
         self.window_epoch += 1     # worker get-cache staleness clock
         tflight.record("window.applied", epoch=self.window_epoch,
@@ -1366,17 +1368,35 @@ class Server(Actor):
         # apply the later Add before the restore and silently wipe it),
         # and a Get queued after it must not join a gather dispatched
         # before it.
-        segments: list = [[]]
-        for m in batch:
-            if m.msg_type in (MsgType.Request_Add, MsgType.Request_Get):
-                segments[-1].append(m)
-                # round 20 — policy routing input (actor thread only)
-                if m.table_id >= 0:
-                    self.table_verbs[m.table_id] = (
-                        self.table_verbs.get(m.table_id, 0) + 1)
-            else:
-                segments.append(m)       # barrier marker
-                segments.append([])
+        with ttrace.span("server.window.form", cat="server"):
+            segments: list = [[]]
+            for m in batch:
+                if m.msg_type in (MsgType.Request_Add, MsgType.Request_Get):
+                    segments[-1].append(m)
+                    # round 20 — policy routing input (actor thread only)
+                    if m.table_id >= 0:
+                        self.table_verbs[m.table_id] = (
+                            self.table_verbs.get(m.table_id, 0) + 1)
+                else:
+                    segments.append(m)       # barrier marker
+                    segments.append([])
+            # a verb segment's Adds grouped into per-table runs, and its
+            # Gets' dedup keys (key cost — tobytes of the payload arrays
+            # — only when the segment could hold a duplicate)
+            formed = []     # barrier message | (verbs, add runs, keys)
+            for seg in segments:
+                if not isinstance(seg, list):
+                    formed.append(seg)
+                    continue
+                add_runs: Dict[int, list] = {}
+                for m in seg:
+                    if m.msg_type is MsgType.Request_Add:
+                        add_runs.setdefault(m.table_id, []).append(m)
+                n_gets = len(seg) - sum(map(len, add_runs.values()))
+                formed.append((seg, add_runs, [
+                    self._get_dedup_key(m) if n_gets > 1
+                    and m.msg_type is MsgType.Request_Get else None
+                    for m in seg]))
         pending = []   # (finalize, [msgs]) in dispatch order
         seen: Dict[tuple, int] = {}
         # perf forensics: per-(table, verb) apply seconds — only on the
@@ -1384,8 +1404,8 @@ class Server(Actor):
         # post-transition drain path leaves the flag wherever the last
         # window set it, which is fine for a sampled surface)
         tbl = {} if self._ph_stamp_this else None
-        for seg in segments:
-            if not isinstance(seg, list):
+        for seg in formed:
+            if not isinstance(seg, tuple):
                 # barrier: runs its normal handler in order, with
                 # standard error routing; no dedup survives it
                 self.window_barrier_splits += 1
@@ -1396,15 +1416,9 @@ class Server(Actor):
                 self._dispatch(seg)
                 seen.clear()
                 continue
-            add_runs: Dict[int, list] = {}
-            n_gets = 0
-            for m in seg:
-                if m.msg_type is MsgType.Request_Add:
-                    add_runs.setdefault(m.table_id, []).append(m)
-                else:
-                    n_gets += 1
+            seg, add_runs, keys = seg
             applied = set()
-            for m in seg:
+            for m, key in zip(seg, keys):
                 if m.msg_type is MsgType.Request_Add:
                     if m.table_id not in applied:
                         applied.add(m.table_id)
@@ -1422,9 +1436,6 @@ class Server(Actor):
                         seen = {k: v for k, v in seen.items()
                                 if k[0] != m.table_id}
                 else:
-                    # key cost (tobytes of the payload arrays) only when
-                    # the window could actually contain a duplicate
-                    key = self._get_dedup_key(m) if n_gets > 1 else None
                     if key is not None and key in seen:
                         pending[seen[key]][1].append(m)
                         continue
@@ -1452,26 +1463,28 @@ class Server(Actor):
                         k = (m.table_id, "G")
                         tbl[k] = (tbl.get(k, 0.0)
                                   + _time.perf_counter() - _tt)
-        for finalize, msgs in pending:
-            _tt = _time.perf_counter() if tbl is not None else 0.0
-            err = None
-            try:
-                result = finalize()
-            except Exception as exc:
-                Log.Error("table %d Get finalize failed: %r",
-                          msgs[0].table_id, exc)
-                err = exc
-            if tbl is not None:
-                k = (msgs[0].table_id, "G")
-                tbl[k] = tbl.get(k, 0.0) + _time.perf_counter() - _tt
-            if err is not None:
-                for m in msgs:
-                    m.reply(err)
-                continue
-            msgs[0].reply(result)
-            for m in msgs[1:]:
-                # each deduped caller owns its result arrays
-                m.reply(copy_result(result))
+        # the blocking device->host fetch of each Get, and the replies
+        with ttrace.span("server.window.finalize", cat="server"):
+            for finalize, msgs in pending:
+                _tt = _time.perf_counter() if tbl is not None else 0.0
+                err = None
+                try:
+                    result = finalize()
+                except Exception as exc:
+                    Log.Error("table %d Get finalize failed: %r",
+                              msgs[0].table_id, exc)
+                    err = exc
+                if tbl is not None:
+                    k = (msgs[0].table_id, "G")
+                    tbl[k] = tbl.get(k, 0.0) + _time.perf_counter() - _tt
+                if err is not None:
+                    for m in msgs:
+                        m.reply(err)
+                    continue
+                msgs[0].reply(result)
+                for m in msgs[1:]:
+                    # each deduped caller owns its result arrays
+                    m.reply(copy_result(result))
         if tbl:
             self._ph_tables(tbl, -1, 0)
 

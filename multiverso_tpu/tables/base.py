@@ -534,36 +534,42 @@ class WorkerTable:
         CHECK(waiter is not None, f"unknown msg_id {msg_id}")
         max_retries = _max_retries_flag()
         attempt = 0
-        while True:
-            if not waiter.Wait(fdeadline.timeout_or_none()):
-                try:
-                    # bundle first (it reports THIS in-flight request),
-                    # then abandon it: every bookkeeping slot is dropped
-                    # (an app catching DeadlineExceeded per request must
-                    # not leak a waiter + pinned payload per miss;
-                    # _on_reply ignores replies to abandoned ids)
-                    fdeadline.raise_deadline(
-                        f"table {self.table_id} reply to msg_id {msg_id}")
-                finally:
-                    with self._lock:
-                        self._waiters.pop(msg_id, None)
-                        self._inflight.pop(msg_id, None)
-                        self._results.pop(msg_id, None)
-                        self._gc_fill.pop(msg_id, None)
-            with self._lock:
-                result = self._results.pop(msg_id, None)
-            if isinstance(result, TransientError) and attempt < max_retries:
-                attempt += 1
-                tmetrics.counter("failsafe.retries").inc()
-                backoff = _RETRY_BACKOFF_BASE_S * (2 ** (attempt - 1))
-                backoff += random.random() * _RETRY_BACKOFF_BASE_S
-                Log.Debug("table %d msg_id %d transient (%r) — retry "
-                          "%d/%d in %.3fs", self.table_id, msg_id,
-                          result, attempt, max_retries, backoff)
-                time.sleep(backoff)
-                waiter = self._resubmit(msg_id)
-                continue
-            break
+        # the wait for the reply, which worker.get / worker.add (closed
+        # at the enqueue) leave out
+        with ttrace.span("worker.wait", cat="worker",
+                         args=({"table_id": self.table_id}
+                               if ttrace.enabled() else None)):
+            while True:
+                if not waiter.Wait(fdeadline.timeout_or_none()):
+                    try:
+                        # bundle first (it reports THIS in-flight request),
+                        # then abandon it: every bookkeeping slot is dropped
+                        # (an app catching DeadlineExceeded per request must
+                        # not leak a waiter + pinned payload per miss;
+                        # _on_reply ignores replies to abandoned ids)
+                        fdeadline.raise_deadline(
+                            f"table {self.table_id} reply to msg_id {msg_id}")
+                    finally:
+                        with self._lock:
+                            self._waiters.pop(msg_id, None)
+                            self._inflight.pop(msg_id, None)
+                            self._results.pop(msg_id, None)
+                            self._gc_fill.pop(msg_id, None)
+                with self._lock:
+                    result = self._results.pop(msg_id, None)
+                if (isinstance(result, TransientError)
+                        and attempt < max_retries):
+                    attempt += 1
+                    tmetrics.counter("failsafe.retries").inc()
+                    backoff = _RETRY_BACKOFF_BASE_S * (2 ** (attempt - 1))
+                    backoff += random.random() * _RETRY_BACKOFF_BASE_S
+                    Log.Debug("table %d msg_id %d transient (%r) — retry "
+                              "%d/%d in %.3fs", self.table_id, msg_id,
+                              result, attempt, max_retries, backoff)
+                    time.sleep(backoff)
+                    waiter = self._resubmit(msg_id)
+                    continue
+                break
         with self._lock:
             self._waiters.pop(msg_id, None)
             self._inflight.pop(msg_id, None)
@@ -589,7 +595,8 @@ class WorkerTable:
             if hit is not None:
                 return hit
             with ttrace.span("worker.get", cat="worker",
-                             args={"table_id": self.table_id}):
+                             args=({"table_id": self.table_id}
+                                   if ttrace.enabled() else None)):
                 handle = self._submit(MsgType.Request_Get, payload,
                                       worker_id=opt.worker_id)
             if key is not None:
@@ -632,7 +639,8 @@ class WorkerTable:
             # table's cached Gets
             self._write_epoch += 1
             with ttrace.span("worker.add", cat="worker",
-                             args={"table_id": self.table_id}):
+                             args=({"table_id": self.table_id}
+                                   if ttrace.enabled() else None)):
                 if not track:
                     if self._wc_try_buffer(payload, opt):
                         return 0

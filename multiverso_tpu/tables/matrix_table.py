@@ -34,6 +34,7 @@ contract for the others.
 from __future__ import annotations
 
 import functools
+import time
 from dataclasses import dataclass
 from typing import Callable, Dict, Optional, Tuple
 
@@ -50,7 +51,9 @@ from multiverso_tpu.parallel.mesh import (SERVER_AXIS, ceil_block_rows,
                                           parts_bucket, place_parts,
                                           storage_partition_server)
 from multiverso_tpu.tables.base import ServerTable, TableOption, WorkerTable
+from multiverso_tpu.telemetry import metrics as tmetrics
 from multiverso_tpu.telemetry import sketch as tsketch
+from multiverso_tpu.telemetry import trace as ttrace
 from multiverso_tpu.updaters.base import AddOption, CreateUpdater, GetOption
 from multiverso_tpu.utils.log import CHECK, Log
 
@@ -192,6 +195,9 @@ class MatrixServerTable(ServerTable):
         self._mesh = ctx.mesh
 
         self._sharding = ctx.sharding_rows()
+        # table.create_s: the initialiser, _to_storage and placement below
+        # (which materialises the whole table on one device first)
+        t_create = time.perf_counter()
         if initializer is not None:
             init = np.asarray(initializer((num_rows, num_cols)), self.dtype)
             data = self._to_storage(init)  # host numpy; place() shards it
@@ -233,6 +239,9 @@ class MatrixServerTable(ServerTable):
             "aux": jax.tree.map(
                 lambda a: ctx.place(a, self._aux_sharding(a, ctx)), aux),
         }
+        jax.block_until_ready(self._state)
+        tmetrics.histogram("table.create_s").observe(
+            time.perf_counter() - t_create)
         self._aux_specs = jax.tree.map(
             lambda a: P(SERVER_AXIS, None) if a.ndim == 2
             else P(None, SERVER_AXIS, None), aux)
@@ -314,6 +323,8 @@ class MatrixServerTable(ServerTable):
 
         store_cols = self.store_cols
 
+        # named scopes: stable names in the device trace's op metadata
+        @jax.named_scope("table.update_rows")
         def _update_rows(state, ids, deltas, opt):
             if deltas.shape[-1] != store_cols:   # logical cols in, pad zeros
                 deltas = jnp.pad(
@@ -336,6 +347,7 @@ class MatrixServerTable(ServerTable):
 
         self._update_rows = jax.jit(_update_rows, donate_argnums=(0,))
 
+        @jax.named_scope("table.merged_add_rows")
         def _merged_add_rows(state, uniq_ids, deltas, inv, opt):
             """A window's stacked Add batches as ONE dispatch. The
             duplicate structure (unique ids + inverse mapping) is
@@ -413,6 +425,7 @@ class MatrixServerTable(ServerTable):
                 return rows  # no peers to sum with
             return lax.psum(rows, SERVER_AXIS)
 
+        @jax.named_scope("table.gather_rows")
         def _gather_rows(data, aux, ids):
             if single:
                 # 1-server fast path (see _update_rows)
@@ -681,81 +694,98 @@ class MatrixServerTable(ServerTable):
         (the per-message path then reports precise errors)."""
         if multihost.world_size() > 1 or not self._merge_adds:
             return False
-        ids_list, deltas_list = [], []
-        for p in payloads:
-            row_ids = p.get("row_ids")
-            if row_ids is None or p.get("compressed") is not None:
-                return False
-            ids = np.asarray(row_ids, np.int32).ravel()
-            if (ids.size == 0 or int(ids.min()) < 0
-                    or int(ids.max()) >= self.num_rows):
-                return False
-            values = np.asarray(p.get("values"), self.dtype)
-            if values.size != ids.size * self.num_cols:
-                return False
-            ids_list.append(ids)
-            deltas_list.append(values.reshape(len(ids), self.num_cols))
-        nat = self._host_store()
-        if nat is not None:
-            # native merged apply. Same-id-set payloads (one worker
-            # hammering, or replicated pushes) collapse to vector-summed
-            # deltas + ONE C++ add; otherwise per-payload pre-combine +
-            # one GIL-free add each (uniqueness is only needed WITHIN one
-            # threaded apply — linear updaters sum across applies). A
-            # cross-window np.add.at combine measured ~3x slower than
-            # the applies it saved.
-            first = ids_list[0]
-            if len(ids_list) > 1 and all(
-                    a.shape == first.shape and np.array_equal(a, first)
-                    for a in ids_list[1:]):
-                total = deltas_list[0].astype(self.dtype, copy=True)
-                for d in deltas_list[1:]:
-                    total += d
-                ua, ud = _combine_duplicate_rows(first, total,
-                                                 self.num_cols, self.dtype)
-                nat.add_rows(ua, ud)
-            else:
-                for a, d in zip(ids_list, deltas_list):
-                    ua, ud = _combine_duplicate_rows(a, d, self.num_cols,
-                                                     self.dtype)
+        # a window's run of Adds in two named halves: .merge is host
+        # numpy (validation, stacking, np.unique, padding), .dispatch the
+        # host-to-device copies and the call of the merged program
+        targs = ({"table_id": getattr(self, "table_id", -1),
+                  "adds": len(payloads)}
+                 if ttrace.enabled() else None)
+        with ttrace.span("server.table.add_run.merge", cat="server",
+                         args=targs):
+            ids_list, deltas_list = [], []
+            for p in payloads:
+                row_ids = p.get("row_ids")
+                if row_ids is None or p.get("compressed") is not None:
+                    return False
+                ids = np.asarray(row_ids, np.int32).ravel()
+                if (ids.size == 0 or int(ids.min()) < 0
+                        or int(ids.max()) >= self.num_rows):
+                    return False
+                values = np.asarray(p.get("values"), self.dtype)
+                if values.size != ids.size * self.num_cols:
+                    return False
+                ids_list.append(ids)
+                deltas_list.append(values.reshape(len(ids), self.num_cols))
+            nat = self._host_store()
+            if nat is None:
+                if len({a.shape for a in deltas_list}) != 1:
+                    # mixed batch shapes would mint a fresh compile per
+                    # window composition — the per-message path is
+                    # cheaper than that
+                    return False
+                # option scalars are irrelevant to linear updaters
+                # (default/sgd ignore them), so runs merge regardless of
+                # per-message options. The batch count quantizes to a
+                # power of two and the unique-id count to the bucket
+                # ladder, so the jit cache holds a bounded shape set
+                # however the engine's windows race the producers.
+                n, k = len(ids_list), ids_list[0].size
+                nb = 1 << (n - 1).bit_length()
+                if nb * k * 4 > ops.rows.SMEM_IDS_BYTES:
+                    # the merged id vector must fit the Pallas SMEM
+                    # prefetch budget (shared constant, ops/rows.py) —
+                    # huge windows process per-message so they keep the
+                    # row-DMA fast path
+                    return False
+                ids = np.full((nb, k), -1, np.int32)
+                deltas = np.zeros((nb, k, self.num_cols), self.dtype)
+                for i, (a, d) in enumerate(zip(ids_list, deltas_list)):
+                    ids[i] = a
+                    deltas[i] = d
+                uniq, inv = np.unique(ids.reshape(-1), return_inverse=True)
+                # POWER-OF-TWO bucket (coarser than the ladder): the
+                # unique count varies continuously with window overlap,
+                # and every distinct bucket is a compile of this table's
+                # merged program — pow2 caps the shape set at log2(window)
+                # sizes, all warmable up front
+                bucket = max(8, 1 << (len(uniq) - 1).bit_length())
+                uniq_p = np.full(bucket, -1, np.int32)
+                uniq_p[: len(uniq)] = uniq
+        with ttrace.span("server.table.add_run.dispatch", cat="server",
+                         args=targs):
+            if nat is not None:
+                # native merged apply. Same-id-set payloads (one worker
+                # hammering, or replicated pushes) collapse to
+                # vector-summed deltas + ONE C++ add; otherwise
+                # per-payload pre-combine + one GIL-free add each
+                # (uniqueness is only needed WITHIN one threaded apply —
+                # linear updaters sum across applies). A cross-window
+                # np.add.at combine measured ~3x slower than the applies
+                # it saved.
+                first = ids_list[0]
+                if len(ids_list) > 1 and all(
+                        a.shape == first.shape and np.array_equal(a, first)
+                        for a in ids_list[1:]):
+                    total = deltas_list[0].astype(self.dtype, copy=True)
+                    for d in deltas_list[1:]:
+                        total += d
+                    ua, ud = _combine_duplicate_rows(
+                        first, total, self.num_cols, self.dtype)
                     nat.add_rows(ua, ud)
-            self._nat_dirty = True
-            for p, a in zip(payloads, ids_list):
-                self._note_add_parts(p.get("option") or AddOption(), [a])
-            return True
-        if len({a.shape for a in deltas_list}) != 1:
-            # mixed batch shapes would mint a fresh compile per window
-            # composition — the per-message path is cheaper than that
-            return False
-        # option scalars are irrelevant to linear updaters (default/sgd
-        # ignore them), so runs merge regardless of per-message options.
-        # The batch count quantizes to a power of two and the unique-id
-        # count to the bucket ladder, so the jit cache holds a bounded
-        # shape set however the engine's windows race the producers.
-        n, k = len(ids_list), ids_list[0].size
-        nb = 1 << (n - 1).bit_length()
-        if nb * k * 4 > ops.rows.SMEM_IDS_BYTES:
-            # the merged id vector must fit the Pallas SMEM prefetch
-            # budget (shared constant, ops/rows.py) — huge windows
-            # process per-message so they keep the row-DMA fast path
-            return False
-        ids = np.full((nb, k), -1, np.int32)
-        deltas = np.zeros((nb, k, self.num_cols), self.dtype)
-        for i, (a, d) in enumerate(zip(ids_list, deltas_list)):
-            ids[i] = a
-            deltas[i] = d
-        uniq, inv = np.unique(ids.reshape(-1), return_inverse=True)
-        # POWER-OF-TWO bucket (coarser than the ladder): the unique count
-        # varies continuously with window overlap, and every distinct
-        # bucket is a compile of this table's merged program — pow2 caps
-        # the shape set at log2(window) sizes, all warmable up front
-        bucket = max(8, 1 << (len(uniq) - 1).bit_length())
-        uniq_p = np.full(bucket, -1, np.int32)
-        uniq_p[: len(uniq)] = uniq
-        # mv-lint: ok(cross-domain-state): same one-plane-per-table argument as the state getter — engine window applies and device-plane collective verbs never drive one table concurrently
-        self.state = self._merged_add_rows(
-            self.state, jnp.asarray(uniq_p), jnp.asarray(deltas),
-            jnp.asarray(inv.astype(np.int32)), AddOption().as_jnp())
+                else:
+                    for a, d in zip(ids_list, deltas_list):
+                        ua, ud = _combine_duplicate_rows(
+                            a, d, self.num_cols, self.dtype)
+                        nat.add_rows(ua, ud)
+                self._nat_dirty = True
+                for p, a in zip(payloads, ids_list):
+                    self._note_add_parts(p.get("option") or AddOption(),
+                                         [a])
+                return True
+            # mv-lint: ok(cross-domain-state): same one-plane-per-table argument as the state getter — engine window applies and device-plane collective verbs never drive one table concurrently
+            self.state = self._merged_add_rows(
+                self.state, jnp.asarray(uniq_p), jnp.asarray(deltas),
+                jnp.asarray(inv.astype(np.int32)), AddOption().as_jnp())
         # subclass bookkeeping fires per payload in message order, exactly
         # like the per-message path (SparseMatrixTable's freshness bits
         # must see every add's id set + worker attribution)
@@ -858,16 +888,20 @@ class MatrixServerTable(ServerTable):
                                                          with_parts=True)
             self._apply_summed_full(values, option, parts)
             return
-        ids = np.asarray(row_ids, np.int32).ravel()
-        deltas = np.asarray(values, self.dtype).reshape(len(ids), self.num_cols)
-        self._check_ids(ids)
-        # multihost: merge every process's (ids, deltas) batch of this
-        # collective Add — each process may push different rows; after the
-        # merge all processes issue identical device programs over
-        # identical data (identity single-process)
-        (ids, deltas), parts = multihost.merge_collective_add(
-            option, ids, deltas, with_parts=True)
-        self._check_ids(ids)  # every rank's part validated on every replica
+        # a lone row Add is a run of one: the same two span names as
+        # ProcessAddRun (the second half is in _apply_merged_rows)
+        with ttrace.span("server.table.add_run.merge", cat="server"):
+            ids = np.asarray(row_ids, np.int32).ravel()
+            deltas = np.asarray(values, self.dtype).reshape(len(ids),
+                                                            self.num_cols)
+            self._check_ids(ids)
+            # multihost: merge every process's (ids, deltas) batch of this
+            # collective Add — each process may push different rows; after
+            # the merge all processes issue identical device programs over
+            # identical data (identity single-process)
+            (ids, deltas), parts = multihost.merge_collective_add(
+                option, ids, deltas, with_parts=True)
+            self._check_ids(ids)  # every rank's part, on every replica
         self._apply_merged_rows(ids, deltas, option, parts)
 
     def _apply_summed_full(self, values: np.ndarray, option: AddOption,
@@ -887,19 +921,21 @@ class MatrixServerTable(ServerTable):
     def _apply_merged_rows(self, ids: np.ndarray, deltas: np.ndarray,
                            option: AddOption, parts) -> None:
         """Apply an (already cross-rank merged, validated) row batch."""
-        ids, deltas = self._combine_duplicates(ids, deltas)
-        nat = self._host_store()
-        if nat is not None:
-            # unique validated ids: the threaded C++ apply is race-free
-            nat.add_rows(ids, deltas)
-            self._nat_dirty = True
-        else:
-            # ship exact-size arrays; pad to the bucket on device
-            padded_ids, padded_deltas = _pad_row_batch(
-                jnp.asarray(ids), jnp.asarray(deltas),
-                next_bucket(len(ids)))
-            self.state = self._update_rows(self.state, padded_ids,
-                                           padded_deltas, option.as_jnp())
+        with ttrace.span("server.table.add_run.merge", cat="server"):
+            ids, deltas = self._combine_duplicates(ids, deltas)
+        with ttrace.span("server.table.add_run.dispatch", cat="server"):
+            nat = self._host_store()
+            if nat is not None:
+                # unique validated ids: the threaded C++ apply is race-free
+                nat.add_rows(ids, deltas)
+                self._nat_dirty = True
+            else:
+                # ship exact-size arrays; pad to the bucket on device
+                padded_ids, padded_deltas = _pad_row_batch(
+                    jnp.asarray(ids), jnp.asarray(deltas),
+                    next_bucket(len(ids)))
+                self.state = self._update_rows(
+                    self.state, padded_ids, padded_deltas, option.as_jnp())
         self._note_add_parts(option, parts)
 
     # -- windowed-engine parts hooks (round 5; tables/base.py contract) -----
@@ -1418,10 +1454,12 @@ class MatrixServerTable(ServerTable):
             if row_ids is None:
                 out = nat.get_all()
             else:
-                ids = np.asarray(row_ids, np.int32).ravel()
-                self._check_ids(ids)
-                self._note_row_access(ids)
-                out = nat.get_rows(ids)
+                with ttrace.span("server.table.get.prepare", cat="server"):
+                    ids = np.asarray(row_ids, np.int32).ravel()
+                    self._check_ids(ids)
+                    self._note_row_access(ids)
+                with ttrace.span("server.table.get.dispatch", cat="server"):
+                    out = nat.get_rows(ids)
             return lambda: out
         if row_ids is None:
             data = self.updater.access(self.state["data"], self.state["aux"],
@@ -1434,14 +1472,17 @@ class MatrixServerTable(ServerTable):
                 data = jnp.copy(data)
             data.copy_to_host_async()
             return lambda: self._from_storage(np.asarray(data))
-        ids = np.asarray(row_ids, np.int32).ravel()
-        self._check_ids(ids)
-        self._note_row_access(ids)
-        padded_ids = _pad_id_batch(jnp.asarray(ids), next_bucket(len(ids)))
-        rows = self._gather_rows(self.state["data"], self.state["aux"],
-                                 padded_ids)
-        sliced = rows[: len(ids)]
-        sliced.copy_to_host_async()
+        with ttrace.span("server.table.get.prepare", cat="server"):
+            ids = np.asarray(row_ids, np.int32).ravel()
+            self._check_ids(ids)
+            self._note_row_access(ids)
+        with ttrace.span("server.table.get.dispatch", cat="server"):
+            padded_ids = _pad_id_batch(jnp.asarray(ids),
+                                       next_bucket(len(ids)))
+            rows = self._gather_rows(self.state["data"], self.state["aux"],
+                                     padded_ids)
+            sliced = rows[: len(ids)]
+            sliced.copy_to_host_async()
         return lambda: np.asarray(sliced)
 
     # -- eager device plane (public) ----------------------------------------
@@ -1498,47 +1539,69 @@ class MatrixServerTable(ServerTable):
         """Rows for ``row_ids`` as a DEVICE array (never leaves HBM).
         Multi-process: collective; each process gets its own rows out of
         one merged SPMD gather round."""
-        ids = np.asarray(row_ids, np.int32).ravel()
-        self._check_ids(ids)
-        if multihost.world_size() > 1:
-            gids = self.device_place_batch(ids)
-            bucket = gids.shape[0] // multihost.world_size()
-            rows = self._gather_rows_parts_j(self.state["data"],
-                                             self.state["aux"], gids)
-            # rows is fully replicated: slice THIS process's range out of
-            # an addressable single-device copy — a per-process-divergent
-            # slice of the global array would claim replicated contents
-            # it doesn't have
-            start = multihost.world_rank() * bucket
-            return rows.addressable_data(0)[start: start + len(ids)]
-        padded = _pad_id_batch(jnp.asarray(ids), next_bucket(len(ids)))
-        rows = self._gather_rows(self.state["data"], self.state["aux"],
-                                 padded)
-        return rows[: len(ids)]
+        nproc = multihost.world_size()
+        with ttrace.span("server.table.device_fetch", cat="server",
+                         args=({"table_id": getattr(self, "table_id", -1)}
+                               if ttrace.enabled() else None)):
+            with ttrace.span("server.table.device_fetch.prepare",
+                             cat="server"):
+                ids = np.asarray(row_ids, np.int32).ravel()
+                self._check_ids(ids)
+                if nproc > 1:
+                    gids = self.device_place_batch(ids)
+            with ttrace.span("server.table.device_fetch.dispatch",
+                             cat="server"):
+                if nproc > 1:
+                    bucket = gids.shape[0] // nproc
+                    rows = self._gather_rows_parts_j(
+                        self.state["data"], self.state["aux"], gids)
+                    # rows is fully replicated: slice THIS process's range
+                    # out of an addressable single-device copy — a
+                    # per-process-divergent slice of the global array
+                    # would claim replicated contents it doesn't have
+                    start = multihost.world_rank() * bucket
+                    return rows.addressable_data(0)[start: start + len(ids)]
+                padded = _pad_id_batch(jnp.asarray(ids),
+                                       next_bucket(len(ids)))
+                rows = self._gather_rows(self.state["data"],
+                                         self.state["aux"], padded)
+                return rows[: len(ids)]
 
     def device_apply_rows(self, row_ids, deltas,
                           option: Optional[AddOption] = None) -> None:
         """Apply a (device or host) delta batch to ``row_ids`` in place —
         same validation and duplicate pre-combining as ProcessAdd.
         Multi-process: collective; per-process batches merge on device."""
-        ids = np.asarray(row_ids, np.int32).ravel()
-        self._check_ids(ids)
-        if multihost.world_size() > 1:
-            gids, gdeltas = self.device_place_batch(ids, deltas)
-            self.state = self._update_rows_parts_j(
-                self.state, gids, gdeltas, (option or AddOption()).as_jnp())
-            return
-        if len(np.unique(ids)) != len(ids):
-            # duplicates must pre-combine on the host (scatter order is
-            # undefined — module docstring); costs a device->host hop, so
-            # callers should dedupe their id sets (block row sets are)
-            host = np.asarray(deltas, self.dtype).reshape(len(ids),
-                                                          self.num_cols)
-            ids, deltas = self._combine_duplicates(ids, host)
-        padded_ids, padded_deltas = _pad_row_batch(
-            jnp.asarray(ids), jnp.asarray(deltas), next_bucket(len(ids)))
-        self.state = self._update_rows(self.state, padded_ids, padded_deltas,
-                                       (option or AddOption()).as_jnp())
+        nproc = multihost.world_size()
+        with ttrace.span("server.table.device_apply", cat="server",
+                         args=({"table_id": getattr(self, "table_id", -1)}
+                               if ttrace.enabled() else None)):
+            with ttrace.span("server.table.device_apply.prepare",
+                             cat="server"):
+                ids = np.asarray(row_ids, np.int32).ravel()
+                self._check_ids(ids)
+                if nproc > 1:
+                    gids, gdeltas = self.device_place_batch(ids, deltas)
+                elif len(np.unique(ids)) != len(ids):
+                    # duplicates must pre-combine on the host (scatter
+                    # order is undefined — module docstring); costs a
+                    # device->host hop, so callers should dedupe their id
+                    # sets (block row sets are)
+                    host = np.asarray(deltas, self.dtype).reshape(
+                        len(ids), self.num_cols)
+                    ids, deltas = self._combine_duplicates(ids, host)
+            with ttrace.span("server.table.device_apply.dispatch",
+                             cat="server"):
+                opt = (option or AddOption()).as_jnp()  # five small copies
+                if nproc > 1:
+                    self.state = self._update_rows_parts_j(
+                        self.state, gids, gdeltas, opt)
+                    return
+                padded_ids, padded_deltas = _pad_row_batch(
+                    jnp.asarray(ids), jnp.asarray(deltas),
+                    next_bucket(len(ids)))
+                self.state = self._update_rows(self.state, padded_ids,
+                                               padded_deltas, opt)
 
     def raw(self) -> np.ndarray:
         """Logical-view snapshot (host numpy)."""

@@ -204,6 +204,245 @@ class TestTraceExport:
         assert len(trace.to_chrome_trace()["traceEvents"]) == 1  # meta only
 
 
+class TestHostSpans:
+    """PR 23: the spans and counters inside the app loop, the engine's
+    single-process window and the device-plane verbs. Counts and
+    structure only: no wall-clock threshold."""
+
+    PLANES = {"host": [], "host_pipeline": ["-is_pipeline", "1"],
+              "device_plane": ["-device_plane", "1"],
+              "device_pairs": ["-device_pairs", "1"]}
+    #: worker.we.* children of worker.we.block, by plane (a prefetched
+    #: block's fetch is a sibling: train() waits for it after the block
+    #: before; the fused program has no row fetch or push)
+    BLOCK_CHILDREN = {
+        "host": {"fetch", "upload", "dispatch", "push"},
+        "host_pipeline": {"upload", "dispatch", "push"},
+        "device_plane": {"fetch", "upload", "dispatch", "push"},
+        "device_pairs": {"upload", "dispatch"}}
+
+    @staticmethod
+    def _spans():
+        return [e for e in trace.to_chrome_trace()["traceEvents"]
+                if e["ph"] == "X"]
+
+    @staticmethod
+    def _children(spans, parent):
+        return [e for e in spans
+                if e["args"]["parent_id"] == parent["args"]["span_id"]]
+
+    def _train(self, tmp_path, plane, trace_on):
+        """A tiny WordEmbedding job in one plane. -> (app, wall of
+        train() in us, the ring's spans, the metrics snapshot)."""
+        import multiverso_tpu as mv
+        from multiverso_tpu.models.wordembedding.distributed import (
+            DistributedWordEmbedding)
+        from multiverso_tpu.models.wordembedding.option import Option
+        rng = np.random.default_rng(0)
+        words = [f"w{i}" for i in range(120)]
+        corpus = tmp_path / "corpus.txt"
+        corpus.write_text("".join(
+            " ".join(rng.choice(words, 12)) + "\n" for _ in range(200)))
+        opt = Option.parse_args(
+            ["-train_file", str(corpus), "-output", str(tmp_path / "v.bin"),
+             "-size", "8", "-min_count", "1", "-data_block_size", "6000",
+             "-epoch", "1", "-is_pipeline", "0", "-pair_batch", "256",
+             "-use_adagrad", "1"] + self.PLANES[plane])
+        trace._reset_for_tests()
+        metrics._reset_for_tests()
+        mv.MV_Init(["-trace=true"] if trace_on else [])
+        we = DistributedWordEmbedding(opt)
+        try:
+            we.prepare()
+            t0 = time.perf_counter()
+            we.train()
+            wall_us = (time.perf_counter() - t0) * 1e6
+            return we, wall_us, self._spans(), metrics.snapshot()
+        finally:
+            we.close()
+            mv.MV_ShutDown()
+
+    @pytest.mark.parametrize("plane", list(PLANES))
+    def test_train_leaves_one_tree_a_block(self, tmp_path, plane):
+        _, wall_us, spans, snap = self._train(tmp_path, plane, True)
+        named = lambda n: [e for e in spans if e["name"] == n]  # noqa: E731
+        blocks = named("worker.we.block")
+        assert len(blocks) >= 3, "the corpus should make several blocks"
+        assert len(blocks) == snap["we.blocks"]["value"]
+        assert sorted(b["args"]["block"] for b in blocks) == list(
+            range(len(blocks)))
+        assert snap["we.pop_wait_s"]["count"] == len(blocks) + 1
+        assert len(named("worker.we.pop_wait")) == len(blocks) + 1
+        made = {e["args"]["span_id"]: e
+                for e in named("worker.we.load.make_block")}
+        assert len(made) == len(blocks)
+        by_id = {e["args"]["span_id"]: e for e in spans}
+        for b in blocks:
+            parent = made.get(b["args"]["parent_id"])
+            assert parent is not None, "block not parented to a make_block"
+            assert parent["tid"] != b["tid"], "loader and loop share a thread"
+            kids = {e["name"] for e in self._children(spans, b)}
+            want = set(self.BLOCK_CHILDREN[plane])
+            if plane == "host_pipeline" and b["args"]["block"] == 0:
+                want.add("fetch")       # nothing prefetched the first
+            assert {k[len("worker.we."):] for k in kids
+                    if k.startswith("worker.we.")} == want
+        # each block's harvest hangs off the same make_block span: one
+        # tree from the loader to the harvest
+        harvests = named("worker.we.harvest")
+        assert len(harvests) == len(blocks)
+        assert {h["args"]["parent_id"] for h in harvests} == set(made)
+        assert {h["args"]["trace_id"] for h in harvests} == {
+            b["args"]["trace_id"] for b in blocks}
+        if plane == "host_pipeline":
+            fetches = [e for e in named("worker.we.fetch")
+                       if e["args"]["parent_id"] in made]
+            assert len(fetches) == len(blocks) - 1   # all but the first
+        if plane == "device_plane":
+            for f in named("server.table.device_fetch"):
+                assert by_id[f["args"]["parent_id"]]["name"] == \
+                    "worker.we.fetch"
+        # the loop's three serial parts fit inside train()'s wall
+        serial = sum(e["dur"] for n in ("worker.we.pop_wait",
+                                        "worker.we.block",
+                                        "worker.we.harvest")
+                     for e in named(n))
+        assert serial <= wall_us
+        reads = named("worker.we.load.read")
+        assert len(reads) == len(blocks) + 1     # the last finds the end
+
+    @pytest.mark.parametrize("plane", ["host", "device_pairs"])
+    def test_prepare_sets_its_gauges(self, tmp_path, plane):
+        _, _, _, snap = self._train(tmp_path, plane, False)
+        for part in ("dictionary", "sampler", "world", "tables", "trainer"):
+            g = snap[f"we.prepare.{part}_s"]
+            assert g["type"] == "gauge" and g["value"] >= 0.0
+        made = snap["table.create_s"]
+        assert made["count"] == 4       # input, output, two AdaGrad tables
+        assert 0.0 < made["sum"] <= snap["we.prepare.tables_s"]["value"]
+
+    @staticmethod
+    def _window_drive(mv):
+        """Two Adds and two Gets in ONE engine window (one batched
+        envelope), then a lone blocking Add."""
+        from multiverso_tpu.tables import MatrixTableOption
+        from multiverso_tpu.tables.base import submit_multi
+        t = mv.MV_CreateTable(MatrixTableOption(num_rows=64, num_cols=4))
+        ids = np.arange(8, dtype=np.int32)
+        ones = np.ones((8, 4), np.float32)
+        got = submit_multi(
+            [(t, "A", {"row_ids": ids, "values": ones}),
+             (t, "A", {"row_ids": ids + 8, "values": ones}),
+             (t, "G", {"row_ids": ids}),
+             (t, "G", {"row_ids": ids + 8})]).Wait()
+        t.AddRows(ids, ones)
+        return t, got
+
+    def test_single_process_window_tree(self):
+        import multiverso_tpu as mv
+        trace._reset_for_tests()
+        mv.MV_Init(["-trace=true"])
+        try:
+            t, got = self._window_drive(mv)
+            np.testing.assert_array_equal(got[2], np.ones((8, 4)))
+            spans = self._spans()
+        finally:
+            mv.MV_ShutDown()
+        window = [e for e in spans if e["name"] == "server.window"
+                  and e["args"]["verbs"] == 4]
+        assert len(window) == 1
+        window = window[0]
+        kids = sorted(self._children(spans, window), key=lambda e: e["ts"])
+        assert [k["name"] for k in kids] == [
+            "server.window.form",
+            "server.table.add_run.merge", "server.table.add_run.dispatch",
+            "server.table.get.prepare", "server.table.get.dispatch",
+            "server.table.get.prepare", "server.table.get.dispatch",
+            "server.window.finalize"]
+        assert kids[1]["args"]["adds"] == 2
+        for k in kids:      # nested in time as well as in the tree
+            assert window["ts"] <= k["ts"]
+            assert k["ts"] + k["dur"] <= window["ts"] + window["dur"] + 1
+        # admission is the window's elder sibling on the engine's thread
+        admits = [e for e in spans if e["name"] == "server.window.admit"
+                  and e["args"]["parent_id"] == window["args"]["parent_id"]]
+        assert len(admits) == 1
+        assert admits[0]["ts"] + admits[0]["dur"] <= window["ts"] + 1
+        assert admits[0]["tid"] == window["tid"]
+        # the blocking Add waited for its reply on the caller's thread
+        waits = [e for e in spans if e["name"] == "worker.wait"]
+        assert waits and all(w["tid"] == threading.get_ident()
+                             and w["args"]["table_id"] == t.table_id
+                             for w in waits)
+        assert waits[-1]["tid"] != window["tid"]
+        # a lone Add is a run of one: the same two names, no form of many
+        lone = [e for e in spans if e["name"] == "server.window"
+                and e["args"]["verbs"] == 1]
+        assert lone
+        names = [k["name"] for k in self._children(spans, lone[-1])]
+        assert names.count("server.table.add_run.dispatch") == 1
+        assert "server.table.add_run.merge" in names
+
+    @pytest.mark.parametrize("verb", ["device_fetch", "device_apply"])
+    def test_device_plane_verb_spans(self, verb):
+        import multiverso_tpu as mv
+        from multiverso_tpu.tables import MatrixTableOption
+        trace._reset_for_tests()
+        mv.MV_Init(["-trace=true"])
+        try:
+            t = mv.MV_CreateTable(MatrixTableOption(num_rows=64,
+                                                    num_cols=4))
+            ids = np.arange(0, 16, 2, dtype=np.int32)
+            srv = t.server()
+            trace.clear()
+            if verb == "device_fetch":
+                rows = srv.device_fetch_rows(ids)
+                assert rows.shape == (8, 4)
+            else:
+                srv.device_apply_rows(ids, np.ones((8, 4), np.float32))
+                np.testing.assert_array_equal(t.GetRows(ids),
+                                              np.ones((8, 4)))
+            spans = self._spans()
+        finally:
+            mv.MV_ShutDown()
+        top = [e for e in spans if e["name"] == f"server.table.{verb}"]
+        assert len(top) == 1 and top[0]["args"]["table_id"] == t.table_id
+        kids = sorted(self._children(spans, top[0]), key=lambda e: e["ts"])
+        assert [k["name"] for k in kids] == [
+            f"server.table.{verb}.prepare", f"server.table.{verb}.dispatch"]
+        assert sum(k["dur"] for k in kids) <= top[0]["dur"]
+
+    @pytest.mark.parametrize("drive", ["train", "window", "device_verbs"])
+    def test_trace_off_leaves_the_ring_empty(self, tmp_path, drive):
+        """-trace off: the same drives record nothing, and every span()
+        is the one shared no-op object (no allocation per call)."""
+        import multiverso_tpu as mv
+        from multiverso_tpu.tables import MatrixTableOption
+        if drive == "train":
+            _, _, spans, snap = self._train(tmp_path, "device_plane", False)
+            assert snap["we.blocks"]["value"] >= 3  # counters stay on
+        else:
+            trace._reset_for_tests()
+            mv.MV_Init([])
+            try:
+                if drive == "window":
+                    self._window_drive(mv)
+                else:
+                    t = mv.MV_CreateTable(MatrixTableOption(num_rows=64,
+                                                            num_cols=4))
+                    ids = np.arange(8, dtype=np.int32)
+                    t.server().device_apply_rows(
+                        ids, np.ones((8, 4), np.float32))
+                    t.server().device_fetch_rows(ids)
+                assert trace.span("worker.we.block") is trace.span(
+                    "server.window.form", args=None)
+                spans = self._spans()
+            finally:
+                mv.MV_ShutDown()
+        assert spans == []
+        assert trace.span("server.table.device_fetch") is trace._NULL_SPAN
+
+
 class TestProfilerGuard:
     def test_double_start_checks_and_stop_without_start_noop(self, tmp_path):
         import multiverso_tpu as mv
