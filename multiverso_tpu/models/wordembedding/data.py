@@ -68,13 +68,13 @@ def sentences_from_file(path: str, dictionary: Dictionary) -> Iterator[Tuple[np.
     """Tokenize -> word ids; yields (ids, raw_token_count) per sentence
     (line), clipped to MAX_SENTENCE_LENGTH (reference reader.cpp).
 
-    Fast path: the native tokenizer (native/src/reader.cc, loaded via
-    multiverso_tpu.native.VocabTokenizer) tokenizes megabyte chunks in ONE
-    foreign call each — ids come back with -2 sentinels at newlines and
-    are split into sentences vectorized; pure-python fallback otherwise."""
-    from multiverso_tpu.native import VocabTokenizer
-    with ttrace.span("worker.we.load.tokenizer", cat="worker"):
-        tok = VocabTokenizer.create(dictionary.words())
+    Fast path: the dictionary's native tokenizer (native/src/reader.cc,
+    ``Dictionary.tokenizer()``: built once for a dictionary, by
+    ``prepare()`` or by the first pass that finds none) tokenizes megabyte
+    chunks in ONE foreign call each — ids come back with -2 sentinels at
+    newlines and are split into sentences vectorized; pure-python fallback
+    otherwise."""
+    tok = dictionary.tokenizer()
 
     def emit(ids: np.ndarray):
         for start in range(0, len(ids), MAX_SENTENCE_LENGTH):

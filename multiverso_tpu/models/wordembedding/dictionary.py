@@ -12,6 +12,9 @@ from __future__ import annotations
 import collections
 from typing import Dict, Iterable, List, Optional, Set, Tuple
 
+from multiverso_tpu.telemetry import metrics as tmetrics
+from multiverso_tpu.telemetry import trace as ttrace
+
 
 class WordInfo:
     __slots__ = ("word", "freq")
@@ -26,6 +29,9 @@ class Dictionary:
         self._word_idx: Dict[str, int] = {}
         self._infos: List[WordInfo] = []
         self._stopwords = stopwords or set()
+        # the native word -> id table of tokenizer(): a function of the
+        # word list alone, so whatever changes that list drops it
+        self._tokenizer = None
 
     # -- construction -------------------------------------------------------
 
@@ -36,6 +42,7 @@ class Dictionary:
         if idx is None:
             self._word_idx[word] = len(self._infos)
             self._infos.append(WordInfo(word, count))
+            self._tokenizer = None
         else:
             self._infos[idx].freq += count
 
@@ -54,6 +61,7 @@ class Dictionary:
         kept.sort(key=lambda w: -w.freq)
         self._infos = kept
         self._word_idx = {w.word: i for i, w in enumerate(kept)}
+        self._tokenizer = None
 
     # -- persistence (word2vec "word count" lines) --------------------------
 
@@ -77,6 +85,22 @@ class Dictionary:
 
     def GetWordIdx(self, word: str) -> int:
         return self._word_idx.get(word, -1)
+
+    def tokenizer(self):
+        """The native tokenizer over this dictionary's words
+        (multiverso_tpu.native.VocabTokenizer), built on the first call
+        and kept until a word is inserted or pruned: every pass of every
+        ``train()`` reads the corpus through the one table. Read-only once
+        built, so loader threads may share it. None without the native
+        library or without words; the callers then look words up in
+        python (GetWordIdx)."""
+        if self._tokenizer is None:
+            from multiverso_tpu.native import VocabTokenizer
+            with ttrace.span("worker.we.load.tokenizer", cat="worker"):
+                self._tokenizer = VocabTokenizer.create(self.words())
+            if self._tokenizer is not None:
+                tmetrics.counter("we.tokenizer.builds").inc()
+        return self._tokenizer
 
     def GetWordInfo(self, idx: int) -> WordInfo:
         return self._infos[idx]

@@ -46,6 +46,11 @@ class DistributedWordEmbedding:
         self.total_loss = 0.0
         self.total_pairs = 0
         self._blocks_done = 0   # index of the next block, per train()
+        # what depends on prepare()'s products alone is made once per
+        # trainer, not per train() call: the jit'd pair-batch step and
+        # (use_adagrad, the block program scanning it)
+        self._step = None
+        self._block_scan_cache = None
 
     # -- setup --------------------------------------------------------------
 
@@ -73,6 +78,12 @@ class DistributedWordEmbedding:
         self.dictionary.RemoveWordsLessThan(max(opt.min_count, 1))
         if self.dictionary.Size() == 0:
             raise ValueError("empty vocabulary after min_count pruning")
+        # the dictionary is final: build the loader's tokenizer here, once,
+        # and not at the start of every pass. Part of the dictionary's lap;
+        # its own seconds go to we.prepare.tokenizer_s besides
+        built = time.perf_counter()
+        self.dictionary.tokenizer()
+        laps["tokenizer"] = time.perf_counter() - built
         if opt.total_words <= 0:
             opt.total_words = self.dictionary.WordCount()
         counts = self.dictionary.counts()
@@ -96,6 +107,7 @@ class DistributedWordEmbedding:
                     DevicePairsTrainer)
                 self._dp_trainer = DevicePairsTrainer(opt, self.comm, counts,
                                                       huffman=self.huffman)
+            self._step = make_train_step(opt.use_adagrad)
             lap("trainer")
         for part, seconds in laps.items():
             tmetrics.gauge(f"we.prepare.{part}_s").set(seconds)
@@ -116,7 +128,7 @@ class DistributedWordEmbedding:
         queue = BlockQueue(capacity=3 if opt.is_pipeline else 1)
         loader = start_loader(opt, self.dictionary, generator, queue,
                               opt.epoch)
-        step = make_train_step(opt.use_adagrad)
+        step = self._step
         timer = Timer()
         words_done = 0
         self.total_loss = 0.0
@@ -239,9 +251,13 @@ class DistributedWordEmbedding:
         """One jit'd program scanning the train step over a whole block's
         stacked batches: the device-plane path pays ONE upload + ONE
         dispatch per block instead of one per batch. Retraces per distinct
-        batch-count, which block sizing keeps to a handful."""
-        if getattr(self, "_block_scan_cache", None) is None \
-                or self._block_scan_cache[0] is not step:
+        batch-count, which block sizing keeps to a handful. Kept for the
+        trainer's life under what it depends on, ``use_adagrad`` (which
+        fixes ``step``), so a later train() dispatches the program the
+        first one traced."""
+        use_adagrad = bool(self.opt.use_adagrad)
+        if self._block_scan_cache is None \
+                or self._block_scan_cache[0] != use_adagrad:
             import jax
             import jax.numpy as jnp
             from jax import lax
@@ -260,8 +276,9 @@ class DistributedWordEmbedding:
             # buffers (jnp.copy in request_parameter_device keeps the
             # originals alive for the delta push), so the scan may update
             # the row matrices in place
-            self._block_scan_cache = (step, jax.jit(run,
-                                                    donate_argnums=(0,)))
+            self._block_scan_cache = (use_adagrad,
+                                      jax.jit(run, donate_argnums=(0,)))
+            tmetrics.counter("we.block_program.builds").inc()
         return self._block_scan_cache[1]
 
     def _train_block(self, block: DataBlock, step) -> tuple:

@@ -178,9 +178,26 @@ class VocabTokenizer:
 
     def __init__(self, handle: ctypes.CDLL, words):
         self._h = handle
-        self._word_bytes = [w.encode("utf-8") for w in words]  # keep alive
-        self._words = (ctypes.c_char_p * len(words))(*self._word_bytes)
         self._n = len(words)
+        # the native side takes a plain ``const char**``: one
+        # NUL-separated blob and a numpy array of addresses into it serve,
+        # with no bytes object and no ctypes slot per word (at 2.1 M words
+        # those were 2 s of a 2.4 s build)
+        blob = np.frombuffer(("\0".join(words) + "\0").encode("utf-8"),
+                             np.uint8)
+        ends = np.flatnonzero(blob == 0)
+        if len(ends) != self._n:
+            # a word holds a NUL itself (C reads it up to there, as it
+            # always did): take the ends from the words' own lengths
+            encoded = [w.encode("utf-8") for w in words]
+            blob = np.frombuffer(b"\0".join(encoded) + b"\0", np.uint8)
+            ends = np.cumsum(np.fromiter(map(len, encoded), np.int64,
+                                         self._n) + 1) - 1
+        self._blob = blob       # the array keeps the bytes alive
+        starts = np.concatenate(([0], ends[:-1] + 1))
+        self._addrs = (starts + self._blob.ctypes.data).astype(np.uint64)
+        self._words = self._addrs.ctypes.data_as(
+            ctypes.POINTER(ctypes.c_char_p))
         cap = 8
         while cap < 2 * self._n + 1:
             cap <<= 1

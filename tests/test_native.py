@@ -305,29 +305,108 @@ class TestNativeReader:
             np.testing.assert_array_equal(k1, k2)
             np.testing.assert_allclose(v1, v2)
 
+    @staticmethod
+    def _sentences(path, d, with_native):
+        """sentences_from_file over ``d``, through the native tokenizer
+        or with the library held away (the python path)."""
+        from multiverso_tpu import native as native_mod
+        from multiverso_tpu.models.wordembedding import data as we_data
+        orig = native_mod.lib
+        if not with_native:
+            native_mod.lib = lambda: None
+        try:
+            return [(ids.tolist(), n) for ids, n in
+                    we_data.sentences_from_file(str(path), d)]
+        finally:
+            native_mod.lib = orig
+
     def test_vocab_tokenizer_matches_python(self, native_build, tmp_path):
         """WE sentence reader: native tokenizer path == python path."""
-        from multiverso_tpu.models.wordembedding import data as we_data
         from multiverso_tpu.models.wordembedding.dictionary import Dictionary
-        d = Dictionary()
-        for w in ["the", "cat", "sat", "on", "mat"]:
-            d.Insert(w, 10)
+
+        def dictionary():   # one each: a dictionary keeps its tokenizer
+            d = Dictionary()
+            for w in ["the", "cat", "sat", "on", "mat"]:
+                d.Insert(w, 10)
+            return d
+
         corpus = tmp_path / "c.txt"
         # mixed line endings: \n, blank line, \r\n (both paths must agree)
         corpus.write_bytes(
             b"the cat sat on the unknown mat\n\nmat cat\r\nsat mat\n")
-        native_out = [(ids.tolist(), n) for ids, n in
-                      we_data.sentences_from_file(str(corpus), d)]
-        from multiverso_tpu import native as native_mod
-        orig = native_mod.lib
-        native_mod.lib = lambda: None
-        try:
-            py_out = [(ids.tolist(), n) for ids, n in
-                      we_data.sentences_from_file(str(corpus), d)]
-        finally:
-            native_mod.lib = orig
+        with_lib, without = dictionary(), dictionary()
+        native_out = self._sentences(corpus, with_lib, True)
+        py_out = self._sentences(corpus, without, False)
+        assert with_lib.tokenizer() is not None
+        assert without._tokenizer is None       # the python path ran
         assert native_out == py_out
         assert len(native_out) == 3  # blank line skipped, OOV filtered
+
+    @pytest.mark.parametrize("words, min_count", [
+        pytest.param([("the", 9), ("cat", 8), ("mat", 7)], 1, id="ascii"),
+        pytest.param([("caf\u00e9", 9), ("\u732b", 8), ("na\u00efve", 7),
+                      ("cafe", 6), ("\U0001f600", 5)], 1, id="non_ascii"),
+        pytest.param([("cat", 9), ("ca", 8), ("cats", 7), ("c", 6),
+                      ("catsup", 5)], 1, id="prefix_of_another"),
+        pytest.param([("a\0b", 9), ("a", 8), ("b", 7)], 1,
+                     id="nul_inside_a_word"),
+        pytest.param([("rare", 1), ("rarer", 1)], 2,
+                     id="empty_after_pruning"),
+        pytest.param([("kept", 5), ("gone", 1), ("k", 3)], 2,
+                     id="pruned_and_recompacted"),
+    ])
+    def test_cheap_tokenizer_build_matches_old(self, native_build, tmp_path,
+                                               words, min_count):
+        """The blob-and-addresses build gives the table, the ids and the
+        -1 / -2 sentinels of the build it replaced (a bytes object and a
+        ctypes slot a word), and the python path's sentences."""
+        import ctypes
+        from multiverso_tpu import native
+        from multiverso_tpu.models.wordembedding.dictionary import Dictionary
+
+        def dictionary():
+            d = Dictionary()
+            for w, c in words:
+                d.Insert(w, c)
+            d.RemoveWordsLessThan(min_count)
+            return d
+
+        d = dictionary()
+        text = (" ".join(w for w, _ in words) + " unknown\n\n"
+                + " ".join(w + "x " + w[:-1] for w, _ in words)
+                + "\r\n" + words[0][0]).encode("utf-8")
+        corpus = tmp_path / "c.txt"
+        corpus.write_bytes(text)
+        tok = d.tokenizer()
+        if d.Size() == 0:
+            assert tok is None
+            assert self._sentences(corpus, d, True) == []
+            return
+        # the old build, as it stood before this tokenizer
+        kept = d.words()
+        old_bytes = [w.encode("utf-8") for w in kept]
+        old_words = (ctypes.c_char_p * len(kept))(*old_bytes)
+        old_table = np.empty(tok._cap, np.int64)
+        h = native.lib()
+        h.MV_BuildVocabHash(old_words, len(kept), old_table, tok._cap)
+        np.testing.assert_array_equal(tok._table, old_table)
+        old_ids = np.empty(len(text) + 2, np.int32)
+        n = h.MV_TokenizeLinesToIds(text, len(text), old_words, len(kept),
+                                    old_table, tok._cap, old_ids,
+                                    len(old_ids))
+        got = tok.tokenize_lines(text)
+        np.testing.assert_array_equal(got, old_ids[:n])
+        assert -1 in got and -2 in got
+        flat = tok.tokenize(text, len(text))
+        np.testing.assert_array_equal(flat, got[got != -2])
+        if not any("\0" in w for w in kept):
+            # (C reads a word up to its NUL; python does not)
+            want = [d.GetWordIdx(t) for t in text.decode("utf-8").split()]
+            assert flat.tolist() == want
+            plain = dictionary()
+            assert (self._sentences(corpus, d, True)
+                    == self._sentences(corpus, plain, False))
+            assert plain._tokenizer is None     # the python path ran
 
     def test_malformed_input_raises(self, native_build):
         """Malformed tokens must fail the run, not parse as zeros

@@ -310,16 +310,25 @@ class TestHostSpans:
         assert serial <= wall_us
         reads = named("worker.we.load.read")
         assert len(reads) == len(blocks) + 1     # the last finds the end
+        # the tokenizer's one build was prepare()'s, never the loader's
+        loader_tids = {e["tid"] for e in reads}
+        builds = named("worker.we.load.tokenizer")
+        assert len(builds) <= 1     # none without the native library
+        assert not loader_tids & {e["tid"] for e in builds}
 
     @pytest.mark.parametrize("plane", ["host", "device_pairs"])
     def test_prepare_sets_its_gauges(self, tmp_path, plane):
         _, _, _, snap = self._train(tmp_path, plane, False)
-        for part in ("dictionary", "sampler", "world", "tables", "trainer"):
+        for part in ("dictionary", "tokenizer", "sampler", "world",
+                     "tables", "trainer"):
             g = snap[f"we.prepare.{part}_s"]
             assert g["type"] == "gauge" and g["value"] >= 0.0
         made = snap["table.create_s"]
         assert made["count"] == 4       # input, output, two AdaGrad tables
         assert 0.0 < made["sum"] <= snap["we.prepare.tables_s"]["value"]
+        # the tokenizer's build is a part of the dictionary's lap
+        assert (snap["we.prepare.tokenizer_s"]["value"]
+                <= snap["we.prepare.dictionary_s"]["value"])
 
     @staticmethod
     def _window_drive(mv):

@@ -153,6 +153,151 @@ class TestBlockQueue:
         assert q.pop() is None
 
 
+def _sentences(path, d):
+    from multiverso_tpu.models.wordembedding.data import sentences_from_file
+    return [ids.tolist() for ids, _ in sentences_from_file(str(path), d)]
+
+
+def _counter(name):
+    from multiverso_tpu.telemetry import metrics
+    return metrics.counter(name).value
+
+
+class TestBuiltOncePerTrainer:
+    """What depends on prepare()'s products alone (the native tokenizer of
+    the dictionary, the block program of use_adagrad) is built once per
+    trainer: not once a pass, not once a train() call."""
+
+    PLANES = {"host": dict(is_pipeline=False),
+              "host_pipeline": dict(is_pipeline=True),
+              "device_plane": dict(device_plane=True, is_pipeline=False),
+              "device_pairs": dict(device_pairs=True, is_pipeline=False)}
+
+    def _trainer(self, tmp_path, **kw):
+        from multiverso_tpu.models.wordembedding.distributed import (
+            DistributedWordEmbedding)
+        corpus = tmp_path / "corpus.txt"
+        _make_corpus(str(corpus), n_sentences=120)
+        opt = Option(train_file=str(corpus),
+                     output_file=str(tmp_path / "vec.txt"),
+                     embedding_size=8, window_size=2, negative_num=3,
+                     min_count=1, epoch=3, data_block_size=4000,
+                     pair_batch_size=256, use_adagrad=True)
+        for k, v in kw.items():
+            setattr(opt, k, v)
+        return DistributedWordEmbedding(opt)
+
+    @pytest.mark.parametrize("plane", list(PLANES))
+    def test_train_builds_no_tokenizer_and_one_block_program(
+            self, tmp_path, plane):
+        from multiverso_tpu import native
+        from multiverso_tpu.telemetry import metrics
+        if native.lib() is None:
+            pytest.skip("native toolchain unavailable")
+        we = self._trainer(tmp_path, **self.PLANES[plane])
+        toks, progs = (_counter("we.tokenizer.builds"),
+                       _counter("we.block_program.builds"))
+        try:
+            we.prepare()
+            assert _counter("we.tokenizer.builds") == toks + 1
+            built = metrics.snapshot()["we.prepare.tokenizer_s"]["value"]
+            assert 0.0 < built <= metrics.snapshot()[
+                "we.prepare.dictionary_s"]["value"]
+            tok, step = we.dictionary._tokenizer, we._step
+            assert tok is not None
+            first = we.train()          # three passes over the corpus
+            programs = 0 if plane == "device_pairs" else 1
+            assert _counter("we.block_program.builds") == progs + programs
+            program = we._block_scan_cache
+            we.opt.epoch = 1
+            second = we.train()
+            assert np.isfinite(first) and np.isfinite(second)
+            # four passes and two train() calls later: the one table, the
+            # one step, the one block program
+            assert _counter("we.tokenizer.builds") == toks + 1
+            assert _counter("we.block_program.builds") == progs + programs
+            assert we.dictionary.tokenizer() is tok
+            assert we._step is step and we._block_scan_cache is program
+        finally:
+            we.close()
+
+    @pytest.mark.parametrize("mutate, rebuilt", [
+        (lambda d, p: d.Insert("w99", 3), True),
+        (lambda d, p: d.Insert("w3", 100), False),    # a count, no new id
+        (lambda d, p: d.RemoveWordsLessThan(25), True),    # some of the 20
+        (lambda d, p: d.build_from_corpus(p), True),
+    ], ids=["Insert_new_word", "Insert_known_word", "RemoveWordsLessThan",
+            "build_from_corpus"])
+    def test_mutator_cannot_leave_a_stale_tokenizer(self, tmp_path, mutate,
+                                                    rebuilt):
+        from multiverso_tpu import native
+        if native.lib() is None:
+            pytest.skip("native toolchain unavailable")
+        corpus = tmp_path / "corpus.txt"
+        _make_corpus(str(corpus), n_sentences=40)
+        extra = tmp_path / "extra.txt"
+        extra.write_text("w99 w98 w99 w0\n")
+        d = Dictionary()
+        d.build_from_corpus(str(corpus))
+        d.RemoveWordsLessThan(1)
+        tok = d.tokenizer()
+        assert tok is not None and d.tokenizer() is tok
+        before = _sentences(corpus, d)
+        mutate(d, str(extra))
+        assert d.Size() > 0
+        assert (d._tokenizer is None) == rebuilt
+        # the next reader tokenizes by the dictionary as it now stands
+        want = [[i for i in (d.GetWordIdx(t) for t in line.split())
+                 if i >= 0]
+                for line in (corpus.read_text() + extra.read_text())
+                .splitlines()]
+        both = tmp_path / "both.txt"
+        both.write_text(corpus.read_text() + extra.read_text())
+        assert _sentences(both, d) == [s for s in want if s]
+        assert (d.tokenizer() is tok) == (not rebuilt)
+        if not rebuilt:
+            assert _sentences(corpus, d) == before
+
+    def test_load_vocab_starts_without_a_tokenizer(self, tmp_path):
+        d = Dictionary()
+        for w, c in [("x", 10), ("y", 5)]:
+            d.Insert(w, c)
+        d.tokenizer()
+        path = str(tmp_path / "vocab.txt")
+        d.save_vocab(path)
+        assert Dictionary.load_vocab(path)._tokenizer is None
+
+    @pytest.mark.parametrize("plane", ["host", "device_pairs"])
+    def test_without_the_native_library_training_is_the_same(
+            self, tmp_path, monkeypatch, plane):
+        """No library: prepare() builds nothing, every pass looks words
+        up in python, and the job trains the very same stream."""
+        from multiverso_tpu import native
+        if native.lib() is None:
+            pytest.skip("native toolchain unavailable")
+        corpus = tmp_path / "corpus.txt"
+        _make_corpus(str(corpus), n_sentences=120)
+        with_lib = Dictionary()
+        with_lib.build_from_corpus(str(corpus))
+        want = _sentences(corpus, with_lib)
+        losses = {}
+        for library in (True, False):
+            if not library:
+                monkeypatch.setattr(native, "lib", lambda: None)
+            builds = _counter("we.tokenizer.builds")
+            we = self._trainer(tmp_path, epoch=2, **self.PLANES[plane])
+            try:
+                we.prepare()
+                losses[library] = we.train()
+                assert (_counter("we.tokenizer.builds")
+                        == builds + (1 if library else 0))
+                assert (we.dictionary.tokenizer() is None) == (not library)
+                assert _sentences(corpus, we.dictionary) == want
+            finally:
+                we.close()
+        assert losses[True] == losses[False]
+
+
 class TestEndToEnd:
     def test_skipgram_neg_trains_and_saves(self, tmp_path):
         opt, avg_loss = _run(tmp_path)
