@@ -1547,6 +1547,9 @@ class MatrixServerTable(ServerTable):
                              cat="server"):
                 ids = np.asarray(row_ids, np.int32).ravel()
                 self._check_ids(ids)
+                tmetrics.counter("table.device_fetch.rows").inc(len(ids))
+                tmetrics.counter("table.device_fetch.bytes").inc(
+                    len(ids) * self.num_cols * self.dtype.itemsize)
                 if nproc > 1:
                     gids = self.device_place_batch(ids)
             with ttrace.span("server.table.device_fetch.dispatch",
@@ -1570,8 +1573,13 @@ class MatrixServerTable(ServerTable):
     def device_apply_rows(self, row_ids, deltas,
                           option: Optional[AddOption] = None) -> None:
         """Apply a (device or host) delta batch to ``row_ids`` in place —
-        same validation and duplicate pre-combining as ProcessAdd.
-        Multi-process: collective; per-process batches merge on device."""
+        same validation and duplicate pre-combining as ProcessAdd: repeated
+        ids sum before the updater runs. A device-resident delta whose ids
+        repeat is combined ON the device (the host knows the duplicate
+        structure from the ids alone; the payload never leaves HBM); a
+        host numpy delta keeps the host combine. A distinct id set takes
+        neither. Multi-process: collective; per-process batches merge on
+        device."""
         nproc = multihost.world_size()
         with ttrace.span("server.table.device_apply", cat="server",
                          args=({"table_id": getattr(self, "table_id", -1)}
@@ -1580,22 +1588,60 @@ class MatrixServerTable(ServerTable):
                              cat="server"):
                 ids = np.asarray(row_ids, np.int32).ravel()
                 self._check_ids(ids)
+                inv = None      # set: combine on the device in the dispatch
+                positions = unique = len(ids)
                 if nproc > 1:
                     gids, gdeltas = self.device_place_batch(ids, deltas)
-                elif len(np.unique(ids)) != len(ids):
-                    # duplicates must pre-combine on the host (scatter
-                    # order is undefined — module docstring); costs a
-                    # device->host hop, so callers should dedupe their id
-                    # sets (block row sets are)
-                    host = np.asarray(deltas, self.dtype).reshape(
-                        len(ids), self.num_cols)
-                    ids, deltas = self._combine_duplicates(ids, host)
+                else:
+                    uniq = np.unique(ids)
+                    unique = len(uniq)
+                    if unique != positions:
+                        # duplicates must pre-combine (scatter order is
+                        # undefined — module docstring)
+                        with ttrace.span(
+                                "server.table.device_apply.combine",
+                                cat="server"):
+                            if isinstance(deltas, jax.Array):
+                                inv = np.searchsorted(uniq, ids).astype(
+                                    np.int32)
+                            else:
+                                host = np.asarray(deltas, self.dtype).reshape(
+                                    positions, self.num_cols)
+                                ids, deltas = self._combine_duplicates(ids,
+                                                                       host)
+                tmetrics.counter("table.device_apply.rows").inc(positions)
+                tmetrics.counter("table.device_apply.unique_rows").inc(unique)
+                tmetrics.counter("table.device_apply.bytes").inc(
+                    positions * self.num_cols * self.dtype.itemsize)
+                # bytes of delta copied to the host to combine repeats:
+                # none since the device combine; registered (at 0) so that
+                # a path which brings the copy back has a counter to step
+                tmetrics.counter("table.device_apply.d2h_bytes")
             with ttrace.span("server.table.device_apply.dispatch",
                              cat="server"):
                 opt = (option or AddOption()).as_jnp()  # five small copies
                 if nproc > 1:
                     self.state = self._update_rows_parts_j(
                         self.state, gids, gdeltas, opt)
+                    return
+                if inv is not None:
+                    # the merged-Add program: one segment-sum by the
+                    # host's inverse map, then the row update at the
+                    # unique count's POWER-OF-TWO bucket (as ProcessAddRun:
+                    # the count varies from batch to batch, the ladder's
+                    # quarter-octave rungs would each be a compile)
+                    bucket = max(8, 1 << (unique - 1).bit_length())
+                    uniq_p = np.full(bucket, -1, np.int32)
+                    uniq_p[:unique] = uniq
+                    # pad lanes: segment -1, which segment_sum drops
+                    inv_p, padded_deltas = _pad_row_batch(
+                        jnp.asarray(inv),
+                        deltas.reshape(positions, self.num_cols).astype(
+                            self.dtype),
+                        next_bucket(positions))
+                    self.state = self._merged_add_rows(
+                        self.state, jnp.asarray(uniq_p), padded_deltas,
+                        inv_p, opt)
                     return
                 padded_ids, padded_deltas = _pad_row_batch(
                     jnp.asarray(ids), jnp.asarray(deltas),
