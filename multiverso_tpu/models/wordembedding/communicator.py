@@ -14,6 +14,7 @@ from __future__ import annotations
 
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -136,7 +137,13 @@ class Communicator:
         rows = {}
         train = {}
         for name, table, ids in self._row_specs(input_rows, output_rows):
-            rows[name] = table.server().device_fetch_rows(ids)
+            srv = table.server()
+            # a fetch allocates its rows when it is dispatched: wait until
+            # this table's last apply has run and freed the last block's,
+            # or the host runs a block ahead and HBM holds two blocks' row
+            # sets (7.8 -> 11.3 GB at 1,048,500 words; PERF.md, PR 26)
+            jax.block_until_ready(srv.state)
+            rows[name] = srv.device_fetch_rows(ids)
             # the train step DONATES its state; the original must survive
             # for the delta push, so the state gets its own buffer
             train[name] = jnp.copy(rows[name])

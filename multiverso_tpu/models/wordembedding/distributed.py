@@ -304,6 +304,13 @@ class DistributedWordEmbedding:
         if not block.pair_count:
             return 0.0, 0
         import jax.numpy as jnp
+        st = block.stacked
+        # before the fetch, which may wait for the last block's applies
+        # (communicator.request_parameter_device): the copies ride under
+        # them
+        with ttrace.span("worker.we.upload", cat="worker"):
+            tensors = [jnp.asarray(st[k]) for k in (
+                "inputs", "input_mask", "outputs", "labels", "output_mask")]
         pre = getattr(block, "_prefetched", None)
         if pre is not None and not self.opt.device_plane:
             state, fetched = pre    # train() waited for it, under its span
@@ -319,10 +326,6 @@ class DistributedWordEmbedding:
                 else:
                     state, fetched = self.comm.request_parameter(
                         block.input_rows, block.output_rows)
-        st = block.stacked
-        with ttrace.span("worker.we.upload", cat="worker"):
-            tensors = [jnp.asarray(st[k]) for k in (
-                "inputs", "input_mask", "outputs", "labels", "output_mask")]
         with ttrace.span("worker.we.dispatch", cat="worker"):
             state, loss_dev = self._block_scan_fn(step)(
                 state, *tensors, jnp.float32(self._current_lr()))

@@ -34,6 +34,7 @@ contract for the others.
 from __future__ import annotations
 
 import functools
+import threading
 import time
 from dataclasses import dataclass
 from typing import Callable, Dict, Optional, Tuple
@@ -59,19 +60,30 @@ from multiverso_tpu.utils.log import CHECK, Log
 
 
 @functools.partial(jax.jit, static_argnames=("bucket",))
-def _pad_row_batch(ids: jax.Array, deltas: jax.Array, bucket: int):
-    """Pad an exact-size (ids, deltas) batch to its power-of-two bucket ON
-    DEVICE (pad lane = -1 -> trash row, pad delta = 0). The host sends
-    exact-size arrays — host->device wire bytes are what the protocol pays
-    for (the reference likewise ships only the partitioned row payloads,
-    matrix_table.cpp:235-296) — and this tiny jitted pad (one compile per
-    distinct batch size) expands to the handful of shapes the big row
-    program is compiled for."""
-    pad = bucket - ids.shape[0]
-    ids = jnp.concatenate([ids, jnp.full((pad,), -1, ids.dtype)])
-    deltas = jnp.concatenate(
+def _pad_row_batch(deltas: jax.Array, bucket: int):
+    """Pad an exact-size delta batch to its bucket ON DEVICE (pad delta =
+    0). The host sends exact-size DELTAS — host->device wire bytes are
+    what the protocol pays for (the reference likewise ships only the
+    partitioned row payloads, matrix_table.cpp:235-296), and a delta row is
+    512 B to 8 KB — and this tiny jitted pad (one compile per distinct
+    batch size) expands to the handful of shapes the big row program is
+    compiled for. Ids are 4 bytes a row: they are padded on the host
+    (``_device_ids``) and copied once. Call through ``_pad_rows``, which
+    runs no program when there is nothing to pad."""
+    pad = bucket - deltas.shape[0]
+    return jnp.concatenate(
         [deltas, jnp.zeros((pad, deltas.shape[1]), deltas.dtype)])
-    return ids, deltas
+
+
+def _pad_rows(deltas: jax.Array, bucket: int) -> jax.Array:
+    """``deltas`` at ``bucket`` rows: the array itself when the batch is
+    the bucket (a pad of nothing is still a dispatch, and a copy of its
+    operand — 1.34 GB for a whole-table delta), else ``_pad_row_batch``.
+    The row programs donate the state alone, so the caller's array
+    survives either way."""
+    if deltas.shape[0] == bucket:
+        return deltas
+    return _pad_row_batch(deltas, bucket)
 
 
 def _combine_duplicate_rows(ids: np.ndarray, deltas: np.ndarray,
@@ -93,12 +105,6 @@ def _combine_duplicate_rows(ids: np.ndarray, deltas: np.ndarray,
     combined[inverse[~dup_pos]] = deltas[~dup_pos]
     np.add.at(combined, inverse[dup_pos], deltas[dup_pos])
     return uniq.astype(np.int32), combined
-
-
-@functools.partial(jax.jit, static_argnames=("bucket",))
-def _pad_id_batch(ids: jax.Array, bucket: int):
-    pad = bucket - ids.shape[0]
-    return jnp.concatenate([ids, jnp.full((pad,), -1, ids.dtype)])
 
 
 # -- in-trace accumulators for the multi-process compressed window path ------
@@ -195,6 +201,16 @@ class MatrixServerTable(ServerTable):
         self._mesh = ctx.mesh
 
         self._sharding = ctx.sharding_rows()
+        # what the row programs declare for ids and option scalars (P()
+        # over the table's mesh): small operands are placed so, and the
+        # program reshards nothing on entry. Only a process that owns the
+        # whole mesh can place a replicated array by itself; otherwise
+        # they stay process-local, which jit takes as replicated.
+        self._replicated = (ctx.replicated()
+                            if local_device_count(ctx.mesh) == ctx.mesh.size
+                            else None)
+        self._opt_cache: Dict[tuple, Dict[str, jax.Array]] = {}
+        self._opt_lock = threading.Lock()
         # table.create_s: the initialiser, _to_storage and placement below
         # (which materialises the whole table on one device first)
         t_create = time.perf_counter()
@@ -650,22 +666,68 @@ class MatrixServerTable(ServerTable):
         nat = self._host_store()
         if nat is not None:
             return nat.get_rows(np.asarray(union_ids, np.int32))
-        padded = _pad_id_batch(jnp.asarray(np.asarray(union_ids, np.int32)),
-                               next_bucket(len(union_ids)))
-        rows = self._gather_rows(self.state["data"], self.state["aux"],
-                                 padded)
+        rows = self._gather_rows(
+            self.state["data"], self.state["aux"],
+            self._device_ids(np.asarray(union_ids, np.int32)))
         return np.asarray(self._zoo.mesh_ctx.fetch(rows[: len(union_ids)]))
 
     # -- helpers ------------------------------------------------------------
 
-    def _pad_ids(self, ids: np.ndarray) -> np.ndarray:
-        bucket = next_bucket(len(ids))
+    def _pad_ids(self, ids: np.ndarray,
+                 bucket: Optional[int] = None) -> np.ndarray:
+        if bucket is None:
+            bucket = next_bucket(len(ids))
         out = np.full(bucket, -1, np.int32)
         out[: len(ids)] = ids
         return out
 
     # public for device-plane callers (pad lane = -1 -> trash row)
     pad_ids = _pad_ids
+
+    def _place_small(self, host):
+        """Host array(s) -> the device in ONE copy, in the sharding the
+        row programs declare for ids and option scalars."""
+        if self._replicated is None:
+            return jax.tree.map(jnp.asarray, host)
+        return jax.device_put(host, self._replicated)
+
+    def _device_ids(self, ids: np.ndarray) -> jax.Array:
+        """A validated id vector padded ON THE HOST to its bucket (pad
+        lane = -1 -> trash row; at most a quarter of 4 bytes an id) and
+        copied once — no pad program, no resharding on entry."""
+        return self._place_small(self._pad_ids(ids))
+
+    #: distinct options whose device scalars a table keeps (FIFO): one a
+    #: worker under a fixed rule; a learning-rate schedule is a miss a step
+    _OPT_CACHE_SIZE = 64
+
+    def _device_opt(self, option: Optional[AddOption] = None
+                    ) -> Dict[str, jax.Array]:
+        """``option.as_jnp()`` (None = the default option) without the
+        five copies: the device scalars of each distinct option are built
+        once, in one ``device_put``, and kept. They stay TRACED arguments
+        of the row programs — a changed learning rate is a miss here,
+        never a retrace there."""
+        o = option or AddOption()
+        key = (int(o.worker_id), float(o.momentum), float(o.learning_rate),
+               float(o.rho), float(o.lambda_))
+        opt = self._opt_cache.get(key)
+        if opt is not None:
+            tmetrics.counter("table.option_cache.hits").inc()
+            return opt
+        tmetrics.counter("table.option_cache.misses").inc()
+        opt = self._place_small({
+            "worker_id": np.int32(key[0]),
+            "momentum": np.float32(key[1]),
+            "learning_rate": np.float32(key[2]),
+            "rho": np.float32(key[3]),
+            "lambda_": np.float32(key[4]),
+        })
+        with self._opt_lock:    # engine applies and device-plane verbs
+            while len(self._opt_cache) >= self._OPT_CACHE_SIZE:
+                del self._opt_cache[next(iter(self._opt_cache))]
+            self._opt_cache[key] = opt
+        return opt
 
     def _check_ids(self, ids: np.ndarray) -> None:
         CHECK(ids.size > 0, "empty row id set")
@@ -748,9 +810,8 @@ class MatrixServerTable(ServerTable):
                 # and every distinct bucket is a compile of this table's
                 # merged program — pow2 caps the shape set at log2(window)
                 # sizes, all warmable up front
-                bucket = max(8, 1 << (len(uniq) - 1).bit_length())
-                uniq_p = np.full(bucket, -1, np.int32)
-                uniq_p[: len(uniq)] = uniq
+                uniq_p = self._pad_ids(
+                    uniq, max(8, 1 << (len(uniq) - 1).bit_length()))
         with ttrace.span("server.table.add_run.dispatch", cat="server",
                          args=targs):
             if nat is not None:
@@ -785,7 +846,7 @@ class MatrixServerTable(ServerTable):
             # mv-lint: ok(cross-domain-state): same one-plane-per-table argument as the state getter — engine window applies and device-plane collective verbs never drive one table concurrently
             self.state = self._merged_add_rows(
                 self.state, jnp.asarray(uniq_p), jnp.asarray(deltas),
-                jnp.asarray(inv.astype(np.int32)), AddOption().as_jnp())
+                jnp.asarray(inv.astype(np.int32)), self._device_opt())
         # subclass bookkeeping fires per payload in message order, exactly
         # like the per-message path (SparseMatrixTable's freshness bits
         # must see every add's id set + worker attribution)
@@ -830,7 +891,7 @@ class MatrixServerTable(ServerTable):
             val_p[: len(val)] = val
             self.state = self._consume_sparse(
                 self.state, jnp.asarray(padded), jnp.asarray(idx_p),
-                jnp.asarray(val_p), option.as_jnp())
+                jnp.asarray(val_p), self._device_opt(option))
             self._note_wire(dense_bytes, idx_p.nbytes + val_p.nbytes)
         else:
             packed = np.asarray(comp["packed"], np.uint8)
@@ -842,7 +903,8 @@ class MatrixServerTable(ServerTable):
             neg[: len(ids)] = comp["neg"]
             self.state = self._consume_1bit(
                 self.state, jnp.asarray(padded), jnp.asarray(packed),
-                jnp.asarray(pos), jnp.asarray(neg), option.as_jnp())
+                jnp.asarray(pos), jnp.asarray(neg),
+                self._device_opt(option))
             self._note_wire(dense_bytes,
                             packed.nbytes + pos.nbytes + neg.nbytes)
 
@@ -915,7 +977,8 @@ class MatrixServerTable(ServerTable):
             return
         delta = self._zoo.mesh_ctx.place(self._to_storage(values),
                                          self._sharding)
-        self.state = self._update_full(self.state, delta, option.as_jnp())
+        self.state = self._update_full(self.state, delta,
+                                       self._device_opt(option))
         self._note_add_parts(option, parts)
 
     def _apply_merged_rows(self, ids: np.ndarray, deltas: np.ndarray,
@@ -930,12 +993,12 @@ class MatrixServerTable(ServerTable):
                 nat.add_rows(ids, deltas)
                 self._nat_dirty = True
             else:
-                # ship exact-size arrays; pad to the bucket on device
-                padded_ids, padded_deltas = _pad_row_batch(
-                    jnp.asarray(ids), jnp.asarray(deltas),
-                    next_bucket(len(ids)))
+                # ship exact-size deltas; pad them to the bucket on device
+                padded_ids = self._device_ids(ids)
                 self.state = self._update_rows(
-                    self.state, padded_ids, padded_deltas, option.as_jnp())
+                    self.state, padded_ids,
+                    _pad_rows(jnp.asarray(deltas), padded_ids.shape[0]),
+                    self._device_opt(option))
         self._note_add_parts(option, parts)
 
     # -- windowed-engine parts hooks (round 5; tables/base.py contract) -----
@@ -1074,10 +1137,9 @@ class MatrixServerTable(ServerTable):
                     cols=cols)
                 self._note_wire(dense_bytes,
                                 packed.nbytes + pos.nbytes + neg.nbytes)
-        union_p = np.full(bucket, -1, np.int32)
-        union_p[: len(union)] = union
-        self.state = self._update_rows(self.state, jnp.asarray(union_p),
-                                       combined, option.as_jnp())
+        self.state = self._update_rows(
+            self.state, jnp.asarray(self._pad_ids(union, bucket)), combined,
+            self._device_opt(option))
         # ONE rank-ordered note for the whole collective Add (sparse
         # freshness attributes each rank's part to its global worker)
         self._note_add_parts(option, rank_ids)
@@ -1141,12 +1203,11 @@ class MatrixServerTable(ServerTable):
             nat.add_rows(ids, deltas)
             self._nat_dirty = True
         else:
-            padded_ids, padded_deltas = _pad_row_batch(
-                jnp.asarray(ids), jnp.asarray(deltas),
-                next_bucket(len(ids)))
-            self.state = self._update_rows(self.state, padded_ids,
-                                           padded_deltas,
-                                           AddOption().as_jnp())
+            padded_ids = self._device_ids(ids)
+            self.state = self._update_rows(
+                self.state, padded_ids,
+                _pad_rows(jnp.asarray(deltas), padded_ids.shape[0]),
+                self._device_opt())
         # subclass bookkeeping fires per position in window order with
         # per-rank id sets (SparseMatrixTable freshness needs each add's
         # attribution), exactly like the per-position path
@@ -1208,7 +1269,7 @@ class MatrixServerTable(ServerTable):
         gids, gdeltas = self.device_place_batch(rank_ids[my_rank],
                                                 local_vals, bucket=bucket)
         self.state = self._update_rows_parts_j(self.state, gids, gdeltas,
-                                               opts[0].as_jnp())
+                                               self._device_opt(opts[0]))
         self._note_add_parts(opts[0], rank_ids)
 
     def ProcessAddRunPartsDevice(self, positions, my_rank: int) -> bool:
@@ -1266,7 +1327,7 @@ class MatrixServerTable(ServerTable):
         # linear contract: option scalars are ignored, exactly like the
         # merged host run's single default-option apply
         self.state = self._update_rows_parts_j(self.state, gids, gdeltas,
-                                               AddOption().as_jnp())
+                                               self._device_opt())
         for option, rank_ids in noted:
             self._note_add_parts(option, rank_ids)
         return True
@@ -1359,10 +1420,8 @@ class MatrixServerTable(ServerTable):
         if not union_list:
             return pos_ids        # every position failed validation
         union = np.unique(np.concatenate(union_list)).astype(np.int32)
-        padded_ids = _pad_id_batch(jnp.asarray(union),
-                                   next_bucket(len(union)))
         rows = self._gather_rows(self.state["data"], self.state["aux"],
-                                 padded_ids)
+                                 self._device_ids(union))
         host_rows = np.asarray(rows[: len(union)])
         for rank_ids in pos_ids:
             if isinstance(rank_ids, Exception):
@@ -1427,15 +1486,12 @@ class MatrixServerTable(ServerTable):
             # Get: gather the union with one identical program everywhere,
             # then slice this process's rows out of the union result
             union = union.astype(np.int32)
-            padded_ids = _pad_id_batch(jnp.asarray(union),
-                                       next_bucket(len(union)))
             rows = self._gather_rows(self.state["data"], self.state["aux"],
-                                     padded_ids)
+                                     self._device_ids(union))
             host_rows = self._zoo.mesh_ctx.fetch(rows[: len(union)])
             return host_rows[np.searchsorted(union, ids)]
-        padded_ids = _pad_id_batch(jnp.asarray(ids), next_bucket(len(ids)))
         rows = self._gather_rows(self.state["data"], self.state["aux"],
-                                 padded_ids)
+                                 self._device_ids(ids))
         # device-slice the pad off BEFORE fetching: only the requested rows
         # cross the (slow) host<->device link
         return self._zoo.mesh_ctx.fetch(rows[: len(ids)])
@@ -1477,10 +1533,8 @@ class MatrixServerTable(ServerTable):
             self._check_ids(ids)
             self._note_row_access(ids)
         with ttrace.span("server.table.get.dispatch", cat="server"):
-            padded_ids = _pad_id_batch(jnp.asarray(ids),
-                                       next_bucket(len(ids)))
             rows = self._gather_rows(self.state["data"], self.state["aux"],
-                                     padded_ids)
+                                     self._device_ids(ids))
             sliced = rows[: len(ids)]
             sliced.copy_to_host_async()
         return lambda: np.asarray(sliced)
@@ -1536,7 +1590,10 @@ class MatrixServerTable(ServerTable):
         return gids, place_parts(self._mesh, d, nproc)
 
     def device_fetch_rows(self, row_ids) -> jax.Array:
-        """Rows for ``row_ids`` as a DEVICE array (never leaves HBM).
+        """Rows for ``row_ids`` as a DEVICE array (never leaves HBM),
+        exactly ``len(row_ids)`` of them. The ids are padded to their
+        bucket on the host and copied once; when the batch IS its bucket
+        the gather's output is returned as it is (no slice program).
         Multi-process: collective; each process gets its own rows out of
         one merged SPMD gather round."""
         nproc = multihost.world_size()
@@ -1552,6 +1609,8 @@ class MatrixServerTable(ServerTable):
                     len(ids) * self.num_cols * self.dtype.itemsize)
                 if nproc > 1:
                     gids = self.device_place_batch(ids)
+                else:
+                    padded = self._pad_ids(ids)
             with ttrace.span("server.table.device_fetch.dispatch",
                              cat="server"):
                 if nproc > 1:
@@ -1564,11 +1623,10 @@ class MatrixServerTable(ServerTable):
                     # would claim replicated contents it doesn't have
                     start = multihost.world_rank() * bucket
                     return rows.addressable_data(0)[start: start + len(ids)]
-                padded = _pad_id_batch(jnp.asarray(ids),
-                                       next_bucket(len(ids)))
                 rows = self._gather_rows(self.state["data"],
-                                         self.state["aux"], padded)
-                return rows[: len(ids)]
+                                         self.state["aux"],
+                                         self._place_small(padded))
+                return rows if len(ids) == len(padded) else rows[: len(ids)]
 
     def device_apply_rows(self, row_ids, deltas,
                           option: Optional[AddOption] = None) -> None:
@@ -1578,8 +1636,14 @@ class MatrixServerTable(ServerTable):
         repeat is combined ON the device (the host knows the duplicate
         structure from the ids alone; the payload never leaves HBM); a
         host numpy delta keeps the host combine. A distinct id set takes
-        neither. Multi-process: collective; per-process batches merge on
-        device."""
+        neither. What the dispatch hands the device is one copy and one
+        call: the ids (and the inverse map of repeats) are padded on the
+        host and copied once, the option's scalars are the table's kept
+        ones (``_device_opt``), and a delta whose length is its bucket goes
+        to the row program as it is — no pad program runs; a shorter one
+        is padded on the device (``_pad_rows``). The delta is never
+        donated: the caller's array stays readable. Multi-process:
+        collective; per-process batches merge on device."""
         nproc = multihost.world_size()
         with ttrace.span("server.table.device_apply", cat="server",
                          args=({"table_id": getattr(self, "table_id", -1)}
@@ -1588,11 +1652,15 @@ class MatrixServerTable(ServerTable):
                              cat="server"):
                 ids = np.asarray(row_ids, np.int32).ravel()
                 self._check_ids(ids)
-                inv = None      # set: combine on the device in the dispatch
                 positions = unique = len(ids)
+                on_device = isinstance(deltas, jax.Array)
+                inv = None      # set: combine on the device in the dispatch
                 if nproc > 1:
                     gids, gdeltas = self.device_place_batch(ids, deltas)
                 else:
+                    if not on_device:
+                        deltas = np.asarray(deltas, self.dtype).reshape(
+                            positions, self.num_cols)
                     uniq = np.unique(ids)
                     unique = len(uniq)
                     if unique != positions:
@@ -1601,14 +1669,25 @@ class MatrixServerTable(ServerTable):
                         with ttrace.span(
                                 "server.table.device_apply.combine",
                                 cat="server"):
-                            if isinstance(deltas, jax.Array):
-                                inv = np.searchsorted(uniq, ids).astype(
-                                    np.int32)
+                            if on_device:
+                                # the merged-Add program: one segment-sum
+                                # by the host's inverse map (pad lanes:
+                                # segment -1, which segment_sum drops),
+                                # then the row update at the unique
+                                # count's POWER-OF-TWO bucket (as
+                                # ProcessAddRun: the count varies from
+                                # batch to batch, the ladder's
+                                # quarter-octave rungs would each be a
+                                # compile)
+                                inv = self._pad_ids(
+                                    np.searchsorted(uniq, ids))
+                                padded = self._pad_ids(uniq, max(
+                                    8, 1 << (unique - 1).bit_length()))
                             else:
-                                host = np.asarray(deltas, self.dtype).reshape(
-                                    positions, self.num_cols)
-                                ids, deltas = self._combine_duplicates(ids,
-                                                                       host)
+                                ids, deltas = self._combine_duplicates(
+                                    ids, deltas)
+                    if inv is None:
+                        padded = self._pad_ids(ids)
                 tmetrics.counter("table.device_apply.rows").inc(positions)
                 tmetrics.counter("table.device_apply.unique_rows").inc(unique)
                 tmetrics.counter("table.device_apply.bytes").inc(
@@ -1619,35 +1698,27 @@ class MatrixServerTable(ServerTable):
                 tmetrics.counter("table.device_apply.d2h_bytes")
             with ttrace.span("server.table.device_apply.dispatch",
                              cat="server"):
-                opt = (option or AddOption()).as_jnp()  # five small copies
+                opt = self._device_opt(option)
                 if nproc > 1:
                     self.state = self._update_rows_parts_j(
                         self.state, gids, gdeltas, opt)
                     return
+                if not on_device:
+                    # a host delta is shipped at its exact size
+                    deltas = jnp.asarray(deltas)
+                elif (deltas.shape != (positions, self.num_cols)
+                        or deltas.dtype != self.dtype):
+                    deltas = deltas.reshape(
+                        positions, self.num_cols).astype(self.dtype)
                 if inv is not None:
-                    # the merged-Add program: one segment-sum by the
-                    # host's inverse map, then the row update at the
-                    # unique count's POWER-OF-TWO bucket (as ProcessAddRun:
-                    # the count varies from batch to batch, the ladder's
-                    # quarter-octave rungs would each be a compile)
-                    bucket = max(8, 1 << (unique - 1).bit_length())
-                    uniq_p = np.full(bucket, -1, np.int32)
-                    uniq_p[:unique] = uniq
-                    # pad lanes: segment -1, which segment_sum drops
-                    inv_p, padded_deltas = _pad_row_batch(
-                        jnp.asarray(inv),
-                        deltas.reshape(positions, self.num_cols).astype(
-                            self.dtype),
-                        next_bucket(positions))
+                    padded, inv = self._place_small((padded, inv))
                     self.state = self._merged_add_rows(
-                        self.state, jnp.asarray(uniq_p), padded_deltas,
-                        inv_p, opt)
+                        self.state, padded,
+                        _pad_rows(deltas, inv.shape[0]), inv, opt)
                     return
-                padded_ids, padded_deltas = _pad_row_batch(
-                    jnp.asarray(ids), jnp.asarray(deltas),
-                    next_bucket(len(ids)))
-                self.state = self._update_rows(self.state, padded_ids,
-                                               padded_deltas, opt)
+                self.state = self._update_rows(
+                    self.state, self._place_small(padded),
+                    _pad_rows(deltas, len(padded)), opt)
 
     def raw(self) -> np.ndarray:
         """Logical-view snapshot (host numpy)."""
@@ -1684,13 +1755,10 @@ class MatrixServerTable(ServerTable):
         want_device = mode == "device" or (
             mode == "auto" and jax.default_backend() != "cpu")
         if want_device and device_legal:
-            def _pad(ids):
-                return _pad_id_batch(
-                    jnp.asarray(np.asarray(ids, np.int32)),
-                    next_bucket(len(ids)))
             return ssnap.MatrixSnapshot.device(
                 jnp.copy(self.state["data"]), self.state["aux"],
-                self._gather_rows, _pad, self.num_rows, self.num_cols)
+                self._gather_rows, self._device_ids, self.num_rows,
+                self.num_cols)
         return ssnap.MatrixSnapshot.host(self._full_logical())
 
     # -- aux (updater state) <-> logical layout, for the checkpoint driver --
@@ -1741,7 +1809,6 @@ class MatrixWorkerTable(WorkerTable):
         self._compress = compress
         self._onebit = None
         if compress == "1bit":
-            import threading
             from multiverso_tpu.utils.quantization import RowOneBitsFilter
             self._onebit = RowOneBitsFilter(num_rows, num_cols)
             self._onebit_lock = threading.Lock()

@@ -477,6 +477,34 @@ class TestEndToEnd:
         for w in hv:
             np.testing.assert_allclose(dv[w], hv[w], rtol=1e-3, atol=1e-4)
 
+    def test_device_plane_fetch_waits_for_the_tables_last_apply(
+            self, tmp_path, monkeypatch):
+        """One block's row sets in HBM at a time: before a table's rows are
+        fetched (and allocated), the host waits until that table's last
+        apply has run. A CPU run cannot read device memory, so the test
+        holds the order of the calls."""
+        import jax
+        from multiverso_tpu.tables.matrix_table import MatrixServerTable
+        events = []
+        wait, fetch = jax.block_until_ready, MatrixServerTable.device_fetch_rows
+
+        def waiting(x):
+            if isinstance(x, dict) and "data" in x:
+                events.append(("wait", id(x["data"])))
+            return wait(x)
+
+        def fetching(self, ids):
+            events.append(("fetch", id(self.state["data"])))
+            return fetch(self, ids)
+        monkeypatch.setattr(jax, "block_until_ready", waiting)
+        monkeypatch.setattr(MatrixServerTable, "device_fetch_rows", fetching)
+        _run(tmp_path, use_adagrad=True, device_plane=True,
+             is_pipeline=False, epoch=1)
+        fetches = [i for i, e in enumerate(events) if e[0] == "fetch"]
+        assert len(fetches) >= 8            # two blocks or more, four tables
+        for i in fetches:
+            assert events[i - 1] == ("wait", events[i][1])
+
     def test_device_plane_cbow_and_hs(self, tmp_path):
         """The device-plane path must serve every model variant (CBOW,
         hierarchical softmax), not just skipgram+NEG."""
