@@ -4,8 +4,8 @@
 Trains the WordEmbedding app (the BASELINE.json north star) twice through
 its normal entry points — ``Option.parse_args``,
 ``DistributedWordEmbedding(opt).run()``, ``.close()``, the three calls
-``models.wordembedding.distributed.main`` makes — at the width bench.py
-benchmarks: vocabulary 100,000, ``-size 128 -negative 5 -window 5
+``models.wordembedding.distributed.main`` makes — at the width the
+benchmark's WordEmbedding cells use: vocabulary 100,000, ``-size 128 -negative 5 -window 5
 -use_adagrad 1 -pair_batch 8192 -min_count 1``, on a corpus generated from
 a seed (four blocks of 130,000 words). Leg ``device_plane`` moves block
 rows through ``device_fetch_rows`` / ``device_apply_rows`` (XLA gather,

@@ -157,9 +157,9 @@ class HotPathFlagCacheChecker(Checker):
          r"feed_)|_ApplyPool\.(?:submit|_loop))",
          "pipelined exchange stage / apply pool"),
         (r"^ops/rows\.py$",
-         r"^(?:use_pallas|_forced_on|_pallas_eligible|dedup_rows|"
-         r"gather_rows|scatter_set_rows|update_rows|update_gather_rows|"
-         r"_update_gather_impl|_dense_run)",
+         r"^(?:use_pallas|_pallas_eligible|dedup_rows|gather_rows|"
+         r"scatter_set_rows|update_rows|update_gather_rows|_set_rows|"
+         r"_dense_run)",
          "row-op dispatch predicates run per verb"),
         (r"^tables/.*\.py$",
          r"\.(?:Add|Get|AddAsync|GetAsync)$|\._?[Aa]pply",

@@ -101,10 +101,10 @@ def MV_MultiAddAsync(ops, option=None, track: bool = True):
     ids, "values": deltas}`` for matrix, ``{"keys": k, "values": v}``
     for kv). The whole batch rides ONE engine mailbox message and one
     window admission, amortizing the per-verb round trip the blocking
-    path pays (~3k verbs/s GIL wall, PR 9 bench); per-table op order is
-    submission order, so the result is bit-identical to issuing the
-    Adds serially. Returns a ``MultiCall`` — ``Wait()`` blocks for the
-    replies. ``track=False`` is fire-and-forget (returns immediately
+    path pays (a mailbox hop a verb, behind the interpreter lock);
+    per-table op order is submission order, so the result is
+    bit-identical to issuing the Adds serially. Returns a ``MultiCall``
+    — ``Wait()`` blocks for the replies. ``track=False`` is fire-and-forget (returns immediately
     with nothing to wait on). The reference's worker talks to tables
     through coalescable Get/Add with an async buffer hand-off (PAPER.md
     ASyncBuffer); this is that idiom as a first-class verb."""

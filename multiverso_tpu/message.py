@@ -72,19 +72,35 @@ def to_controller(t: MsgType) -> bool:
     return int(t) > 32
 
 
+def _map_arrays(fn, result):
+    """``result`` with ``fn`` applied to every array leaf of its tuples
+    and lists; non-array leaves are shared."""
+    if isinstance(result, np.ndarray):
+        return fn(result)
+    if isinstance(result, tuple):
+        return tuple(_map_arrays(fn, r) for r in result)
+    if isinstance(result, list):
+        return [_map_arrays(fn, r) for r in result]
+    return result
+
+
 def copy_result(result):
     """Fresh buffers for a result served to more than one owner — a
     deduped Get's extra repliers (sync/server.py) or a worker-side
     cache hit (tables/base.py): callers own and may mutate their
-    result arrays, so every extra serving gets copies. Non-array
-    leaves are shared."""
-    if isinstance(result, np.ndarray):
-        return result.copy()
-    if isinstance(result, tuple):
-        return tuple(copy_result(r) for r in result)
-    if isinstance(result, list):
-        return [copy_result(r) for r in result]
-    return result
+    result arrays, so every extra serving gets copies."""
+    return _map_arrays(np.ndarray.copy, result)
+
+
+def own_result(result):
+    """``result`` with every read-only array leaf replaced by a copy:
+    what a server hands its FIRST owner may be a view of a buffer it
+    does not own (``np.asarray`` of a device array is read-only), while
+    the contract is that a caller owns and may mutate its result.
+    Writable leaves pass through untouched, so a result that is already
+    the caller's costs nothing."""
+    return _map_arrays(
+        lambda a: a if a.flags.writeable else a.copy(), result)
 
 
 _msg_id_counter = itertools.count(1)

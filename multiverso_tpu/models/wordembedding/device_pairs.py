@@ -92,8 +92,8 @@ class _LazyStats:
 
 # Module-level program cache: keyed by every static the program closes
 # over, so a fresh trainer instance (e.g. a second app run in the same
-# process — the bench's warm/timed pattern) reuses the compiled
-# executable instead of retracing per instance.
+# process — the benchmark's warm-up, then its timed ``train()``) reuses
+# the compiled executable instead of retracing per instance.
 _PROGRAM_CACHE = {}
 
 #: above this table size (bytes of one table), the adagrad scan body

@@ -3,8 +3,9 @@
 The reference's hot loops are the server's per-row updater application and
 the serialize/memcpy path (reference src/updater/updater.cpp:21-29 OpenMP
 loops; src/net/mpi_net.h:300-349 serialize memcpys). Here they are device
-kernels: Pallas row gather / scatter on TPU (one DMA per requested row,
-no full-table traffic), with an XLA fallback for CPU test meshes.
+programs: XLA's gather for row reads and, on TPU, a Pallas row-DMA scatter
+for row writes (only touched rows move), with XLA's scatter everywhere else
+(``rows.py`` holds the one decision).
 """
 
 from multiverso_tpu.ops.rows import (dedup_rows, gather_rows, padded_cols,

@@ -1,23 +1,41 @@
 """Backend dispatch for the row gather/scatter/update table ops.
 
-``use_pallas`` is governed by the ``use_pallas`` flag:
-``auto`` (default) — reads via XLA's native gather everywhere, writes via
-the coalesced Pallas DMA kernels on TPU (the measured-fastest split: TPU
-vector loads gather random 512B rows at ~100 GB/s while XLA scatter
-crawls at ~6 GB/s, so each half rides its fast lane); ``on`` — Pallas for
-every verb incl. the fused single-kernel RMW (interpreter mode off-TPU;
-used by tests); ``off`` — XLA only.
+One decision, made here and nowhere else:
 
-The XLA fallback relies on jit'd gather + ``.at[].set`` — on a CPU test
-mesh that is both correct and fast enough.
+* **reads** are XLA's native gather, ``jnp.take(..., mode="clip")``, on
+  every backend;
+* **writes** are the Pallas row-DMA kernel ``pallas_scatter_set_rows``
+  where ``use_pallas(data, ids)`` says so — a TPU backend, a row shape
+  Mosaic compiles (``_pallas_eligible``: a 4-byte dtype at exactly one
+  128-lane tile a row) and an id vector within ``SMEM_IDS_BYTES`` — and
+  XLA's ``.at[].set`` scatter otherwise;
+* a **dense run** (consecutive ids, detected at run time by
+  ``_dense_run``) takes one bulk slice -> combine -> update-slice where
+  ``_dense_backend_ok()`` (TPU only) and the caller allows it.
 
-Row DMAs slice HBM along the lane dim, and Mosaic compiles them only for
-rows of exactly one 128-lane tile of a 4-byte dtype (``_pallas_eligible``).
-The table layer pads its storage column dim to ``padded_cols``, which keeps
-tables of up to 128 columns on the kernels; wider ones ride XLA. The pad
-alone measured ~5.6x on the reference 1Mx50 row-op benchmark even for plain
-XLA (aligned rows vs 200-byte ragged rows), with the fused Pallas update
-another ~1.6x on top.
+This is the split every benchmark cell runs (``PERF_LEDGER.jsonl``'s
+``breakdown`` names ``pallas_scatter_set_rows`` and XLA's ``fusion``
+gathers, no other kernel). It was chosen in the rounds before the
+ledger (r02-r05, on a v5e, by that time's own microbenchmarks). One
+figure survives in a record the repo carries: XLA's gather of random
+512-byte rows at about 100 GB/s (``BENCH_r05.json``, the plug-in's
+``we_device_bound_note``; not the ledger). The rest — a per-row DMA
+gather several times slower than that, XLA's serialising scatter an
+order of magnitude under the DMA kernel — is in no record, so no
+figure is quoted. What the ledger does hold is the other end: at 2,048
+columns XLA scatters 8 KB rows at 120-215 GB/s (``PERF.md`` section 6,
+PR 25), and such tables are not eligible for the kernel.
+
+``-use_pallas`` is the hook around that decision, not a second design:
+``auto`` (default) as above; ``on`` lets the CPU suite run the one
+kernel in interpreter mode through a whole table (it drops the TPU
+condition and nothing else, so it selects no path ``auto`` cannot
+select on a chip); ``off`` lets an operator take every table off the
+kernel.
+
+The table layer pads its storage column dim to ``padded_cols``: tables
+of up to 128 four-byte columns are stored as one lane tile a row, which
+is what keeps them on the kernel and their rows aligned.
 """
 
 from __future__ import annotations
@@ -25,7 +43,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from multiverso_tpu.utils.configure import (GetFlag, MV_DEFINE_string,
+from multiverso_tpu.utils.configure import (MV_DEFINE_string,
                                             cached_str_flag)
 
 #: one constant feeds both the flag registration and the cached
@@ -33,14 +51,12 @@ from multiverso_tpu.utils.configure import (GetFlag, MV_DEFINE_string,
 _USE_PALLAS_DEFAULT = "auto"
 MV_DEFINE_string("use_pallas", _USE_PALLAS_DEFAULT,
                  "row-op kernels: auto (TPU only) / on / off")
-MV_DEFINE_string("matrix_pad_cols", "auto",
-                 "pad matrix storage cols to the 128-lane tile: auto/on/off")
-#: use_pallas/_forced_on run per row-op dispatch (every verb on the
-#: apply path) — listener-cached read, not a registry walk per call
+#: use_pallas runs per row-op dispatch (every verb on the apply path) —
+#: listener-cached read, not a registry walk per call
 _use_pallas_flag = cached_str_flag("use_pallas", _USE_PALLAS_DEFAULT)
 
 LANE = 128
-#: Pallas row kernels take the id vector as a SCALAR-PREFETCH operand in
+#: The Pallas row kernel takes the id vector as a SCALAR-PREFETCH operand in
 #: SMEM (1MB/core on v5e): a 262144-id batch (exactly 1MB of i32) OOM'd
 #: SMEM by its 1.1KB of spill slots. Id vectors above this BYTE budget
 #: (half of SMEM — headroom for spills/other scalars) route to the XLA
@@ -62,44 +78,33 @@ def _pallas_eligible(data) -> bool:
 
 
 def use_pallas(data=None, ids=None) -> bool:
+    """Whether a WRITE of ``rows[ids]`` into ``data`` takes the Pallas
+    kernel (module docstring). ``on`` drops the backend condition only:
+    the lowering constraints hold in every mode — an ineligible shape
+    would be a Mosaic compile error rather than a kernel choice."""
     if ids is not None and ids.shape[0] * 4 > SMEM_IDS_BYTES:
         return False   # id vector would overflow the SMEM prefetch
     mode = _use_pallas_flag()
-    if mode == "on":
-        # forced on (interpreter mode off-TPU; tests): still respect the
-        # lowering constraints — an ineligible shape would be a Mosaic
-        # compile error rather than a kernel choice
-        return data is None or _pallas_eligible(data)
     if mode == "off":
         return False
-    return (jax.default_backend() == "tpu"
+    return ((mode == "on" or jax.default_backend() == "tpu")
             and (data is None or _pallas_eligible(data)))
 
 
 def padded_cols(num_cols: int, itemsize: int = 4) -> int:
-    """Storage column count for a logical ``num_cols``, governed by the
-    ``matrix_pad_cols`` flag: ``auto``/``on`` — pad 4-byte dtypes up to the
-    128-lane tile; ``off`` — never. Aligned rows are what make the row hot
-    path fast (ragged 200-byte rows measured ~5.6x slower even on the plain
-    XLA path) and what the Pallas row-DMA kernels require. The pad trades
-    HBM capacity for alignment; padded columns hold zeros and every updater
-    is identity on a zero delta, so they stay zero."""
-    mode = str(GetFlag("matrix_pad_cols")).lower()
-    if mode == "off" or itemsize != 4:
+    """Storage column count for a logical ``num_cols``: 4-byte dtypes pad
+    up to the 128-lane tile. Aligned rows are what the Pallas row-DMA
+    kernel requires and what keeps XLA's row ops off ragged 200-byte
+    rows. The pad trades HBM capacity for alignment; padded columns hold
+    zeros and every updater is identity on a zero delta, so they stay
+    zero."""
+    if itemsize != 4:
         return num_cols
     return -(-num_cols // LANE) * LANE
 
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
-
-
-def _forced_on(data, ids=None) -> bool:
-    """``use_pallas=on`` (test mode): force the Pallas kernel for verbs
-    whose default path is XLA, so tests keep covering the kernels."""
-    if ids is not None and ids.shape[0] * 4 > SMEM_IDS_BYTES:
-        return False
-    return _use_pallas_flag() == "on" and _pallas_eligible(data)
 
 
 def dedup_rows(ids: jax.Array, deltas: jax.Array):
@@ -134,9 +139,10 @@ def dedup_rows(ids: jax.Array, deltas: jax.Array):
 def _dense_backend_ok() -> bool:
     """The dense-run lax.cond is a TPU-only optimization: on the CPU
     backend XLA fails to alias the donated table through a conditional
-    whose branches read-modify-write it — every call copies the whole
-    table (measured ~300x). TPU aliases it fine (measured: dense rounds
-    9-18 Gelem/s, random unharmed)."""
+    whose branches read-modify-write it, so every call copies the whole
+    table. On the chip it aliases (``lm_vocab_steps`` writes a whole
+    table's rows this way: ``dynamic_update_slice.1``, ``PERF.md``
+    section 5)."""
     return jax.default_backend() == "tpu"
 
 
@@ -150,10 +156,9 @@ def _dense_run(ids: jax.Array, n_rows: int):
     Lead-trash batches (a shard seeing the middle of a cross-shard run)
     and interior trash (dedup_rows output) route to the general path on
     purpose: the prefix form needs NO lane rolls — the slice lanes line
-    up with the batch lanes 1:1, which measured ~3x faster than the
-    roll-compensated general-segment variant on v5e (and rolls plus a
-    read-back slice defeated XLA's in-place aliasing of the table
-    buffer, turning every round into a whole-table copy)."""
+    up with the batch lanes 1:1 (rolls plus a read-back slice defeated
+    XLA's in-place aliasing of the table buffer, turning every round
+    into a whole-table copy)."""
     trash = n_rows - 1
     bucket = ids.shape[0]
     mine = ids != trash
@@ -166,53 +171,45 @@ def _dense_run(ids: jax.Array, n_rows: int):
     return ok, start, count
 
 
-def gather_rows(data: jax.Array, ids: jax.Array, *,
-                dense: bool = True) -> jax.Array:
+def gather_rows(data: jax.Array, ids: jax.Array) -> jax.Array:
     """rows[i] = data[ids[i]]; all ids must be in range (caller maps
     out-of-shard lanes to the trash row). Trash/pad lanes may return
     ARBITRARY row content — every caller masks or trash-routes them.
 
-    Reads ride XLA's native gather (``mode='clip'`` — the jnp default
-    'fill' adds an out-of-bounds select measured 3x slower on v5e).
-    ``use_pallas=on`` still forces the Pallas kernel so tests cover it.
+    Reads ride XLA's native gather on every backend (``mode='clip'``:
+    the jnp default 'fill' adds an out-of-bounds select the in-range
+    contract makes needless).
 
     NO dense-run cond here, deliberately: a lax.cond over a LIVE
     (non-donated) table defeats XLA's buffer aliasing — each branch gets
-    an operand copy of the whole table (measured ~150x on the CPU
-    backend: 512MB copied per Get). The dense bulk-slice fast path lives
-    only in the verbs that consume/donate the table (scatter_set_rows,
-    update_rows, update_gather_rows), where the in-place chain survives
-    the cond. ``dense`` is accepted for signature symmetry."""
-    del dense
-    if _forced_on(data, ids):
-        from multiverso_tpu.ops.pallas_rows import pallas_gather_rows
-        return pallas_gather_rows(data, ids, interpret=_interpret())
+    an operand copy of the whole table. The dense bulk-slice fast path
+    lives only in the verbs that consume/donate the table
+    (scatter_set_rows, update_rows, update_gather_rows), where the
+    in-place chain survives the cond."""
     return jnp.take(data, ids, axis=0, mode="clip")
+
+
+def _set_rows(data, ids, rows, pallas_write: bool):
+    """The general write: the Pallas row-DMA kernel or XLA's scatter."""
+    if pallas_write:
+        from multiverso_tpu.ops.pallas_rows import pallas_scatter_set_rows
+        return pallas_scatter_set_rows(data, ids, rows,
+                                       interpret=_interpret())
+    return data.at[ids].set(rows)
 
 
 def scatter_set_rows(data: jax.Array, ids: jax.Array,
                      rows: jax.Array, *, dense: bool = True) -> jax.Array:
     """data[ids[i]] = rows[i]; duplicates only on the trash row.
 
-    Writes are the mirror image of reads on TPU: XLA's scatter measured
-    ~3-6 GB/s (it serializes), while the Pallas row-DMA kernel does
-    ~30 GB/s random (17ns/row DMA-issue floor on v5e) and 60-200 GB/s
-    on coalesced contiguous runs — so writes keep the Pallas path
-    wherever it is eligible. A runtime-detected dense run takes the bulk
-    slice-merge-update path (~300 GB/s r+w) instead."""
-    if _forced_on(data, ids):
-        # test mode: keep the Pallas kernel covered even for dense runs
-        from multiverso_tpu.ops.pallas_rows import pallas_scatter_set_rows
-        return pallas_scatter_set_rows(data, ids, rows,
-                                       interpret=_interpret())
-    fallback_pallas = use_pallas(data, ids)
+    Writes are the mirror image of reads on TPU: XLA's scatter
+    serialises over one-tile rows, so they keep the Pallas row-DMA
+    kernel wherever ``use_pallas`` admits it. A runtime-detected dense
+    run takes the bulk slice-merge-update path instead."""
+    pallas_write = use_pallas(data, ids)
 
     def general(_):
-        if fallback_pallas:
-            from multiverso_tpu.ops.pallas_rows import pallas_scatter_set_rows
-            return pallas_scatter_set_rows(data, ids, rows,
-                                           interpret=_interpret())
-        return data.at[ids].set(rows)
+        return _set_rows(data, ids, rows, pallas_write)
 
     if (not dense or not _dense_backend_ok()
             or ids.shape[0] >= data.shape[0]):
@@ -235,25 +232,17 @@ def scatter_set_rows(data: jax.Array, ids: jax.Array,
 def update_rows(data: jax.Array, ids: jax.Array, deltas: jax.Array,
                 combine, *, dense: bool = True) -> jax.Array:
     """data[ids[i]] = combine(data[ids[i]], deltas[i]) — the server-side
-    Add for aux-free elementwise updaters. ``combine`` must satisfy
-    combine(rows, 0) == rows (see pallas_rows contract) and be
-    identity-stable (one object per table) so the jit cache holds.
+    Add for aux-free elementwise updaters. ``combine`` must be a
+    jax-traceable elementwise fn with combine(rows, 0) == rows (pad and
+    foreign lanes rely on it) and be identity-stable (one object per
+    table) so the jit cache holds.
 
-    Default TPU path is the HYBRID: XLA vector-gather for the read half
-    (clip mode, see gather_rows), combine fused elementwise, and the
-    Pallas scatter for the write half. A runtime-detected dense run
-    instead does ONE bulk dynamic_slice -> combine -> dynamic_update_slice
-    (~290 GB/s r+w measured v5e — the 64-row chunk DMAs can't touch bulk
-    copies). ``use_pallas=on`` forces the fused single-kernel RMW so
-    tests cover it; the XLA fallback is gather + combine + scatter."""
-    if _forced_on(data, ids):
-        from multiverso_tpu.ops.pallas_rows import pallas_update_rows
-        return pallas_update_rows(data, ids, deltas, combine,
-                                  interpret=_interpret())
-    # ONE implementation with update_gather_rows: the dropped rows output
-    # is an intermediate both branches compute anyway (zero extra work)
-    return _update_gather_impl(data, ids, deltas, combine,
-                               use_pallas(data, ids), dense)[0]
+    One implementation with ``update_gather_rows``: XLA's gather for the
+    read half, combine fused elementwise, ``_set_rows`` for the write
+    half; a runtime-detected dense run instead does ONE bulk
+    dynamic_slice -> combine -> dynamic_update_slice. The dropped rows
+    output is an intermediate both branches compute anyway."""
+    return update_gather_rows(data, ids, deltas, combine, dense=dense)[0]
 
 
 def update_gather_rows(data: jax.Array, ids: jax.Array, deltas: jax.Array,
@@ -264,17 +253,7 @@ def update_gather_rows(data: jax.Array, ids: jax.Array, deltas: jax.Array,
     round pays two). Returns (new_data, rows); trash/pad lanes of
     ``rows`` are arbitrary (callers mask). Dense runs ride the bulk
     slice path end to end."""
-    if _forced_on(data, ids):
-        from multiverso_tpu.ops.pallas_rows import pallas_update_rows
-        new_data = pallas_update_rows(data, ids, deltas, combine,
-                                      interpret=_interpret())
-        return new_data, jnp.take(new_data, ids, axis=0, mode="clip")
-    return _update_gather_impl(data, ids, deltas, combine,
-                               use_pallas(data, ids), dense)
-
-
-def _update_gather_impl(data, ids, deltas, combine, pallas_write,
-                        allow_dense):
+    pallas_write = use_pallas(data, ids)
     bucket = ids.shape[0]
     trash = data.shape[0] - 1
 
@@ -291,15 +270,9 @@ def _update_gather_impl(data, ids, deltas, combine, pallas_write,
     def general(_):
         rows = jnp.take(data, ids, axis=0, mode="clip")
         new = combine(rows, deltas)
-        if pallas_write:
-            from multiverso_tpu.ops.pallas_rows import pallas_scatter_set_rows
-            out = pallas_scatter_set_rows(data, ids, new,
-                                          interpret=_interpret())
-        else:
-            out = data.at[ids].set(new)
-        return out, new
+        return _set_rows(data, ids, new, pallas_write), new
 
-    if (not allow_dense or not _dense_backend_ok()
+    if (not dense or not _dense_backend_ok()
             or bucket >= data.shape[0]):
         return general(None)   # static guards (see gather_rows)
     ok, start, _ = _dense_run(ids, data.shape[0])

@@ -40,7 +40,7 @@ additionally require a per-table opt-in via ``-mv_compress_lossy``
 (comma-separated table ids, or ``all``), so KV/sparse tables stay
 lossless by default. Telemetry: ``compress.pre_bytes.<path>`` /
 ``compress.post_bytes.<path>`` counters per hot path (``replica`` /
-``window`` / ``serve``) feed bench.py's bytes-ceiling ratchets.
+``window`` / ``serve``), served by ``/metrics``.
 
 Lossy determinism contract: decode(encode(x)) is a pure function of the
 envelope BYTES — no host state, no float environment dependence beyond

@@ -117,17 +117,12 @@ _owns_runtime = False   # True only when WE called jax.distributed.initialize
 #: mesh.fetch's reassembly allgather). The r4 verdict's scale-out
 #: critique was "one host collective per table verb"; the windowed
 #: engine protocol (sync/server.py) is judged by THIS counter per verb
-#: (bench two_proc_collectives_per_op). XLA-level collectives (psum
-#: etc. inside jit programs) ride ICI and are deliberately not counted
-#: — they are the fast path, not the protocol cost.
-STATS = {"host_collective_rounds": 0,
-         #: wall seconds spent inside capped_exchange (the windowed
-         #: engine's one host-collective path) — lets the bench decompose
-         #: the 2-proc cost into protocol rounds vs shared-core compute.
-         #: Wire encode/decode timing moved to the telemetry histograms
-         #: server.wire.{encode,decode}_s (telemetry/metrics.py) — the
-         #: bench reads those from MV_MetricsSnapshot now.
-         "exchange_seconds": 0.0}
+#: (tests/test_windowed_multihost.py, test_serving.py, test_replica.py).
+#: XLA-level collectives (psum etc. inside jit programs) ride ICI and
+#: are deliberately not counted — they are the fast path, not the
+#: protocol cost. Seconds inside an exchange are the phase stamps'
+#: (_stamp_exchange) and the histograms server.wire.{encode,decode}_s.
+STATS = {"host_collective_rounds": 0}
 
 
 def note_collective(n: int = 1) -> None:
@@ -467,23 +462,6 @@ def close_wire() -> None:
         w.close()
 
 
-class wire_bypass:
-    """Bench/drill helper: run the body on the RAW gloo collective
-    path while a host wire is installed (the A/B the shm/tcp-vs-gloo
-    bench rows need). COLLECTIVE discipline applies: every rank must
-    enter and exit at the same stream position, or the two transports'
-    streams interleave divergently."""
-
-    def __enter__(self):
-        global _wire
-        self._saved = _wire
-        _wire = None
-        return self
-
-    def __exit__(self, *exc):
-        global _wire
-        _wire = self._saved
-
 #: collective isolation (elastic rebuild_world): the host-byte exchange
 #: layer answers as a single-member world while a transition fence
 #: rebuilds tables — constructors re-run boot-time agreement
@@ -761,8 +739,8 @@ def _enable_cpu_collectives() -> None:
     ``'none'``, under which EVERY multi-process computation — including
     the ``device_put`` equality check inside table creation — fails with
     "Multiprocess computations aren't implemented on the CPU backend";
-    a 2-process CPU world (tests, single-host bring-up, the bench's
-    subprocess children) therefore needs gloo. Only applies when the job
+    a 2-process CPU world (tests, single-host bring-up) therefore needs
+    gloo. Only applies when the job
     explicitly targets CPU (``jax_platforms``/``JAX_PLATFORMS``): TPU
     pods keep their platform default."""
     import jax
@@ -992,7 +970,6 @@ def capped_exchange(blob: bytes, caps: dict, key, channel: int = 0) -> list:
         out = _wire.exchange(blob, channel)
         _done_m, _done_w = _time.perf_counter(), _time.time()
         _stamp_exchange(_t0, _done_m - _t0, _done_m, _done_w)
-        STATS["exchange_seconds"] += _done_m - _t0
         return out
     CHECK(channel == 0,
           "gloo host wire has ONE collective stream — channel "
@@ -1021,7 +998,6 @@ def capped_exchange(blob: bytes, caps: dict, key, channel: int = 0) -> list:
     caps[key] = next_bucket(max(lens) + 9, min_bucket=4096)
     if all(fits):
         _stamp_exchange(_t0, coll_s, _done_m, _done_w)
-        STATS["exchange_seconds"] += _time.perf_counter() - _t0
         return [gathered[i, 9:9 + lens[i]].tobytes()
                 for i in range(process_count())]
     # overflow: one more round at the (now agreed) ladder cap
@@ -1037,7 +1013,6 @@ def capped_exchange(blob: bytes, caps: dict, key, channel: int = 0) -> list:
     _done_m, _done_w = _time.perf_counter(), _time.time()
     coll_s += _done_m - _tc
     _stamp_exchange(_t0, coll_s, _done_m, _done_w)
-    STATS["exchange_seconds"] += _time.perf_counter() - _t0
     return [gathered2[i, : lens[i]].tobytes()
             for i in range(process_count())]
 

@@ -5,8 +5,8 @@ picks between a ZMQ socket wire and MPI collectives per deployment
 (PAPER.md L2, allreduce_engine.cpp). The TPU build's equivalent split:
 ``multihost.capped_exchange`` is the engine's one host-byte collective,
 and gloo (a socket allgather) is its only implementation — measured at
-~410 MB/s between two processes of the SAME machine (bench
-``matrix_table_2proc_host_exchange_MB_s``), i.e. the window wire pays
+~410 MB/s between two processes of the SAME machine (a CPU-backend
+measurement of round 9, not a chip number), i.e. the window wire pays
 socket-stack prices for what is physically a memcpy. This module is
 the same-host transport: every rank owns one POSIX shared-memory
 segment per (channel, rank) and an exchange round is N-1 memcpys in,
@@ -211,7 +211,7 @@ class ShmWire:
         #: the failsafe wire's seal trailer (parallel/seal.py,
         #: verified BEFORE parsing), and a second full-blob pass costs
         #: real bandwidth — zlib.crc32 MEASURED at ~0.8 GB/s on this
-        #: host class (PR 9 bench; slower than the memcpy it would
+        #: host class (round 9, CPU host; slower than the memcpy it would
         #: guard). Round 19: the pass now rides seal.fast_crc
         #: (hardware CRC32C, ~8x zlib here), so payload_crc=True is
         #: merely cheap rather than bandwidth-halving — the engine

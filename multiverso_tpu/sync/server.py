@@ -65,15 +65,15 @@ MV_DEFINE_int("backup_worker_ratio", 0, "ratio% of backup workers (dead flag, pa
 # device-parts collectives (place_parts + one traced program; on a pod
 # that is ICI at fabric bandwidth). "auto": per-verb by payload size
 # against -window_device_min_bytes. The default threshold sits just
-# above this repo's MEASURED single-host crossover (bench.py transport
-# profile: one host window round costs ~1.6 ms latency + bytes at
-# ~350-410 MB/s, while one device-parts round costs a FIXED ~14-15 ms
-# floor on the CPU backend — per-call jit dispatch + gloo collectives
-# over padded parts buffers — so the device wire only wins past ~4-6 MB
-# per window, which a 4 MB-budget window barely reaches). A POD
-# deployment, where the device wire moves 100+ GB/s with ~us dispatch,
-# should run -window_transport=device (or drop the threshold to ~1 MB)
-# — see docs/BENCHMARK.md "transport selection".
+# above a single-host crossover that is a CPU-backend measurement
+# (gloo), not a chip number; see ROADMAP.md D2/S7: one host window round
+# cost ~1.6 ms latency + bytes at ~350-410 MB/s, while one device-parts
+# round cost a FIXED ~14-15 ms floor on the CPU backend — per-call jit
+# dispatch + gloo collectives over padded parts buffers — so the device
+# wire only won past ~4-6 MB per window, which a 4 MB-budget window
+# barely reaches. No cell exchanges windows between processes, so the
+# chip's crossover is not measured; a pod deployment, whose device wire
+# is ICI, would want -window_transport=device or a lower threshold.
 # each constant feeds both the flag registration and the cached
 # accessor's fallback, so the two defaults cannot drift apart
 _WINDOW_TRANSPORT_DEFAULT = "auto"
@@ -1504,8 +1504,8 @@ class Server(Actor):
     # server.cpp:23-58: requests fan out and apply as they arrive)
     # under the SPMD collective contract: every process still issues
     # the same verb sequence, but now pays ~2 host rounds per WINDOW
-    # instead of ~2 per verb (multihost.STATS counts them; bench
-    # two_proc_collectives_per_op is the metric).
+    # instead of ~2 per verb (multihost.STATS counts them;
+    # tests/test_windowed_multihost.py holds the count).
     #
     # Ordering semantics match the single-process window: a table's
     # window Adds apply at its FIRST Add position (a Get queued after
@@ -1524,7 +1524,8 @@ class Server(Actor):
     # on the host staging allgather; large eligible payloads ship only
     # their dtype/shape metadata and the VALUES ride the table's
     # device-parts collectives (-window_transport /
-    # -window_device_min_bytes; bench.py measures the crossover).
+    # -window_device_min_bytes; the crossover behind the default is a
+    # CPU-backend measurement, see the flags' comment above).
 
     def _mh_windows(self, batch) -> None:
         """Process drained messages through collective windows until
@@ -1915,7 +1916,6 @@ class Server(Actor):
             # graph walk + buffer copies were pure overhead for payloads
             # that are already contiguous arrays; decode below is
             # zero-copy. server.wire.encode_s times the CODEC only
-            # (bench compares it against the pickled baseline)
             _t0 = _time.perf_counter()
             blob = wire.encode_window(local, seq=self._mh_seq)
             _enc_s = _time.perf_counter() - _t0

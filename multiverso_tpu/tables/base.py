@@ -37,7 +37,7 @@ import numpy as np
 from multiverso_tpu.failsafe import deadline as fdeadline
 from multiverso_tpu.failsafe.errors import TransientError
 from multiverso_tpu.message import (Message, MsgType, copy_result,
-                                    next_msg_id)
+                                    next_msg_id, own_result)
 from multiverso_tpu.parallel.wire import payload_nbytes
 from multiverso_tpu.telemetry import metrics as tmetrics
 from multiverso_tpu.telemetry import trace as ttrace
@@ -384,6 +384,10 @@ class MultiCall:
                 tmetrics.digest("digest.worker.rtt_s").observe(
                     time.perf_counter() - self._t0)
                 self._t0 = None
+        # every member owns its result whichever branch served it: a
+        # device-served Get is a read-only view of the device buffer
+        # (here, on the caller's thread, not in the engine's reply)
+        self._results[:] = [own_result(r) for r in self._results]
         if not return_exceptions:
             for r in self._results:
                 if isinstance(r, Exception):

@@ -288,9 +288,8 @@ class MatrixServerTable(ServerTable):
         def _scatter_aux(aux, new_aux, safe):
             def s(leaf, new_leaf):
                 if leaf.ndim == 2:
-                    # row-shaped aux (momentum smooth, 2-D hist) writes ride
-                    # the same coalesced Pallas scatter as data rows — XLA's
-                    # scatter measured ~25x slower on TPU (rows.py)
+                    # row-shaped aux (momentum smooth, 2-D hist) writes take
+                    # the same write path as data rows (ops/rows.py)
                     return ops.scatter_set_rows(leaf, safe, new_leaf,
                                                 dense=single)
                 return leaf.at[:, safe].set(new_leaf)
@@ -304,8 +303,9 @@ class MatrixServerTable(ServerTable):
         self._update_full = jax.jit(_update_full, donate_argnums=(0,))
 
         # Fused path: aux-free elementwise updaters (default add, sgd) run
-        # the whole server-side Add as ONE read-modify-write kernel over the
-        # touched rows (ops.update_rows) — no separate gather/scatter.
+        # the whole server-side Add as ops.update_rows over the touched
+        # rows: gather, ``combine`` fused elementwise, one write — no aux
+        # gather/scatter and no updater.update call.
         # Foreign lanes carry their real deltas into this shard's trash row,
         # which therefore accumulates garbage; that's fine solely because
         # the trash row is don't-care (never read back: Get masks non-mine
@@ -326,7 +326,7 @@ class MatrixServerTable(ServerTable):
             if fuse:
                 return ops.update_rows(local_data, safe, deltas,
                                        combine, dense=single), local_aux
-            rows = ops.gather_rows(local_data, safe, dense=single)
+            rows = ops.gather_rows(local_data, safe)
             aux_rows = _gather_aux(local_aux, safe)
             new_rows, new_aux_rows = updater.update(rows, aux_rows, deltas,
                                                     opt)
@@ -430,7 +430,7 @@ class MatrixServerTable(ServerTable):
 
         def _gather_rows_local(local_data, local_aux, ids):
             mine, safe = _local_lanes(ids)
-            rows = ops.gather_rows(local_data, safe, dense=single)
+            rows = ops.gather_rows(local_data, safe)
             if has_access:
                 rows = updater.access(rows, _gather_aux(local_aux, safe),
                                       None)
@@ -478,7 +478,7 @@ class MatrixServerTable(ServerTable):
                 # — reuse them instead of a second full gather (duplicates
                 # are caller-pre-combined, so per-lane new_rows are exact;
                 # trash lanes are garbage and masked below)
-                rows_in = ops.gather_rows(local_data, safe, dense=single)
+                rows_in = ops.gather_rows(local_data, safe)
                 aux_rows = _gather_aux(local_aux, safe)
                 rows, new_aux_rows = updater.update(rows_in, aux_rows,
                                                     deltas, opt)
@@ -909,8 +909,9 @@ class MatrixServerTable(ServerTable):
                             packed.nbytes + pos.nbytes + neg.nbytes)
 
     def _note_wire(self, dense_bytes: int, payload_bytes: int) -> None:
-        """Record one compressed payload's wire economics, locally (the
-        bench's wire_reduction metric) and in the telemetry registry."""
+        """Record one compressed payload's wire economics, locally
+        (``wire_stats``, which the tests read) and in the telemetry
+        registry."""
         from multiverso_tpu.telemetry import metrics as tmetrics
         self.wire_stats["dense_bytes"] += dense_bytes
         self.wire_stats["payload_bytes"] += payload_bytes
@@ -1541,7 +1542,7 @@ class MatrixServerTable(ServerTable):
 
     # -- eager device plane (public) ----------------------------------------
     # device_gather_rows / device_update_rows above are the TRACEABLE hooks
-    # (scan them into a jit'd step — bench.py, examples/device_plane.py);
+    # (scan them into a jit'd step — examples/device_plane.py);
     # these two are their eager siblings for callers that want per-block
     # dispatch with host-plane validation but no host round-trip of the
     # row data (e.g. the WordEmbedding communicator's -device_plane path).

@@ -8,9 +8,9 @@ allgathers would interleave with the engine's window exchanges and
 corrupt the SPMD stream; job-wide totals come from the explicitly
 collective ``MV_MetricsSnapshot()`` instead.
 
-``write_snapshot_sidecar`` serializes a snapshot next to a bench/run
-artifact (bench.py writes docs/TELEMETRY_latest.json beside
-BENCH_FULL_latest.json every run).
+``write_snapshot_sidecar`` serializes a snapshot next to a run's other
+artifacts (the ops plane's ``-mv_diag_dir`` dump writes
+``telemetry_rank<r>.json`` with it, telemetry/ops.py).
 """
 
 from __future__ import annotations

@@ -13,9 +13,8 @@ representation):
 * :func:`build_rollup` snapshots the process's mergeable digest
   vectors (telemetry/metrics.py ``Digest``) plus key gauges into one
   compact dict and :func:`encode_rollup` frames it with the sealed
-  flat codec — a couple of KB per heartbeat at worst (the bench
-  freezes ``fleet_rollup_bytes_per_hb`` as a ratcheted byte ceiling),
-  never collective;
+  flat codec — a couple of KB per heartbeat at worst, never
+  collective;
 * the blob rides EXISTING lease traffic — ``replica_hb`` for reader
   processes, the elastic member heartbeat for trainer ranks, the
   fan-out owner's ``replica_roster`` tick for rank 0 — so the plane

@@ -78,8 +78,8 @@ class Updater:
 
     name = "default"
     #: True when the rule is a pure elementwise fn of (data, delta) — no aux,
-    #: no opt, identity on zero delta — so the row path may use the fused
-    #: read-modify-write kernel (ops.update_rows) via ``combine``. Defaults
+    #: no opt, identity on zero delta — so the row path may run it as
+    #: ``combine`` inside ops.update_rows (no aux round). Defaults
     #: to False so a subclass overriding ``update()`` is never silently
     #: replaced by the inherited '+=' combine on the row path; opt in by
     #: setting True AND overriding ``combine`` to match ``update``.

@@ -1,6 +1,6 @@
 """Where compiled programs are kept between processes.
 
-Entry points (the app ``main()``s, ``bench.py``, ``chip_smoke.py``) call
+Entry points (the app ``main()``s, ``chip_smoke.py``, ``benchmark/run.py``) call
 :func:`enable` before the first backend use; ``MV_Init`` does not, so a
 library user's own JAX configuration is left alone.
 """

@@ -3,8 +3,8 @@
 This stands in for the reference's 1-process MPI world fixture
 (reference Test/unittests/multiverso_env.h:10-29) — the whole PS path runs
 in-process, but over a *real* 8-device jax mesh so sharding/collective code
-paths are exercised without TPU hardware. Bench runs (bench.py) use the real
-chip instead.
+paths are exercised without TPU hardware. The benchmark (``benchmark/run.py``)
+and ``chip_smoke.py`` use the real chip instead.
 """
 
 import os
@@ -19,7 +19,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 # The environment variable is read when jax is imported; if something
 # imported jax before this file ran, only the config switch still applies.
-# Tests never touch a chip: chip_smoke.py and bench.py own it.
+# Tests never touch a chip: chip_smoke.py and benchmark/run.py own it.
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
@@ -45,6 +45,21 @@ def _hang_guard():
     faulthandler.dump_traceback_later(_HANG_DUMP_S, exit=False)
     yield
     faulthandler.cancel_dump_traceback_later()
+
+
+@pytest.fixture()
+def off_host_mirror():
+    """``off(table) -> table`` with its server taken off the CPU
+    backend's native host mirror, so that host verbs run the row programs
+    the chip runs (the chip has no mirror; ROADMAP.md D8). Eligibility is
+    read at the first host verb, when the store is created: call it right
+    after ``MV_CreateTable``."""
+    def off(table):
+        srv = table.server()
+        assert srv._nat_store is None
+        srv._native_host_ok = False
+        return table
+    return off
 
 
 @pytest.fixture()

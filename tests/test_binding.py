@@ -219,13 +219,19 @@ class TestParamManager:
         try:
             t = mv.ArrayTableHandler(4, init_value=np.zeros(4, np.float32))
             results = {}
+            # both deltas are taken against the base BEFORE either lands:
+            # without the barrier a worker's second get() could see the
+            # other's add and push 1 - 2, a race of the test's own making
+            both_read = threading.Barrier(2)
 
             def worker(wid):
                 from multiverso_tpu.zoo import Zoo
                 with Zoo.Get().worker_context(wid):
                     local = t.get().copy()
                     local += (wid + 1)  # local training
-                    t.add(local - t.get(), sync=True)
+                    delta = local - t.get()
+                    both_read.wait(timeout=30)
+                    t.add(delta, sync=True)
                     results[wid] = True
 
             ts = [threading.Thread(target=worker, args=(w,)) for w in range(2)]
@@ -233,6 +239,7 @@ class TestParamManager:
                 th.start()
             for th in ts:
                 th.join(timeout=30)
+            assert results == {0: True, 1: True}
             np.testing.assert_allclose(t.get(), 3.0)
         finally:
             mv.shutdown()

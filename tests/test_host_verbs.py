@@ -1,0 +1,138 @@
+"""The host-plane verbs of a default-updater float32 MatrixTable on the
+branch the chip runs.
+
+On the CPU backend such a table serves every host verb from the native
+host mirror (``MatrixServerTable._host_store``), and the chip has no
+mirror (``native_host_mirror=False``): the cell ``mt_host_verbs`` spends
+its window in the OTHER branch of each ``nat = self._host_store()`` fork
+(``_merged_add_rows`` under ``ProcessAddRun``, ``_device_ids`` /
+``_device_opt``, the gather and slice of ``ProcessGetAsync``,
+``_update_full``). Eligibility is one attribute read lazily at the first
+host verb, so a test turns the mirror off right after creation (the
+``off_host_mirror`` fixture of conftest.py) and tier-1 runs the chip's branch; every case then runs once more with the mirror
+left on. Deltas are whole numbers, so both must equal a numpy replay bit
+for bit, in any order of summation.
+"""
+
+import threading
+
+import numpy as np
+import pytest
+
+from multiverso_tpu.message import Message, MsgType
+from multiverso_tpu.tables import MatrixTableOption
+from multiverso_tpu.telemetry import metrics
+from multiverso_tpu.zoo import Zoo
+
+#: the benchmark's table shape in small: 50 logical columns in one lane tile
+ROWS, COLS = 200, 50
+
+
+@pytest.fixture()
+def world():
+    import multiverso_tpu as mv
+    mv.MV_Init(["-num_workers=2"])
+    yield mv
+    mv.MV_ShutDown()
+
+
+def _counter(name: str) -> float:
+    return metrics.snapshot().get(name, {}).get("value", 0)
+
+
+def _one_window(table, batches):
+    """Tracked AddRows that reach the engine as ONE window: a message
+    ahead of them holds the engine until all are queued."""
+    gate = threading.Event()
+    Zoo.Get().SendToServer(Message(
+        msg_type=MsgType.Request_StoreLoad,
+        payload={"fn": lambda: gate.wait(60)}))
+    handles = [table.AddAsyncHandle(delta, ids) for ids, delta in batches]
+    gate.set()
+    for h in handles:
+        table.Wait(h)
+
+
+def _batch(rng, n: int):
+    """n ids, a third of them distinct (repeats inside the payload)."""
+    uniq = rng.choice(ROWS, max(n // 3, 1), replace=False)
+    ids = rng.permutation(np.concatenate(
+        [uniq, rng.choice(uniq, n - len(uniq))])).astype(np.int32)
+    return ids, rng.integers(-3, 4, (n, COLS)).astype(np.float32)
+
+
+def _merged_window(table, replay, rng, mirror):
+    first = _batch(rng, 24)
+    # the same id set again (repeats across payloads), then another
+    batches = [first, (first[0], _batch(rng, 24)[1]), _batch(rng, 24)]
+    merged = _counter("server.add.run_merged")
+    _one_window(table, batches)
+    assert _counter("server.add.run_merged") - merged == 1
+    for ids, delta in batches:
+        np.add.at(replay, ids, delta)
+
+
+def _mixed_shapes(table, replay, rng, mirror):
+    batches = [_batch(rng, 8), _batch(rng, 12), _batch(rng, 8)]
+    merged = _counter("server.add.run_merged")
+    _one_window(table, batches)
+    if mirror == "off":    # a compile a window shape: the run declines
+        assert _counter("server.add.run_merged") == merged
+    for ids, delta in batches:
+        np.add.at(replay, ids, delta)
+
+
+def _get_rows(table, replay, rng, mirror):
+    ids, delta = _batch(rng, 40)
+    table.AddRows(ids, delta)
+    np.add.at(replay, ids, delta)
+    for n in (64, 40):      # at its bucket (no slice to cut) and under it
+        ask = rng.choice(ROWS, n).astype(np.int32)
+        got = table.GetRows(ask)
+        assert got.shape == (n, COLS) and got.dtype == np.float32
+        np.testing.assert_array_equal(got, replay[ask])
+    np.testing.assert_array_equal(table.Get(), replay)
+
+
+def _whole_add(table, replay, rng, mirror):
+    for _ in range(2):
+        full = rng.integers(-3, 4, (ROWS, COLS)).astype(np.float32)
+        table.Add(full)
+        replay += full
+
+
+def _multiget(table, replay, rng, mirror):
+    ids = np.arange(3, dtype=np.int32)
+    other = np.array([7, 5], np.int32)
+    got = table.MultiGet([{"row_ids": ids}, {"row_ids": ids},
+                          {"row_ids": other}])
+    for member, asked in zip(got, (ids, ids, other)):
+        np.testing.assert_array_equal(member, replay[asked])
+        assert member.flags.writeable and member.flags.owndata
+    got[0][:] = 99.0        # a member's rows are its own
+    got[2][:] = 99.0
+    np.testing.assert_array_equal(got[1], replay[ids])
+    np.testing.assert_array_equal(table.GetRows(other), replay[other])
+
+
+@pytest.mark.parametrize("mirror", ["off", "on"])
+@pytest.mark.parametrize("case", [_merged_window, _mixed_shapes, _get_rows,
+                                  _whole_add, _multiget],
+                         ids=lambda f: f.__name__.lstrip("_"))
+def test_host_verb_equals_replay(world, off_host_mirror, case, mirror):
+    rng = np.random.default_rng(5)
+    replay = rng.integers(-8, 9, (ROWS, COLS)).astype(np.float32)
+    init = replay.copy()
+    table = world.MV_CreateTable(MatrixTableOption(
+        num_rows=ROWS, num_cols=COLS, initializer=lambda shape: init))
+    srv = table.server()
+    assert srv._nat_store is None   # created at the first host verb
+    if mirror == "off":
+        off_host_mirror(table)
+    case(table, replay, rng, mirror)
+    np.testing.assert_array_equal(table.GetRows(np.arange(ROWS)), replay)
+    if mirror == "off":
+        assert srv._nat_store is None and not srv._native_host_ok
+    elif srv._native_host_ok:       # False only without a native toolchain
+        assert srv._nat_store is not None
+    np.testing.assert_array_equal(srv.raw(), replay)

@@ -1,8 +1,9 @@
-"""Pallas/XLA row-op kernels and the sharded matrix hot path.
+"""Pallas/XLA row ops and the sharded matrix hot path.
 
-The interpreter runs the Pallas kernels off-TPU, so these tests exercise the
-same kernel code the TPU path compiles (ops/pallas_rows.py); the end-to-end
-class drives the full MatrixTable PS path with ``-use_pallas=on``.
+The interpreter runs the Pallas scatter kernel off-TPU, so these tests
+exercise the same kernel code the TPU path compiles (ops/pallas_rows.py);
+the end-to-end class drives the full MatrixTable PS path with
+``-use_pallas=on``: XLA reads, interpreted Pallas writes, the chip's split.
 """
 
 import numpy as np
@@ -13,15 +14,6 @@ import jax.numpy as jnp
 
 
 class TestPallasKernels:
-    def test_gather(self):
-        from multiverso_tpu.ops.pallas_rows import pallas_gather_rows
-        rng = np.random.default_rng(0)
-        data = rng.standard_normal((32, 9)).astype(np.float32)
-        ids = np.array([5, 0, 31, 31, 7], np.int32)
-        out = pallas_gather_rows(jnp.asarray(data), jnp.asarray(ids),
-                                 interpret=True)
-        np.testing.assert_array_equal(np.asarray(out), data[ids])
-
     def test_scatter_set(self):
         from multiverso_tpu.ops.pallas_rows import pallas_scatter_set_rows
         rng = np.random.default_rng(1)
@@ -34,83 +26,35 @@ class TestPallasKernels:
         expect[ids] = rows
         np.testing.assert_array_equal(np.asarray(out), expect)
 
-    def test_update_rows_fused(self):
-        from multiverso_tpu.ops.pallas_rows import pallas_update_rows
-        rng = np.random.default_rng(2)
-        data = rng.standard_normal((24, 6)).astype(np.float32)
-        # kernel contract (caller = matrix_table): live ids unique;
-        # duplicates only on the trash row (here: 23), content don't-care
-        ids = np.array([1, 23, 8, 23, 0], np.int32)
-        deltas = rng.standard_normal((5, 6)).astype(np.float32)
-        out = pallas_update_rows(jnp.asarray(data), jnp.asarray(ids),
-                                 jnp.asarray(deltas),
-                                 combine=lambda r, d: r + d, interpret=True)
-        live = [1, 8, 0]
-        expect = data.copy()
-        expect[live] += deltas[[0, 2, 4]]
-        got = np.asarray(out)
-        np.testing.assert_allclose(got[live], expect[live], rtol=1e-6)
-        # untouched live rows intact (trash row 23 excluded: don't-care)
-        untouched = [r for r in range(24) if r not in (0, 1, 8, 23)]
-        np.testing.assert_array_equal(got[untouched], data[untouched])
-
-    def test_update_rows_sgd_combine(self):
-        from multiverso_tpu.ops.pallas_rows import pallas_update_rows
-        data = np.ones((10, 4), np.float32)
-        ids = np.array([2, 7], np.int32)
-        deltas = np.full((2, 4), 0.25, np.float32)
-        out = pallas_update_rows(jnp.asarray(data), jnp.asarray(ids),
-                                 jnp.asarray(deltas),
-                                 combine=lambda r, d: r - d, interpret=True)
-        expect = data.copy()
-        expect[ids] -= deltas
-        np.testing.assert_allclose(np.asarray(out), expect)
-        # untouched rows intact
-        np.testing.assert_array_equal(np.asarray(out)[[0, 1, 3]], 1.0)
-
-    def test_coalesced_contiguous_chunks(self):
-        """Chunks whose ids are strictly consecutive take the single
-        multi-row-DMA branch (pallas_rows._contig); this drives full-chunk
-        contiguous id sets through all three kernels and checks they match
-        the per-row semantics exactly."""
-        from multiverso_tpu.ops.pallas_rows import (CHUNK, pallas_gather_rows,
-                                                    pallas_scatter_set_rows,
-                                                    pallas_update_rows)
+    @pytest.mark.parametrize("layout", ["chunk_aligned", "ragged",
+                                        "non_contiguous"])
+    def test_coalesced_contiguous_chunks(self, layout):
+        """A chunk whose ids are strictly consecutive takes the single
+        multi-row-DMA branch (pallas_rows._contig), any other the per-row
+        branch, a ragged tail the replicated last pair: each must equal
+        a numpy scatter exactly."""
+        from multiverso_tpu.ops.pallas_rows import (CHUNK,
+                                                    pallas_scatter_set_rows)
         rng = np.random.default_rng(3)
         rows_n = 4 * CHUNK
         data = rng.standard_normal((rows_n, 8)).astype(np.float32)
-        # chunk 0: contiguous run; chunk 1: shuffled (per-row branch)
-        contig = np.arange(CHUNK, dtype=np.int32) + 17
-        scattered = rng.choice(rows_n, CHUNK, replace=False).astype(np.int32)
-        rng.shuffle(scattered)
-        # drop duplicates between the halves so update stays race-free
-        seen = set(contig.tolist())
-        scattered = np.array([i for i in scattered if i not in seen],
-                             np.int32)[:CHUNK]
-        while len(scattered) < CHUNK:   # refill to a full chunk
-            cand = int(rng.integers(0, rows_n))
-            if cand not in seen and cand not in scattered:
-                scattered = np.append(scattered, np.int32(cand))
-        ids = np.concatenate([contig, scattered]).astype(np.int32)
-
-        got = pallas_gather_rows(jnp.asarray(data), jnp.asarray(ids),
-                                 interpret=True)
-        np.testing.assert_array_equal(np.asarray(got), data[ids])
-
+        run = np.arange(CHUNK, dtype=np.int32) + 17
+        rest = np.setdiff1d(np.arange(rows_n, dtype=np.int32), run)
+        ids = {
+            # chunk 0 a run (one DMA), chunk 1 shuffled (row DMAs)
+            "chunk_aligned": np.concatenate(
+                [run, rng.permutation(rest)[:CHUNK]]),
+            # a run that ends mid-chunk: the tail lanes repeat the last pair
+            "ragged": np.concatenate([run, run[-1] + 1 + np.arange(5)]),
+            # no chunk is a run, and the count is no chunk multiple
+            "non_contiguous": rng.permutation(rest)[:CHUNK + 9],
+        }[layout].astype(np.int32)
         new_rows = rng.standard_normal((len(ids), 8)).astype(np.float32)
         out = pallas_scatter_set_rows(jnp.asarray(data), jnp.asarray(ids),
                                       jnp.asarray(new_rows), interpret=True)
         expect = data.copy()
         expect[ids] = new_rows
         np.testing.assert_array_equal(np.asarray(out), expect)
-
-        deltas = rng.standard_normal((len(ids), 8)).astype(np.float32)
-        out = pallas_update_rows(jnp.asarray(data), jnp.asarray(ids),
-                                 jnp.asarray(deltas),
-                                 combine=lambda r, d: r + d, interpret=True)
-        expect = data.copy()
-        expect[ids] += deltas
-        np.testing.assert_allclose(np.asarray(out), expect, rtol=1e-6)
 
     def test_scatter_preserves_untouched(self):
         from multiverso_tpu.ops.pallas_rows import pallas_scatter_set_rows
@@ -167,17 +111,15 @@ for cols in (128, 256, 512, 2048):
     admitted.append(cols)
     ids = jax.ShapeDtypeStruct((8192,), jnp.int32, sharding=sh)
     rows = jax.ShapeDtypeStruct((8192, cols), jnp.float32, sharding=sh)
-    pr.pallas_gather_rows.lower(data, ids).compile()
     pr.pallas_scatter_set_rows.lower(data, ids, rows).compile()
-    pr.pallas_update_rows.lower(data, ids, rows, jnp.add).compile()
 print("ADMITTED", admitted)
 """
 
 
 class TestKernelsCompileForTpu:
     def test_every_admitted_width_compiles_for_v5e(self):
-        """Tier-1 runs the kernels in interpreter mode only; this compiles
-        them with Mosaic against a v5e topology description (no chip
+        """Tier-1 runs the kernel in interpreter mode only; this compiles
+        it with Mosaic against a v5e topology description (no chip
         needed). A width ``_pallas_eligible`` admits but Mosaic refuses
         would crash a table's first Add on the chip."""
         import os
@@ -196,7 +138,7 @@ class TestKernelsCompileForTpu:
 
 
 class TestMatrixTableWithPallas:
-    """Full PS path through the Pallas kernels (interpret mode on CPU)."""
+    """Full PS path with the Pallas write kernel (interpret mode on CPU)."""
 
     @pytest.fixture()
     def pallas_env(self, mv_env):
@@ -205,10 +147,12 @@ class TestMatrixTableWithPallas:
         yield mv_env
         SetCMDFlag("use_pallas", "auto")
 
-    def test_row_add_get(self, pallas_env):
+    def test_row_add_get(self, pallas_env, off_host_mirror):
+        from multiverso_tpu import ops
         from multiverso_tpu.tables.matrix_table import MatrixTableOption
-        table = pallas_env.MV_CreateTable(
-            MatrixTableOption(num_rows=33, num_cols=7))
+        table = off_host_mirror(pallas_env.MV_CreateTable(
+            MatrixTableOption(num_rows=33, num_cols=7)))
+        assert ops.use_pallas(table.server().state["data"])
         ids = np.array([0, 4, 17, 32], np.int32)
         deltas = np.arange(4 * 7, dtype=np.float32).reshape(4, 7)
         table.AddRows(ids, deltas)
@@ -218,14 +162,14 @@ class TestMatrixTableWithPallas:
         # untouched rows stay zero
         np.testing.assert_allclose(table.GetRows([1, 16, 31]), 0.0)
 
-    def test_wider_than_one_tile_takes_the_xla_path(self, pallas_env):
+    def test_wider_than_one_tile_takes_the_xla_path(self, pallas_env, off_host_mirror):
         """256 f32 columns: Mosaic refuses the row kernels there, so even
         ``-use_pallas=on`` must route the table to XLA (on the chip the
         old gate crashed this table's first Add) and stay exact."""
         from multiverso_tpu import ops
         from multiverso_tpu.tables.matrix_table import MatrixTableOption
-        table = pallas_env.MV_CreateTable(
-            MatrixTableOption(num_rows=300, num_cols=256))
+        table = off_host_mirror(pallas_env.MV_CreateTable(
+            MatrixTableOption(num_rows=300, num_cols=256)))
         assert not ops.use_pallas(table.server().state["data"])
         rng = np.random.default_rng(5)
         ids = rng.choice(300, 40, replace=False).astype(np.int32)
@@ -236,11 +180,11 @@ class TestMatrixTableWithPallas:
         untouched = np.setdiff1d(np.arange(300), ids).astype(np.int32)
         assert not table.GetRows(untouched).any()
 
-    def test_full_table_roundtrip(self, pallas_env):
+    def test_full_table_roundtrip(self, pallas_env, off_host_mirror):
         from multiverso_tpu.tables.matrix_table import MatrixTableOption
         rng = np.random.default_rng(3)
-        table = pallas_env.MV_CreateTable(
-            MatrixTableOption(num_rows=19, num_cols=4))
+        table = off_host_mirror(pallas_env.MV_CreateTable(
+            MatrixTableOption(num_rows=19, num_cols=4)))
         full = rng.standard_normal((19, 4)).astype(np.float32)
         table.Add(full)
         np.testing.assert_allclose(table.Get(), full, rtol=1e-6)

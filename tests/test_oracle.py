@@ -94,16 +94,19 @@ class TestMatrixOracle:
 
 
 class TestMatrixOraclePallas:
-    def test_random_walk_through_pallas_kernels(self, mv_env):
-        """Same oracle walk with -use_pallas=on: the interpreter runs the
-        actual kernel code (fused RMW, row gather) inside the PS path."""
+    def test_random_walk_through_pallas_kernels(self, mv_env,
+                                                off_host_mirror):
+        """Same oracle walk with -use_pallas=on, on the chip's own split
+        inside the PS path: XLA reads, the interpreter running the Pallas
+        write kernel. The table is taken off the CPU backend's native
+        host mirror, which would answer without a row program."""
         from multiverso_tpu.utils.configure import SetCMDFlag
         SetCMDFlag("use_pallas", "on")
         try:
             rng = np.random.default_rng(12)
             R, C = 24, 8
-            table = mv_env.MV_CreateTable(MatrixTableOption(num_rows=R,
-                                                            num_cols=C))
+            table = off_host_mirror(mv_env.MV_CreateTable(
+                MatrixTableOption(num_rows=R, num_cols=C)))
             oracle = np.zeros((R, C), np.float32)
             for _ in range(12):
                 k = int(rng.integers(1, R + 1))
@@ -113,6 +116,7 @@ class TestMatrixOraclePallas:
                 np.add.at(oracle, ids, deltas)
                 np.testing.assert_allclose(table.GetRows(ids), oracle[ids],
                                            rtol=1e-5, atol=1e-5)
+            assert table.server()._nat_store is None
         finally:
             SetCMDFlag("use_pallas", "auto")
 
