@@ -48,17 +48,17 @@ def _aux_leaves(table):
     return [(jax.tree_util.keystr(path), leaf) for path, leaf in leaves]
 
 
-def _to_logical(table, leaf) -> np.ndarray:
+def _to_logical(table, keypath: str, leaf) -> np.ndarray:
     """Aux leaf in mesh-independent (logical) layout when the table knows
     how; raw host layout otherwise."""
     if hasattr(table, "aux_to_logical"):
-        return table.aux_to_logical(leaf)
+        return table.aux_to_logical(keypath, leaf)
     return np.asarray(leaf)
 
 
-def _from_logical(table, arr: np.ndarray) -> np.ndarray:
+def _from_logical(table, keypath: str, arr: np.ndarray) -> np.ndarray:
     if hasattr(table, "aux_from_logical"):
-        return table.aux_from_logical(arr)
+        return table.aux_from_logical(keypath, arr)
     return arr
 
 
@@ -73,7 +73,7 @@ def _write_table(stream: Stream, table_id: int, table) -> None:
     leaves = _aux_leaves(table)
     stream.WriteInt(len(leaves))
     for keypath, leaf in leaves:
-        host = _to_logical(table, leaf)
+        host = _to_logical(table, keypath, leaf)
         stream.WriteStr(keypath)
         stream.WriteStr(str(host.dtype))
         stream.WriteInt(host.ndim)
@@ -108,14 +108,14 @@ def _read_table(stream: Stream, table) -> None:
                           else dtype.itemsize)
         arr = np.frombuffer(raw, dtype).reshape(shape)
         CHECK(keypath in live, f"unknown aux leaf {keypath} in checkpoint")
-        live_logical = _to_logical(table, live[keypath])
+        live_logical = _to_logical(table, keypath, live[keypath])
         CHECK(live_logical.shape == arr.shape,
               f"aux leaf {keypath} shape mismatch: checkpoint {arr.shape} "
               f"vs live {live_logical.shape}")
         CHECK(live_logical.dtype == arr.dtype,
               f"aux leaf {keypath} dtype mismatch: checkpoint {arr.dtype} "
               f"vs live {live_logical.dtype}")
-        restored[keypath] = _from_logical(table, arr)
+        restored[keypath] = _from_logical(table, keypath, arr)
     # re-place every restored leaf with the table's live sharding
     def replace(path, leaf):
         key = jax.tree_util.keystr(path)

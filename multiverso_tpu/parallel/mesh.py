@@ -184,13 +184,6 @@ class MeshContext:
         """Row shards of a 2-D array (MatrixTable layout)."""
         return NamedSharding(self.mesh, P(SERVER_AXIS, None))
 
-    def sharding_worker_rows(self) -> NamedSharding:
-        """(num_workers, rows, ...) state sharded on the row axis — used for
-        per-worker server state such as AdaGrad accumulators
-        (reference adagrad_updater.h:19,26) and SparseMatrixTable dirty bits
-        (reference sparse_matrix_table.h:67-69)."""
-        return NamedSharding(self.mesh, P(None, SERVER_AXIS))
-
     def replicated(self) -> NamedSharding:
         return NamedSharding(self.mesh, P())
 

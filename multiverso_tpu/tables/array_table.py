@@ -36,7 +36,8 @@ from multiverso_tpu.parallel import multihost
 from multiverso_tpu.parallel.mesh import pad_to_multiple, partition_offsets
 from multiverso_tpu.tables.base import ServerTable, TableOption, WorkerTable
 from multiverso_tpu.updaters.base import (AddOption, CreateUpdater, GetOption,
-                                          Updater)
+                                          Updater, stack_workers,
+                                          unstack_workers)
 from multiverso_tpu.utils.log import CHECK
 
 
@@ -70,8 +71,9 @@ class ArrayServer(ServerTable):
         aux = self.updater.init_aux((self.padded,), self.dtype, zoo.num_workers)
         self.state = {
             "data": ctx.place(data, self._sharding),
-            "aux": jax.tree.map(lambda a: ctx.place(
-                a, self._per_leaf_sharding(a, ctx)), aux),
+            # every state leaf is 1-D on the data's axis (per-worker state:
+            # updaters.base.worker_rows)
+            "aux": jax.tree.map(lambda a: ctx.place(a, self._sharding), aux),
         }
 
         # the engine's jitted programs ARE the device-plane bodies —
@@ -87,13 +89,6 @@ class ArrayServer(ServerTable):
         # matrix table's _merge_adds gate; updaters/base.py combine_scale)
         self._merge_adds = (self.updater.combine_scale is not None
                             and not jax.tree.leaves(aux))
-
-    def _per_leaf_sharding(self, leaf, ctx):
-        """data-shaped leaves shard like data; (num_workers, ...) leaves shard
-        on the parameter axis (axis 1)."""
-        if leaf.ndim == 1:
-            return ctx.sharding_1d()
-        return ctx.sharding_worker_rows()
 
     def ProcessAdd(self, values: np.ndarray, option: AddOption) -> None:
         values = np.asarray(values, self.dtype).ravel()
@@ -314,8 +309,9 @@ class ArrayServer(ServerTable):
     def device_update(self, state, padded_delta, opt):
         """Traceable: one whole-table Add through the table's updater
         (delta must be padded to ``self.padded``; opt = AddOption.as_jnp())."""
-        new_data, new_aux = self.updater.update(state["data"], state["aux"],
-                                                padded_delta, opt)
+        new_data, new_aux = self.updater.update_worker(
+            state["data"], state["aux"], padded_delta, opt,
+            self._zoo.num_workers, self.num_servers)
         return {"data": new_data, "aux": new_aux}
 
     def device_access(self, state, opt=None):
@@ -385,15 +381,24 @@ class ArrayServer(ServerTable):
 
     # -- aux (updater state) <-> logical layout, for the checkpoint driver --
 
-    def aux_to_logical(self, leaf) -> np.ndarray:
-        """Strip padding: last axis padded -> logical size."""
-        return self._zoo.mesh_ctx.fetch(leaf)[..., : self.size]
+    # Logical form: shared state (size,), per-worker state (workers, size),
+    # whatever the mesh and however a shard stacks its workers.
 
-    def aux_from_logical(self, arr: np.ndarray) -> np.ndarray:
+    def aux_to_logical(self, keypath: str, leaf) -> np.ndarray:
+        """A stored state leaf -> its logical form (padding stripped)."""
+        host = self._zoo.mesh_ctx.fetch(leaf)
+        if self.updater.is_per_worker(keypath):
+            host = unstack_workers(host, self._zoo.num_workers,
+                                   self.num_servers)
+        return host[..., : self.size]
+
+    def aux_from_logical(self, keypath: str, arr: np.ndarray) -> np.ndarray:
         pad = self.padded - self.size
         if pad:
             widths = [(0, 0)] * (arr.ndim - 1) + [(0, pad)]
             arr = np.pad(arr, widths)
+        if self.updater.is_per_worker(keypath):
+            arr = stack_workers(arr, self.num_servers)
         return arr
 
 

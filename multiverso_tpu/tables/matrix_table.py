@@ -22,9 +22,11 @@ assembled Get result is a ``psum`` of masked shard contributions, so only
 the requested rows ever ride ICI (no full-table all-gather, mirroring the
 reference where only the partitioned row payloads cross the network,
 matrix_table.cpp:235-296). Row-id batches are padded to power-of-two
-buckets (pad lane = -1) so XLA compiles a handful of shapes. Per-worker
-updater state (AdaGrad) is sharded along the same row axis and
-gathered/scattered alongside the data rows. Duplicate ids inside one Add
+buckets (pad lane = -1) so XLA compiles a handful of shapes. Updater
+state is row-shaped storage sharded along the same row axis and read and
+written alongside the data rows, on their path; per-worker state (AdaGrad)
+stacks a shard's workers block after block, and an Add touches the rows
+of the worker that sent it (``_aux_lanes``). Duplicate ids inside one Add
 are pre-combined on the host (np.add.at) because scatter order is
 undefined — the reference applies rows sequentially so duplicates stack;
 combining first preserves the default/sgd semantics and is the documented
@@ -55,7 +57,8 @@ from multiverso_tpu.tables.base import ServerTable, TableOption, WorkerTable
 from multiverso_tpu.telemetry import metrics as tmetrics
 from multiverso_tpu.telemetry import sketch as tsketch
 from multiverso_tpu.telemetry import trace as ttrace
-from multiverso_tpu.updaters.base import AddOption, CreateUpdater, GetOption
+from multiverso_tpu.updaters.base import (AddOption, CreateUpdater, GetOption,
+                                          stack_workers, unstack_workers)
 from multiverso_tpu.utils.log import CHECK, Log
 
 
@@ -253,14 +256,13 @@ class MatrixServerTable(ServerTable):
         self.state = {
             "data": ctx.place(data, self._sharding),
             "aux": jax.tree.map(
-                lambda a: ctx.place(a, self._aux_sharding(a, ctx)), aux),
+                lambda a: ctx.place(a, self._sharding), aux),
         }
         jax.block_until_ready(self._state)
         tmetrics.histogram("table.create_s").observe(
             time.perf_counter() - t_create)
-        self._aux_specs = jax.tree.map(
-            lambda a: P(SERVER_AXIS, None) if a.ndim == 2
-            else P(None, SERVER_AXIS, None), aux)
+        # every state leaf is row-shaped 2-D storage on the data's axis
+        self._aux_specs = jax.tree.map(lambda a: P(SERVER_AXIS, None), aux)
 
         block_rows = self.block_rows
         updater = self.updater
@@ -278,26 +280,44 @@ class MatrixServerTable(ServerTable):
             safe = jnp.where(mine, ids - s * block_rows, block_rows)
             return mine, safe.astype(jnp.int32)
 
-        def _gather_aux(aux, safe):
-            def g(leaf):
-                if leaf.ndim == 2:           # shared state, shaped like data
-                    return jnp.take(leaf, safe, axis=0)
-                return jnp.take(leaf, safe, axis=1)  # per-worker state
-            return jax.tree.map(g, aux)
+        shard_rows = self.shard_rows
 
-        def _scatter_aux(aux, new_aux, safe):
-            def s(leaf, new_leaf):
-                if leaf.ndim == 2:
-                    # row-shaped aux (momentum smooth, 2-D hist) writes take
-                    # the same write path as data rows (ops/rows.py)
-                    return ops.scatter_set_rows(leaf, safe, new_leaf,
-                                                dense=single)
-                return leaf.at[:, safe].set(new_leaf)
-            return jax.tree.map(s, aux, new_aux)
+        def _aux_lanes(aux, safe, opt):
+            """Per leaf, the rows of ``safe`` in that leaf: ``safe`` itself
+            in shared state (shaped like the shard: momentum's smooth), and
+            in per-worker state (updaters.base.worker_rows: this shard's
+            rows of every worker, block after block) the same rows of the
+            block of the worker that sent the Add — ``opt["worker_id"]``
+            stays traced, one program serves every worker. Trash lanes go
+            to the LEAF's last row, the last worker's trash row, which is
+            where ops.rows' dense-run test looks for them."""
+            lanes = {shard_rows: safe}
+            for leaf in jax.tree.leaves(aux):
+                if leaf.shape[0] not in lanes:
+                    lanes[leaf.shape[0]] = jnp.where(
+                        safe == block_rows, leaf.shape[0] - 1,
+                        opt["worker_id"] * shard_rows + safe)
+            return jax.tree.map(lambda leaf: lanes[leaf.shape[0]], aux)
+
+        def _gather_aux(aux, lanes):
+            return jax.tree.map(lambda leaf, idx: jnp.take(leaf, idx, axis=0),
+                                aux, lanes)
+
+        def _scatter_aux(aux, new_aux, lanes):
+            # state is row-shaped like the data (one worker's rows of it
+            # are len(ids) rows of a 2-D leaf), so its writes take the
+            # data rows' write path (ops/rows.py): the Pallas kernel at 128
+            # lanes, XLA's scatter wider, the dense run on one shard
+            return jax.tree.map(
+                lambda leaf, idx, rows: ops.scatter_set_rows(
+                    leaf, idx, rows, dense=single), aux, lanes, new_aux)
+
+        num_workers, num_servers = zoo.num_workers, self.num_servers
 
         def _update_full(state, delta, opt):
-            new_data, new_aux = updater.update(state["data"], state["aux"],
-                                               delta, opt)
+            new_data, new_aux = updater.update_worker(
+                state["data"], state["aux"], delta, opt, num_workers,
+                num_servers)
             return {"data": new_data, "aux": new_aux}
 
         self._update_full = jax.jit(_update_full, donate_argnums=(0,))
@@ -327,14 +347,14 @@ class MatrixServerTable(ServerTable):
                 return ops.update_rows(local_data, safe, deltas,
                                        combine, dense=single), local_aux
             rows = ops.gather_rows(local_data, safe)
-            aux_rows = _gather_aux(local_aux, safe)
-            new_rows, new_aux_rows = updater.update(rows, aux_rows, deltas,
-                                                    opt)
+            lanes = _aux_lanes(local_aux, safe, opt)
+            new_rows, new_aux_rows = updater.update(
+                rows, _gather_aux(local_aux, lanes), deltas, opt)
             # Non-mine lanes computed garbage from the trash row — it goes
             # straight back to the trash row, never to live data.
             data = ops.scatter_set_rows(local_data, safe, new_rows,
                                         dense=single)
-            aux = _scatter_aux(local_aux, new_aux_rows, safe)
+            aux = _scatter_aux(local_aux, new_aux_rows, lanes)
             return data, aux
 
         store_cols = self.store_cols
@@ -425,6 +445,10 @@ class MatrixServerTable(ServerTable):
         # updater.cpp:32) — the common case skips the aux gather.
         from multiverso_tpu.updaters.base import Updater as _UpdaterBase
         has_access = type(updater).access is not _UpdaterBase.access
+        # the gather program carries no worker id (``_aux_lanes`` is given
+        # no option there): the hook reads shared state at the rows it serves
+        CHECK(not (has_access and updater.per_worker),
+              "an access hook over per-worker state is not supported")
 
         num_cols_ = num_cols
 
@@ -432,8 +456,8 @@ class MatrixServerTable(ServerTable):
             mine, safe = _local_lanes(ids)
             rows = ops.gather_rows(local_data, safe)
             if has_access:
-                rows = updater.access(rows, _gather_aux(local_aux, safe),
-                                      None)
+                rows = updater.access(rows, _gather_aux(
+                    local_aux, _aux_lanes(local_aux, safe, None)), None)
             # slice the storage pad off BEFORE the psum: only logical
             # columns ride ICI
             rows = jnp.where(mine[:, None], rows[:, :num_cols_], 0)
@@ -479,14 +503,15 @@ class MatrixServerTable(ServerTable):
                 # are caller-pre-combined, so per-lane new_rows are exact;
                 # trash lanes are garbage and masked below)
                 rows_in = ops.gather_rows(local_data, safe)
-                aux_rows = _gather_aux(local_aux, safe)
-                rows, new_aux_rows = updater.update(rows_in, aux_rows,
-                                                    deltas, opt)
+                lanes = _aux_lanes(local_aux, safe, opt)
+                rows, new_aux_rows = updater.update(
+                    rows_in, _gather_aux(local_aux, lanes), deltas, opt)
                 data = ops.scatter_set_rows(local_data, safe, rows,
                                             dense=single)
-                aux = _scatter_aux(local_aux, new_aux_rows, safe)
+                aux = _scatter_aux(local_aux, new_aux_rows, lanes)
             if has_access:
-                rows = updater.access(rows, _gather_aux(aux, safe), None)
+                rows = updater.access(rows, _gather_aux(
+                    aux, _aux_lanes(aux, safe, None)), None)
             rows = jnp.where(mine[:, None], rows[:, :num_cols_], 0)
             if single:
                 return data, aux, rows
@@ -538,11 +563,6 @@ class MatrixServerTable(ServerTable):
 
         self.device_gather_rows_parts = _gather_rows_parts
         self._gather_rows_parts_j = jax.jit(_gather_rows_parts)
-
-    def _aux_sharding(self, leaf, ctx):
-        if leaf.ndim == 2:
-            return ctx.sharding_rows()
-        return ctx.sharding_worker_rows()
 
     # -- storage layout (interleaved shard blocks + trash rows) -------------
 
@@ -1764,18 +1784,24 @@ class MatrixServerTable(ServerTable):
 
     # -- aux (updater state) <-> logical layout, for the checkpoint driver --
 
-    def aux_to_logical(self, leaf) -> np.ndarray:
-        """(padded_rows, cols) or (workers, padded_rows, cols) storage ->
-        logical row layout (interleaving + trash rows stripped)."""
-        host = self._zoo.mesh_ctx.fetch(leaf)
-        if host.ndim == 2:
-            return self._from_storage(host)
-        return np.stack([self._from_storage(h) for h in host])
+    # The checkpoint's form is independent of the mesh and of how a shard
+    # stacks its workers: shared state (rows, cols), per-worker state
+    # (workers, rows, cols) — what every earlier layout wrote too.
 
-    def aux_from_logical(self, arr: np.ndarray) -> np.ndarray:
-        if arr.ndim == 2:
+    def aux_to_logical(self, keypath: str, leaf) -> np.ndarray:
+        """A stored state leaf -> its logical form (interleaving, trash
+        rows and column pad stripped)."""
+        host = self._zoo.mesh_ctx.fetch(leaf)
+        if not self.updater.is_per_worker(keypath):
+            return self._from_storage(host)
+        return np.stack([self._from_storage(h) for h in unstack_workers(
+            host, self._zoo.num_workers, self.num_servers)])
+
+    def aux_from_logical(self, keypath: str, arr: np.ndarray) -> np.ndarray:
+        if not self.updater.is_per_worker(keypath):
             return self._to_storage(arr)
-        return np.stack([self._to_storage(a) for a in arr])
+        return stack_workers(np.stack([self._to_storage(a) for a in arr]),
+                             self.num_servers)
 
     # -- checkpoint (reference matrix_table.cpp:457-465) --------------------
 

@@ -11,9 +11,14 @@ TPU design: each updater is a *pure elementwise transform*
 over its sharded storage (donated, so HBM is updated in place). Option
 scalars are traced ``jnp`` values, not static args — changing lr per Add
 does NOT retrigger compilation (SURVEY.md §7 "option-carrying updates").
-Per-worker state (AdaGrad's historic g², reference adagrad_updater.h:19,26)
-is an aux leaf of shape ``(num_workers,) + data.shape`` sharded along the
-same server axis as the data.
+Per-worker state (AdaGrad's historic g², reference adagrad_updater.h:19,26;
+the leaves an updater names in ``per_worker``) is ROW-SHAPED like the data
+and sharded along the same server axis: a leaf of ``num_workers *
+data.shape[0]`` rows in which every shard holds its workers' blocks one
+after another (``worker_block`` / ``set_worker_block``). ``update`` never
+sees that layout: the caller hands it ONE worker's state, shaped like the
+data it updates — the rows of the Add on the row path, that worker's block
+in a whole-table Add (``update_worker``) — and stores what it returns.
 
 Updater selection is keyed by the ``updater_type`` flag exactly like the
 reference factory (src/updater/updater.cpp:46-57).
@@ -34,6 +39,7 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from multiverso_tpu.utils.configure import MV_DEFINE_int, MV_DEFINE_string
 
@@ -93,11 +99,19 @@ class Updater:
     #: whose update reads opt must leave combine_scale = None).
     #: None = not linear, never merge.
     combine_scale = None
+    #: names of the aux leaves that are PER-WORKER state (``worker_rows``
+    #: makes them); every other leaf is shared state, shaped like data
+    per_worker = ()
 
     def init_aux(self, shape, dtype, num_workers: int) -> Dict[str, Any]:
-        """Aux state pytree. Leaves shaped like data are shared state;
-        leaves shaped (num_workers,)+shape are per-worker state."""
+        """Aux state pytree: shared leaves shaped like data, per-worker
+        leaves (``per_worker``) made by ``worker_rows``."""
         return {}
+
+    def is_per_worker(self, keypath: str) -> bool:
+        """Whether the aux leaf at ``keypath`` (its key, or the key path
+        ``jax.tree_util.keystr`` prints: "['hist']") is per-worker."""
+        return keypath.strip("[']") in self.per_worker
 
     def combine(self, rows: jax.Array, deltas: jax.Array) -> jax.Array:
         """The fusable elementwise rule (only called when ``fusable``)."""
@@ -105,13 +119,75 @@ class Updater:
 
     def update(self, data: jax.Array, aux: Dict[str, Any], delta: jax.Array,
                opt: Dict[str, jax.Array]):
+        """The rule, elementwise over ``data``. Every aux leaf arrives
+        shaped like ``data``: a per-worker leaf is the state of the ONE
+        worker that sent the Add (module docstring)."""
         return data + delta, aux
+
+    def update_worker(self, data, aux, delta, opt, num_workers: int,
+                      num_shards: int):
+        """A WHOLE-TABLE Add over the stored aux: ``update`` on the
+        sending worker's blocks of the per-worker leaves (``opt
+        ["worker_id"]``, traced), the other workers' left as they were."""
+        wid = opt["worker_id"]
+        mine = {k: worker_block(v, wid, num_workers, num_shards)
+                if self.is_per_worker(k) else v for k, v in aux.items()}
+        data, new = self.update(data, mine, delta, opt)
+        return data, {k: set_worker_block(aux[k], v, wid, num_workers,
+                                          num_shards)
+                      if self.is_per_worker(k) else v for k, v in new.items()}
 
     def access(self, data: jax.Array, aux: Dict[str, Any],
                opt: Dict[str, jax.Array]) -> jax.Array:
         """Get path — identity for every reference updater (memcpy,
         updater.cpp:32)."""
         return data
+
+
+def worker_rows(shape, dtype, num_workers: int) -> jax.Array:
+    """Zeroed per-worker state for data of ``shape``: ``num_workers *
+    shape[0]`` rows shaped like the data's. Sharded on the row axis like
+    the data, a shard holds ITS rows of every worker, block after block:
+    worker ``w``'s row ``r`` of a shard is that shard's row ``w *
+    shard_rows + r``."""
+    return jnp.zeros((num_workers * shape[0],) + tuple(shape[1:]), dtype)
+
+
+def _shard_blocks(leaf, num_workers: int, num_shards: int):
+    return leaf.reshape((num_shards, num_workers, -1) + leaf.shape[1:])
+
+
+def worker_block(leaf, wid, num_workers: int, num_shards: int):
+    """One worker's state out of a ``worker_rows`` leaf, whole table:
+    shaped like the data (``wid`` may be traced)."""
+    block = lax.dynamic_index_in_dim(
+        _shard_blocks(leaf, num_workers, num_shards), wid, axis=1,
+        keepdims=False)
+    return block.reshape((-1,) + leaf.shape[1:])
+
+
+def unstack_workers(leaf, num_workers: int, num_shards: int):
+    """A whole ``worker_rows`` leaf (a host or a device array) ->
+    ``(num_workers,) + the data's shape``: the checkpoint's form."""
+    blocks = _shard_blocks(leaf, num_workers, num_shards)
+    return blocks.swapaxes(0, 1).reshape(
+        (num_workers, -1) + leaf.shape[1:])
+
+
+def stack_workers(per_worker, num_shards: int):
+    """The inverse: ``(num_workers,) + the data's shape`` -> the
+    ``worker_rows`` layout."""
+    blocks = per_worker.reshape(
+        (per_worker.shape[0], num_shards, -1) + per_worker.shape[2:])
+    return blocks.swapaxes(0, 1).reshape((-1,) + per_worker.shape[2:])
+
+
+def set_worker_block(leaf, block, wid, num_workers: int, num_shards: int):
+    """``leaf`` with one worker's whole-table state replaced."""
+    block = block.reshape((num_shards, 1, -1) + leaf.shape[1:])
+    return lax.dynamic_update_slice_in_dim(
+        _shard_blocks(leaf, num_workers, num_shards), block, wid,
+        axis=1).reshape(leaf.shape)
 
 
 class AddUpdater(Updater):
@@ -158,19 +234,17 @@ class AdaGradUpdater(Updater):
 
     name = "adagrad"
     eps = 1e-6
+    per_worker = ("hist",)
 
     def init_aux(self, shape, dtype, num_workers):
-        return {"hist": jnp.zeros((num_workers,) + tuple(shape), dtype)}
+        return {"hist": worker_rows(shape, dtype, num_workers)}
 
     def update(self, data, aux, delta, opt):
-        wid = opt["worker_id"]
         lr = opt["learning_rate"].astype(data.dtype)
         rho = opt["rho"].astype(data.dtype)
         grad = delta / lr
-        hist = aux["hist"]
-        h = hist[wid] + grad * grad
-        data = data - rho * grad / jnp.sqrt(h + self.eps)
-        hist = hist.at[wid].set(h)
+        hist = aux["hist"] + grad * grad
+        data = data - rho * grad / jnp.sqrt(hist + self.eps)
         return data, {"hist": hist}
 
 
@@ -196,23 +270,22 @@ class DCASGDUpdater(Updater):
     (the reference gates the same choice at compile time)."""
 
     name = "dcasgd"
+    per_worker = ("backup",)
 
     def init_aux(self, shape, dtype, num_workers):
-        return {"backup": jnp.zeros((num_workers,) + tuple(shape), dtype)}
+        return {"backup": worker_rows(shape, dtype, num_workers)}
 
     def update(self, data, aux, delta, opt):
-        wid = opt["worker_id"]
         lr = opt["learning_rate"].astype(data.dtype)
         lam = opt["lambda_"].astype(data.dtype)
-        bak = aux["backup"][wid]
+        bak = aux["backup"]
         # lr rides in traced (no retrace on change), so a zero can't raise
         # here — degrade the compensation to plain SGD instead of poisoning
         # the table with inf/NaN (the native mirror applies the same
         # degrade, store.cc DcasgdUpdaterC)
         lam_over_lr = jnp.where(lr > 0, lam / jnp.maximum(lr, 1e-30), 0.0)
         new = data - (delta + lam_over_lr * delta * delta * (data - bak))
-        backup = aux["backup"].at[wid].set(new)
-        return new, {"backup": backup}
+        return new, {"backup": new}
 
 
 _REGISTRY = {

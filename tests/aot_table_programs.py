@@ -1,0 +1,251 @@
+"""A MatrixTable's row programs lowered and compiled for a DESCRIBED v5e,
+with no chip and no table in memory (run as a child process: it makes
+``jax.default_backend()`` answer "tpu" so that the programs take the
+branches the chip takes — the Pallas row write at 128 lanes, the
+dense-run ``cond`` on one shard).
+
+``--dump DIR`` writes the lowered text of the programs the benchmark's
+cells compile for tables WITHOUT updater state (``FENCE``), one file a
+program, the Mosaic bodies' debug locations stripped: run it on two
+trees and ``diff -r`` the directories (ISSUE 29's fence, PERF.md 6).
+
+``--alias`` compiles the row programs of tables WITH per-worker updater
+state (``STATEFUL``) and prints, a program, whether every state leaf is
+aliased input to output and how many table-sized ``copy`` instructions
+the compiled module holds: tests/test_ops.py asserts on it.
+
+Prints ``SKIP ...`` and exits 0 where the topology description is missing.
+"""
+
+import argparse
+import contextlib
+import os
+import re
+import sys
+import types
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+from jax.sharding import NamedSharding, SingleDeviceSharding  # noqa: E402
+from jax.sharding import PartitionSpec as P  # noqa: E402
+
+jax.default_backend = lambda: "tpu"     # the branch the chip runs
+
+from multiverso_tpu.parallel.mesh import MeshContext  # noqa: E402
+from multiverso_tpu.tables.matrix_table import MatrixServerTable  # noqa: E402
+
+# (name, rows, cols, chips, updater, workers, id buckets, merged (nb, k, uniq))
+FENCE = [
+    # mt_host_verbs: Adds of 10,000 rows from 4 workers, merged by window
+    ("mt_host_verbs", 9_000_000, 50, 1, "default", 4, (10_240,),
+     ((1, 10_000, 16_384), (2, 10_000, 32_768), (4, 10_000, 65_536))),
+    # tables_rounds_4c: 65,536 ids a table a round, fewer once unique
+    ("tables_rounds_4c", 12_000_000, 128, 4, "default", 1,
+     (49_152, 57_344, 65_536), ()),
+    ("we_rows", 1_048_500, 128, 1, "default", 1, (524_288, 655_360), ()),
+    ("we_pairs", 2_097_100, 128, 1, "default", 1, (8_192,), ()),
+    ("sgd_1c", 1_048_500, 128, 1, "sgd", 2, (8_192,), ((2, 4_096, 8_192),)),
+    ("sgd_4c", 1_048_500, 128, 4, "sgd", 2, (8_192,), ()),
+    ("momentum_1c", 163_840, 2_048, 1, "momentum", 2, (32_768, 163_840),
+     ((1, 32_768, 16_384),)),
+    ("momentum_4c", 1_048_500, 128, 4, "momentum", 2, (8_192,), ()),
+]
+STATEFUL = [
+    # lm_vocab_steps' shapes (2,048 columns; a whole table in order, and
+    # 32,768 positions over fewer distinct rows) at three workers, and a
+    # one-tile table on 2x2: what tests/test_ops.py compiles
+    ("adagrad_2048_w3", 163_840, 2_048, 1, "adagrad", 3, (163_840,),
+     ((1, 32_768, 16_384),)),
+    ("adagrad_128_4c_w3", 1_048_500, 128, 4, "adagrad", 3, (8_192,),
+     ((2, 4_096, 8_192),)),
+]
+STATEFUL_MORE = [   # --alias-all: the cell's own worker count, and dcasgd
+    ("adagrad_2048_w1", 163_840, 2_048, 1, "adagrad", 1, (16_384, 163_840),
+     ((1, 32_768, 16_384),)),
+    ("adagrad_2048_w3_touched", 163_840, 2_048, 1, "adagrad", 3, (16_384,),
+     ()),
+    ("dcasgd_2048_w3", 163_840, 2_048, 1, "dcasgd", 3, (16_384,), ()),
+]
+
+
+def _devices(chips):
+    from jax.experimental import topologies
+    return topologies.get_topology_desc("v5e:2x2", "tpu").devices[:chips]
+
+
+@contextlib.contextmanager
+def _no_allocation():
+    """While a table is built: zeros and placements are shapes only."""
+    zeros, place = jnp.zeros, MeshContext.place
+    jnp.zeros = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        tuple(np.atleast_1d(shape)), dtype)
+    MeshContext.place = lambda self, a, sharding: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=sharding)
+    try:
+        yield
+    finally:
+        jnp.zeros, MeshContext.place = zeros, place
+
+
+def build(rows, cols, chips, updater, workers):
+    ctx = MeshContext.create(_devices(chips))
+    zoo = types.SimpleNamespace(mesh_ctx=ctx, num_workers=workers)
+    with _no_allocation():
+        srv = MatrixServerTable(rows, cols, np.float32, zoo, updater)
+    return srv, ctx
+
+
+def programs(srv, ctx, buckets, merged):
+    """(name, jitted, args) of the row programs a table runs."""
+    rep = (NamedSharding(ctx.mesh, P()) if ctx.mesh.size > 1
+           else SingleDeviceSharding(ctx.mesh.devices.flat[0]))
+    s = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=rep)
+    opt = {"worker_id": s((), jnp.int32)}
+    for k in ("momentum", "learning_rate", "rho", "lambda_"):
+        opt[k] = s((), jnp.float32)
+    state = srv._state
+    update_gather = jax.jit(srv.device_update_gather_rows,
+                            donate_argnums=(0,))
+    for b in buckets:
+        ids, deltas = s((b,), jnp.int32), s((b, srv.num_cols), jnp.float32)
+        yield f"update_rows.{b}", srv._update_rows, (state, ids, deltas, opt)
+        yield (f"gather_rows.{b}", srv._gather_rows,
+               (state["data"], state["aux"], ids))
+        yield (f"update_gather_rows.{b}", update_gather,
+               (state, ids, deltas, opt))
+    for nb, k, uniq in merged:
+        yield (f"merged_add_rows.{nb}x{k}.{uniq}", srv._merged_add_rows,
+               (state, s((uniq,), jnp.int32),
+                s((nb, k, srv.num_cols), jnp.float32),
+                s((nb * k,), jnp.int32), opt))
+
+
+_LOC = re.compile(r'loc\((?:[^()]|\([^()]*\))*\)|#loc\d*( = .*)?')
+_BODY = re.compile(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22')
+
+
+def _mosaic_text(match):
+    """A Mosaic kernel's body (base64 MLIR bytecode, file paths and line
+    numbers of ops/pallas_rows.py inside) as text without locations."""
+    import base64
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+    ctx = mlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        body = ir.Module.parse(base64.b64decode(match.group(1)))
+        return "body: " + body.operation.get_asm(enable_debug_info=False)
+
+
+def dump(directory):
+    os.makedirs(directory, exist_ok=True)
+    for name, rows, cols, chips, updater, workers, buckets, merged in FENCE:
+        srv, ctx = build(rows, cols, chips, updater, workers)
+        assert type(srv._state["aux"]) is dict
+        progs = list(programs(srv, ctx, buckets, merged))
+        if chips == 1:   # the whole-table Add, where a test can place one
+            progs.append(("update_full", srv._update_full,
+                          (srv._state, srv._state["data"], progs[0][2][3])))
+        for prog, fn, args in progs:
+            text = fn.lower(*args).as_text(debug_info=True)
+            text = _BODY.sub(_mosaic_text, _LOC.sub("", text))
+            text = "\n".join(ln.rstrip() for ln in text.splitlines()
+                             if ln.strip())
+            with open(os.path.join(directory, f"{name}.{prog}.txt"),
+                      "w") as f:
+                f.write(text)
+            print("LOWERED", name, prog, len(text))
+        print("STATE", name, jax.tree.structure(srv._state),
+              sorted(vars(srv)))
+
+
+#: what may produce a table-sized array in a row program: the state
+#: itself passed along, and the in-place writes
+_IN_PLACE = {"parameter", "tuple", "get-tuple-element", "bitcast",
+             "conditional", "scatter", "dynamic-update-slice", "custom-call"}
+_INSTR = re.compile(
+    r"^\s*(?:ROOT )?%(\S+) = (\(?)f32\[([\d,]*)\]\S* ([\w-]+)\((.*)")
+
+
+def table_sized_passes(hlo, leaf_elems):
+    """Instructions of a compiled module that write an array as large as
+    a (shard of a) state leaf and are not an in-place row write: a
+    ``copy``, or a fusion whose root is neither a scatter nor a
+    dynamic-update-slice. Each is a pass over a whole table."""
+    roots, body = {}, None
+    for ln in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%(\S+) \(.*\) -> .* \{$", ln)
+        if head:
+            body = head.group(1)
+        elif ln.lstrip().startswith("ROOT ") and body:
+            m = _INSTR.match(ln)
+            roots[body] = (m.group(4), m.group(5)) if m else ("tuple", "")
+
+    def root_op(op, rest):
+        while op == "fusion":    # a fusion's work is its body's root
+            called = re.search(r"calls=%(\S+?)[,\s}]", rest + " ").group(1)
+            op, rest = roots.get(called, ("?", ""))
+        return op
+    found, body = [], None
+    for ln in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%(\S+) \(.*\) -> .* \{$", ln)
+        if head:
+            body = head.group(1)
+            continue
+        m = _INSTR.match(ln)
+        if not m or m.group(2) or (body or "").startswith("fused_"):
+            continue
+        name, _, dims, op, rest = m.groups()
+        if not dims or int(np.prod([int(d) for d in dims.split(",")])) \
+                < leaf_elems:
+            continue
+        if root_op(op, rest) not in _IN_PLACE:
+            found.append(f"{name} = f32[{dims}] {op}:{root_op(op, rest)}")
+    return found
+
+
+def alias(specs):
+    """ALIAS <table> <program> aliased=<n>/<state leaves> passes=<n>"""
+    for name, rows, cols, chips, updater, workers, buckets, merged in specs:
+        srv, ctx = build(rows, cols, chips, updater, workers)
+        leaves = jax.tree.leaves(srv._state)
+        leaf_elems = min(int(np.prod(leaf.shape)) for leaf in leaves) \
+            // srv.num_servers
+        for prog, fn, args in programs(srv, ctx, buckets, merged):
+            if prog.startswith("gather_rows"):
+                continue
+            try:
+                hlo = fn.lower(*args).compile().as_text()
+            except Exception as exc:  # noqa: BLE001 (e.g. over the HBM)
+                print(f"ALIAS {name} {prog} FAILED "
+                      f"{str(exc).splitlines()[0][:200]}", flush=True)
+                continue
+            head = hlo.split("\n", 1)[0]
+            aliased = len(re.findall(r"\{\d+\}: \(\d+, \{\}", head))
+            passes = table_sized_passes(hlo, leaf_elems)
+            print(f"ALIAS {name} {prog} aliased={aliased}/{len(leaves)} "
+                  f"passes={len(passes)}", flush=True)
+            for ln in passes:
+                print("  PASS", ln, flush=True)
+
+
+if __name__ == "__main__":
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--dump")
+    ap.add_argument("--alias", action="store_true")
+    ap.add_argument("--alias-all", action="store_true")
+    a = ap.parse_args()
+    try:
+        _devices(1)
+    except Exception as exc:  # noqa: BLE001
+        print(f"SKIP no v5e topology description: {exc!r}")
+        sys.exit(0)
+    if a.dump:
+        dump(a.dump)
+    if a.alias or a.alias_all:
+        alias(STATEFUL + (STATEFUL_MORE if a.alias_all else []))

@@ -6,6 +6,8 @@ adds per SURVEY.md §5: all tables in one call, updater aux state included,
 resume exactness across a simulated restart.
 """
 
+import os
+
 import numpy as np
 import pytest
 
@@ -152,4 +154,94 @@ class TestCheckpointDriver:
         t.AddRows(ids, np.ones((3, 4), np.float32), option=opt)
         resumed_next = t.GetRows(ids)
         np.testing.assert_allclose(resumed_next, expected_next, rtol=1e-6)
+        mv.MV_ShutDown()
+
+
+# A checkpoint written by the tree BEFORE per-worker updater state became
+# row-shaped storage (PR 27, 276ca25: leaves (workers, rows, cols) in HBM):
+# three MatrixTables 10 x 4 under adagrad, dcasgd and momentum and one
+# ArrayTable of 10 under adagrad, -num_workers=3, after the Adds below from
+# workers 0, 2, 1. The file holds the LOGICAL form, per-worker state as
+# (workers, rows, cols), which no layout change may move.
+_PR27_CKPT = os.path.join(os.path.dirname(__file__), "fixtures",
+                          "ckpt_pr27_workers3.mvt")
+_PR27_OPTION = dict(momentum=0.9, learning_rate=0.02, rho=0.05, lambda_=0.2)
+
+
+def _pr27_replay():
+    """What the fixture's writer did, on the plain reference."""
+    from multiverso_tpu.updaters import reference
+    rng = np.random.default_rng(29)
+    init = (0.02 * rng.standard_normal((10, 4))).astype(np.float32)
+    want = {u: reference.new_state(init, u, 3)
+            for u in ("adagrad", "dcasgd", "momentum")}
+    want["array"] = reference.new_state(np.zeros((10, 1)), "adagrad", 3)
+    for wid, ids in ((0, [1, 4, 7]), (2, [4, 5, 9, 0]), (1, [7, 2])):
+        delta = (1e-3 * rng.standard_normal((len(ids), 4))).astype(np.float32)
+        for u in ("adagrad", "dcasgd", "momentum"):
+            reference.apply_rows(u, want[u], ids, delta, worker_id=wid,
+                                 **_PR27_OPTION)
+        reference.apply_rows(
+            "adagrad", want["array"], np.arange(10),
+            (1e-3 * rng.standard_normal(10)).astype(np.float32),
+            worker_id=wid, **_PR27_OPTION)
+    return want
+
+
+@pytest.mark.parametrize("devices", [1, 2, 8])
+def test_checkpoint_of_the_3d_layout_loads_trains_and_stores(devices,
+                                                             ckpt_path):
+    import jax
+    import multiverso_tpu as mv
+    from multiverso_tpu.tables import ArrayTableOption, MatrixTableOption
+    from multiverso_tpu.updaters import AddOption, reference
+
+    want = _pr27_replay()
+    mv.MV_Init(["-num_workers=3"], devices=jax.devices()[:devices])
+    try:
+        tables = {u: mv.MV_CreateTable(MatrixTableOption(
+            num_rows=10, num_cols=4, updater_type=u))
+            for u in ("adagrad", "dcasgd", "momentum")}
+        tables["array"] = mv.MV_CreateTable(ArrayTableOption(
+            size=10, updater_type="adagrad"))
+        assert mv.MV_LoadCheckpoint(_PR27_CKPT) == 4
+        # the same bytes come back: the logical form did not move
+        assert mv.MV_SaveCheckpoint(ckpt_path) == 4
+        with open(_PR27_CKPT, "rb") as a, open(ckpt_path, "rb") as b:
+            assert a.read() == b.read()
+
+        def check():
+            for u, table in tables.items():
+                srv = table.server()
+                got = table.Get() if u == "array" else srv.raw()
+                np.testing.assert_allclose(
+                    np.asarray(got).reshape(want[u]["data"].shape),
+                    want[u]["data"], rtol=2e-5, atol=2e-6)
+                for name, leaf in srv.state["aux"].items():
+                    assert leaf.ndim == srv.state["data"].ndim
+                    logical = srv.aux_to_logical(name, leaf)
+                    assert logical.shape == want[u][name].shape[
+                        : logical.ndim]
+                    np.testing.assert_allclose(
+                        logical.reshape(want[u][name].shape), want[u][name],
+                        rtol=2e-5, atol=2e-6)
+        check()
+        # train a step from worker 1 on the loaded state
+        ids = np.array([7, 3, 0], np.int32)
+        delta = np.full((3, 4), 2e-3, np.float32)
+        opt = dict(_PR27_OPTION, worker_id=1)
+        for u in ("adagrad", "dcasgd", "momentum"):
+            tables[u].AddRows(ids, delta, AddOption(**opt))
+            reference.apply_rows(u, want[u], ids, delta, **opt)
+        tables["array"].Add(np.full(10, 2e-3, np.float32), AddOption(**opt))
+        reference.apply_rows("adagrad", want["array"], np.arange(10),
+                             np.full((10, 1), 2e-3, np.float32), **opt)
+        check()
+        # ... and a restart from what this tree stores resumes exactly
+        assert mv.MV_SaveCheckpoint(ckpt_path) == 4
+        for u in ("adagrad", "dcasgd", "momentum"):
+            tables[u].AddRows(ids, delta, AddOption(**opt))
+        assert mv.MV_LoadCheckpoint(ckpt_path) == 4
+        check()
+    finally:
         mv.MV_ShutDown()

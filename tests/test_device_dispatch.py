@@ -124,7 +124,7 @@ def test_changed_learning_rate_is_one_miss_exact_and_no_retrace(world):
     assert moved() == (2, 2)                    # the first rate was kept
     np.testing.assert_array_equal(srv.raw(), want["data"])
     np.testing.assert_array_equal(
-        srv.aux_to_logical(np.asarray(srv.state["aux"]["backup"])),
+        srv.aux_to_logical("backup", srv.state["aux"]["backup"]),
         want["backup"])
 
 

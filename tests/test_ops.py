@@ -137,6 +137,59 @@ class TestKernelsCompileForTpu:
         assert last.startswith("ADMITTED [128"), res.stdout[-2000:]
 
 
+class TestStatefulRowProgramsAliasOnTpu:
+    """The row programs of a table WITH per-worker updater state, compiled
+    for a described v5e by tests/aot_table_programs.py (a child: it makes
+    ``jax.default_backend()`` answer "tpu"; in this file, after the kernel
+    compile above, because one process at a time holds the TPU library):
+    every state leaf is aliased input to output and no instruction passes
+    over a whole table outside the in-place row writes. This is what keeps
+    ``lm_vocab_steps``' gain (PERF.md section 6, PR 29) between chip
+    checks: a (workers, rows, cols) leaf, an ``.at[wid]`` in an updater or
+    a read-back that breaks the in-place chain shows here as a table-sized
+    ``copy`` or fusion."""
+
+    PROGRAMS = [
+        ("adagrad_2048_w3", "update_rows.163840"),        # the dense run
+        ("adagrad_2048_w3", "update_gather_rows.163840"),
+        ("adagrad_2048_w3", "merged_add_rows.1x32768.16384"),
+        ("adagrad_128_4c_w3", "update_rows.8192"),        # shard_map, Pallas
+        ("adagrad_128_4c_w3", "update_gather_rows.8192"),
+        ("adagrad_128_4c_w3", "merged_add_rows.2x4096.8192"),
+    ]
+
+    @pytest.fixture(scope="class")
+    def compiled(self):
+        import os
+        import re
+        import subprocess
+        import sys
+        here = os.path.dirname(os.path.abspath(__file__))
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
+        res = subprocess.run(
+            [sys.executable, os.path.join(here, "aot_table_programs.py"),
+             "--alias"], env=env, capture_output=True, text=True,
+            timeout=900)
+        assert res.returncode == 0, res.stderr[-3000:]
+        lines = res.stdout.strip().splitlines()
+        if lines and lines[-1].startswith("SKIP"):
+            pytest.skip(lines[-1])
+        found = {}
+        for ln in lines:
+            m = re.match(r"ALIAS (\S+) (\S+) (.*)", ln)
+            if m:
+                found[m.group(1), m.group(2)] = m.group(3)
+        return found, res.stdout
+
+    @pytest.mark.parametrize("table,program", PROGRAMS)
+    def test_state_aliases_through_the_row_program(self, compiled, table,
+                                                   program):
+        found, out = compiled
+        assert (table, program) in found, out[-2000:]
+        # data and the history, both donated, both updated in place
+        assert found[table, program] == "aliased=2/2 passes=0", out[-3000:]
+
+
 class TestMatrixTableWithPallas:
     """Full PS path with the Pallas write kernel (interpret mode on CPU)."""
 

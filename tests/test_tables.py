@@ -830,7 +830,7 @@ class TestArrayDevicePlane:
             got = table.Get()
             assert np.all(np.isfinite(got)) and np.all(got != 0)
             # per-worker hist updated for worker 1 only
-            hist = np.asarray(server.aux_to_logical(state["aux"]["hist"]))
+            hist = server.aux_to_logical("hist", state["aux"]["hist"])
             assert hist.shape[0] == 2
             assert np.all(hist[1] > 0) and np.all(hist[0] == 0)
         finally:

@@ -50,7 +50,7 @@ def _id_sets(kind: str, rng) -> list:
 
 def _state_leaf(srv, name):
     """A per-worker or shared aux leaf in the logical row layout."""
-    return srv.aux_to_logical(np.asarray(srv.state["aux"][name]))
+    return srv.aux_to_logical(name, srv.state["aux"][name])
 
 
 @pytest.fixture()
@@ -147,3 +147,97 @@ def test_host_delta_with_repeats_takes_the_host_combine(world, monkeypatch):
     np.testing.assert_allclose(srv.raw(), want["data"], rtol=RTOL, atol=ATOL)
     np.testing.assert_allclose(_state_leaf(srv, "hist"), want["hist"],
                                rtol=RTOL, atol=ATOL)
+
+
+# -- per-worker state, workers interleaved (ISSUE 29) -----------------------
+# Three workers' Adds in the order 0, 2, 1, 0 through every path that
+# reaches a stateful updater. Per-worker state is row-shaped storage in
+# which a shard stacks its workers' blocks (updaters/base.py worker_rows):
+# an Add reads and writes the rows of the worker that sent it, whose id is
+# a TRACED scalar of the one compiled program.
+
+_COMPILES = []
+jax.monitoring.register_event_duration_secs_listener(
+    lambda name, secs, **kw: _COMPILES.append(name)
+    if name == "/jax/core/compile/backend_compile_duration" else None)
+
+WORKER_ORDER = (0, 2, 1, 0)
+PATHS = ["device_distinct", "device_repeated", "device_whole",
+         "device_whole_dense_run", "host_add_rows", "whole_add", "array"]
+
+
+def _apply(path, table, ids, delta, option):
+    srv = table.server()
+    if path.startswith("device"):
+        srv.device_apply_rows(ids, jnp.asarray(delta), option)
+    elif path == "host_add_rows":
+        table.AddRows(ids, delta, option)
+    else:                       # whole_add and array: every row, in order
+        table.Add(delta, option)
+
+
+@pytest.mark.parametrize("path", PATHS)
+@pytest.mark.parametrize("updater", ["adagrad", "dcasgd"])
+def test_workers_interleaved(updater, path, monkeypatch):
+    import multiverso_tpu as mv
+    from multiverso_tpu.ops import rows as ops_rows
+    from multiverso_tpu.tables import ArrayTableOption
+    cols = 1 if path == "array" else 128
+    # the dense run (a slice for consecutive ids) belongs to one shard on
+    # the chip; here it runs on one CPU device, which copies where the
+    # chip aliases and computes the same
+    one_shard = path == "device_whole_dense_run"
+    if one_shard:
+        monkeypatch.setattr(ops_rows, "_dense_backend_ok", lambda: True)
+    kind = {"device_distinct": "distinct", "device_repeated": "repeated",
+            "host_add_rows": "repeated"}.get(path, "whole")
+    rng = np.random.default_rng(len(path) * 31 + len(updater))
+    init = (0.02 * rng.standard_normal((ROWS, cols))).astype(np.float32)
+    mv.MV_Init(["-num_workers=3"],
+               devices=jax.devices()[:1] if one_shard else None)
+    try:
+        if path == "array":
+            table = mv.MV_CreateTable(ArrayTableOption(
+                size=ROWS, updater_type=updater))
+            want = reference.new_state(np.zeros_like(init), updater, 3)
+        else:
+            table = mv.MV_CreateTable(MatrixTableOption(
+                num_rows=ROWS, num_cols=cols, updater_type=updater,
+                initializer=lambda shape: init))
+            want = reference.new_state(init, updater, 3)
+        srv = table.server()
+        name = "hist" if updater == "adagrad" else "backup"
+        for leaf in jax.tree.leaves(srv.state["aux"]):
+            assert leaf.ndim == srv.state["data"].ndim   # no (W, rows, cols)
+        id_sets = _id_sets(kind, rng) + _id_sets(kind, rng)
+        compiles, start = None, len(_COMPILES)
+        for wid, ids in zip(WORKER_ORDER, id_sets):
+            delta = (1e-3 * rng.standard_normal((len(ids), cols))
+                     ).astype(np.float32)
+            option = AddOption(**dict(OPTION, worker_id=wid))
+            before = _state_leaf(srv, name)
+            _apply(path, table, ids,
+                   delta.ravel() if path == "array" else delta, option)
+            jax.block_until_ready(srv.state)
+            reference.apply_rows(updater, want, ids, delta,
+                                 **dict(OPTION, worker_id=wid))
+            after = _state_leaf(srv, name)
+            assert after.shape == (3,) + before.shape[1:]
+            others = [w for w in range(3) if w != wid]
+            # bit for bit: another worker's Add is no event for a worker
+            np.testing.assert_array_equal(after[others], before[others])
+            assert not np.array_equal(after[wid], before[wid])
+            if compiles is None:
+                compiles = len(_COMPILES)   # worker 0 compiled the programs
+                assert compiles > start
+        # a change of worker compiled nothing
+        assert len(_COMPILES) == compiles
+        data = table.Get() if path == "array" else srv.raw()
+        np.testing.assert_allclose(
+            np.asarray(data).reshape(ROWS, cols), want["data"],
+            rtol=RTOL, atol=ATOL)
+        np.testing.assert_allclose(
+            _state_leaf(srv, name).reshape(want[name].shape), want[name],
+            rtol=RTOL, atol=ATOL)
+    finally:
+        mv.MV_ShutDown()
