@@ -10,7 +10,9 @@ for row writes (only touched rows move), with XLA's scatter everywhere else
 
 from multiverso_tpu.ops.rows import (dedup_rows, gather_rows, padded_cols,
                                      scatter_set_rows, update_gather_rows,
-                                     update_rows, use_pallas)
+                                     update_rows, update_rows_with_state,
+                                     use_pallas)
 
 __all__ = ["dedup_rows", "gather_rows", "padded_cols", "scatter_set_rows",
-           "update_gather_rows", "update_rows", "use_pallas"]
+           "update_gather_rows", "update_rows", "update_rows_with_state",
+           "use_pallas"]

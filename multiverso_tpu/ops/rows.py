@@ -11,7 +11,12 @@ One decision, made here and nowhere else:
   XLA's ``.at[].set`` scatter otherwise;
 * a **dense run** (consecutive ids, detected at run time by
   ``_dense_run``) takes one bulk slice -> combine -> update-slice where
-  ``_dense_backend_ok()`` (TPU only) and the caller allows it.
+  ``_dense_backend_ok()`` (TPU only) and the caller allows it, in every
+  verb that consumes the table: ``update_rows`` / ``update_gather_rows``
+  (aux-free updaters), ``update_rows_with_state`` (the rows and every
+  state leaf of a stateful updater under ONE test: a slice, the update
+  and an update-slice a table) and ``scatter_set_rows`` (a write of rows
+  made elsewhere, which reads the old rows to keep its pad lanes).
 
 This is the split every benchmark cell runs (``PERF_LEDGER.jsonl``'s
 ``breakdown`` names ``pallas_scatter_set_rows`` and XLA's ``fusion``
@@ -184,8 +189,9 @@ def gather_rows(data: jax.Array, ids: jax.Array) -> jax.Array:
     (non-donated) table defeats XLA's buffer aliasing — each branch gets
     an operand copy of the whole table. The dense bulk-slice fast path
     lives only in the verbs that consume/donate the table
-    (scatter_set_rows, update_rows, update_gather_rows), where the
-    in-place chain survives the cond."""
+    (scatter_set_rows, update_rows, update_gather_rows,
+    update_rows_with_state), where the in-place chain survives the
+    cond."""
     return jnp.take(data, ids, axis=0, mode="clip")
 
 
@@ -277,3 +283,63 @@ def update_gather_rows(data: jax.Array, ids: jax.Array, deltas: jax.Array,
         return general(None)   # static guards (see gather_rows)
     ok, start, _ = _dense_run(ids, data.shape[0])
     return jax.lax.cond(ok, dense_fn, general, None)
+
+
+def update_rows_with_state(data: jax.Array, aux, ids: jax.Array, aux_lanes,
+                           deltas: jax.Array, update, *, dense: bool = True):
+    """``update_gather_rows`` for an updater that holds state:
+    ``data[ids], aux[lanes] = update(data[ids], aux[lanes], deltas)``.
+    ``aux`` is a pytree of row-shaped 2-D leaves and ``aux_lanes`` gives,
+    a leaf, the rows of ``ids`` in it (trash lanes: the leaf's last row);
+    ``update(rows, aux_rows, deltas) -> (rows, aux_rows)`` is elementwise
+    over the rows. Returns (new_data, new_aux, rows); trash/pad lanes of
+    ``rows`` are arbitrary (callers mask).
+
+    The dense run is decided ONCE for the apply, on ``ids``: a leaf's
+    lanes are ``ids`` plus an offset, so its run starts at its lanes'
+    first element. That branch slices the rows and every leaf once,
+    updates the slices, and writes each back with one update-slice; the
+    lanes past the run's end keep the SLICE's values, rows and state (an
+    updater need not be the identity on a zero delta: momentum's smooth
+    decays), so nothing is gathered and no table is read twice."""
+    bucket = ids.shape[0]
+
+    def first(leaf, lanes):
+        # a bucket as long as the leaf's live rows can only start at row
+        # 0: a constant, which lets XLA write the update-slice in place
+        # from the update's own fusion (a dynamic start is a copy apart)
+        return 0 if bucket == leaf.shape[0] - 1 else lanes[0]
+
+    def dense_fn(data, aux):
+        def cut(leaf, at):
+            return jax.lax.dynamic_slice(leaf, (at, 0),
+                                         (bucket, leaf.shape[1]))
+
+        def put(leaf, rows, at):
+            return jax.lax.dynamic_update_slice(leaf, rows, (at, 0))
+        tables = (data, aux)     # the rows and the state, one tree
+        starts = jax.tree.map(first, tables, (ids, aux_lanes))
+        old = jax.tree.map(cut, tables, starts)
+        keep = (jnp.arange(bucket) < count)[:, None]
+        new = jax.tree.map(lambda n, o: jnp.where(keep, n, o),
+                           tuple(update(*old, deltas)), old)
+        return (*jax.tree.map(put, tables, new, starts), new[0])
+
+    def general(data, aux):
+        rows = jnp.take(data, ids, axis=0, mode="clip")
+        aux_rows = jax.tree.map(lambda leaf, lanes: jnp.take(leaf, lanes,
+                                                             axis=0),
+                                aux, aux_lanes)
+        new, new_aux = update(rows, aux_rows, deltas)
+        # trash lanes computed garbage from the trash row: it goes
+        # straight back to the trash row, never to live data
+        return (_set_rows(data, ids, new, use_pallas(data, ids)),
+                jax.tree.map(lambda leaf, lanes, rows: _set_rows(
+                    leaf, lanes, rows, use_pallas(leaf, lanes)),
+                    aux, aux_lanes, new_aux), new)
+
+    if (not dense or not _dense_backend_ok()
+            or bucket >= data.shape[0]):
+        return general(data, aux)   # static guards (see gather_rows)
+    ok, _, count = _dense_run(ids, data.shape[0])
+    return jax.lax.cond(ok, dense_fn, general, data, aux)

@@ -303,15 +303,6 @@ class MatrixServerTable(ServerTable):
             return jax.tree.map(lambda leaf, idx: jnp.take(leaf, idx, axis=0),
                                 aux, lanes)
 
-        def _scatter_aux(aux, new_aux, lanes):
-            # state is row-shaped like the data (one worker's rows of it
-            # are len(ids) rows of a 2-D leaf), so its writes take the
-            # data rows' write path (ops/rows.py): the Pallas kernel at 128
-            # lanes, XLA's scatter wider, the dense run on one shard
-            return jax.tree.map(
-                lambda leaf, idx, rows: ops.scatter_set_rows(
-                    leaf, idx, rows, dense=single), aux, lanes, new_aux)
-
         num_workers, num_servers = zoo.num_workers, self.num_servers
 
         def _update_full(state, delta, opt):
@@ -338,6 +329,18 @@ class MatrixServerTable(ServerTable):
         self._merge_adds = fuse and merge_scale is not None
         combine = updater.combine  # captured once: identity-stable jit key
 
+        def _update_stateful(local_data, local_aux, safe, deltas, opt):
+            # state is row-shaped like the data (one worker's rows of it
+            # are len(ids) rows of a 2-D leaf), so rows and state take one
+            # row op (ops/rows.py): the Pallas kernel at 128 lanes, XLA's
+            # scatter wider, one dense run for all of them on one shard
+            return ops.update_rows_with_state(
+                local_data, local_aux, safe,
+                _aux_lanes(local_aux, safe, opt), deltas,
+                lambda rows, aux_rows, d: updater.update(rows, aux_rows, d,
+                                                         opt),
+                dense=single)
+
         def _update_rows_local(local_data, local_aux, ids, deltas, opt):
             _, safe = _local_lanes(ids)
             # dense=single: the runtime dense-run cond belongs to the
@@ -346,16 +349,8 @@ class MatrixServerTable(ServerTable):
             if fuse:
                 return ops.update_rows(local_data, safe, deltas,
                                        combine, dense=single), local_aux
-            rows = ops.gather_rows(local_data, safe)
-            lanes = _aux_lanes(local_aux, safe, opt)
-            new_rows, new_aux_rows = updater.update(
-                rows, _gather_aux(local_aux, lanes), deltas, opt)
-            # Non-mine lanes computed garbage from the trash row — it goes
-            # straight back to the trash row, never to live data.
-            data = ops.scatter_set_rows(local_data, safe, new_rows,
-                                        dense=single)
-            aux = _scatter_aux(local_aux, new_aux_rows, lanes)
-            return data, aux
+            return _update_stateful(local_data, local_aux, safe, deltas,
+                                    opt)[:2]
 
         store_cols = self.store_cols
 
@@ -502,13 +497,8 @@ class MatrixServerTable(ServerTable):
                 # — reuse them instead of a second full gather (duplicates
                 # are caller-pre-combined, so per-lane new_rows are exact;
                 # trash lanes are garbage and masked below)
-                rows_in = ops.gather_rows(local_data, safe)
-                lanes = _aux_lanes(local_aux, safe, opt)
-                rows, new_aux_rows = updater.update(
-                    rows_in, _gather_aux(local_aux, lanes), deltas, opt)
-                data = ops.scatter_set_rows(local_data, safe, rows,
-                                            dense=single)
-                aux = _scatter_aux(local_aux, new_aux_rows, lanes)
+                data, aux, rows = _update_stateful(local_data, local_aux,
+                                                   safe, deltas, opt)
             if has_access:
                 rows = updater.access(rows, _gather_aux(
                     aux, _aux_lanes(aux, safe, None)), None)
@@ -1711,6 +1701,18 @@ class MatrixServerTable(ServerTable):
                         padded = self._pad_ids(ids)
                 tmetrics.counter("table.device_apply.rows").inc(positions)
                 tmetrics.counter("table.device_apply.unique_rows").inc(unique)
+                # the device chooses the dense run (ops/rows.py _dense_run);
+                # the host counts the batches it will accept: one shard,
+                # distinct ids in order with no gap (``uniq`` is the sorted
+                # set, so repeats never reach the comparison), the bucket
+                # inside the live rows
+                dense_runs = tmetrics.counter("table.device_apply.dense_runs")
+                if (nproc == 1 and self.num_servers == 1
+                        and unique == positions > 0
+                        and uniq[-1] - uniq[0] + 1 == unique
+                        and uniq[0] + len(padded) <= self.block_rows
+                        and np.array_equal(uniq, ids)):
+                    dense_runs.inc()
                 tmetrics.counter("table.device_apply.bytes").inc(
                     positions * self.num_cols * self.dtype.itemsize)
                 # bytes of delta copied to the host to combine repeats:

@@ -18,11 +18,14 @@ step (AdaGrad: rho / sqrt(t)), rows or history kept in bfloat16 by 2**-9
 of an entry.
 """
 
+import collections
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from multiverso_tpu.parallel.mesh import next_bucket
 from multiverso_tpu.tables import MatrixTableOption
 from multiverso_tpu.telemetry import metrics
 from multiverso_tpu.updaters import reference
@@ -239,5 +242,171 @@ def test_workers_interleaved(updater, path, monkeypatch):
         np.testing.assert_allclose(
             _state_leaf(srv, name).reshape(want[name].shape), want[name],
             rtol=RTOL, atol=ATOL)
+    finally:
+        mv.MV_ShutDown()
+
+
+# -- the stateful apply's dense run (ISSUE 32) -------------------------------
+# ops.update_rows_with_state decides the dense run once for the rows and
+# every state leaf. The chip takes that branch; here the backend test is
+# patched so that one CPU device takes it too, and the same Adds through
+# the general branch (gather, update, scatter) must give the same bits.
+
+DENSE_ROWS = 128        # buckets 64 and 128 fit inside the 129 stored rows
+DENSE_CASES = {         # ids of every Add, and whether the run is dense
+    "whole_table": (np.arange(DENSE_ROWS), True),
+    "starts_past_row_0": (np.arange(40, 104), True),
+    # 40 ids in a bucket of 64: rows 50..73 and their state are pad lanes
+    "shorter_than_bucket": (np.arange(10, 50), True),
+    "in_order_with_gap": (np.r_[10:30, 31:51], False),
+    "shuffled": (np.random.default_rng(3).permutation(np.arange(10, 74)),
+                 False),
+}
+
+
+# momentum's ``m * smooth + (1 - m) * delta`` is a multiply-add that the CPU's
+# compiler contracts in one branch's loop and not in the other's (an ulp);
+# with m = 0.5 and values on a binary grid every step is exact, so the
+# comparison stays bit for bit and a lane that took the wrong value shows
+DENSE_OPTION = dict(OPTION, momentum=0.5)
+
+
+def _on_grid(values, step):
+    return (np.round(values / step) * step).astype(np.float32)
+
+
+def _one_shard_table(updater, dense, monkeypatch, workers=3):
+    """A world of one CPU device (MV_ShutDown is the caller's) whose row
+    programs hold the dense-run ``cond`` or do not, and a primed table."""
+    import multiverso_tpu as mv
+    from multiverso_tpu.ops import rows as ops_rows
+    monkeypatch.setattr(ops_rows, "_dense_backend_ok", lambda: dense)
+    rng = np.random.default_rng(11)
+    init = _on_grid(0.02 * rng.standard_normal((DENSE_ROWS, 128)), 2.0 ** -16)
+    mv.MV_Init([f"-num_workers={workers}"], devices=jax.devices()[:1])
+    table = mv.MV_CreateTable(MatrixTableOption(
+        num_rows=DENSE_ROWS, num_cols=128, updater_type=updater,
+        initializer=lambda shape: init))
+    # every row's state away from zero before the Adds under test (the
+    # whole-table program holds no cond): a pad lane that decayed its
+    # momentum or refreshed its backup would show
+    table.Add(_on_grid(1e-3 * rng.standard_normal(init.shape), 2.0 ** -12),
+              AddOption(**DENSE_OPTION))
+    return table.server()
+
+
+def _dense_runs_counted():
+    return metrics.snapshot().get(
+        "table.device_apply.dense_runs", {}).get("value", 0)
+
+
+def _states_after_adds(updater, ids, dense, monkeypatch):
+    """Storage (rows and every state leaf, trash rows included) after each
+    of four Adds of ``ids`` from workers 0, 2, 1, 0."""
+    import multiverso_tpu as mv
+    from multiverso_tpu.ops import rows as ops_rows
+    ids = np.asarray(ids, np.int32)
+    rng = np.random.default_rng(17)
+    seen = []
+    try:
+        srv = _one_shard_table(updater, dense, monkeypatch)
+        if dense:   # the static guards pass: the program holds the cond
+            assert next_bucket(len(ids)) < srv.state["data"].shape[0]
+        safe = jnp.asarray(srv.pad_ids(ids))
+        safe = jnp.where(safe >= 0, safe, srv.block_rows)
+        run = bool(ops_rows._dense_run(safe, srv.shard_rows)[0])
+        before = _dense_runs_counted()
+        for wid in WORKER_ORDER:
+            delta = _on_grid(1e-3 * rng.standard_normal((len(ids), 128)),
+                             2.0 ** -12)
+            srv.device_apply_rows(
+                ids, jnp.asarray(delta),
+                AddOption(**dict(DENSE_OPTION, worker_id=wid)))
+            seen.append(jax.tree.map(np.asarray, srv.state))
+        counted = _dense_runs_counted() - before
+    finally:
+        mv.MV_ShutDown()
+    return seen, run, counted
+
+
+@pytest.mark.parametrize("case", list(DENSE_CASES))
+@pytest.mark.parametrize("updater", ["adagrad", "dcasgd", "momentum"])
+def test_dense_branch_equals_general_branch(updater, case, monkeypatch):
+    ids, is_run = DENSE_CASES[case]
+    dense, run, counted = _states_after_adds(updater, ids, True, monkeypatch)
+    general, _, _ = _states_after_adds(updater, ids, False, monkeypatch)
+    # the device's own test and the host's counter agree on the case
+    assert run == is_run
+    assert counted == (len(WORKER_ORDER) if is_run else 0)
+    for a, b in zip(dense, general):
+        # rows, the sending worker's state, every other worker's: bit for bit
+        jax.tree.map(np.testing.assert_array_equal, a, b)
+    # and an Add changed what it named and nothing after the run's end
+    named = np.zeros(DENSE_ROWS + 1, bool)
+    named[ids] = True
+    for prev, cur in zip(dense, dense[1:]):
+        changed = np.any(prev["data"] != cur["data"], axis=1)
+        assert changed[named].all() and not changed[~named].any()
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of everything it calls."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+@pytest.mark.parametrize("updater", ["adagrad", "dcasgd", "momentum"])
+def test_stateful_row_program_has_one_cond_and_a_slice_a_table(
+        updater, monkeypatch):
+    """The row program of a table with updater state holds ONE ``cond``
+    (not one a leaf), and its dense branch reads each table with one
+    slice and writes it with one update-slice: no gather, no scatter."""
+    import multiverso_tpu as mv
+    try:
+        srv = _one_shard_table(updater, True, monkeypatch)
+        ids = jnp.asarray(srv.pad_ids(np.arange(10, 50, dtype=np.int32)))
+        program = jax.make_jaxpr(srv.device_update_rows)(
+            srv.state, ids, jnp.zeros((64, 128), jnp.float32),
+            AddOption().as_jnp())
+    finally:
+        mv.MV_ShutDown()
+    conds = [e for e in _equations(program.jaxpr)
+             if e.primitive.name == "cond"]
+    assert len(conds) == 1
+    general, dense = (
+        collections.Counter(e.primitive.name for e in _equations(b.jaxpr))
+        for b in conds[0].params["branches"])
+    tables = 1 + len(jax.tree.leaves(srv._state["aux"]))
+    assert tables == 2
+    assert dense["gather"] == 0 and dense["scatter"] == 0
+    assert dense["dynamic_slice"] == tables
+    assert dense["dynamic_update_slice"] == tables
+    assert general["gather"] == tables and general["scatter"] == tables
+    assert general["dynamic_update_slice"] == 0
+
+
+@pytest.mark.parametrize("kind,stepped", [
+    ("whole_in_order", 1), ("shuffled", 0), ("repeated", 0), ("sharded", 0)])
+def test_dense_runs_counter(kind, stepped):
+    """``table.device_apply.dense_runs`` counts the batches the device's
+    dense-run test will accept, as far as the host can see them."""
+    import multiverso_tpu as mv
+    ids = np.arange(DENSE_ROWS, dtype=np.int32)
+    if kind == "shuffled":
+        ids = np.random.default_rng(2).permutation(ids).astype(np.int32)
+    elif kind == "repeated":
+        ids = np.sort(np.concatenate([ids, ids[:8]]))
+    mv.MV_Init(["-num_workers=1"],
+               devices=None if kind == "sharded" else jax.devices()[:1])
+    try:
+        srv = mv.MV_CreateTable(MatrixTableOption(
+            num_rows=DENSE_ROWS, num_cols=128,
+            updater_type="adagrad")).server()
+        assert (srv.num_servers > 1) == (kind == "sharded")
+        before = _dense_runs_counted()
+        srv.device_apply_rows(ids, jnp.ones((len(ids), 128), jnp.float32))
+        assert _dense_runs_counted() - before == stepped
     finally:
         mv.MV_ShutDown()
