@@ -9,10 +9,10 @@ for row writes (only touched rows move), with XLA's scatter everywhere else
 """
 
 from multiverso_tpu.ops.rows import (dedup_rows, gather_rows, padded_cols,
-                                     scatter_set_rows, update_gather_rows,
-                                     update_rows, update_rows_with_state,
-                                     use_pallas)
+                                     row_write, scatter_set_rows,
+                                     update_gather_rows, update_rows,
+                                     update_rows_with_state, use_pallas)
 
-__all__ = ["dedup_rows", "gather_rows", "padded_cols", "scatter_set_rows",
-           "update_gather_rows", "update_rows", "update_rows_with_state",
-           "use_pallas"]
+__all__ = ["dedup_rows", "gather_rows", "padded_cols", "row_write",
+           "scatter_set_rows", "update_gather_rows", "update_rows",
+           "update_rows_with_state", "use_pallas"]

@@ -343,3 +343,27 @@ def update_rows_with_state(data: jax.Array, aux, ids: jax.Array, aux_lanes,
         return general(data, aux)   # static guards (see gather_rows)
     ok, _, count = _dense_run(ids, data.shape[0])
     return jax.lax.cond(ok, dense_fn, general, data, aux)
+
+
+def row_write(shard_rows: int, cols: int, dtype, bucket: int) -> str:
+    """Which write a row-update program of ``bucket`` id lanes takes on a
+    shard of ``shard_rows`` x ``cols`` (trash row counted), by the tests
+    the update verbs above make on the same static shapes; the table layer
+    counts its applies by it (``table.device_apply.*_verbs``):
+
+    * ``"small_table"``: the bucket is not under the shard's rows, so the
+      program is the general branch alone, with no dense-run ``cond``
+      (a slice of ``bucket`` rows does not fit the table); its rows are
+      written by the kernel or by XLA's scatter as below;
+    * ``"pallas"``: the general branch writes through
+      ``pallas_scatter_set_rows`` (``use_pallas``);
+    * ``"xla"``: it writes through XLA's scatter: a row shape Mosaic
+      does not compile, an id vector over ``SMEM_IDS_BYTES``, or no TPU.
+
+    On one shard the last two sit behind the dense-run test, which the
+    device decides from the ids."""
+    if bucket >= shard_rows:
+        return "small_table"
+    data = jax.ShapeDtypeStruct((shard_rows, cols), dtype)
+    ids = jax.ShapeDtypeStruct((bucket,), jnp.int32)
+    return "pallas" if use_pallas(data, ids) else "xla"

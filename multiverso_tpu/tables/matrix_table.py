@@ -1703,9 +1703,8 @@ class MatrixServerTable(ServerTable):
                 tmetrics.counter("table.device_apply.unique_rows").inc(unique)
                 # the device chooses the dense run (ops/rows.py _dense_run);
                 # the host counts the batches it will accept: one shard,
-                # distinct ids in order with no gap (``uniq`` is the sorted
-                # set, so repeats never reach the comparison), the bucket
-                # inside the live rows
+                # distinct ids in order with no gap (``uniq`` is sorted, so
+                # repeats never reach the comparison), the bucket in the rows
                 dense_runs = tmetrics.counter("table.device_apply.dense_runs")
                 if (nproc == 1 and self.num_servers == 1
                         and unique == positions > 0
@@ -1719,6 +1718,7 @@ class MatrixServerTable(ServerTable):
                 # none since the device combine; registered (at 0) so that
                 # a path which brings the copy back has a counter to step
                 tmetrics.counter("table.device_apply.d2h_bytes")
+                self._count_apply_write(len(gids if nproc > 1 else padded))
             with ttrace.span("server.table.device_apply.dispatch",
                              cat="server"):
                 opt = self._device_opt(option)
@@ -1742,6 +1742,23 @@ class MatrixServerTable(ServerTable):
                 self.state = self._update_rows(
                     self.state, self._place_small(padded),
                     _pad_rows(deltas, len(padded)), opt)
+
+    def _count_apply_write(self, bucket: int) -> None:
+        """One apply verb under the write its row program takes on this
+        table's shard at ``bucket`` id lanes (``ops.row_write``, static
+        shapes only): ``table.device_apply.pallas_verbs``, ``.xla_verbs``
+        or ``.small_table_verbs``. The name is kept a bucket, as the jit
+        cache keeps the program: a verb pays one dictionary lookup."""
+        # made here and not in __init__: a line added above the row
+        # programs' call sites recompiles every kernel-holding program
+        # once a checkout (ROADMAP.md D13)
+        names = self.__dict__.setdefault("_apply_write_counter", {})
+        name = names.get(bucket)
+        if name is None:
+            name = names[bucket] = "table.device_apply.%s_verbs" % (
+                ops.row_write(self.shard_rows, self.store_cols, self.dtype,
+                              bucket))
+        tmetrics.counter(name).inc()
 
     def raw(self) -> np.ndarray:
         """Logical-view snapshot (host numpy)."""

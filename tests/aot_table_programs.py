@@ -14,6 +14,9 @@ state (``STATEFUL``) and prints, a program, whether every state leaf is
 aliased input to output and how many table-sized ``copy`` instructions
 the compiled module holds: tests/test_ops.py asserts on it.
 
+``--tiny`` compiles the row programs of AdaGrad tables of 1 to 5 live rows
+at one lane tile (``TINY``) and prints how many Mosaic kernels each holds.
+
 Prints ``SKIP ...`` and exits 0 where the topology description is missing.
 """
 
@@ -69,6 +72,11 @@ STATEFUL_MORE = [   # --alias-all: the cell's own worker count, and dcasgd
      ()),
     ("dcasgd_2048_w3", 163_840, 2_048, 1, "dcasgd", 3, (16_384,), ()),
 ]
+TINY = [    # --tiny: rec_bag_steps' smallest tables, 1, 2, 4 and 5 live rows
+    # (and the trash row) at one lane tile under AdaGrad: a verb's 2,048
+    # positions, and the 8-lane bucket their distinct rows fold into
+    (f"adagrad_128_r{rows}", rows, 128, 1, "adagrad", 1, (2_048,),
+     ((1, 2_048, 8),)) for rows in (1, 2, 4, 5)]
 
 
 def _devices(chips):
@@ -234,11 +242,25 @@ def alias(specs):
                 print("  PASS", ln, flush=True)
 
 
+def tiny(specs):
+    """TINY <table> <program> kernels=<n>: the program compiled for the
+    chip, with so many Mosaic kernels in it (the rows' write and the
+    history's, where the program writes)."""
+    for name, rows, cols, chips, updater, workers, buckets, merged in specs:
+        srv, ctx = build(rows, cols, chips, updater, workers)
+        for prog, fn, args in programs(srv, ctx, buckets, merged):
+            hlo = fn.lower(*args).compile().as_text()
+            kernels = len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                                     hlo))
+            print(f"TINY {name} {prog} kernels={kernels}", flush=True)
+
+
 if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--dump")
     ap.add_argument("--alias", action="store_true")
     ap.add_argument("--alias-all", action="store_true")
+    ap.add_argument("--tiny", action="store_true")
     a = ap.parse_args()
     try:
         _devices(1)
@@ -249,3 +271,5 @@ if __name__ == "__main__":
         dump(a.dump)
     if a.alias or a.alias_all:
         alias(STATEFUL + (STATEFUL_MORE if a.alias_all else []))
+    if a.tiny:
+        tiny(TINY)

@@ -168,7 +168,7 @@ class TestStatefulRowProgramsAliasOnTpu:
         env = dict(os.environ, JAX_PLATFORMS="cpu")
         res = subprocess.run(
             [sys.executable, os.path.join(here, "aot_table_programs.py"),
-             "--alias"], env=env, capture_output=True, text=True,
+             "--alias", "--tiny"], env=env, capture_output=True, text=True,
             timeout=900)
         assert res.returncode == 0, res.stderr[-3000:]
         lines = res.stdout.strip().splitlines()
@@ -176,7 +176,7 @@ class TestStatefulRowProgramsAliasOnTpu:
             pytest.skip(lines[-1])
         found = {}
         for ln in lines:
-            m = re.match(r"ALIAS (\S+) (\S+) (.*)", ln)
+            m = re.match(r"(?:ALIAS|TINY) (\S+) (\S+) (.*)", ln)
             if m:
                 found[m.group(1), m.group(2)] = m.group(3)
         return found, res.stdout
@@ -188,6 +188,21 @@ class TestStatefulRowProgramsAliasOnTpu:
         assert (table, program) in found, out[-2000:]
         # data and the history, both donated, both updated in place
         assert found[table, program] == "aliased=2/2 passes=0", out[-3000:]
+
+    @pytest.mark.parametrize("rows", [1, 2, 4, 5])
+    @pytest.mark.parametrize("program,kernels", [
+        ("gather_rows.2048", 0), ("update_rows.2048", 2),
+        ("merged_add_rows.1x2048.8", 2)])
+    def test_tiny_tables_compile_with_the_kernel(self, compiled, rows,
+                                                 program, kernels):
+        """``rec_bag_steps``' smallest tables: 1 to 5 live rows and the
+        trash row, under Mosaic's tiling of 8 sublanes. A verb's 2,048
+        positions and the 8-lane bucket of their distinct rows both compile
+        for a v5e, the rows and the history each written by the kernel."""
+        found, out = compiled
+        table = f"adagrad_128_r{rows}"
+        assert (table, program) in found, out[-2000:]
+        assert found[table, program] == f"kernels={kernels}", out[-3000:]
 
 
 class TestMatrixTableWithPallas:
