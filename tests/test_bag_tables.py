@@ -13,6 +13,11 @@ one live row up, every step naming all of them.
 * the share: a table the system block-shards over four devices holds,
   shard by shard, what ``share_reference.replay_share`` replays for
   servers 0 to 3, and the shares' row counts add up to the table's.
+* the inverse map of an apply's repeated ids (``_inverse_of_repeats``, a
+  rank scratch on the table): ``np.searchsorted(np.unique(ids), ids)``
+  element for element on each of the 26 shares' id sets and on the sets
+  that would show a stale, short or shard-local scratch; which applies
+  make the scratch and step ``table.device_apply.combined_verbs``.
 
 Tolerance as tests/test_updaters_reference.py: both sides compute in
 float32 and differ in the order repeated deltas are summed in and in how
@@ -236,3 +241,110 @@ class TestShare:
         for rows in PUBLISHED + [SHARED_ROWS, 1, 31, 32, 33]:
             assert sum(share_reference.share_rows(rows, 32, s)
                        for s in range(32)) == rows
+
+
+# -- the inverse map of repeated ids -----------------------------------------
+
+def _bag_law(rng, rows: int, bags: int, hot: int) -> np.ndarray:
+    """The cell's id law (benchmark/runners/table_bag_steps.py ``bag_ids``):
+    a bag's first id by a log-uniform rank through a permutation, the
+    others uniform."""
+    ranks = np.floor(np.exp(rng.random(bags) * np.log(rows + 1))).astype(
+        np.int64) - 1
+    ids = rng.integers(0, rows, (bags, hot))
+    ids[:, 0] = rng.permutation(rows)[np.clip(ranks, 0, rows - 1)]
+    return ids.astype(np.int32).ravel()
+
+
+def _shape_only(num_rows: int) -> MatrixServerTable:
+    srv = object.__new__(MatrixServerTable)      # no world, no device
+    srv.num_rows = num_rows
+    return srv
+
+
+def _inverse_equals_the_search(srv, ids):
+    uniq = np.unique(ids)
+    inv = srv._inverse_of_repeats(uniq, ids)
+    assert inv.dtype == np.int32 and inv.shape == ids.shape
+    np.testing.assert_array_equal(inv, np.searchsorted(uniq, ids))
+    assert srv._rank_scratch.shape == (srv.num_rows,)
+
+
+@pytest.mark.parametrize("table", TABLES)
+def test_inverse_of_a_step_of_each_share(table):
+    """A verb's ids as the deployment draws them: 2,048 bags on server 0's
+    block of each published table (table 20: 204,800 ids over 1,250,000
+    rows; five tables of one row named 2,048 times or more)."""
+    rows = CONFIG["rows"][table]
+    ids = _bag_law(np.random.default_rng(34 + table), rows, 2048, HOT[table])
+    _inverse_equals_the_search(_shape_only(rows), ids)
+
+
+def _applies_in_a_row(rng):
+    # the second set names rows of the first at other ranks and rows the
+    # first never wrote; the third is the first again, the fourth one id
+    first = rng.integers(0, 1000, 700)
+    return [first, np.concatenate([first[::7] + 1, rng.integers(0, 1000, 90),
+                                   first[:50]]) % 1000, first,
+            np.full(64, 999)]
+
+
+ID_SETS = {
+    "one_row_2048_times": (1, lambda rng: [np.zeros(2048, np.int64)]),
+    "distinct_shuffled": (4096, lambda rng: [rng.permutation(4096)[:3000]]),
+    "applies_in_a_row": (1000, _applies_in_a_row),
+    "first_and_last_row": (12_000_000, lambda rng: [
+        np.array([11_999_999, 0, 11_999_999, 0, 5])]),
+}
+
+
+@pytest.mark.parametrize("case", ID_SETS)
+def test_inverse_of_repeats_equals_the_search(case):
+    rows, sets = ID_SETS[case]
+    srv = _shape_only(rows)
+    scratch = None
+    for ids in sets(np.random.default_rng(34)):
+        _inverse_equals_the_search(srv, ids.astype(np.int32))
+        if scratch is None:
+            scratch = srv._rank_scratch
+            scratch[:] = np.iinfo(np.int32).max     # what was never written
+        assert srv._rank_scratch is scratch         # made once, never cleared
+
+
+APPLIES = {     # ids, delta on the device, verbs that take the device combine
+    "repeats_on_device": ([5, 3, 5, 9, 3, 5], True, 1),
+    "distinct_on_device": ([9, 3, 5, 0], True, 0),
+    "repeats_from_host": ([5, 3, 5, 9, 3, 5], False, 0),
+}
+
+
+@pytest.mark.parametrize("shards", [1, SERVERS])
+@pytest.mark.parametrize("case", APPLIES)
+def test_the_scratch_is_made_by_the_first_device_combine(case, shards):
+    """Whole-number deltas under the default ``+=``: the table equals the
+    host's sums bit for bit, on one shard and on four (ids are GLOBAL row
+    ids: the scratch is ``num_rows`` long, not a shard's)."""
+    import multiverso_tpu as mv
+    ids, on_device, combined = APPLIES[case]
+    mv.MV_Init([], devices=jax.devices()[:shards])
+    try:
+        srv = mv.MV_CreateTable(MatrixTableOption(
+            num_rows=SHARED_ROWS, num_cols=128)).server()
+        assert srv.num_servers == shards
+        want = np.zeros((SHARED_ROWS, 128), np.float32)
+        rng = np.random.default_rng(34)
+        for shift in (0, 27, 14):       # three applies: rows of every shard
+            rows = (np.asarray(ids, np.int32) + shift) % SHARED_ROWS
+            delta = rng.integers(-9, 10, (len(rows), 128)).astype(np.float32)
+            before = metrics.snapshot()
+            srv.device_apply_rows(
+                rows, jnp.asarray(delta) if on_device else delta)
+            assert _moved(before, metrics.snapshot(), "combined") == combined
+            np.add.at(want, rows, delta)
+        np.testing.assert_array_equal(srv.raw(), want)
+        if combined:
+            assert srv._rank_scratch.shape == (SHARED_ROWS,)
+        else:
+            assert "_rank_scratch" not in srv.__dict__
+    finally:
+        mv.MV_ShutDown()
