@@ -1,0 +1,11 @@
+"""Share of the traced window's wall in the table layer's copies back to
+the host, the wait for the device taken off (``.wait`` comes before):
+every span under ``server.`` whose name ends in ``.take``. Nothing where
+the program records no such span. Layer: row ops and kernels. Moves
+``table_rows_per_s``."""
+
+from benchmark.harness import crossings
+
+
+def read(run):
+    return crossings.share_pct(run.trace, ".take")

@@ -1,0 +1,84 @@
+"""The three things a table does to the device, one helper each.
+
+A verb of the table layer crosses into the row-ops layer in three ways:
+it copies host arrays in (:func:`place`), it launches a program
+(``with`` :func:`call`: the jitted row programs and the eager ``jnp``
+operations around them alike — a slice of a device array is a launch of
+its own) and it copies a device array back (:func:`take`). Each crossing
+is a counter step whatever the flags, and with ``-trace`` on a leaf span
+named after the span it runs in (``ttrace.child``): ``<verb's
+span>.place``, ``.call``, ``.take`` and, before a ``.take``, ``.wait``.
+
+=================  ==========================  ===========================
+crossing           counters                    span suffix
+=================  ==========================  ===========================
+host to device     ``table.device.h2d_copies`` ``.place``
+                   (host arrays),
+                   ``table.device.h2d_bytes``
+                   (their bytes, once,
+                   whatever the sharding
+                   replicates)
+a program's call   ``table.device.calls``      ``.call`` (``args``: the
+                                               program's name)
+device to host     ``table.device.d2h_copies`` ``.wait`` (``-trace`` only),
+                   ``table.device.d2h_bytes``  then ``.take``
+=================  ==========================  ===========================
+
+With ``-trace`` off a helper adds one flag read a span and one counter
+step a crossing to what the verb did before: no ``block_until_ready``, no
+copy, no program. ``.wait`` exists only while a trace runs: it blocks on
+the array about to be copied so that ``.take`` is the copy alone; the
+copy would have waited as long.
+"""
+
+from __future__ import annotations
+
+from typing import Callable, Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from multiverso_tpu.telemetry import metrics as tmetrics
+from multiverso_tpu.telemetry import trace as ttrace
+
+
+def place(host, put: Callable = jnp.asarray):
+    """``put(host)``: a host array, or a pytree of them in one ``put``,
+    onto the device."""
+    with ttrace.child(".place"):
+        out = put(host)
+    leaves = (host,) if isinstance(host, np.ndarray) else jax.tree.leaves(host)
+    tmetrics.counter("table.device.h2d_copies").inc(len(leaves))
+    tmetrics.counter("table.device.h2d_bytes").inc(
+        sum(leaf.nbytes for leaf in leaves))
+    return out
+
+
+def call(program: str):
+    """One launch of the program so named: ``with crossing.call(name):``
+    around the call alone, its operands made before. A context manager
+    and not a wrapper of the call: the jitted program's call site stays
+    where it was, with no frame of ours between the verb and the program
+    when it is traced."""
+    tmetrics.counter("table.device.calls").inc()
+    if not ttrace.enabled():
+        return ttrace.NULL_SPAN
+    return ttrace.child(".call", {"program": program})
+
+
+def take(arr, fetch: Callable = np.asarray,
+         also: Optional[str] = None) -> np.ndarray:
+    """``fetch(arr)``: a device array onto the host. ``also`` names a
+    second counter that takes the same bytes (a verb's own, such as
+    ``table.device_apply.d2h_bytes``)."""
+    if ttrace.enabled():
+        with ttrace.child(".wait"):
+            jax.block_until_ready(arr)
+    with ttrace.child(".take"):
+        host = fetch(arr)
+    tmetrics.counter("table.device.d2h_copies").inc()
+    tmetrics.counter("table.device.d2h_bytes").inc(host.nbytes)
+    if also is not None:
+        tmetrics.counter(also).inc(host.nbytes)
+    return host

@@ -53,6 +53,7 @@ from multiverso_tpu.parallel.mesh import (SERVER_AXIS, ceil_block_rows,
                                           local_device_count, next_bucket,
                                           parts_bucket, place_parts,
                                           storage_partition_server)
+from multiverso_tpu.tables import crossing
 from multiverso_tpu.tables.base import ServerTable, TableOption, WorkerTable
 from multiverso_tpu.telemetry import metrics as tmetrics
 from multiverso_tpu.telemetry import sketch as tsketch
@@ -86,7 +87,18 @@ def _pad_rows(deltas: jax.Array, bucket: int) -> jax.Array:
     survives either way."""
     if deltas.shape[0] == bucket:
         return deltas
-    return _pad_row_batch(deltas, bucket)
+    with crossing.call("_pad_row_batch"):
+        return _pad_row_batch(deltas, bucket)
+
+
+def _cut_rows(rows: jax.Array, n: int) -> jax.Array:
+    """The first ``n`` of a gather's bucket of rows, on the device: the
+    bucket itself when it is ``n`` long (no program, as ``_pad_rows``),
+    else a slice program."""
+    if n == rows.shape[0]:
+        return rows
+    with crossing.call("slice"):
+        return rows[:n]
 
 
 def _combine_duplicate_rows(ids: np.ndarray, deltas: np.ndarray,
@@ -676,10 +688,11 @@ class MatrixServerTable(ServerTable):
         nat = self._host_store()
         if nat is not None:
             return nat.get_rows(np.asarray(union_ids, np.int32))
-        rows = self._gather_rows(
-            self.state["data"], self.state["aux"],
-            self._device_ids(np.asarray(union_ids, np.int32)))
-        return np.asarray(self._zoo.mesh_ctx.fetch(rows[: len(union_ids)]))
+        device_ids = self._device_ids(np.asarray(union_ids, np.int32))
+        with crossing.call("_gather_rows"):
+            rows = self._gather_rows(self.state["data"], self.state["aux"],
+                                     device_ids)
+        return np.asarray(self._take_rows(rows, len(union_ids)))
 
     # -- helpers ------------------------------------------------------------
 
@@ -697,9 +710,25 @@ class MatrixServerTable(ServerTable):
     def _place_small(self, host):
         """Host array(s) -> the device in ONE copy, in the sharding the
         row programs declare for ids and option scalars."""
+        return crossing.place(host, self._put_small)
+
+    def _put_small(self, host):
         if self._replicated is None:
             return jax.tree.map(jnp.asarray, host)
         return jax.device_put(host, self._replicated)
+
+    def _take_rows(self, rows: jax.Array, n: int) -> np.ndarray:
+        """``_cut_rows`` brought to the host: the pad is cut off on the
+        device, so only the requested rows cross."""
+        return crossing.take(_cut_rows(rows, n),
+                             self._zoo.mesh_ctx.fetch)
+
+    def _verb_span(self, name: str, **args):
+        """A verb's span under ``server.``; its ``args`` (the table's id
+        and the caller's) are built only while a trace runs."""
+        if ttrace.enabled():
+            args["table_id"] = getattr(self, "table_id", -1)
+        return ttrace.span(name, cat="server", args=args)
 
     def _device_ids(self, ids: np.ndarray) -> jax.Array:
         """A validated id vector padded ON THE HOST to its bucket (pad
@@ -768,12 +797,9 @@ class MatrixServerTable(ServerTable):
             return False
         # a window's run of Adds in two named halves: .merge is host
         # numpy (validation, stacking, np.unique, padding), .dispatch the
-        # host-to-device copies and the call of the merged program
-        targs = ({"table_id": getattr(self, "table_id", -1),
-                  "adds": len(payloads)}
-                 if ttrace.enabled() else None)
-        with ttrace.span("server.table.add_run.merge", cat="server",
-                         args=targs):
+        # host-to-device copies (.place) and the merged program's .call
+        with self._verb_span("server.table.add_run.merge",
+                             adds=len(payloads)):
             ids_list, deltas_list = [], []
             for p in payloads:
                 row_ids = p.get("row_ids")
@@ -822,8 +848,8 @@ class MatrixServerTable(ServerTable):
                 # sizes, all warmable up front
                 uniq_p = self._pad_ids(
                     uniq, max(8, 1 << (len(uniq) - 1).bit_length()))
-        with ttrace.span("server.table.add_run.dispatch", cat="server",
-                         args=targs):
+        with self._verb_span("server.table.add_run.dispatch",
+                             adds=len(payloads)):
             if nat is not None:
                 # native merged apply. Same-id-set payloads (one worker
                 # hammering, or replicated pushes) collapse to
@@ -853,10 +879,12 @@ class MatrixServerTable(ServerTable):
                     self._note_add_parts(p.get("option") or AddOption(),
                                          [a])
                 return True
-            # mv-lint: ok(cross-domain-state): same one-plane-per-table argument as the state getter — engine window applies and device-plane collective verbs never drive one table concurrently
-            self.state = self._merged_add_rows(
-                self.state, jnp.asarray(uniq_p), jnp.asarray(deltas),
-                jnp.asarray(inv.astype(np.int32)), self._device_opt())
+            operands = (crossing.place(uniq_p), crossing.place(deltas),
+                        crossing.place(inv.astype(np.int32)),
+                        self._device_opt())
+            with crossing.call("_merged_add_rows"):
+                # mv-lint: ok(cross-domain-state): same one-plane-per-table argument as the state getter — engine window applies and device-plane collective verbs never drive one table concurrently
+                self.state = self._merged_add_rows(self.state, *operands)
         # subclass bookkeeping fires per payload in message order, exactly
         # like the per-message path (SparseMatrixTable's freshness bits
         # must see every add's id set + worker attribution)
@@ -1006,10 +1034,12 @@ class MatrixServerTable(ServerTable):
             else:
                 # ship exact-size deltas; pad them to the bucket on device
                 padded_ids = self._device_ids(ids)
-                self.state = self._update_rows(
-                    self.state, padded_ids,
-                    _pad_rows(jnp.asarray(deltas), padded_ids.shape[0]),
-                    self._device_opt(option))
+                deltas = _pad_rows(crossing.place(deltas),
+                                   padded_ids.shape[0])
+                opt = self._device_opt(option)
+                with crossing.call("_update_rows"):
+                    self.state = self._update_rows(self.state, padded_ids,
+                                                   deltas, opt)
         self._note_add_parts(option, parts)
 
     # -- windowed-engine parts hooks (round 5; tables/base.py contract) -----
@@ -1497,15 +1527,17 @@ class MatrixServerTable(ServerTable):
             # Get: gather the union with one identical program everywhere,
             # then slice this process's rows out of the union result
             union = union.astype(np.int32)
-            rows = self._gather_rows(self.state["data"], self.state["aux"],
-                                     self._device_ids(union))
-            host_rows = self._zoo.mesh_ctx.fetch(rows[: len(union)])
+            device_ids = self._device_ids(union)
+            with crossing.call("_gather_rows"):
+                rows = self._gather_rows(self.state["data"],
+                                         self.state["aux"], device_ids)
+            host_rows = self._take_rows(rows, len(union))
             return host_rows[np.searchsorted(union, ids)]
-        rows = self._gather_rows(self.state["data"], self.state["aux"],
-                                 self._device_ids(ids))
-        # device-slice the pad off BEFORE fetching: only the requested rows
-        # cross the (slow) host<->device link
-        return self._zoo.mesh_ctx.fetch(rows[: len(ids)])
+        device_ids = self._device_ids(ids)
+        with crossing.call("_gather_rows"):
+            rows = self._gather_rows(self.state["data"], self.state["aux"],
+                                     device_ids)
+        return self._take_rows(rows, len(ids))
 
     def ProcessGetAsync(self, option: GetOption = None, row_ids=None):
         """Two-phase Get (base-class contract, tables/base.py): dispatch
@@ -1536,19 +1568,23 @@ class MatrixServerTable(ServerTable):
                 # drained later in the same pipeline window donates it
                 # (donate_argnums) — finalize would read a deleted array.
                 # Snapshot to a fresh buffer before the async copy.
-                data = jnp.copy(data)
+                with crossing.call("copy"):
+                    data = jnp.copy(data)
             data.copy_to_host_async()
-            return lambda: self._from_storage(np.asarray(data))
+            return lambda: self._from_storage(crossing.take(data))
         with ttrace.span("server.table.get.prepare", cat="server"):
             ids = np.asarray(row_ids, np.int32).ravel()
             self._check_ids(ids)
             self._note_row_access(ids)
         with ttrace.span("server.table.get.dispatch", cat="server"):
-            rows = self._gather_rows(self.state["data"], self.state["aux"],
-                                     self._device_ids(ids))
-            sliced = rows[: len(ids)]
+            device_ids = self._device_ids(ids)
+            with crossing.call("_gather_rows"):
+                rows = self._gather_rows(self.state["data"],
+                                         self.state["aux"], device_ids)
+            sliced = _cut_rows(rows, len(ids))
             sliced.copy_to_host_async()
-        return lambda: np.asarray(sliced)
+        # run by the engine inside server.window.finalize: .wait, .take
+        return lambda: crossing.take(sliced)
 
     # -- eager device plane (public) ----------------------------------------
     # device_gather_rows / device_update_rows above are the TRACEABLE hooks
@@ -1587,18 +1623,19 @@ class MatrixServerTable(ServerTable):
               f"the {local_dev} local devices (use parts_bucket)")
         padded = np.full(bucket, -1, np.int32)
         padded[: len(ids)] = ids
-        gids = place_parts(self._mesh, padded, nproc)
+        to_parts = functools.partial(place_parts, self._mesh, nproc=nproc)
+        gids = crossing.place(padded, to_parts)
         if deltas is None:
             return gids
-        if isinstance(deltas, jax.Array):
+        if isinstance(deltas, jax.Array):   # stays in HBM: no crossing
             d = deltas.reshape(len(ids), self.num_cols).astype(self.dtype)
             if len(ids) < bucket:
                 d = jnp.pad(d, ((0, bucket - len(ids)), (0, 0)))
-        else:
-            d = np.zeros((bucket, self.num_cols), self.dtype)
-            d[: len(ids)] = np.asarray(deltas, self.dtype).reshape(
-                len(ids), self.num_cols)
-        return gids, place_parts(self._mesh, d, nproc)
+            return gids, to_parts(d)
+        d = np.zeros((bucket, self.num_cols), self.dtype)
+        d[: len(ids)] = np.asarray(deltas, self.dtype).reshape(
+            len(ids), self.num_cols)
+        return gids, crossing.place(d, to_parts)
 
     def device_fetch_rows(self, row_ids) -> jax.Array:
         """Rows for ``row_ids`` as a DEVICE array (never leaves HBM),
@@ -1608,9 +1645,7 @@ class MatrixServerTable(ServerTable):
         Multi-process: collective; each process gets its own rows out of
         one merged SPMD gather round."""
         nproc = multihost.world_size()
-        with ttrace.span("server.table.device_fetch", cat="server",
-                         args=({"table_id": getattr(self, "table_id", -1)}
-                               if ttrace.enabled() else None)):
+        with self._verb_span("server.table.device_fetch"):
             with ttrace.span("server.table.device_fetch.prepare",
                              cat="server"):
                 ids = np.asarray(row_ids, np.int32).ravel()
@@ -1626,18 +1661,22 @@ class MatrixServerTable(ServerTable):
                              cat="server"):
                 if nproc > 1:
                     bucket = gids.shape[0] // nproc
-                    rows = self._gather_rows_parts_j(
-                        self.state["data"], self.state["aux"], gids)
+                    with crossing.call("_gather_rows_parts"):
+                        rows = self._gather_rows_parts_j(
+                            self.state["data"], self.state["aux"], gids)
                     # rows is fully replicated: slice THIS process's range
                     # out of an addressable single-device copy — a
                     # per-process-divergent slice of the global array
                     # would claim replicated contents it doesn't have
                     start = multihost.world_rank() * bucket
-                    return rows.addressable_data(0)[start: start + len(ids)]
-                rows = self._gather_rows(self.state["data"],
-                                         self.state["aux"],
-                                         self._place_small(padded))
-                return rows if len(ids) == len(padded) else rows[: len(ids)]
+                    with crossing.call("slice"):
+                        return rows.addressable_data(0)[
+                            start: start + len(ids)]
+                device_ids = self._place_small(padded)
+                with crossing.call("_gather_rows"):
+                    rows = self._gather_rows(self.state["data"],
+                                             self.state["aux"], device_ids)
+                return _cut_rows(rows, len(ids))
 
     def device_apply_rows(self, row_ids, deltas,
                           option: Optional[AddOption] = None) -> None:
@@ -1656,9 +1695,7 @@ class MatrixServerTable(ServerTable):
         (``_pad_rows``). The delta is never donated: the caller's array
         stays readable. Multi-process: collective; batches merge on device."""
         nproc = multihost.world_size()
-        with ttrace.span("server.table.device_apply", cat="server",
-                         args=({"table_id": getattr(self, "table_id", -1)}
-                               if ttrace.enabled() else None)):
+        with self._verb_span("server.table.device_apply"):
             with ttrace.span("server.table.device_apply.prepare",
                              cat="server"):
                 ids = np.asarray(row_ids, np.int32).ravel()
@@ -1672,7 +1709,8 @@ class MatrixServerTable(ServerTable):
                     if not on_device:
                         deltas = np.asarray(deltas, self.dtype).reshape(
                             positions, self.num_cols)
-                    uniq = np.unique(ids)
+                    with ttrace.child(".unique"):
+                        uniq = np.unique(ids)
                     unique = len(uniq)
                     if unique != positions:
                         # duplicates must pre-combine (scatter order is
@@ -1717,31 +1755,41 @@ class MatrixServerTable(ServerTable):
                 # bytes of delta copied to the host to combine repeats:
                 # none since the device combine; registered (at 0) so that
                 # a path which brings the copy back has a counter to step
+                # (``crossing.take(..., also=`` this name ``)``)
                 tmetrics.counter("table.device_apply.d2h_bytes")
                 self._count_apply_write(len(gids if nproc > 1 else padded))
             with ttrace.span("server.table.device_apply.dispatch",
                              cat="server"):
                 opt = self._device_opt(option)
                 if nproc > 1:
-                    self.state = self._update_rows_parts_j(
-                        self.state, gids, gdeltas, opt)
+                    with crossing.call("_update_rows_parts"):
+                        self.state = self._update_rows_parts_j(
+                            self.state, gids, gdeltas, opt)
                     return
                 if not on_device:
                     # a host delta is shipped at its exact size
-                    deltas = jnp.asarray(deltas)
-                elif (deltas.shape != (positions, self.num_cols)
-                        or deltas.dtype != self.dtype):
-                    deltas = deltas.reshape(
-                        positions, self.num_cols).astype(self.dtype)
+                    deltas = crossing.place(deltas)
+                else:
+                    # each of the two is a program only where it changes
+                    # something
+                    if deltas.shape != (positions, self.num_cols):
+                        with crossing.call("reshape"):
+                            deltas = deltas.reshape(positions, self.num_cols)
+                    if deltas.dtype != self.dtype:
+                        with crossing.call("astype"):
+                            deltas = deltas.astype(self.dtype)
                 if inv is not None:
                     padded, inv = self._place_small((padded, inv))
-                    self.state = self._merged_add_rows(
-                        self.state, padded,
-                        _pad_rows(deltas, inv.shape[0]), inv, opt)
+                    deltas = _pad_rows(deltas, inv.shape[0])
+                    with crossing.call("_merged_add_rows"):
+                        self.state = self._merged_add_rows(
+                            self.state, padded, deltas, inv, opt)
                     return
-                self.state = self._update_rows(
-                    self.state, self._place_small(padded),
-                    _pad_rows(deltas, len(padded)), opt)
+                padded = self._place_small(padded)
+                deltas = _pad_rows(deltas, len(padded))
+                with crossing.call("_update_rows"):
+                    self.state = self._update_rows(self.state, padded,
+                                                   deltas, opt)
 
     def _count_apply_write(self, bucket: int) -> None:
         """One apply verb under the write its row program takes on this

@@ -62,6 +62,7 @@ import numpy as np
 from jax import lax
 
 from multiverso_tpu.parallel.mesh import next_bucket
+from multiverso_tpu.tables import crossing
 from multiverso_tpu.tables.matrix_table import (MatrixServerTable,
                                                 MatrixTableOption,
                                                 MatrixWorkerTable)
@@ -348,14 +349,20 @@ class SparseMatrixServerTable(MatrixServerTable):
         fetch = self._zoo.mesh_ctx.fetch
         cap = self.READ_ROWS_CAP
         if len(ids) <= cap:
-            return fetch(self._gather_rows(
-                data, aux, self._device_ids(ids)))[: len(ids)]
+            device_ids = self._device_ids(ids)
+            with crossing.call("_gather_rows"):
+                rows = self._gather_rows(data, aux, device_ids)
+            return crossing.take(rows, fetch)[: len(ids)]
         # dispatch every piece, then copy back: the copies overlap
-        pieces = [(len(ids[at: at + cap]), self._gather_rows(
-            data, aux, self._place_small(self._pad_ids(ids[at: at + cap],
-                                                       cap))))
-            for at in range(0, len(ids), cap)]
-        return np.concatenate([fetch(rows)[:n] for n, rows in pieces])
+        pieces = []
+        for at in range(0, len(ids), cap):
+            piece = ids[at: at + cap]
+            device_ids = self._place_small(self._pad_ids(piece, cap))
+            with crossing.call("_gather_rows"):
+                pieces.append((len(piece),
+                               self._gather_rows(data, aux, device_ids)))
+        return np.concatenate([crossing.take(rows, fetch)[:n]
+                               for n, rows in pieces])
 
     def read_stale(self, raw: np.ndarray
                    ) -> Tuple[np.ndarray, np.ndarray]:
@@ -370,11 +377,14 @@ class SparseMatrixServerTable(MatrixServerTable):
         if len(raw) > self.READ_ROWS_CAP or self._host_store() is not None:
             ids = np.unique(raw)
             return ids, self.read_rows(ids)
-        rows = self._read_stale(self.state["data"], self.state["aux"],
-                                self._device_ids(raw))
+        device_ids = self._device_ids(raw)
+        with crossing.call("_read_stale"):
+            rows = self._read_stale(self.state["data"], self.state["aux"],
+                                    device_ids)
         rows.copy_to_host_async()
-        ids = np.unique(raw)
-        return ids, self._zoo.mesh_ctx.fetch(rows)[: ids.size]
+        with ttrace.child(".unique"):
+            ids = np.unique(raw)
+        return ids, crossing.take(rows, self._zoo.mesh_ctx.fetch)[: ids.size]
 
     def _get_all(self, gwid: int) -> Tuple[np.ndarray, np.ndarray]:
         """A single-process Get without ids by worker ``gwid``

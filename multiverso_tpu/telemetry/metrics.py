@@ -402,14 +402,20 @@ class MetricsRegistry:
     def _get(self, name: str, cls):
         if not enabled():
             return NULL
-        with self._lock:
-            inst = self._instruments.get(name)
-            if inst is None:
-                inst = cls(name)
-                self._instruments[name] = inst
-        CHECK(isinstance(inst, cls),
-              f"telemetry instrument {name!r} already registered as "
-              f"{type(inst).__name__}, requested {cls.__name__}")
+        # a hit (every step of a hot path's counter) takes no lock and
+        # builds no message: a dict read is atomic under the interpreter
+        # lock
+        inst = self._instruments.get(name)
+        if inst is None:
+            with self._lock:
+                inst = self._instruments.get(name)
+                if inst is None:
+                    inst = cls(name)
+                    self._instruments[name] = inst
+        if not isinstance(inst, cls):
+            CHECK(False,
+                  f"telemetry instrument {name!r} already registered as "
+                  f"{type(inst).__name__}, requested {cls.__name__}")
         return inst
 
     def counter(self, name: str) -> Counter:

@@ -49,14 +49,20 @@ enabled = cached_bool_flag("trace", False)
 #: completed-event ring bound — oldest events drop first
 MAX_EVENTS = 200_000
 
+#: the ring: finished spans (``_Span`` objects, made into events at
+#: export) and flow events (dicts). ``deque.append`` is atomic under the
+#: interpreter lock, so a span's end takes no lock of ours
 _events = collections.deque(maxlen=MAX_EVENTS)
-_events_lock = threading.Lock()
 _tls = threading.local()
+#: ``next()`` of an ``itertools.count`` is atomic under the interpreter lock
 _id_counter = itertools.count(1)
-_id_lock = threading.Lock()
-#: set by api.MV_StartProfiler/MV_StopProfiler: bridge spans into
-#: jax.profiler.TraceAnnotation while an xplane trace runs
-_xplane_active = False
+_pid = os.getpid()
+#: set by api.MV_StartProfiler/MV_StopProfiler: ``jax.profiler.
+#: TraceAnnotation`` while an xplane trace runs (spans bridge into it),
+#: else None. Resolved once here, not in every span's ``__enter__``
+_annotation = None
+#: what :func:`child` names a span that no open span encloses
+ORPHAN = "server.table.device"
 
 
 class SpanContext(NamedTuple):
@@ -64,17 +70,21 @@ class SpanContext(NamedTuple):
     span_id: int
 
 
+def _after_fork() -> None:
+    global _pid
+    _pid = os.getpid()
+
+
+os.register_at_fork(after_in_child=_after_fork)
 
 
 def set_xplane(active: bool) -> None:
-    global _xplane_active
-    _xplane_active = bool(active)
-
-
-def _next_id() -> int:
-    # pid-prefixed so ids from different ranks' dumps never collide
-    with _id_lock:
-        return (os.getpid() << 24) | (next(_id_counter) & 0xFFFFFF)
+    global _annotation
+    if active:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    else:
+        _annotation = None
 
 
 def _now_us() -> float:
@@ -84,12 +94,8 @@ def _now_us() -> float:
 def current_ctx() -> Optional[SpanContext]:
     """The calling thread's innermost open span, or None (used to stamp
     ``Message.trace_ctx`` at enqueue)."""
-    return getattr(_tls, "ctx", None)
-
-
-def _record(event: dict) -> None:
-    with _events_lock:
-        _events.append(event)
+    top = getattr(_tls, "top", None)
+    return top._ctx if top is not None else None
 
 
 class _NullSpan:
@@ -105,12 +111,16 @@ class _NullSpan:
         return False
 
 
-_NULL_SPAN = _NullSpan()
+NULL_SPAN = _NULL_SPAN = _NullSpan()
 
 
 class _Span:
+    """One span, and after its end its own record in the ring: what a
+    span pays while a trace runs is two clock reads, an id, a tuple and a
+    ``deque.append``; the event's dicts are built at export."""
+
     __slots__ = ("name", "cat", "args", "_parent", "_prev", "_ctx",
-                 "_ann", "_t0")
+                 "_ann", "_t0", "_dur", "_tid")
 
     def __init__(self, name, parent, cat, args):
         self.name = name
@@ -119,38 +129,42 @@ class _Span:
         self._parent = parent
 
     def __enter__(self):
-        self._prev = getattr(_tls, "ctx", None)
-        parent_ctx = self._parent if self._parent is not None else self._prev
-        self._parent = parent_ctx
-        sid = _next_id()
-        self._ctx = SpanContext(
-            parent_ctx.trace_id if parent_ctx else sid, sid)
-        _tls.ctx = self._ctx
-        self._ann = None
-        if _xplane_active:
-            try:
-                import jax
-                self._ann = jax.profiler.TraceAnnotation(self.name)
-                self._ann.__enter__()
-            except Exception:
-                self._ann = None
+        prev = self._prev = getattr(_tls, "top", None)
+        parent = self._parent
+        if parent is None and prev is not None:
+            parent = self._parent = prev._ctx
+        # pid-prefixed so ids from different ranks' dumps never collide
+        sid = (_pid << 24) | (next(_id_counter) & 0xFFFFFF)
+        self._ctx = SpanContext(parent.trace_id if parent else sid, sid)
+        _tls.top = self
+        ann = _annotation
+        if ann is not None:
+            ann = ann(self.name)
+            ann.__enter__()
+        self._ann = ann
         self._t0 = _now_us()
         return self._ctx
 
     def __exit__(self, *exc):
-        dur = _now_us() - self._t0
+        self._dur = _now_us() - self._t0
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
-        _tls.ctx = self._prev
+            self._ann = None
+        _tls.top = self._prev
+        self._prev = None       # the ring keeps this span, not its elders
+        self._tid = threading.get_ident()
+        _events.append(self)
+        return False
+
+    def _event(self) -> dict:
         ev_args = {"trace_id": self._ctx.trace_id,
                    "span_id": self._ctx.span_id,
                    "parent_id": self._parent.span_id if self._parent else 0}
         if self.args:
             ev_args.update(self.args)
-        _record({"name": self.name, "cat": self.cat, "ph": "X",
-                 "ts": self._t0, "dur": dur, "pid": os.getpid(),
-                 "tid": threading.get_ident(), "args": ev_args})
-        return False
+        return {"name": self.name, "cat": self.cat, "ph": "X",
+                "ts": self._t0, "dur": self._dur, "pid": _pid,
+                "tid": self._tid, "args": ev_args}
 
 
 def span(name: str, parent: Optional[SpanContext] = None, cat: str = "mv",
@@ -164,13 +178,28 @@ def span(name: str, parent: Optional[SpanContext] = None, cat: str = "mv",
     return _Span(name, parent, cat, args)
 
 
+def child(suffix: str, args: Optional[dict] = None):
+    """A span named AFTER the innermost span open on this thread:
+    ``<its name><suffix>``, in its category (``ORPHAN`` + suffix where
+    none is open). For a helper that many verbs share and that must show
+    under each by name: a device crossing under
+    ``server.table.get.dispatch`` is ``server.table.get.dispatch.place``.
+    Tracing off: one flag read, the shared no-op."""
+    if not enabled():
+        return _NULL_SPAN
+    top = getattr(_tls, "top", None)
+    if top is None:
+        return _Span(ORPHAN + suffix, None, "server", args)
+    return _Span(top.name + suffix, None, top.cat, args)
+
+
 def flow_start(ctx: Optional[SpanContext], name: str = "mv.msg") -> None:
     """Flow-arrow origin (message enqueue). No-op when ``ctx`` is None
     or tracing is off."""
     if ctx is None or not enabled():
         return
-    _record({"name": name, "cat": "msg", "ph": "s", "id": ctx.span_id,
-             "ts": _now_us(), "pid": os.getpid(),
+    _events.append({"name": name, "cat": "msg", "ph": "s", "id": ctx.span_id,
+             "ts": _now_us(), "pid": _pid,
              "tid": threading.get_ident()})
 
 
@@ -178,8 +207,8 @@ def flow_end(ctx: Optional[SpanContext], name: str = "mv.msg") -> None:
     """Flow-arrow target (message dequeue on the actor thread)."""
     if ctx is None or not enabled():
         return
-    _record({"name": name, "cat": "msg", "ph": "f", "bp": "e",
-             "id": ctx.span_id, "ts": _now_us(), "pid": os.getpid(),
+    _events.append({"name": name, "cat": "msg", "ph": "f", "bp": "e",
+             "id": ctx.span_id, "ts": _now_us(), "pid": _pid,
              "tid": threading.get_ident()})
 
 
@@ -203,17 +232,22 @@ def chrome_trace(events: list, process_names: Optional[dict] = None,
 
 def to_chrome_trace() -> dict:
     """The buffered events as a Chrome trace-event object (JSON-ready)."""
-    with _events_lock:
-        events = list(_events)
+    while True:
+        try:
+            ring = list(_events)
+            break
+        except RuntimeError:    # a span ended while the ring was copied
+            continue
+    events = [e if type(e) is dict else e._event() for e in ring]
     out = chrome_trace(events,
-                       process_names={os.getpid(): _process_label()})
+                       process_names={_pid: _process_label()})
     # round 22: a (wall, mono) anchor pair sampled at export time. Span
     # timestamps are perf_counter-based (each process its own zero);
     # the fleet trace-merge CLI (telemetry/fleet.py --trace) uses this
     # pair to map every dump onto one wall timeline before refining the
     # residual offset from matched client/server span pairs.
     out["clock"] = {"wall_s": time.time(), "mono_us": _now_us(),
-                    "pid": os.getpid()}
+                    "pid": _pid}
     return out
 
 
@@ -247,8 +281,7 @@ def dump(path: str) -> str:
 
 
 def clear() -> None:
-    with _events_lock:
-        _events.clear()
+    _events.clear()
 
 
 def _reset_for_tests() -> None:

@@ -451,6 +451,297 @@ class TestHostSpans:
         assert spans == []
         assert trace.span("server.table.device_fetch") is trace._NULL_SPAN
 
+    # -- PR 35: the table layer's crossings into and out of the device ------
+
+    #: path -> (the span the crossings hang under, its children in order
+    #: as (suffix, program or None), the counters' steps). ``b`` is the id
+    #: bucket of the 40 ids the drives use, ``row`` a logical row's bytes.
+    N, B, COLS = 40, 64, 4
+
+    @classmethod
+    def _crossing_cases(cls):
+        n, b, row = cls.N, cls.B, cls.COLS * 4
+        place, wait, take = (".place", None), (".wait", None), (".take", None)
+        call = lambda program: (".call", program)  # noqa: E731
+        return {
+            # a fetch of n ids at bucket b: one copy of 4 b bytes and one
+            # call, two when n != b
+            "fetch_at_bucket": (
+                "server.table.device_fetch.dispatch",
+                [place, call("_gather_rows")],
+                dict(h2d_copies=1, h2d_bytes=4 * b, calls=1)),
+            "fetch_under_bucket": (
+                "server.table.device_fetch.dispatch",
+                [place, call("_gather_rows"), call("slice")],
+                dict(h2d_copies=1, h2d_bytes=4 * b, calls=2)),
+            # the table's first apply: the option's five scalars in one
+            # put, then the host delta at its exact size, then the ids
+            "apply_first_option": (
+                "server.table.device_apply.dispatch",
+                [place, place, place, call("_pad_row_batch"),
+                 call("_update_rows")],
+                dict(h2d_copies=7, h2d_bytes=20 + n * row + 4 * b,
+                     calls=2)),
+            "apply_host_delta": (
+                "server.table.device_apply.dispatch",
+                [place, place, call("_pad_row_batch"),
+                 call("_update_rows")],
+                dict(h2d_copies=2, h2d_bytes=n * row + 4 * b, calls=2)),
+            # a device delta that is its bucket: ids alone cross
+            "apply_device_delta_at_bucket": (
+                "server.table.device_apply.dispatch",
+                [place, call("_update_rows")],
+                dict(h2d_copies=1, h2d_bytes=4 * b, calls=1)),
+            # a short device delta: the pad program is a call of its own
+            "apply_device_delta_short": (
+                "server.table.device_apply.dispatch",
+                [place, call("_pad_row_batch"), call("_update_rows")],
+                dict(h2d_copies=1, h2d_bytes=4 * b, calls=2)),
+            # repeats: ids (at the distinct count's power of two, 32) and
+            # inverse map (at the positions' bucket) in ONE put
+            "apply_repeats_at_bucket": (
+                "server.table.device_apply.dispatch",
+                [place, call("_merged_add_rows")],
+                dict(h2d_copies=2, h2d_bytes=4 * 32 + 4 * b, calls=1)),
+            "apply_repeats_short": (
+                "server.table.device_apply.dispatch",
+                [place, call("_pad_row_batch"), call("_merged_add_rows")],
+                dict(h2d_copies=2, h2d_bytes=4 * 32 + 4 * b, calls=2)),
+            # a merged run of two Adds of n rows: ids (power of two over
+            # 2 n distinct), stacked deltas, inverse map
+            "add_run": (
+                "server.table.add_run.dispatch",
+                [place, place, place, call("_merged_add_rows")],
+                dict(h2d_copies=3,
+                     h2d_bytes=4 * 128 + 2 * n * row + 4 * 2 * n, calls=1)),
+            "lone_add": (
+                "server.table.add_run.dispatch",
+                [place, place, call("_pad_row_batch"),
+                 call("_update_rows")],
+                dict(h2d_copies=2, h2d_bytes=4 * b + n * row, calls=2)),
+            # a host Get: the copy back is waited for and taken in the
+            # window's finalize
+            "host_get": (
+                "server.table.get.dispatch",
+                [place, call("_gather_rows"), call("slice")],
+                dict(h2d_copies=1, h2d_bytes=4 * b, calls=2,
+                     d2h_copies=1, d2h_bytes=n * row)),
+            "host_get_finalize": (
+                "server.window.finalize", [wait, take],
+                dict(h2d_copies=1, h2d_bytes=4 * b, calls=2,
+                     d2h_copies=1, d2h_bytes=n * row)),
+            # a sparse Get: the whole bucket comes back
+            "sparse_get": (
+                "server.table.sparse.get.read",
+                [place, call("_read_stale"), (".unique", None), wait, take],
+                dict(h2d_copies=1, h2d_bytes=4 * b, calls=1,
+                     d2h_copies=1, d2h_bytes=b * row)),
+        }
+
+    def _crossing_drive(self, mv, path):
+        """Warm the path's verb (programs compiled, the option's scalars
+        kept), then run it once between two snapshots. -> (spans of the
+        one run, how far each table.device.* counter moved, the
+        ``block_until_ready`` calls inside it)."""
+        import jax
+        import jax.numpy as jnp
+        from multiverso_tpu.tables import (MatrixTableOption,
+                                           SparseMatrixTableOption)
+        from multiverso_tpu.tables.base import submit_multi
+        from multiverso_tpu.updaters.base import GetOption
+        n, b, cols = self.N, self.B, self.COLS
+        sparse = path == "sparse_get"
+        make = SparseMatrixTableOption if sparse else MatrixTableOption
+        t = mv.MV_CreateTable(make(num_rows=256, num_cols=cols))
+        srv = t.server()
+        srv._native_host_ok = False     # the chip's branch (verify skill)
+        ids = np.arange(0, 2 * n, 2, dtype=np.int32)
+        at_bucket = np.arange(b, dtype=np.int32)
+        repeats = np.concatenate([ids[:24], ids[:24], ids[:16]])  # 64 / 24
+        ones = lambda k: np.ones((k, cols), np.float32)  # noqa: E731
+        drives = {
+            "fetch_at_bucket": lambda: srv.device_fetch_rows(at_bucket),
+            "fetch_under_bucket": lambda: srv.device_fetch_rows(ids),
+            "apply_first_option": lambda: srv.device_apply_rows(
+                ids, ones(n)),
+            "apply_host_delta": lambda: srv.device_apply_rows(ids, ones(n)),
+            "apply_device_delta_at_bucket": lambda: srv.device_apply_rows(
+                at_bucket, jnp.ones((b, cols), jnp.float32)),
+            "apply_device_delta_short": lambda: srv.device_apply_rows(
+                ids, jnp.ones((n, cols), jnp.float32)),
+            "apply_repeats_at_bucket": lambda: srv.device_apply_rows(
+                repeats, jnp.ones((b, cols), jnp.float32)),
+            "apply_repeats_short": lambda: srv.device_apply_rows(
+                repeats[:50], jnp.ones((50, cols), jnp.float32)),
+            "add_run": lambda: submit_multi(
+                [(t, "A", {"row_ids": ids, "values": ones(n)}),
+                 (t, "A", {"row_ids": ids + 1, "values": ones(n)})]).Wait(),
+            "lone_add": lambda: t.AddRows(ids, ones(n)),
+            "host_get": lambda: t.GetRows(ids),
+            "host_get_finalize": lambda: t.GetRows(ids),
+        }
+        if sparse:
+            # worker 1 finds the n rows worker 0 added since its last Get
+            def drive():
+                t.AddRows(ids, ones(n))
+                snap = metrics.snapshot()
+                trace.clear()
+                got_ids, rows = t.Get(GetOption(worker_id=1))
+                assert got_ids.shape == (n,) and rows.shape == (n, cols)
+                return snap
+        else:
+            def drive():
+                snap = metrics.snapshot()
+                trace.clear()
+                drives[path]()
+                return snap
+        if path != "apply_first_option":
+            drive()
+        real, blocked = jax.block_until_ready, []
+        jax.block_until_ready = lambda x: (blocked.append(1), real(x))[1]
+        try:
+            before = drive()
+        finally:
+            jax.block_until_ready = real
+        after = metrics.snapshot()
+        names = ("h2d_copies", "h2d_bytes", "calls", "d2h_copies",
+                 "d2h_bytes")
+        moved = {k: after.get(f"table.device.{k}", {}).get("value", 0.0)
+                 - before.get(f"table.device.{k}", {}).get("value", 0.0)
+                 for k in names}
+        return self._spans(), {k: v for k, v in moved.items() if v}, \
+            len(blocked)
+
+    @pytest.mark.parametrize("path", [
+        "fetch_at_bucket", "fetch_under_bucket", "apply_first_option",
+        "apply_host_delta", "apply_device_delta_at_bucket",
+        "apply_device_delta_short", "apply_repeats_at_bucket",
+        "apply_repeats_short", "add_run", "lone_add", "host_get",
+        "host_get_finalize", "sparse_get"])
+    @pytest.mark.parametrize("trace_on", [True, False],
+                             ids=["trace_on", "trace_off"])
+    def test_device_crossings(self, path, trace_on):
+        """Every copy in, program call and copy back of a table verb is a
+        counter step whatever the flags, and with -trace on a leaf span
+        named after the span it runs in, under it, in order."""
+        import multiverso_tpu as mv
+        parent_name, want_kids, want_moved = self._crossing_cases()[path]
+        trace._reset_for_tests()
+        metrics._reset_for_tests()
+        mv.MV_Init(["-num_workers=2"] + (["-trace=true"] if trace_on
+                                         else []))
+        try:
+            spans, moved, blocked = self._crossing_drive(mv, path)
+            null = trace.child(".place") is trace._NULL_SPAN
+        finally:
+            mv.MV_ShutDown()
+        assert moved == {k: float(v) for k, v in want_moved.items()}
+        waits = want_moved.get("d2h_copies", 0)     # one a copy back
+        if not trace_on:
+            # no span object, no synchronisation the verb did not have
+            assert spans == [] and null and blocked == 0
+            return
+        assert not null and blocked == waits
+        parents = [e for e in spans if e["name"] == parent_name]
+        assert len(parents) == 1
+        kids = sorted(self._children(spans, parents[0]),
+                      key=lambda e: e["ts"])
+        assert [(k["name"], k["args"].get("program")) for k in kids] == [
+            (parent_name + suffix, program) for suffix, program in want_kids]
+        assert all(k["cat"] == "server" and k["tid"] == parents[0]["tid"]
+                   for k in kids)
+        # .wait and .take exist nowhere but where a copy comes back
+        ends = [e["name"].rsplit(".", 1)[1] for e in spans
+                if e["cat"] == "server"]    # not the caller's worker.wait
+        assert ends.count("wait") == ends.count("take") == moved.get(
+            "d2h_copies", 0)
+        if path.startswith("apply_repeats"):
+            prepare = [e for e in spans if e["name"]
+                       == "server.table.device_apply.prepare"]
+            assert [k["name"][len(prepare[0]["name"]):] for k in sorted(
+                self._children(spans, prepare[0]), key=lambda e: e["ts"])
+            ] == [".unique", ""]    # the combine keeps its PR 33 name
+            assert [e["name"] for e in spans].count(
+                "server.table.device_apply.combine") == 1
+
+    def test_take_steps_a_verbs_own_counter_too(self):
+        """``crossing.take(..., also=name)``: the path that brings a
+        copy back steps its verb's counter from the same helper."""
+        import jax.numpy as jnp
+        from multiverso_tpu.tables import crossing
+        metrics._reset_for_tests()
+        host = crossing.take(jnp.ones((8, 4), jnp.float32),
+                             also="table.device_apply.d2h_bytes")
+        snap = metrics.snapshot()
+        assert host.shape == (8, 4)
+        assert snap["table.device_apply.d2h_bytes"]["value"] == 128.0
+        assert snap["table.device.d2h_bytes"]["value"] == 128.0
+        assert snap["table.device.d2h_copies"]["value"] == 1.0
+
+    def test_child_outside_any_span_is_named_an_orphan(self):
+        from multiverso_tpu.utils.configure import SetCMDFlag
+        trace._reset_for_tests()
+        SetCMDFlag("trace", True)
+        try:
+            with trace.child(".take"):
+                pass
+            with trace.span("worker.x", cat="worker"):
+                with trace.child(".call", {"program": "p"}) as ctx:
+                    assert trace.current_ctx() == ctx
+        finally:
+            SetCMDFlag("trace", False)
+        spans = self._spans()
+        assert [(e["name"], e["cat"]) for e in spans] == [
+            (trace.ORPHAN + ".take", "server"), ("worker.x.call", "worker"),
+            ("worker.x", "worker")]
+        assert spans[1]["args"]["parent_id"] == spans[2]["args"]["span_id"]
+        assert spans[1]["args"]["program"] == "p"
+        trace._reset_for_tests()
+
+    def test_a_span_with_the_bridge_on_counts_its_work(self):
+        """What a span pays while a trace runs, counted and not timed:
+        one annotation entered and left, one ring entry that is the span
+        itself (its event's dicts are built at export), and neither an
+        import nor a lock in ``__enter__`` / ``__exit__``."""
+        import dis
+        from multiverso_tpu.utils.configure import SetCMDFlag
+
+        class Annotation:
+            made, entered, left = [], 0, 0
+
+            def __init__(self, name):
+                Annotation.made.append(name)
+
+            def __enter__(self):
+                Annotation.entered += 1
+
+            def __exit__(self, *exc):
+                Annotation.left += 1
+
+        trace._reset_for_tests()
+        SetCMDFlag("trace", True)
+        trace._annotation = Annotation
+        try:
+            for _ in range(100):
+                with trace.span("server.a", cat="server"):
+                    with trace.child(".call"):
+                        pass
+        finally:
+            SetCMDFlag("trace", False)
+            trace.set_xplane(False)
+        assert Annotation.entered == Annotation.left == 200
+        assert Annotation.made == ["server.a", "server.a.call"] * 100
+        assert len(trace._events) == 200
+        assert all(type(e) is trace._Span for e in trace._events)
+        ids = {e._ctx.span_id for e in trace._events}
+        assert len(ids) == 200
+        for fn in (trace._Span.__enter__, trace._Span.__exit__):
+            code = {(i.opname, i.argval) for i in dis.get_instructions(fn)}
+            assert not any(op == "IMPORT_NAME" for op, _ in code)
+            assert not any("lock" in str(arg).lower() for _, arg in code)
+        assert len(self._spans()) == 200     # export makes the events
+        trace._reset_for_tests()
+
 
 class TestProfilerGuard:
     def test_double_start_checks_and_stop_without_start_noop(self, tmp_path):
