@@ -3,7 +3,7 @@
 One decision, made here and nowhere else:
 
 * **reads** are XLA's native gather, ``jnp.take(..., mode="clip")``, on
-  every backend;
+  every backend; a run the HOST finds consecutive is ``slice_rows``;
 * **writes** are the Pallas row-DMA kernel ``pallas_scatter_set_rows``
   where ``use_pallas(data, ids)`` says so — a TPU backend, a row shape
   Mosaic compiles (``_pallas_eligible``: a 4-byte dtype at exactly one
@@ -187,11 +187,11 @@ def gather_rows(data: jax.Array, ids: jax.Array) -> jax.Array:
 
     NO dense-run cond here, deliberately: a lax.cond over a LIVE
     (non-donated) table defeats XLA's buffer aliasing — each branch gets
-    an operand copy of the whole table. The dense bulk-slice fast path
-    lives only in the verbs that consume/donate the table
-    (scatter_set_rows, update_rows, update_gather_rows,
-    update_rows_with_state), where the in-place chain survives the
-    cond."""
+    an operand copy of the whole table. The cond lives only in the verbs
+    that consume/donate the table (scatter_set_rows, update_rows,
+    update_gather_rows, update_rows_with_state), where the in-place
+    chain survives it. The dense READ is ``slice_rows``, a program of
+    its own that the host chooses from the ids it holds."""
     return jnp.take(data, ids, axis=0, mode="clip")
 
 
@@ -343,6 +343,29 @@ def update_rows_with_state(data: jax.Array, aux, ids: jax.Array, aux_lanes,
         return general(data, aux)   # static guards (see gather_rows)
     ok, _, count = _dense_run(ids, data.shape[0])
     return jax.lax.cond(ok, dense_fn, general, data, aux)
+
+
+def slice_rows(data: jax.Array, start, count, bucket: int,
+               num_cols: int) -> jax.Array:
+    """The read of a DENSE run, beside ``gather_rows``: ``bucket`` rows of
+    ``data`` from row ``start``, their first ``num_cols`` columns (the
+    storage pad cut off), the lanes at and past ``count`` zero — what the
+    table's gather hands back for ``start .. start + count - 1`` padded to
+    ``bucket`` lanes. ``count=None`` says the run IS its bucket: nothing to
+    mask. No ``lax.cond`` and no ids: the caller decides on the host that
+    its ids are a run (``MatrixServerTable._fetch_run``) and that the
+    slice stays inside the live rows, ``start + bucket <= data.shape[0] -
+    1``, so ``dynamic_slice`` cannot clamp onto the trash row.
+
+    A bucket as long as the live rows can only start at row 0: the start
+    is then a constant (as ``update_rows_with_state``'s ``first``), and
+    with nothing to mask the program is one static slice of the table."""
+    if bucket == data.shape[0] - 1:
+        start = 0
+    rows = jax.lax.dynamic_slice(data, (start, 0), (bucket, num_cols))
+    if count is None:
+        return rows
+    return jnp.where((jnp.arange(bucket) < count)[:, None], rows, 0)
 
 
 def row_write(shard_rows: int, cols: int, dtype, bucket: int) -> str:

@@ -1639,11 +1639,11 @@ class MatrixServerTable(ServerTable):
 
     def device_fetch_rows(self, row_ids) -> jax.Array:
         """Rows for ``row_ids`` as a DEVICE array (never leaves HBM),
-        exactly ``len(row_ids)`` of them. The ids are padded to their
-        bucket on the host and copied once; when the batch IS its bucket
-        the gather's output is returned as it is (no slice program).
-        Multi-process: collective; each process gets its own rows out of
-        one merged SPMD gather round."""
+        exactly ``len(row_ids)`` of them. A consecutive run on one shard
+        is read by a slice; no ids are copied (``_fetch_run``). Any other
+        set is padded to its bucket on the host, copied once and gathered.
+        A batch that IS its bucket is returned as it is (no cut program).
+        Multi-process: collective; one merged SPMD gather round."""
         nproc = multihost.world_size()
         with self._verb_span("server.table.device_fetch"):
             with ttrace.span("server.table.device_fetch.prepare",
@@ -1655,7 +1655,7 @@ class MatrixServerTable(ServerTable):
                     len(ids) * self.num_cols * self.dtype.itemsize)
                 if nproc > 1:
                     gids = self.device_place_batch(ids)
-                else:
+                elif (run := self._fetch_run(ids)) is None:
                     padded = self._pad_ids(ids)
             with ttrace.span("server.table.device_fetch.dispatch",
                              cat="server"):
@@ -1672,6 +1672,10 @@ class MatrixServerTable(ServerTable):
                     with crossing.call("slice"):
                         return rows.addressable_data(0)[
                             start: start + len(ids)]
+                if run is not None:
+                    with crossing.call("_slice_rows"):
+                        rows = self._slice_rows(self.state["data"], **run)
+                    return _cut_rows(rows, len(ids))
                 device_ids = self._place_small(padded)
                 with crossing.call("_gather_rows"):
                     rows = self._gather_rows(self.state["data"],
@@ -1740,15 +1744,11 @@ class MatrixServerTable(ServerTable):
                 tmetrics.counter("table.device_apply.rows").inc(positions)
                 tmetrics.counter("table.device_apply.unique_rows").inc(unique)
                 # the device chooses the dense run (ops/rows.py _dense_run);
-                # the host counts the batches it will accept: one shard,
-                # distinct ids in order with no gap (``uniq`` is sorted, so
-                # repeats never reach the comparison), the bucket in the rows
+                # the host counts the batches it will accept, by the test
+                # that chooses a fetch's slice (``_is_run``)
                 dense_runs = tmetrics.counter("table.device_apply.dense_runs")
-                if (nproc == 1 and self.num_servers == 1
-                        and unique == positions > 0
-                        and uniq[-1] - uniq[0] + 1 == unique
-                        and uniq[0] + len(padded) <= self.block_rows
-                        and np.array_equal(uniq, ids)):
+                if (nproc == 1 and unique == positions
+                        and self._is_run(ids, len(padded))):
                     dense_runs.inc()
                 tmetrics.counter("table.device_apply.bytes").inc(
                     positions * self.num_cols * self.dtype.itemsize)
@@ -1828,6 +1828,50 @@ class MatrixServerTable(ServerTable):
             rank = self._rank_scratch = np.empty(self.num_rows, np.int32)
         rank[uniq] = np.arange(len(uniq), dtype=np.int32)
         return rank.take(ids)
+
+    def _is_run(self, ids: np.ndarray, bucket: int) -> bool:
+        """Whether validated ``ids`` are a dense run of ``bucket`` lanes:
+        one shard; ids strictly consecutive, the ends first (O(1): a set
+        with repeats or gaps pays nothing more), then one comparison over
+        the ids (40 us for 163,840, which does not show in a step); and
+        the bucket-long slice inside the live rows (``ops/rows.py``
+        ``_dense_run``'s own condition: a ``dynamic_slice`` that reached
+        the trash row would clamp). Decided here, on the host, because the
+        host holds the ids before anything is copied: no ``cond`` on the
+        device, which over a live table copies it."""
+        n = len(ids)
+        return (self.num_servers == 1 and n > 0
+                and int(ids[-1]) - int(ids[0]) == n - 1
+                and int(ids[0]) + bucket <= self.block_rows
+                and np.array_equal(ids, np.arange(ids[0], ids[0] + n,
+                                                  dtype=np.int32)))
+
+    #: the dense read (``ops.slice_rows``) as a program; not donating: the
+    #: table is live. ``bucket`` and ``num_cols`` are its static shape,
+    #: ``count=None`` a run that is its bucket. Made here and not in
+    #: ``__init__``, as ``_count_apply_write``'s names are (ROADMAP.md D13)
+    _slice_rows = staticmethod(jax.jit(
+        jax.named_scope("table.slice_rows")(ops.slice_rows),
+        static_argnames=("bucket", "num_cols")))
+
+    def _fetch_run(self, ids: np.ndarray) -> Optional[dict]:
+        """``device_fetch_rows``' choice of program in a one-process world:
+        the small operands of ``_slice_rows`` when ``ids`` are a run
+        (``_is_run`` at their bucket), else None and the verb gathers.
+        For a run no ids are padded or copied: ``start`` and ``count`` go
+        in as scalars with the call. An updater with an ``access`` hook
+        keeps the gather, whose program applies the hook. Steps
+        ``table.device_fetch.dense_runs`` (registered at 0 otherwise)."""
+        from multiverso_tpu.updaters.base import Updater
+        dense_runs = tmetrics.counter("table.device_fetch.dense_runs")
+        bucket = next_bucket(len(ids))
+        if (not self._is_run(ids, bucket)
+                or type(self.updater).access is not Updater.access):
+            return None
+        dense_runs.inc()
+        return {"start": np.int32(ids[0]), "bucket": bucket,
+                "count": None if len(ids) == bucket else np.int32(len(ids)),
+                "num_cols": self.num_cols}
 
     def raw(self) -> np.ndarray:
         """Logical-view snapshot (host numpy)."""

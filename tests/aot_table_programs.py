@@ -5,7 +5,8 @@ branches the chip takes — the Pallas row write at 128 lanes, the
 dense-run ``cond`` on one shard).
 
 ``--dump DIR`` writes the lowered text of the programs the benchmark's
-cells compile for tables WITHOUT updater state (``FENCE``), one file a
+cells compile for tables WITHOUT updater state (``FENCE``; on one chip
+with the dense read of each bucket, ``slice_rows.<bucket>``), one file a
 program, the Mosaic bodies' debug locations stripped: run it on two
 trees and ``diff -r`` the directories (ISSUE 29's fence, PERF.md 6).
 
@@ -16,6 +17,11 @@ the compiled module holds: tests/test_ops.py asserts on it.
 
 ``--tiny`` compiles the row programs of AdaGrad tables of 1 to 5 live rows
 at one lane tile (``TINY``) and prints how many Mosaic kernels each holds.
+
+``--read`` compiles the dense read (``MatrixServerTable._slice_rows``) and
+the gather it stands in for at ``lm_vocab_steps``' shape (``READ``) and
+prints, a program, how many instructions write an array as large as the
+rows it returns and how many gathers it holds.
 
 Prints ``SKIP ...`` and exits 0 where the topology description is missing.
 """
@@ -77,6 +83,13 @@ TINY = [    # --tiny: rec_bag_steps' smallest tables, 1, 2, 4 and 5 live rows
     # positions, and the 8-lane bucket their distinct rows fold into
     (f"adagrad_128_r{rows}", rows, 128, 1, "adagrad", 1, (2_048,),
      ((1, 2_048, 8),)) for rows in (1, 2, 4, 5)]
+
+
+READ = [   # --read: lm_head's fetch, the whole table in order; a run of a
+    # bucket under the table; and each with a run shorter than its bucket
+    ("adagrad_2048_w1", 163_840, 2_048, 1, "adagrad", 1, (163_840, 16_384),
+     ()),
+]
 
 
 def _devices(chips):
@@ -159,8 +172,16 @@ def dump(directory):
         if chips == 1:   # the whole-table Add, where a test can place one
             progs.append(("update_full", srv._update_full,
                           (srv._state, srv._state["data"], progs[0][2][3])))
-        for prog, fn, args in progs:
-            text = fn.lower(*args).as_text(debug_info=True)
+        lowered = [(prog, fn.lower(*args)) for prog, fn, args in progs]
+        if chips == 1:   # the dense read, at each bucket the table holds
+            scalar = jax.ShapeDtypeStruct(
+                (), jnp.int32,
+                sharding=SingleDeviceSharding(ctx.mesh.devices.flat[0]))
+            lowered += [(f"slice_rows.{b}", srv._slice_rows.lower(
+                srv._state["data"], scalar, scalar, bucket=b,
+                num_cols=cols)) for b in buckets if b <= srv.block_rows]
+        for prog, low in lowered:
+            text = low.as_text(debug_info=True)
             text = _BODY.sub(_mosaic_text, _LOC.sub("", text))
             text = "\n".join(ln.rstrip() for ln in text.splitlines()
                              if ln.strip())
@@ -255,12 +276,41 @@ def tiny(specs):
             print(f"TINY {name} {prog} kernels={kernels}", flush=True)
 
 
+def read(specs):
+    """READ <table> <program> passes=<n> gathers=<n>: instructions of the
+    compiled read that write an array as large as the bucket of rows it
+    returns (``table_sized_passes`` at that size: the least is one, the
+    rows themselves), and ``gather`` instructions."""
+    for name, rows, cols, chips, updater, workers, buckets, _ in specs:
+        srv, ctx = build(rows, cols, chips, updater, workers)
+        data, aux = srv._state["data"], srv._state["aux"]
+        one = SingleDeviceSharding(ctx.mesh.devices.flat[0])
+        scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one)
+        for b in buckets:
+            ids = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one)
+            lowered = [
+                (f"slice_rows.{b}", srv._slice_rows.lower(
+                    data, scalar, None, bucket=b, num_cols=cols)),
+                (f"slice_rows.{b}.under", srv._slice_rows.lower(
+                    data, scalar, scalar, bucket=b, num_cols=cols)),
+                (f"gather_rows.{b}", srv._gather_rows.lower(data, aux, ids))]
+            for prog, low in lowered:
+                hlo = low.compile().as_text()
+                passes = table_sized_passes(hlo, b * cols)
+                gathers = len(re.findall(r"\bgather\(", hlo))
+                print(f"READ {name} {prog} passes={len(passes)} "
+                      f"gathers={gathers}", flush=True)
+                for ln in passes:
+                    print("  PASS", ln, flush=True)
+
+
 if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--dump")
     ap.add_argument("--alias", action="store_true")
     ap.add_argument("--alias-all", action="store_true")
     ap.add_argument("--tiny", action="store_true")
+    ap.add_argument("--read", action="store_true")
     a = ap.parse_args()
     try:
         _devices(1)
@@ -273,3 +323,5 @@ if __name__ == "__main__":
         alias(STATEFUL + (STATEFUL_MORE if a.alias_all else []))
     if a.tiny:
         tiny(TINY)
+    if a.read:
+        read(READ)

@@ -231,3 +231,207 @@ def test_fetch_slices_only_under_the_bucket(world, monkeypatch, batch):
     assert rows.shape == (batch, COLS)
     # at the bucket the program's output itself comes back: no slice
     assert (rows is outputs[0]) == (batch == 64)
+
+
+# -- (d) a run of consecutive rows is read by a slice ------------------------
+# The host holds the ids before anything is copied, so it chooses between
+# two programs (``_fetch_run``): on one shard, ids strictly consecutive
+# whose bucket-long slice stays inside the live rows are read by
+# ``ops.slice_rows`` with no ids on the device; anything else gathers, as
+# before. Either way the rows are the gather's, bit for bit.
+
+RUN_ROWS = 256      # a rung of the bucket ladder: the whole table is a run
+
+
+@pytest.fixture()
+def one_shard():
+    import multiverso_tpu as mv
+    mv.MV_Init(["-num_workers=1"], devices=jax.devices()[:1])
+    yield mv
+    mv.MV_ShutDown()
+
+
+def _fetch_runs():
+    return metrics.snapshot().get(
+        "table.device_fetch.dense_runs", {}).get("value", 0)
+
+
+def _programs_of(srv, monkeypatch):
+    """Record which of the two read programs a fetch launches."""
+    ran = []
+    gather, slice_rows = srv._gather_rows, srv._slice_rows
+
+    def gathering(*args):
+        ran.append("gather")
+        return gather(*args)
+
+    def slicing(data, **small):
+        ran.append("slice")
+        return slice_rows(data, **small)
+    monkeypatch.setattr(srv, "_gather_rows", gathering)
+    monkeypatch.setattr(srv, "_slice_rows", slicing)
+    return ran
+
+
+def _the_gathers_rows(srv, ids):
+    rows = srv._gather_rows(srv.state["data"], srv.state["aux"],
+                            srv._device_ids(ids))
+    return np.asarray(rows)[: len(ids)]
+
+
+RUNS = {    # ids, and whether the slice program reads them
+    "from_row_0": (np.arange(0, 64), True),
+    "from_the_middle": (np.arange(40, 104), True),
+    "to_the_last_live_row": (np.arange(RUN_ROWS - 64, RUN_ROWS), True),
+    "shorter_than_its_bucket": (np.arange(10, 50), True),
+    "one_row": (np.arange(7, 8), True),
+    "the_whole_table": (np.arange(RUN_ROWS), True),
+    "most_of_the_table": (np.arange(RUN_ROWS - 30), True),
+    # 40 ids from row 200: the 64 lanes of their bucket would pass row 255
+    "bucket_past_the_live_rows": (np.arange(200, 240), False),
+    "whole_bucket_not_from_row_0": (np.arange(5, RUN_ROWS - 20), False),
+    "a_gap": (np.delete(np.arange(40, 105), 30), False),
+    "a_repeat": (np.sort(np.append(np.arange(40, 103), 70)), False),
+    # ends as far apart as a run's, a repeat and a gap between them
+    "a_repeat_and_a_gap": (np.array([3, 4, 4, 6]), False),
+    "descending": (np.arange(103, 39, -1), False),
+    "shuffled": (np.random.default_rng(5).permutation(np.arange(40, 104)),
+                 False),
+}
+
+
+@pytest.mark.parametrize("cols", [256, 50], ids=["two_tiles", "50_cols"])
+@pytest.mark.parametrize("case", list(RUNS))
+def test_fetch_of_a_run_is_a_slice_and_equals_the_gather(
+        one_shard, monkeypatch, case, cols):
+    ids, sliced = RUNS[case]
+    ids = ids.astype(np.int32)
+    init = np.random.default_rng(1).standard_normal(
+        (RUN_ROWS, cols)).astype(np.float32)
+    srv = _table(one_shard, "adagrad", init, RUN_ROWS, cols).server()
+    assert srv.num_servers == 1 and srv.block_rows == RUN_ROWS
+    assert srv.store_cols == (256 if cols == 256 else 128)  # the pad is cut
+    want = _the_gathers_rows(srv, ids)
+    ran, before = _programs_of(srv, monkeypatch), _fetch_runs()
+    rows = srv.device_fetch_rows(ids)
+    assert isinstance(rows, jax.Array) and rows.shape == (len(ids), cols)
+    assert ran == ["slice" if sliced else "gather"]
+    assert _fetch_runs() - before == (1 if sliced else 0)
+    np.testing.assert_array_equal(np.asarray(rows), want)
+    np.testing.assert_array_equal(want, init[ids])
+
+
+@pytest.mark.parametrize("case", ["the_whole_table", "most_of_the_table",
+                                  "shorter_than_its_bucket"])
+def test_slice_program_hands_back_the_gathers_bucket(one_shard, case):
+    """The program's own output, pad lanes included: zero at and past the
+    run's end, as the gather's mask leaves them."""
+    ids = RUNS[case][0].astype(np.int32)
+    init = np.random.default_rng(2).standard_normal(
+        (RUN_ROWS, 50)).astype(np.float32) + 3.0
+    srv = _table(one_shard, "default", init, RUN_ROWS, 50).server()
+    run = srv._fetch_run(ids)
+    assert (run["count"] is None) == (len(ids) == next_bucket(len(ids)))
+    got = srv._slice_rows(srv.state["data"], **run)
+    want = srv._gather_rows(srv.state["data"], srv.state["aux"],
+                            srv._device_ids(ids))
+    assert got.shape == want.shape == (next_bucket(len(ids)), 50)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_one_row_tables_repeated_id_takes_the_gather(one_shard, monkeypatch):
+    """``rec_bag_steps``' smallest table: 2,048 positions, all of them row
+    0. The ends are equal, the length is not 1: not a run."""
+    init = np.full((1, 128), 2.5, np.float32)
+    srv = _table(one_shard, "adagrad", init, 1, 128).server()
+    ran, before = _programs_of(srv, monkeypatch), _fetch_runs()
+    rows = srv.device_fetch_rows(np.zeros(2048, np.int32))
+    assert ran == ["gather"] and _fetch_runs() == before
+    np.testing.assert_array_equal(np.asarray(rows), 2.5)
+    # its one row alone is a run of one, inside the one live row
+    assert srv._fetch_run(np.zeros(1, np.int32)) is None    # bucket 8 > 1
+
+
+@pytest.mark.parametrize("updater", ["default", "adagrad"])
+def test_fetch_after_an_apply_of_the_same_run_returns_the_applied_rows(
+        one_shard, monkeypatch, updater):
+    """``lm_vocab_steps``' step: fetch a run, apply a delta made on the
+    device to the same run, fetch again. Held to the host plane on a twin
+    table, bit for bit (whole-number deltas)."""
+    rng = np.random.default_rng(4)
+    init = rng.integers(-8, 9, (RUN_ROWS, 256)).astype(np.float32)
+    dev, host = (_table(one_shard, updater, init, RUN_ROWS, 256)
+                 for _ in range(2))
+    srv = dev.server()
+    ran = _programs_of(srv, monkeypatch)
+    for ids in (np.arange(RUN_ROWS, dtype=np.int32),
+                np.arange(32, 96, dtype=np.int32)):
+        for _ in range(2):
+            delta = rng.integers(-3, 4, (len(ids), 256)).astype(np.float32)
+            srv.device_apply_rows(ids, jnp.asarray(delta))
+            host.AddRows(ids, delta)
+            np.testing.assert_array_equal(
+                np.asarray(srv.device_fetch_rows(ids)), host.GetRows(ids))
+    assert ran == ["slice"] * 4
+
+
+def test_four_shards_take_the_gather(monkeypatch):
+    """A shard sees the middle of a cross-shard run: the static one-shard
+    guard keeps ``tables_rounds_4c`` on the ``shard_map`` gather."""
+    import multiverso_tpu as mv
+    mv.MV_Init(["-num_workers=1"], devices=jax.devices()[:4])
+    try:
+        init = np.random.default_rng(6).standard_normal(
+            (RUN_ROWS, 128)).astype(np.float32)
+        srv = _table(mv, "adagrad", init, RUN_ROWS, 128).server()
+        assert srv.num_servers == 4
+        ran, before = _programs_of(srv, monkeypatch), _fetch_runs()
+        for ids in (np.arange(RUN_ROWS), np.arange(8, 16)):   # one shard's
+            rows = srv.device_fetch_rows(ids.astype(np.int32))
+            np.testing.assert_array_equal(np.asarray(rows), init[ids])
+        assert ran == ["gather", "gather"] and _fetch_runs() == before
+    finally:
+        mv.MV_ShutDown()
+
+
+def test_an_access_hook_keeps_the_gather(monkeypatch):
+    """The gather's program applies an updater's ``access`` hook to the
+    rows it reads; the slice program does not, so such a table gathers."""
+    import multiverso_tpu as mv
+    from multiverso_tpu.updaters import base
+
+    class Doubled(base.AddUpdater):
+        def access(self, data, aux, opt):
+            return data * 2
+
+    monkeypatch.setitem(base._REGISTRY, "doubled", Doubled)
+    mv.MV_Init(["-num_workers=1"], devices=jax.devices()[:1])
+    try:
+        init = np.arange(RUN_ROWS * 128, dtype=np.float32).reshape(-1, 128)
+        srv = _table(mv, "doubled", init, RUN_ROWS, 128).server()
+        ran, before = _programs_of(srv, monkeypatch), _fetch_runs()
+        ids = np.arange(64, dtype=np.int32)
+        np.testing.assert_array_equal(
+            np.asarray(srv.device_fetch_rows(ids)), 2 * init[ids])
+        assert ran == ["gather"] and _fetch_runs() == before
+    finally:
+        mv.MV_ShutDown()
+
+
+def test_a_run_copies_no_ids_and_is_one_call(one_shard):
+    """The crossings of a fetch (``tables/crossing.py``): a gathered set
+    is one copy of its padded ids and one call; a run copies nothing and
+    is one call, two when the run is shorter than its bucket (the cut)."""
+    srv = _table(one_shard, rows=RUN_ROWS).server()
+
+    def crossings(ids):
+        def read():
+            snap = metrics.snapshot()
+            return [snap.get(f"table.device.{k}", {}).get("value", 0)
+                    for k in ("h2d_copies", "h2d_bytes", "calls")]
+        before = read()
+        srv.device_fetch_rows(np.asarray(ids, np.int32))
+        return [a - b for a, b in zip(read(), before)]
+    assert crossings(np.arange(64)[::-1]) == [1, 4 * 64, 1]
+    assert crossings(np.arange(64)) == [0, 0, 1]
+    assert crossings(np.arange(40)) == [0, 0, 2]

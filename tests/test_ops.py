@@ -168,7 +168,8 @@ class TestStatefulRowProgramsAliasOnTpu:
         env = dict(os.environ, JAX_PLATFORMS="cpu")
         res = subprocess.run(
             [sys.executable, os.path.join(here, "aot_table_programs.py"),
-             "--alias", "--tiny"], env=env, capture_output=True, text=True,
+             "--alias", "--tiny", "--read"], env=env, capture_output=True,
+            text=True,
             timeout=900)
         assert res.returncode == 0, res.stderr[-3000:]
         lines = res.stdout.strip().splitlines()
@@ -176,7 +177,7 @@ class TestStatefulRowProgramsAliasOnTpu:
             pytest.skip(lines[-1])
         found = {}
         for ln in lines:
-            m = re.match(r"(?:ALIAS|TINY) (\S+) (\S+) (.*)", ln)
+            m = re.match(r"(?:ALIAS|TINY|READ) (\S+) (\S+) (.*)", ln)
             if m:
                 found[m.group(1), m.group(2)] = m.group(3)
         return found, res.stdout
@@ -203,6 +204,25 @@ class TestStatefulRowProgramsAliasOnTpu:
         table = f"adagrad_128_r{rows}"
         assert (table, program) in found, out[-2000:]
         assert found[table, program] == f"kernels={kernels}", out[-3000:]
+
+    @pytest.mark.parametrize("program", [
+        "slice_rows.163840",            # lm_head's fetch: the whole table
+        "slice_rows.163840.under",      # a run from row 0, shorter
+        "slice_rows.16384",             # a run of a bucket under the table
+        "slice_rows.16384.under"])
+    def test_dense_read_is_one_pass_and_no_gather(self, compiled, program):
+        """``lm_vocab_steps``' ``lm_head`` fetch (PERF.md section 6, PR 36):
+        the dense read of 163,840 x 2,048 compiles for a v5e to ONE
+        instruction as large as the rows it returns, mask included (the
+        gather of the same bucket is two, the gather and then its mask:
+        ``--read`` prints both)."""
+        found, out = compiled
+        table = "adagrad_2048_w1"
+        assert (table, program) in found, out[-2000:]
+        assert found[table, program] == "passes=1 gathers=0", out[-3000:]
+        bucket = program.split(".")[1]
+        # the count of gathers finds one where there is one
+        assert found[table, f"gather_rows.{bucket}"].endswith("gathers=1")
 
 
 class TestMatrixTableWithPallas:
@@ -331,6 +351,66 @@ class TestDenseRunPath:
                                    expect[live_rows], rtol=1e-6)
         # the Get half returns POST-update rows
         np.testing.assert_allclose(np.asarray(rows), expect[ids], rtol=1e-5)
+
+    @pytest.mark.parametrize("start,count,bucket", [
+        (0, 8, 8), (16, 8, 8), (55, 8, 8),      # a run that is its bucket
+        (0, 5, 8), (20, 1, 8), (55, 3, 8),      # shorter: pad lanes zero
+        (0, 63, 63), (0, 40, 63), (0, 1, 63),   # as long as the live rows
+    ])
+    @pytest.mark.parametrize("num_cols", [8, 5], ids=["all_cols", "5_cols"])
+    def test_slice_rows_is_the_masked_gather(self, start, count, bucket,
+                                             num_cols):
+        """The dense read against the general one: the gather of ``start
+        .. start + count - 1`` padded to ``bucket`` trash lanes, masked as
+        the table masks it, the storage pad cut off. Bit for bit."""
+        from multiverso_tpu.ops import rows as rops
+        _, data = self._mk(seed=7)
+        trash = data.shape[0] - 1
+        ids = np.full(bucket, trash, np.int32)
+        ids[:count] = np.arange(start, start + count)
+        want = np.where((ids != trash)[:, None],
+                        np.asarray(rops.gather_rows(jnp.asarray(data),
+                                                    jnp.asarray(ids))),
+                        0)[:, :num_cols]
+        program = jax.jit(rops.slice_rows,
+                          static_argnames=("bucket", "num_cols"))
+        got = program(jnp.asarray(data), np.int32(start),
+                      None if count == bucket else np.int32(count),
+                      bucket=bucket, num_cols=num_cols)
+        np.testing.assert_array_equal(np.asarray(got), want)
+        # a traced count that equals the bucket masks nothing either
+        got = program(jnp.asarray(data), np.int32(start), np.int32(count),
+                      bucket=bucket, num_cols=num_cols)
+        np.testing.assert_array_equal(np.asarray(got), want)
+
+    @pytest.mark.parametrize("bucket,masked,primitives", [
+        (8, False, {"dynamic_slice"}),
+        (8, True, {"dynamic_slice", "select_n"}),
+        (63, False, {"dynamic_slice"}),     # from the constant row 0
+        (63, True, {"dynamic_slice", "select_n"}),
+    ])
+    def test_slice_rows_has_no_cond_and_no_gather(self, bucket, masked,
+                                                  primitives):
+        """One slice, one select where a lane is masked; the start is a
+        constant where the bucket is as long as the live rows."""
+        from multiverso_tpu.ops import rows as rops
+        _, data = self._mk()
+        count = jnp.int32(3) if masked else None
+        program = jax.make_jaxpr(
+            lambda d, s, c: rops.slice_rows(d, s, c, bucket, 8))(
+                jnp.asarray(data), jnp.int32(0), count)
+        def equations(jaxpr):
+            for eqn in jaxpr.eqns:
+                yield eqn
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from equations(sub)
+        names = {e.primitive.name for e in equations(program.jaxpr)}
+        assert primitives <= names
+        assert not names & {"cond", "gather", "scatter", "while"}
+        cut, = (e for e in program.jaxpr.eqns
+                if e.primitive.name == "dynamic_slice")
+        start_is_constant = type(cut.invars[1]).__name__ == "Literal"
+        assert start_is_constant == (bucket == data.shape[0] - 1)
 
     def test_table_round_verb_matches_separate_verbs(self, mv_env):
         from multiverso_tpu.tables.matrix_table import MatrixTableOption
