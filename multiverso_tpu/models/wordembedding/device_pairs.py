@@ -57,6 +57,7 @@ from typing import Optional
 import numpy as np
 
 from multiverso_tpu.parallel.mesh import next_bucket
+from multiverso_tpu.telemetry import metrics as tmetrics
 from multiverso_tpu.telemetry import trace as ttrace
 
 
@@ -104,28 +105,48 @@ _PROGRAM_CACHE = {}
 _SPARSE_BYTES = 64 << 20
 
 
-def _make_sparse_adagrad_step(eps: float = 1e-10):
+def _make_sparse_adagrad_step(eps: float = 1e-10, lanes=None):
     """Touched-rows adagrad batch step over FULL storage tables:
     identical math to model.make_train_step's adagrad branch (the batch's
     per-row summed gradient feeds g2 before the update), but the g2/row
     updates gather+scatter only the rows the batch touches —
     ops.dedup_rows combines duplicate ids by sum exactly like the dense
     scatter-add did. All ids are pre-mapped storage ids; dedup pad lanes
-    (-1) route to the storage trash row (shape-1, don't-care)."""
+    (-1) route to the storage trash row (shape-1, don't-care).
+
+    ``lanes`` makes it the step of ONE shard of tables block-sharded by
+    rows, to run under ``shard_map`` over the ``server`` axis: the tables
+    are the shard's own rows, ids are LOGICAL rows, and ``lanes`` is the
+    table's ``device_local_lanes`` (ids -> (mine, the shard's row or its
+    trash row)). A fetch gathers the rows the shard owns and the partial
+    rows are summed (all but one are zero: the sum is exact); the update
+    needs nothing from another shard — every shard holds the batch's
+    deduplicated gradients and writes the rows it owns, the others' lanes
+    and the pad lanes going to its own trash row."""
     import jax
     import jax.numpy as jnp
+    from jax import lax
 
     from multiverso_tpu import ops
     from multiverso_tpu.models.wordembedding.model import TrainState
+    from multiverso_tpu.parallel.mesh import SERVER_AXIS
+
+    @jax.named_scope("we.device_pairs_step.fetch")
+    def fetch(tab, ids):
+        if lanes is None:
+            return ops.gather_rows(tab, ids)
+        mine, safe = lanes(ids)
+        rows = jnp.where(mine[:, None], ops.gather_rows(tab, safe), 0)
+        return lax.psum(rows, SERVER_AXIS)
 
     def step(state, inputs, imask, outputs, labels, omask, lr):
         ie, eo = state.ie, state.eo
         D = ie.shape[1]
-        in_rows = ops.gather_rows(ie, inputs.reshape(-1)).reshape(
+        in_rows = fetch(ie, inputs.reshape(-1)).reshape(
             inputs.shape + (D,))
         denom = jnp.maximum(imask.sum(axis=1, keepdims=True), 1.0)
         h = (in_rows * imask[:, :, None]).sum(axis=1) / denom
-        out_rows = ops.gather_rows(eo, outputs.reshape(-1)).reshape(
+        out_rows = fetch(eo, outputs.reshape(-1)).reshape(
             outputs.shape + (D,))
         logits = jnp.einsum("pd,pcd->pc", h, out_rows)
         f = jax.nn.sigmoid(logits)
@@ -136,16 +157,24 @@ def _make_sparse_adagrad_step(eps: float = 1e-10):
         eo_contrib = err[:, :, None] * h[:, None, :]
         ie_contrib = hid_err[:, None, :] * imask[:, :, None]
 
+        @jax.named_scope("we.device_pairs_step.update")
         def row_update(tab, g2tab, ids, contrib):
             uids, grads = ops.dedup_rows(ids.reshape(-1),
                                          contrib.reshape(-1, D))
-            trash = tab.shape[0] - 1
-            uids = jnp.where(uids < 0, trash, uids)
+            if lanes is None:
+                trash = tab.shape[0] - 1
+                uids = jnp.where(uids < 0, trash, uids)
+            else:
+                _, uids = lanes(uids)
             g2_rows = ops.gather_rows(g2tab, uids) + grads * grads
             rows = ops.gather_rows(tab, uids) + jnp.where(
                 g2_rows > eps, lr * grads / jnp.sqrt(g2_rows + 1e-12), 0.0)
-            return (ops.scatter_set_rows(tab, uids, rows),
-                    ops.scatter_set_rows(g2tab, uids, g2_rows))
+            # the dense-run cond is the one-shard program's (inside a
+            # shard_map body it defeats donation,
+            # matrix_table._update_rows_local)
+            dense = lanes is None
+            return (ops.scatter_set_rows(tab, uids, rows, dense=dense),
+                    ops.scatter_set_rows(g2tab, uids, g2_rows, dense=dense))
 
         eo, eo_g2 = row_update(eo, state.eo_g2, outputs, eo_contrib)
         ie, ie_g2 = row_update(ie, state.ie_g2, inputs, ie_contrib)
@@ -158,10 +187,21 @@ class DevicePairsTrainer:
     """Owns the uploaded sampling tables; programs cache module-wide."""
 
     def __init__(self, opt, comm, counts, huffman=None):
-        import jax.numpy as jnp
         self.opt = opt
         self.comm = comm
         self._block_counter = 0
+        # what the block program takes whole on every shard (the sampling
+        # tables here, a block's token stream later): over more than one
+        # shard it is put in the sharding the tables' row programs state
+        # for ids, replicated over the mesh, ONE copy a chip, and not left
+        # on the default device for jit to send to the others at every
+        # call. One shard keeps its bare copy: a committed operand is
+        # another lowered module, and that world compiles what it did
+        import jax
+        import jax.numpy as jnp
+        srv = comm.input_table.server()
+        put = self._put = (srv._put_small if srv.num_servers > 1 else
+                           lambda host: jax.tree.map(jnp.asarray, host))
         if opt.hs:
             # hierarchical softmax: the (points, 1-codes) tables upload
             # ONCE; each center's output lanes gather from them like the
@@ -185,9 +225,8 @@ class DevicePairsTrainer:
                 pts[w, :L] = info.points
                 labs[w, :L] = [1 - c for c in info.codes]
                 hmask[w, :L] = 1.0
-            self._hs_points = jnp.asarray(pts)
-            self._hs_labels = jnp.asarray(labs)
-            self._hs_mask = jnp.asarray(hmask)
+            self._hs_points, self._hs_labels, self._hs_mask = put(
+                (pts, labs, hmask))
             self._max_code = MC
             self._slots = None
         else:
@@ -202,7 +241,7 @@ class DevicePairsTrainer:
             cum = np.cumsum(probs / probs.sum())
             T = int(min(max(1 << 20, 64 * len(counts)), 1 << 24))
             bounds = np.round(cum * T).astype(np.int64)
-            self._slots = jnp.asarray(np.repeat(
+            self._slots = put(np.repeat(
                 np.arange(len(counts), dtype=np.int32),
                 np.diff(bounds, prepend=0)))
 
@@ -230,10 +269,16 @@ class DevicePairsTrainer:
         srv = self.comm.input_table.server()
         table_bytes = srv.state["data"].size * srv.state["data"].dtype.itemsize
         sparse = opt.use_adagrad and table_bytes > _SPARSE_BYTES
+        # storage of more than one shard: the touched-rows step runs per
+        # shard (its row kernel cannot be partitioned by the compiler, and
+        # no chip may hold a table whole); one shard compiles what it
+        # always did
+        sharded = sparse and srv.num_servers > 1
         cache_key = (t_pad, nb, opt.window_size, opt.negative_num,
                      opt.pair_batch_size, opt.use_adagrad, sparse,
                      srv.block_rows, opt.cbow, opt.hs,
-                     self._max_code if opt.hs else 0)
+                     self._max_code if opt.hs else 0,
+                     srv._mesh if sharded else None)
         if cache_key in _PROGRAM_CACHE:
             return _PROGRAM_CACHE[cache_key]
         import jax
@@ -245,15 +290,17 @@ class DevicePairsTrainer:
 
         W, K = opt.window_size, opt.negative_num
         B = opt.pair_batch_size
-        step = (_make_sparse_adagrad_step() if sparse
-                else make_train_step(opt.use_adagrad))
+        step = (_make_sparse_adagrad_step(
+                    lanes=srv.device_local_lanes if sharded else None)
+                if sparse else make_train_step(opt.use_adagrad))
         block_rows = srv.block_rows   # all four tables share the layout
         use_adagrad = opt.use_adagrad
 
         def smap(r):
             """logical row -> interleaved storage row (matrix_table
-            layout: block_rows live rows + 1 trash row per shard)."""
-            return r + r // block_rows
+            layout: block_rows live rows + 1 trash row per shard). The
+            per-shard step takes logical rows: each shard finds its own."""
+            return r if sharded else r + r // block_rows
 
         cbow, hs = opt.cbow, opt.hs
 
@@ -362,8 +409,22 @@ class DevicePairsTrainer:
                                jnp.sum(pmask).astype(jnp.int32)])
             return out, stats
 
-        import jax as _jax
-        _PROGRAM_CACHE[cache_key] = _jax.jit(program, donate_argnums=(0,))
+        if sharded:
+            # the whole block program per shard: the tables by rows, all
+            # else (tokens, sampling tables, key, rate) whole on each, so
+            # every shard draws the same pairs and computes the same
+            # batch; only the fetched rows cross between chips (the step's
+            # psum). The stats come out equal on every shard.
+            from jax.sharding import PartitionSpec as P
+
+            from multiverso_tpu.parallel.mesh import SERVER_AXIS
+            rows, whole = P(SERVER_AXIS, None), P()
+            program = jax.shard_map(
+                program, mesh=srv._mesh,
+                in_specs=((rows,) * 4, whole, whole, whole, whole, whole),
+                out_specs=((rows,) * 4, whole),
+                check_vma=False)  # pallas_call outputs carry no vma info
+        _PROGRAM_CACHE[cache_key] = jax.jit(program, donate_argnums=(0,))
         return _PROGRAM_CACHE[cache_key]
 
     # -- per-block entry ----------------------------------------------------
@@ -395,6 +456,7 @@ class DevicePairsTrainer:
         from multiverso_tpu.parallel.mesh import place_parts
 
         nproc = multihost.process_count()
+        srv = self.comm.input_table.server()
         T = len(token_ids)
         if nproc > 1:
             from multiverso_tpu.parallel.mesh import (local_device_count,
@@ -414,7 +476,7 @@ class DevicePairsTrainer:
                     (T, local_max_sent), "we_dp_agreed")
                 agreed = (max(p[0] for p in parts),
                           max(p[1] for p in parts))
-            mesh = self.comm.input_table.server()._mesh
+            mesh = srv._mesh
             t_pad = parts_bucket(max(1024, agreed[0]),
                                  local_device_count(mesh))
             sent_span = max(agreed[1], 1)
@@ -437,8 +499,15 @@ class DevicePairsTrainer:
                 n_total = nproc * t_pad
             else:
                 sent[:T] = token_sent
-                ids_g, sent_g = jnp.asarray(ids), jnp.asarray(sent)
+                # whole on every shard, as the block program states it
+                with ttrace.child(".place"):
+                    ids_g, sent_g = self._put((ids, sent))
                 n_total = t_pad
+            # the block's tokens by the shard that owns their input row:
+            # block sharding of ids ordered by count makes shard 0 hot
+            for k, n in enumerate(np.bincount(token_ids // srv.block_rows,
+                                              minlength=srv.num_servers)):
+                tmetrics.counter(f"we.block.tokens.shard{k}").inc(int(n))
         with ttrace.span("worker.we.dispatch", cat="worker"):
             P = n_total if self.opt.cbow \
                 else 2 * self.opt.window_size * n_total

@@ -291,7 +291,7 @@ class MatrixServerTable(ServerTable):
             mine = shard_of == s
             safe = jnp.where(mine, ids - s * block_rows, block_rows)
             return mine, safe.astype(jnp.int32)
-
+        self.device_local_lanes = _local_lanes  # for a caller's shard_map
         shard_rows = self.shard_rows
 
         def _aux_lanes(aux, safe, opt):

@@ -23,6 +23,11 @@ the gather it stands in for at ``lm_vocab_steps``' shape (``READ``) and
 prints, a program, how many instructions write an array as large as the
 rows it returns and how many gathers it holds.
 
+``--pairs`` compiles the WordEmbedding app's ``-device_pairs`` block
+program over four shards at ``we_pairs_4c``'s shapes (``PAIRS``) and
+prints its collectives, its Mosaic kernels, how many instructions hold a
+table's whole rows and its table-sized passes.
+
 Prints ``SKIP ...`` and exits 0 where the topology description is missing.
 """
 
@@ -304,6 +309,55 @@ def read(specs):
                     print("  PASS", ln, flush=True)
 
 
+# (name, vocabulary, chips, tokens a block padded, batches a block)
+PAIRS = [("we_pairs_4c", 8_388_600, 4, 163_840, 256)]
+
+
+def pairs(specs):
+    """PAIRS <cell> block_program all_gather=<n> all_reduce=<n>
+    kernels=<n> whole_table=<n> passes=<n>: the fused generate-and-train
+    program (``models/wordembedding/device_pairs.py``) over ``chips``
+    shards, compiled for the chip: its collectives by kind, its Mosaic
+    kernels (the four row writes of the touched-rows step), instructions
+    that hold an array of a table's WHOLE stored rows (a table gathered
+    onto one chip) and passes over a shard outside the in-place writes."""
+    from multiverso_tpu.models.wordembedding import device_pairs as dp
+    from multiverso_tpu.models.wordembedding.option import Option
+    for name, vocab, chips, t_pad, nb in specs:
+        ctx = MeshContext.create(_devices(chips))
+        zoo = types.SimpleNamespace(mesh_ctx=ctx, num_workers=1)
+        with _no_allocation():
+            srvs = [MatrixServerTable(vocab, 128, np.float32, zoo, "default")
+                    for _ in range(4)]
+        tables = [types.SimpleNamespace(server=lambda s=s: s) for s in srvs]
+        trainer = dp.DevicePairsTrainer.__new__(dp.DevicePairsTrainer)
+        trainer.opt = Option(embedding_size=128, window_size=5,
+                             negative_num=5, use_adagrad=True,
+                             device_pairs=True, pair_batch_size=8192)
+        trainer.comm = types.SimpleNamespace(
+            input_table=tables[0], output_table=tables[1],
+            ie_g2_table=tables[2], eo_g2_table=tables[3])
+        whole = NamedSharding(ctx.mesh, P())
+        s = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+            shape, dtype, sharding=whole)
+        key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+        hlo = trainer._program(t_pad, nb).lower(
+            tuple(srv._state["data"] for srv in srvs),
+            (s((1 << 24,), jnp.int32),), s((t_pad,), jnp.int32),
+            s((t_pad,), jnp.int32), s(key.shape, key.dtype),
+            s((), jnp.float32)).compile().as_text()
+        count = lambda pat: len(re.findall(pat, hlo))  # noqa: E731
+        passes = table_sized_passes(hlo, srvs[0].shard_rows * 128)
+        print(f"PAIRS {name} block_program "
+              f"all_gather={count(r' all-gather(-start)?[(]')} "
+              f"all_reduce={count(r' all-reduce(-start)?[(]')} "
+              f"kernels={count(r'custom_call_target=.tpu_custom_call')} "
+              f"whole_table={count(rf'[[]{srvs[0].padded_rows},128[]]')} "
+              f"passes={len(passes)}", flush=True)
+        for ln in passes:
+            print("  PASS", ln, flush=True)
+
+
 if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--dump")
@@ -311,6 +365,7 @@ if __name__ == "__main__":
     ap.add_argument("--alias-all", action="store_true")
     ap.add_argument("--tiny", action="store_true")
     ap.add_argument("--read", action="store_true")
+    ap.add_argument("--pairs", action="store_true")
     a = ap.parse_args()
     try:
         _devices(1)
@@ -325,3 +380,5 @@ if __name__ == "__main__":
         tiny(TINY)
     if a.read:
         read(READ)
+    if a.pairs:
+        pairs(PAIRS)

@@ -168,7 +168,8 @@ class TestStatefulRowProgramsAliasOnTpu:
         env = dict(os.environ, JAX_PLATFORMS="cpu")
         res = subprocess.run(
             [sys.executable, os.path.join(here, "aot_table_programs.py"),
-             "--alias", "--tiny", "--read"], env=env, capture_output=True,
+             "--alias", "--tiny", "--read", "--pairs"], env=env,
+            capture_output=True,
             text=True,
             timeout=900)
         assert res.returncode == 0, res.stderr[-3000:]
@@ -177,7 +178,7 @@ class TestStatefulRowProgramsAliasOnTpu:
             pytest.skip(lines[-1])
         found = {}
         for ln in lines:
-            m = re.match(r"(?:ALIAS|TINY|READ) (\S+) (\S+) (.*)", ln)
+            m = re.match(r"(?:ALIAS|TINY|READ|PAIRS) (\S+) (\S+) (.*)", ln)
             if m:
                 found[m.group(1), m.group(2)] = m.group(3)
         return found, res.stdout
@@ -189,6 +190,22 @@ class TestStatefulRowProgramsAliasOnTpu:
         assert (table, program) in found, out[-2000:]
         # data and the history, both donated, both updated in place
         assert found[table, program] == "aliased=2/2 passes=0", out[-3000:]
+
+    def test_sharded_block_program_gathers_no_table(self, compiled):
+        """``we_pairs_4c`` (PERF.md section 6, PR 37): the WordEmbedding
+        app's ``-device_pairs`` block program over four shards of
+        8,388,600 x 128 tables compiles for a v5e 2x2 (a plain ``jit``
+        over the sharded storage does not: Mosaic kernels cannot be
+        partitioned by the compiler), moves rows between chips by
+        all-reduce alone, keeps the four row writes on the kernel, holds
+        no array of a table's whole rows and passes over no shard."""
+        found, out = compiled
+        assert ("we_pairs_4c", "block_program") in found, out[-2000:]
+        got = dict(kv.split("=") for kv in
+                   found["we_pairs_4c", "block_program"].split())
+        assert int(got.pop("all_reduce")) >= 1, out[-3000:]
+        assert got == {"all_gather": "0", "kernels": "4",
+                       "whole_table": "0", "passes": "0"}, out[-3000:]
 
     @pytest.mark.parametrize("rows", [1, 2, 4, 5])
     @pytest.mark.parametrize("program,kernels", [
