@@ -22,7 +22,21 @@ a program's call   ``table.device.calls``      ``.call`` (``args``: the
                                                program's name)
 device to host     ``table.device.d2h_copies`` ``.wait`` (``-trace`` only),
                    ``table.device.d2h_bytes``  then ``.take``
+                   (what crossed: a Get's
+                   bucket with its pad, see
+                   below)
 =================  ==========================  ===========================
+
+Where a gather's pad is dropped. A row program returns its bucket, and a
+host-plane Get wants the first ``n`` rows of it on the host. The bucket is
+copied back whole and the caller takes ``[:n]`` of the host array, a view:
+no slice program, so a Get is ONE ``.call`` and the pad's bytes (48 KB of
+2 MB for 10,000 ids under the rung 10,240) are counted in ``d2h_bytes``.
+A launch costs the host more than those bytes do; only a pad over
+``matrix_table._HOST_CUT_PAD_BYTES`` is cut on the device first (a second
+``.call``, program ``slice``). ``table.get.host_cuts`` and
+``table.get.device_cuts`` count the Gets of either kind. Rows that stay in
+HBM (``device_fetch_rows``) are always cut on the device.
 
 With ``-trace`` off a helper adds one flag read a span and one counter
 step a crossing to what the verb did before: no ``block_until_ready``, no

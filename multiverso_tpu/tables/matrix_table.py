@@ -92,13 +92,51 @@ def _pad_rows(deltas: jax.Array, bucket: int) -> jax.Array:
 
 
 def _cut_rows(rows: jax.Array, n: int) -> jax.Array:
-    """The first ``n`` of a gather's bucket of rows, on the device: the
+    """The first ``n`` of a gather's bucket of rows, ON THE DEVICE: the
     bucket itself when it is ``n`` long (no program, as ``_pad_rows``),
-    else a slice program."""
+    else a slice program, a launch of its own. For rows that stay in HBM
+    (``device_fetch_rows``: exactly ``len(row_ids)`` of them) and for a
+    host-bound bucket whose pad is too large to carry
+    (``_leaving_rows``)."""
     if n == rows.shape[0]:
         return rows
     with crossing.call("slice"):
         return rows[:n]
+
+
+#: The most pad bytes a host-bound bucket carries across the boundary
+#: for the host to drop (``_leaving_rows``): half of where the two costs
+#: meet on a v5e. The slice program's launch costs the host 0.70 ms alone
+#: and 0.9 ms on the engine's thread behind four workers (the gather's,
+#: jitted, 0.18 to 0.22 ms); pad bytes come back at 0.11 ms a MB. 10,000
+#: rows x 50 f32 gathered, copied back and cut, by the pad carried
+#: (PR 38's chip run, medians of 40): 0.05 MB 0.78 ms against 1.77 ms
+#: with the slice program, 2 MB 1.03 / 1.82, 4 MB 1.26 / 1.77, 8 MB
+#: 1.66 / 1.78. A quarter-octave rung leaves a pad under a quarter of the
+#: rows asked for, so only a Get of more than 16 MB reaches this.
+_HOST_CUT_PAD_BYTES = 4 << 20
+
+
+def _leaving_rows(rows: jax.Array, n: int) -> jax.Array:
+    """What of a gather's bucket is copied back when its first ``n`` rows
+    are wanted ON THE HOST; the caller takes ``[:n]`` of the host array,
+    a view, whichever this returns. The pad (``bucket - n`` rows of the
+    trash row) is dropped where it is cheaper: carried back and cut by
+    that view while it is under ``_HOST_CUT_PAD_BYTES`` (no slice
+    program, one launch a Get and not two), cut on the device by
+    ``_cut_rows`` over it. One step of ``table.get.host_cuts`` or
+    ``table.get.device_cuts`` a bucket longer than ``n`` (both
+    registered at 0 by the first)."""
+    bucket = rows.shape[0]
+    if n == bucket:
+        return rows
+    host_cuts = tmetrics.counter("table.get.host_cuts")
+    device_cuts = tmetrics.counter("table.get.device_cuts")
+    if (bucket - n) * (rows.nbytes // bucket) <= _HOST_CUT_PAD_BYTES:
+        host_cuts.inc()
+        return rows
+    device_cuts.inc()
+    return _cut_rows(rows, n)
 
 
 def _combine_duplicate_rows(ids: np.ndarray, deltas: np.ndarray,
@@ -718,10 +756,12 @@ class MatrixServerTable(ServerTable):
         return jax.device_put(host, self._replicated)
 
     def _take_rows(self, rows: jax.Array, n: int) -> np.ndarray:
-        """``_cut_rows`` brought to the host: the pad is cut off on the
-        device, so only the requested rows cross."""
-        return crossing.take(_cut_rows(rows, n),
-                             self._zoo.mesh_ctx.fetch)
+        """The first ``n`` of a gather's bucket, on the host: the bucket
+        crosses with its pad and the pad is dropped by a view of the host
+        array, or a slice program drops it first where it is too large
+        to carry (``_leaving_rows``)."""
+        return crossing.take(_leaving_rows(rows, n),
+                             self._zoo.mesh_ctx.fetch)[:n]
 
     def _verb_span(self, name: str, **args):
         """A verb's span under ``server.``; its ``args`` (the table's id
@@ -1543,7 +1583,12 @@ class MatrixServerTable(ServerTable):
         """Two-phase Get (base-class contract, tables/base.py): dispatch
         the gather + start the device->host copy now, fetch in finalize —
         the engine overlaps a window of these so queued host Gets pay one
-        pipelined RTT instead of one each."""
+        pipelined RTT instead of one each. What starts its copy is the
+        gather's bucket itself, pad and all, and the finalize replies the
+        first ``len(row_ids)`` rows as a view of the host array: one
+        launch a Get (``_leaving_rows`` says when a slice program cuts
+        the pad first). The gather's output is a fresh buffer, so an Add
+        later in the window that donates the state cannot reach it."""
         if multihost.world_size() > 1:
             return None  # collective fetch/union — keep the sync path
         nat = self._host_store()
@@ -1581,10 +1626,11 @@ class MatrixServerTable(ServerTable):
             with crossing.call("_gather_rows"):
                 rows = self._gather_rows(self.state["data"],
                                          self.state["aux"], device_ids)
-            sliced = _cut_rows(rows, len(ids))
-            sliced.copy_to_host_async()
+            n = len(ids)
+            leaving = _leaving_rows(rows, n)
+            leaving.copy_to_host_async()
         # run by the engine inside server.window.finalize: .wait, .take
-        return lambda: crossing.take(sliced)
+        return lambda: crossing.take(leaving)[:n]
 
     # -- eager device plane (public) ----------------------------------------
     # device_gather_rows / device_update_rows above are the TRACEABLE hooks

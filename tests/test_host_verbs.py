@@ -6,22 +6,24 @@ host mirror (``MatrixServerTable._host_store``), and the chip has no
 mirror (``native_host_mirror=False``): the cell ``mt_host_verbs`` spends
 its window in the OTHER branch of each ``nat = self._host_store()`` fork
 (``_merged_add_rows`` under ``ProcessAddRun``, ``_device_ids`` /
-``_device_opt``, the gather and slice of ``ProcessGetAsync``,
-``_update_full``). Eligibility is one attribute read lazily at the first
-host verb, so a test turns the mirror off right after creation (the
-``off_host_mirror`` fixture of conftest.py) and tier-1 runs the chip's branch; every case then runs once more with the mirror
-left on. Deltas are whole numbers, so both must equal a numpy replay bit
+``_device_opt``, the gather of ``ProcessGetAsync`` and the cut of its
+bucket's pad, ``_update_full``). Eligibility is one attribute read lazily
+at the first host verb, so a test turns the mirror off right after
+creation (the ``off_host_mirror`` fixture of conftest.py) and tier-1 runs
+the chip's branch; every case then runs once more with the mirror left on. Deltas are whole numbers, so both must equal a numpy replay bit
 for bit, in any order of summation.
 """
 
 import threading
+import time
 
 import numpy as np
 import pytest
 
 from multiverso_tpu.message import Message, MsgType
-from multiverso_tpu.tables import MatrixTableOption
+from multiverso_tpu.tables import MatrixTableOption, matrix_table
 from multiverso_tpu.telemetry import metrics
+from multiverso_tpu.updaters.base import GetOption
 from multiverso_tpu.zoo import Zoo
 
 #: the benchmark's table shape in small: 50 logical columns in one lane tile
@@ -40,17 +42,23 @@ def _counter(name: str) -> float:
     return metrics.snapshot().get(name, {}).get("value", 0)
 
 
-def _one_window(table, batches):
-    """Tracked AddRows that reach the engine as ONE window: a message
-    ahead of them holds the engine until all are queued."""
+def _held_window(table, submit):
+    """The replies to the verbs ``submit()`` sends (it returns their
+    handles), which reach the engine as ONE window: a message ahead of
+    them holds the engine until all are queued."""
     gate = threading.Event()
     Zoo.Get().SendToServer(Message(
         msg_type=MsgType.Request_StoreLoad,
         payload={"fn": lambda: gate.wait(60)}))
-    handles = [table.AddAsyncHandle(delta, ids) for ids, delta in batches]
+    handles = submit()
     gate.set()
-    for h in handles:
-        table.Wait(h)
+    return [table.Wait(h) for h in handles]
+
+
+def _one_window(table, batches):
+    """Tracked AddRows that reach the engine as ONE window."""
+    _held_window(table, lambda: [table.AddAsyncHandle(delta, ids)
+                                 for ids, delta in batches])
 
 
 def _batch(rng, n: int):
@@ -136,3 +144,90 @@ def test_host_verb_equals_replay(world, off_host_mirror, case, mirror):
     elif srv._native_host_ok:       # False only without a native toolchain
         assert srv._nat_store is not None
     np.testing.assert_array_equal(srv.raw(), replay)
+
+
+# -- where the pad of a Get's bucket is dropped (the chip's branch) ---------
+# A gather returns its bucket; the first n rows are the reply. The bucket
+# crosses whole and a host view drops the pad (one launch a Get) unless the
+# pad is over ``matrix_table._HOST_CUT_PAD_BYTES``, when a slice program
+# drops it first (two launches).
+
+def _chip_table(world, off_host_mirror, rng):
+    """A table of whole numbers off the mirror, and its initial rows."""
+    init = rng.integers(-8, 9, (ROWS, COLS)).astype(np.float32)
+    table = off_host_mirror(world.MV_CreateTable(MatrixTableOption(
+        num_rows=ROWS, num_cols=COLS, initializer=lambda shape: init)))
+    return table, init
+
+
+GET_PATHS = {
+    "engine": lambda table, srv, ids: table.GetRows(ids),
+    "process_get": lambda table, srv, ids: srv.ProcessGet(GetOption(),
+                                                          row_ids=ids),
+    "read_rows_union": lambda table, srv, ids: srv._read_rows_union(ids),
+}
+
+#: n, the pad bytes the constant is patched down to (None: as it is), and
+#: the side that drops the pad
+PAD_CASES = {
+    "at_its_bucket": (64, None, None),
+    "one_under_its_bucket": (63, None, "host"),
+    "10000_under_10240": (10_000, None, "host"),
+    "pad_over_the_constant": (40, 24 * COLS * 4 - 1, "device"),
+}
+
+
+@pytest.mark.parametrize("path", GET_PATHS)
+@pytest.mark.parametrize("pad_case", PAD_CASES)
+def test_get_drops_its_buckets_pad(world, off_host_mirror, monkeypatch,
+                                   pad_case, path):
+    n, limit, side = PAD_CASES[pad_case]
+    if limit is not None:
+        monkeypatch.setattr(matrix_table, "_HOST_CUT_PAD_BYTES", limit)
+    rng = np.random.default_rng(11)
+    table, init = _chip_table(world, off_host_mirror, rng)
+    srv = table.server()
+    ids = rng.choice(ROWS, n).astype(np.int32)
+    names = ("table.device.calls", "table.get.host_cuts",
+             "table.get.device_cuts")
+    before = [_counter(name) for name in names]
+    got = GET_PATHS[path](table, srv, ids)
+    stepped = [_counter(name) - was for name, was in zip(names, before)]
+    assert got.shape == (n, COLS) and got.dtype == np.float32
+    np.testing.assert_array_equal(got, srv.raw()[ids])
+    assert stepped == [2 if side == "device" else 1,
+                       int(side == "host"), int(side == "device")]
+    if side is not None:    # the first cut registers both counters
+        assert {"table.get.host_cuts", "table.get.device_cuts"} <= set(
+            metrics.snapshot())
+    assert srv._nat_store is None
+
+
+def test_add_between_two_gets_of_a_window(world, off_host_mirror):
+    """Get, Add, Get of the same rows in ONE window: the first reply is
+    the bucket gathered before the Add, copied back after the Add's
+    program donated the state it was gathered from."""
+    rng = np.random.default_rng(13)
+    table, init = _chip_table(world, off_host_mirror, rng)
+    ids = rng.choice(ROWS, 40, replace=False).astype(np.int32)
+    delta = rng.integers(1, 4, (40, COLS)).astype(np.float32)
+
+    def windows():
+        return metrics.snapshot().get("server.window.latency_s",
+                                      {}).get("count", 0)
+    host_cuts, was = _counter("table.get.host_cuts"), windows()
+    verbs = _counter("server.window.verbs")
+    first, _, second = _held_window(table, lambda: [
+        table.GetAsyncHandle(ids), table.AddAsyncHandle(delta, ids),
+        table.GetAsyncHandle(ids)])
+    # the engine counts a window, then its verbs, after the replies
+    deadline = time.monotonic() + 30
+    while (_counter("server.window.verbs") - verbs < 3
+           and time.monotonic() < deadline):
+        time.sleep(0.01)
+    assert _counter("server.window.verbs") - verbs == 3
+    assert windows() - was == 1
+    assert _counter("table.get.host_cuts") - host_cuts == 2
+    np.testing.assert_array_equal(first, init[ids])
+    np.testing.assert_array_equal(second, init[ids] + delta)
+    assert first.shape == second.shape == (40, COLS)

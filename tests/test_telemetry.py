@@ -519,17 +519,18 @@ class TestHostSpans:
                 [place, place, call("_pad_row_batch"),
                  call("_update_rows")],
                 dict(h2d_copies=2, h2d_bytes=4 * b + n * row, calls=2)),
-            # a host Get: the copy back is waited for and taken in the
-            # window's finalize
+            # a host Get: ONE call; the gather's bucket comes back with
+            # its pad (the host cuts it by a view), waited for and taken
+            # in the window's finalize
             "host_get": (
                 "server.table.get.dispatch",
-                [place, call("_gather_rows"), call("slice")],
-                dict(h2d_copies=1, h2d_bytes=4 * b, calls=2,
-                     d2h_copies=1, d2h_bytes=n * row)),
+                [place, call("_gather_rows")],
+                dict(h2d_copies=1, h2d_bytes=4 * b, calls=1,
+                     d2h_copies=1, d2h_bytes=b * row)),
             "host_get_finalize": (
                 "server.window.finalize", [wait, take],
-                dict(h2d_copies=1, h2d_bytes=4 * b, calls=2,
-                     d2h_copies=1, d2h_bytes=n * row)),
+                dict(h2d_copies=1, h2d_bytes=4 * b, calls=1,
+                     d2h_copies=1, d2h_bytes=b * row)),
             # a sparse Get: the whole bucket comes back
             "sparse_get": (
                 "server.table.sparse.get.read",
