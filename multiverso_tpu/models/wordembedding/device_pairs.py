@@ -29,10 +29,11 @@ and one jit'd, donated program derives the pairs and trains:
     the rare-word tail at word2vec-scale vocabularies);
   * center-collision negative lanes masked (reference skips
     target==word_idx draws);
-  * hierarchical softmax from (points, 1-codes, mask) tables built
-    once from the Huffman tree and gathered per center — the output
-    lanes become the center's root path (huffman_encoder.cpp), no
-    negative draws;
+  * hierarchical softmax from path tables taken whole from the
+    Huffman encoder's arrays (a node id a lane, a word's turns as
+    bits, its path length) and gathered per center — the output
+    lanes become the center's root path (huffman_encoder.cpp), labels
+    (1 - code) and the mask made in the program, no negative draws;
   * the standard train step (model.make_train_step) scanned over the
     lane batches, operating DIRECTLY on the tables' sharded storage
     (ids remapped to the interleaved layout: sid = r + r//block_rows).
@@ -43,7 +44,8 @@ which requires compaction — a data-dependent shape. It is one
 vectorized pass over the tokens and rides the loader thread.
 
 All four mode combinations (skipgram/cbow x NEG/HS) ride the fused
-path, and multi-process worlds train COLLECTIVELY: per-process token
+path (on a chip at a word2vec vocabulary: skipgram+NEG and CBOW+HS,
+the cells we_pairs and we_cbow_hs), and multi-process worlds train COLLECTIVELY: per-process token
 shards merge as one batch-sharded global vector whose gradients sum
 inside the traced program (round 4; rounds 2-3 covered
 skipgram+NEG, single-process only). Within a process the caller owns
@@ -144,8 +146,9 @@ def _make_sparse_adagrad_step(eps: float = 1e-10, lanes=None):
         D = ie.shape[1]
         in_rows = fetch(ie, inputs.reshape(-1)).reshape(
             inputs.shape + (D,))
-        denom = jnp.maximum(imask.sum(axis=1, keepdims=True), 1.0)
-        h = (in_rows * imask[:, :, None]).sum(axis=1) / denom
+        with jax.named_scope("we.device_pairs_step.context_mean"):
+            denom = jnp.maximum(imask.sum(axis=1, keepdims=True), 1.0)
+            h = (in_rows * imask[:, :, None]).sum(axis=1) / denom
         out_rows = fetch(eo, outputs.reshape(-1)).reshape(
             outputs.shape + (D,))
         logits = jnp.einsum("pd,pcd->pc", h, out_rows)
@@ -203,31 +206,36 @@ class DevicePairsTrainer:
         put = self._put = (srv._put_small if srv.num_servers > 1 else
                            lambda host: jax.tree.map(jnp.asarray, host))
         if opt.hs:
-            # hierarchical softmax: the (points, 1-codes) tables upload
-            # ONCE; each center's output lanes gather from them like the
-            # NEG table (reference huffman_encoder.cpp paths; inner-node
-            # ids live in the output table rows like word2vec syn1).
-            # The driver's already-built encoder is reused when passed —
-            # the tree build is O(V log V) at word2vec vocabularies.
+            # hierarchical softmax: each center's output lanes gather from
+            # the path tables like the NEG table (reference
+            # huffman_encoder.cpp paths; inner-node ids live in the output
+            # table rows like word2vec syn1). They upload ONCE, the
+            # encoder's arrays whole and no wider than what they say: a
+            # node id a lane (int32), a word's turns as bits of uint32
+            # words and its path length; labels (1 - code) and the lane
+            # mask are made from those in the program. The driver's
+            # already-built encoder is reused when passed.
             enc = huffman
             if enc is None:
                 from multiverso_tpu.models.wordembedding.huffman import (
                     HuffmanEncoder)
                 enc = HuffmanEncoder()
                 enc.BuildFromTermFrequency(counts)
-            V, MC = len(counts), max(enc.max_code_length, 1)
-            pts = np.zeros((V, MC), np.int32)
-            labs = np.zeros((V, MC), np.float32)
-            hmask = np.zeros((V, MC), np.float32)
-            for w in range(V):
-                info = enc.GetLabelInfo(w)
-                L = len(info.codes)
-                pts[w, :L] = info.points
-                labs[w, :L] = [1 - c for c in info.codes]
-                hmask[w, :L] = 1.0
-            self._hs_points, self._hs_labels, self._hs_mask = put(
-                (pts, labs, hmask))
+            MC, pts, codes = enc.max_code_length, enc.points, enc.codes
+            if not MC:      # one word has no path: a lane under a zero mask
+                MC = 1
+                pts, codes = (np.zeros((len(counts), 1), a.dtype)
+                              for a in (pts, codes))
+            turns = np.packbits(codes, axis=1, bitorder="little")
+            turns = np.pad(turns, ((0, 0), (0, -turns.shape[1] % 4))).view(
+                "<u4")
+            self._hs_lengths = enc.lengths      # the host's, for counters
+            self._hs_points, self._hs_bits, self._hs_len = put(
+                (pts, turns, enc.lengths))
             self._max_code = MC
+            tmetrics.gauge("we.hs.max_code").set(MC)
+            tmetrics.gauge("we.hs.table_bytes").set(
+                pts.nbytes + turns.nbytes + enc.lengths.nbytes)
             self._slots = None
         else:
             # negative-sampling SLOT table (reference util.h
@@ -357,11 +365,17 @@ class DevicePairsTrainer:
                 # output lanes = the center's Huffman path: inner-node
                 # rows + (1-code) labels, gathered from the uploaded
                 # tables exactly like the NEG slot gather
-                hs_points, hs_labels, hs_mask = aux
-                outputs = jnp.take(hs_points, centers, axis=0)
-                labels = jnp.take(hs_labels, centers, axis=0)
-                omask = (jnp.take(hs_mask, centers, axis=0)
-                         * pmask[:, None].astype(jnp.float32))
+                with jax.named_scope("we.device_pairs_step.path"):
+                    hs_points, hs_bits, hs_len = aux
+                    lane = np.arange(hs_points.shape[1])
+                    outputs = jnp.take(hs_points, centers, axis=0)
+                    turns = jnp.take(hs_bits, centers, axis=0)
+                    code = (turns[:, lane // 32]
+                            >> (lane % 32).astype(np.uint32)) & 1
+                    labels = 1.0 - code.astype(jnp.float32)
+                    on_path = lane[None, :] < jnp.take(hs_len,
+                                                       centers)[:, None]
+                    omask = (on_path & pmask[:, None]).astype(jnp.float32)
             else:
                 (slots,) = aux
                 draws = jax.random.randint(kneg, (P, K), 0, slots.shape[0])
@@ -508,6 +522,18 @@ class DevicePairsTrainer:
             for k, n in enumerate(np.bincount(token_ids // srv.block_rows,
                                               minlength=srv.num_servers)):
                 tmetrics.counter(f"we.block.tokens.shard{k}").inc(int(n))
+            # the block's output lanes that lie on a path, of those the
+            # program lays out (every token's, padded to the longest code)
+            if self.opt.hs:
+                tmetrics.counter("we.hs.path_lanes.valid").inc(
+                    int(np.take(self._hs_lengths, token_ids).sum()))
+                tmetrics.counter("we.hs.path_lanes.padded").inc(
+                    T * self._max_code)
+            if self.opt.cbow:
+                # a token is a centre where its sentence holds another
+                pair = token_sent[1:] == token_sent[:-1]
+                tmetrics.counter("we.cbow.centres").inc(int(
+                    (np.append(pair, False) | np.append(False, pair)).sum()))
         with ttrace.span("worker.we.dispatch", cat="worker"):
             P = n_total if self.opt.cbow \
                 else 2 * self.opt.window_size * n_total
@@ -516,7 +542,7 @@ class DevicePairsTrainer:
             self._block_counter += 1
             key = jax.random.fold_in(jax.random.PRNGKey(self.opt.seed),
                                      self._block_counter)
-            aux = ((self._hs_points, self._hs_labels, self._hs_mask)
+            aux = ((self._hs_points, self._hs_bits, self._hs_len)
                    if self.opt.hs else (self._slots,))
             states, stats = program(
                 self._take_states(), aux, ids_g, sent_g, key,

@@ -89,10 +89,11 @@ class DistributedWordEmbedding:
         counts = self.dictionary.counts()
         lap("dictionary")
         self.sampler = Sampler(counts, seed=opt.seed)
+        lap("sampler")
         if opt.hs:
             self.huffman = HuffmanEncoder()
             self.huffman.BuildFromTermFrequency(counts)
-        lap("sampler")
+            lap("huffman")
         self._world.init_if_needed()
         lap("world")
         # exception-safe: anything raising after MV_Init (table creation,

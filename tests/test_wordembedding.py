@@ -323,8 +323,19 @@ class TestEndToEnd:
         assert avg_loss < 0.69 * 4 * 0.9
 
     def test_hierarchical_softmax(self, tmp_path):
-        _, avg_loss = _run(tmp_path, hs=True, negative_num=0)
-        assert avg_loss > 0  # hs loss normalized differently; just trains
+        opt, avg_loss = _run(tmp_path, hs=True, negative_num=0)
+        # a pair's loss is summed over its centre's path: log 2 a node
+        # while the output rows are zero, so the ceiling to beat is the
+        # mean path length over the corpus's words (the tables against the
+        # plain reference: tests/test_we_cbow_hs.py)
+        d = Dictionary()
+        d.build_from_corpus(opt.train_file)
+        d.RemoveWordsLessThan(opt.min_count)
+        enc = HuffmanEncoder()
+        enc.BuildFromTermFrequency(d.counts())
+        counts = np.asarray(d.counts())
+        ceiling = np.log(2) * (counts * enc.lengths).sum() / counts.sum()
+        assert 0 < avg_loss < 0.95 * ceiling
 
     def test_adagrad(self, tmp_path):
         _, avg_loss = _run(tmp_path, use_adagrad=True,
