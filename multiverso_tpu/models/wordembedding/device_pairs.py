@@ -64,7 +64,8 @@ from multiverso_tpu.telemetry import trace as ttrace
 
 
 class _LazyStats:
-    """One element of a shared (2,) INT32 device stats array;
+    """One element of a shared (3,) INT32 device stats array (loss
+    bits, pair count, steps the loop ran);
     float()/int() fetch the WHOLE array once (cached on the array handle
     by jax), so a block's loss+pairs harvest costs one transfer. The
     array is integer-typed with the f32 loss BITCAST into lane 0: the
@@ -91,6 +92,10 @@ class _LazyStats:
 
     def __int__(self):
         return int(self._value())
+
+    def lane(self, i):
+        """Another lane of the same array: the same one fetch."""
+        return _LazyStats(self._arr, i)
 
 
 # Module-level program cache: keyed by every static the program closes
@@ -405,22 +410,39 @@ class DevicePairsTrainer:
             else:
                 state = TrainState(states[0], states[1], None, None)
 
-            def body(st, xs):
-                st, loss = step(st, *xs, lr)
-                return st, loss
+            # the loop runs the lane-batches that hold a pair, in their
+            # order, and not the nb its buckets lay out (t_pad and nb are
+            # each rounded up to a rung; a skip-gram segment ends in dead
+            # lanes). A batch without a pair is a step that rewrites the
+            # rows it gathered with what it gathered: zero gradients,
+            # zero loss. The trip count is read from the mask, the same
+            # on every shard (tokens and key are whole on each).
+            live = batched(pmask).any(axis=(1, 2))
+            n_live = jnp.sum(live, dtype=jnp.int32)
+            order = jnp.argsort(~live, stable=True)
 
-            state, losses = lax.scan(body, state, stacked)
+            def body(i, carry):
+                st, losses = carry
+                at = order[i]
+                st, loss = step(st, *(lax.dynamic_index_in_dim(
+                    a, at, keepdims=False) for a in stacked), lr)
+                return st, losses.at[at].set(loss)
+
+            # a dead batch's loss is the zero its step would return: the
+            # block's sum is over all nb, and keeps its bits
+            state, losses = lax.fori_loop(
+                0, n_live, body, (state, jnp.zeros((nb,), jnp.float32)))
             out = ((state.ie, state.eo, state.ie_g2, state.eo_g2)
                    if use_adagrad else (state.ie, state.eo))
-            # ONE (2,) INT32 stats array: the caller's lazy harvest pays
-            # a single host fetch per block instead of two.
+            # ONE (3,) INT32 stats array: the caller's lazy harvest pays
+            # a single host fetch per block instead of three.
             # The f32 loss rides as raw BITS in lane 0 (see _LazyStats —
             # an f32-typed array would flush the bitcast count lane as a
-            # denormal on TPU).
+            # denormal on TPU); lane 2 is the steps the loop ran.
             loss_bits = lax.bitcast_convert_type(
                 jnp.sum(losses).astype(jnp.float32), jnp.int32)
             stats = jnp.stack([loss_bits,
-                               jnp.sum(pmask).astype(jnp.int32)])
+                               jnp.sum(pmask).astype(jnp.int32), n_live])
             return out, stats
 
         if sharded:
@@ -539,6 +561,9 @@ class DevicePairsTrainer:
                 else 2 * self.opt.window_size * n_total
             nb = next_bucket(-(-P // self.opt.pair_batch_size), min_bucket=4)
             program = self._program(n_total, nb)
+            # the steps the block's buckets lay out; the harvest counts
+            # those the program's loop ran (we.block.steps.run)
+            tmetrics.counter("we.block.steps.laid_out").inc(nb)
             self._block_counter += 1
             key = jax.random.fold_in(jax.random.PRNGKey(self.opt.seed),
                                      self._block_counter)
@@ -548,6 +573,7 @@ class DevicePairsTrainer:
                 self._take_states(), aux, ids_g, sent_g, key,
                 jnp.float32(lr))
         self._put_states(states)
-        # stats is a (2,) int32 device array; one np.asarray in the
-        # harvest fetches both scalars (lane 0 is the bitcast f32 loss)
+        # stats is a (3,) int32 device array; one np.asarray in the
+        # harvest fetches its scalars (lane 0 is the bitcast f32 loss,
+        # lane 2, the steps run, is reached from the pair count's handle)
         return _LazyStats(stats, 0, bits=True), _LazyStats(stats, 1)

@@ -138,6 +138,7 @@ class DistributedWordEmbedding:
         self._blocks_done = 0
         m_pop_wait = tmetrics.histogram("we.pop_wait_s")
         m_blocks = tmetrics.counter("we.blocks")
+        m_steps_run = tmetrics.counter("we.block.steps.run")
 
         def harvest(force: bool = False) -> None:
             while pending and (force or len(pending) >= 2):
@@ -151,6 +152,11 @@ class DistributedWordEmbedding:
                     # device scalar (the program derives the pairs);
                     # int() fetches it
                     self.total_pairs += int(pairs)
+                    if hasattr(pairs, "lane"):
+                        # a -device_pairs block: the steps its program's
+                        # loop ran, of the nb laid out (third lane of the
+                        # copy just made)
+                        m_steps_run.inc(int(pairs.lane(2)))
 
         from multiverso_tpu.parallel import multihost
         from multiverso_tpu.utils.log import CHECK
