@@ -12,6 +12,7 @@ workers' progress merges additively on the default (+=) server updater.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -20,9 +21,30 @@ import numpy as np
 
 import multiverso_tpu as mv
 from multiverso_tpu.models.wordembedding.model import TrainState, init_embedding
+from multiverso_tpu.parallel.mesh import next_bucket
 from multiverso_tpu.tables import KVTableOption, MatrixTableOption
+from multiverso_tpu.tables.matrix_table import _pad_row_batch
 
 WORD_COUNT_KEY = 0
+
+
+def training_rows(fetched_rows: int) -> int:
+    """Rows of the training copy of a block's ``fetched_rows`` rows: the
+    ``next_bucket`` rung with at least ONE row to spare. The spare rows
+    are zero and the last is the trash row the touched-rows AdaGrad step
+    sends its pad lanes to (``device_pairs._make_sparse_adagrad_step``: a
+    block's last fetched row is a live word). A rung, not a count, is
+    also what the block's scan program is compiled for: blocks whose row
+    counts differ inside a rung share one program."""
+    return next_bucket(fetched_rows + 1)
+
+
+@functools.partial(jax.jit, donate_argnums=(1,))
+def _trained_delta(trained: jax.Array, fetched: jax.Array) -> jax.Array:
+    """trained - fetched over the fetched rows, written where the fetched
+    rows were: the spare rows of the training copy are cut in the same
+    program and the delta is no row set more in HBM."""
+    return trained[: fetched.shape[0]] - fetched
 
 
 class Communicator:
@@ -48,6 +70,15 @@ class Communicator:
         self.word_count_table = mv.MV_CreateTable(KVTableOption(dtype=np.int64))
 
     # -- parameter movement -------------------------------------------------
+
+    def _row_specs(self, input_rows, output_rows):
+        """(state field, table, the block's row ids) of every table."""
+        specs = [("ie", self.input_table, input_rows),
+                 ("eo", self.output_table, output_rows)]
+        if self.opt.use_adagrad:
+            specs += [("ie_g2", self.ie_g2_table, input_rows),
+                      ("eo_g2", self.eo_g2_table, output_rows)]
+        return specs
 
     def request_parameter(self, input_rows: np.ndarray,
                           output_rows: np.ndarray) -> Tuple[TrainState, dict]:
@@ -88,12 +119,17 @@ class Communicator:
     def wait_parameter(self, handles: dict) -> Tuple[TrainState, dict]:
         # unbounded-ok: MultiCall.Wait honors -mv_deadline_s internally
         fetched = dict(zip(handles["names"], handles["call"].Wait()))
+
+        def train(rows: np.ndarray) -> jax.Array:
+            # the same training copy as the device plane's: one state
+            # shape a rung, whichever plane fetched it
+            spare = training_rows(len(rows)) - len(rows)
+            return jnp.asarray(np.pad(rows, ((0, spare), (0, 0))))
+
         state = TrainState(
-            ie=jnp.asarray(fetched["ie"]), eo=jnp.asarray(fetched["eo"]),
-            ie_g2=(jnp.asarray(fetched["ie_g2"])
-                   if self.opt.use_adagrad else None),
-            eo_g2=(jnp.asarray(fetched["eo_g2"])
-                   if self.opt.use_adagrad else None))
+            ie=train(fetched["ie"]), eo=train(fetched["eo"]),
+            ie_g2=train(fetched["ie_g2"]) if self.opt.use_adagrad else None,
+            eo_g2=train(fetched["eo_g2"]) if self.opt.use_adagrad else None)
         return state, fetched
 
     def add_delta_parameter(self, state: TrainState, fetched: dict,
@@ -101,27 +137,11 @@ class Communicator:
                             output_rows: np.ndarray) -> None:
         """Push trained - fetched (reference AddDeltaParameter,
         communicator.cpp:157-206)."""
-        self.input_table.AddFireForget(
-            np.asarray(state.ie) - fetched["ie"], row_ids=input_rows)
-        self.output_table.AddFireForget(
-            np.asarray(state.eo) - fetched["eo"], row_ids=output_rows)
-        if self.opt.use_adagrad:
-            self.ie_g2_table.AddFireForget(
-                np.asarray(state.ie_g2) - fetched["ie_g2"],
-                row_ids=input_rows)
-            self.eo_g2_table.AddFireForget(
-                np.asarray(state.eo_g2) - fetched["eo_g2"],
-                row_ids=output_rows)
+        for name, table, ids in self._row_specs(input_rows, output_rows):
+            trained = np.asarray(getattr(state, name))[: len(ids)]
+            table.AddFireForget(trained - fetched[name], row_ids=ids)
 
     # -- device plane (rows never leave HBM) --------------------------------
-
-    def _row_specs(self, input_rows, output_rows):
-        specs = [("ie", self.input_table, input_rows),
-                 ("eo", self.output_table, output_rows)]
-        if self.opt.use_adagrad:
-            specs += [("ie_g2", self.ie_g2_table, input_rows),
-                      ("eo_g2", self.eo_g2_table, output_rows)]
-        return specs
 
     def request_parameter_device(self, input_rows: np.ndarray,
                                  output_rows: np.ndarray
@@ -145,8 +165,12 @@ class Communicator:
             jax.block_until_ready(srv.state)
             rows[name] = srv.device_fetch_rows(ids)
             # the train step DONATES its state; the original must survive
-            # for the delta push, so the state gets its own buffer
-            train[name] = jnp.copy(rows[name])
+            # for the delta push, so the state gets its own buffer: a
+            # rung long, zeros below the rows, the last the touched-rows
+            # step's trash row (the apply's own pad program: where both
+            # rungs are one, as nearly always, one compiled program)
+            train[name] = _pad_row_batch(rows[name],
+                                         training_rows(len(ids)))
         state = TrainState(ie=train["ie"], eo=train["eo"],
                            ie_g2=train.get("ie_g2"),
                            eo_g2=train.get("eo_g2"))
@@ -156,10 +180,11 @@ class Communicator:
                                    input_rows: np.ndarray,
                                    output_rows: np.ndarray) -> None:
         """Push trained - fetched without leaving the device: the delta is
-        computed in HBM and scattered into the store by the same jit'd row
+        computed in HBM, in the buffers of ``fetched`` (which this call
+        consumes), and scattered into the store by the same jit'd row
         program the engine uses."""
         for name, table, ids in self._row_specs(input_rows, output_rows):
-            delta = getattr(state, name) - fetched[name]
+            delta = _trained_delta(getattr(state, name), fetched[name])
             table.server().device_apply_rows(ids, delta)
 
     # -- word count (lr decay coordination) ---------------------------------

@@ -47,10 +47,10 @@ class DistributedWordEmbedding:
         self.total_pairs = 0
         self._blocks_done = 0   # index of the next block, per train()
         # what depends on prepare()'s products alone is made once per
-        # trainer, not per train() call: the jit'd pair-batch step and
-        # (use_adagrad, the block program scanning it)
+        # trainer, not per train() call: the jit'd pair-batch step and the
+        # block programs scanning a step, by (use_adagrad, touched rows)
         self._step = None
-        self._block_scan_cache = None
+        self._block_scan_cache = {}
 
     # -- setup --------------------------------------------------------------
 
@@ -138,6 +138,9 @@ class DistributedWordEmbedding:
         self._blocks_done = 0
         m_pop_wait = tmetrics.histogram("we.pop_wait_s")
         m_blocks = tmetrics.counter("we.blocks")
+        # registered at 0 beside it: the blocks whose scan took the
+        # touched-rows step (_block_scan_fn)
+        tmetrics.counter("we.block_scan.touched_rows_blocks")
         m_steps_run = tmetrics.counter("we.block.steps.run")
 
         def harvest(force: bool = False) -> None:
@@ -254,20 +257,45 @@ class DistributedWordEmbedding:
         return decayed_lr(opt.init_learning_rate, self.comm.get_word_count(),
                           opt.total_words, opt.epoch)
 
-    def _block_scan_fn(self, step):
-        """One jit'd program scanning the train step over a whole block's
-        stacked batches: the device-plane path pays ONE upload + ONE
-        dispatch per block instead of one per batch. Retraces per distinct
-        batch-count, which block sizing keeps to a handful. Kept for the
-        trainer's life under what it depends on, ``use_adagrad`` (which
-        fixes ``step``), so a later train() dispatches the program the
-        first one traced."""
+    def _block_scan_fn(self, state, step):
+        """-> (program, touched): one jit'd program scanning a train step
+        over a whole block's stacked batches, so a block pays ONE upload +
+        ONE dispatch instead of one per batch, and whether that step is
+        the touched-rows one.
+
+        The step is chosen from the ``state`` the program is handed. Under
+        AdaGrad ``step`` (model.make_train_step) streams every row of the
+        state a batch: fast while the state is small. Once the larger of
+        ``state.ie`` / ``state.eo`` passes ``device_pairs._SPARSE_BYTES``
+        (the one constant of this trade: -device_pairs chooses by it too)
+        the scan takes ``device_pairs._make_sparse_adagrad_step``, the same
+        arithmetic on the rows a batch names; its pad lanes go to the
+        state's LAST row, the trash row of the communicator's training
+        copy. That step's row write is a Mosaic kernel, which the compiler
+        cannot partition: a state laid over more than one device keeps
+        ``step``, as plain SGD does at any size (its update is the
+        scatter-add already).
+
+        A program retraces per distinct batch count and state rung, which
+        block sizing and ``communicator.training_rows`` keep to a handful.
+        Kept for the trainer's life under what it depends on,
+        ``use_adagrad`` (which fixes ``step``) and the choice, so a later
+        train() dispatches the programs the first one traced."""
+        from multiverso_tpu.models.wordembedding import device_pairs
         use_adagrad = bool(self.opt.use_adagrad)
-        if self._block_scan_cache is None \
-                or self._block_scan_cache[0] != use_adagrad:
+        touched = (use_adagrad
+                   and max(rows.size * rows.dtype.itemsize
+                           for rows in (state.ie, state.eo))
+                   > device_pairs._SPARSE_BYTES
+                   and all(len(rows.sharding.device_set) == 1
+                           for rows in state if rows is not None))
+        if (use_adagrad, touched) not in self._block_scan_cache:
             import jax
             import jax.numpy as jnp
             from jax import lax
+
+            if touched:
+                step = device_pairs._make_sparse_adagrad_step()
 
             def run(state, inputs, imask, outputs, labels, omask, lr):
                 def body(st, x):
@@ -280,13 +308,13 @@ class DistributedWordEmbedding:
                     return st, jnp.sum(losses)
 
             # donate the block state: the fetch path hands this jit its own
-            # buffers (jnp.copy in request_parameter_device keeps the
-            # originals alive for the delta push), so the scan may update
-            # the row matrices in place
-            self._block_scan_cache = (use_adagrad,
-                                      jax.jit(run, donate_argnums=(0,)))
+            # buffers (the communicator's training copy keeps the originals
+            # alive for the delta push), so the scan may update the row
+            # matrices in place
+            self._block_scan_cache[use_adagrad, touched] = jax.jit(
+                run, donate_argnums=(0,))
             tmetrics.counter("we.block_program.builds").inc()
-        return self._block_scan_cache[1]
+        return self._block_scan_cache[use_adagrad, touched], touched
 
     def _train_block(self, block: DataBlock, step) -> tuple:
         """One block through the scanned program. Returns (loss, pairs)
@@ -334,8 +362,11 @@ class DistributedWordEmbedding:
                     state, fetched = self.comm.request_parameter(
                         block.input_rows, block.output_rows)
         with ttrace.span("worker.we.dispatch", cat="worker"):
-            state, loss_dev = self._block_scan_fn(step)(
+            program, touched = self._block_scan_fn(state, step)
+            state, loss_dev = program(
                 state, *tensors, jnp.float32(self._current_lr()))
+            if touched:
+                tmetrics.counter("we.block_scan.touched_rows_blocks").inc()
         with ttrace.span("worker.we.push", cat="worker"):
             if self.opt.device_plane:
                 self.comm.add_delta_parameter_device(

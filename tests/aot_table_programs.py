@@ -28,6 +28,13 @@ program over four shards at ``we_pairs_4c``'s shapes (``PAIRS``) and
 prints its collectives, its Mosaic kernels, how many instructions hold a
 table's whole rows and its table-sized passes.
 
+``--scan`` compiles the WordEmbedding app's block-round scan program
+(``models/wordembedding/distributed.py`` ``_block_scan_fn``) on one chip
+at ``we_rows``' shapes (``SCAN``): a state over ``_SPARSE_BYTES``, so the
+touched-rows AdaGrad step, and prints its Mosaic kernels, how many of the
+state's four matrices are aliased input to output and its passes over a
+whole state matrix outside the in-place writes.
+
 Prints ``SKIP ...`` and exits 0 where the topology description is missing.
 """
 
@@ -358,6 +365,48 @@ def pairs(specs):
             print("  PASS", ln, flush=True)
 
 
+# (name, fetched input rows, fetched output rows, batches a block, pairs a
+# batch, negatives): we_rows fetches every output row of 1,048,500 and some
+# 330,000 input rows a block
+SCAN = [("we_rows", 330_000, 1_048_500, 96, 8_192, 5)]
+
+
+def scan(specs):
+    """SCAN <cell> block_scan touched=<bool> kernels=<n> aliased=<n>/4
+    passes=<n>: the block round's one program a block, compiled for the
+    chip over the communicator's rung-long training copies."""
+    from multiverso_tpu.models.wordembedding.communicator import training_rows
+    from multiverso_tpu.models.wordembedding.distributed import (
+        DistributedWordEmbedding)
+    from multiverso_tpu.models.wordembedding.model import (TrainState,
+                                                           make_train_step)
+    from multiverso_tpu.models.wordembedding.option import Option
+    one = SingleDeviceSharding(_devices(1)[0])
+    s = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one)
+    for name, rows_in, rows_out, nb, batch, negatives in specs:
+        we = DistributedWordEmbedding(Option(use_adagrad=True))
+        ie = s((training_rows(rows_in), 128), jnp.float32)
+        eo = s((training_rows(rows_out), 128), jnp.float32)
+        state = TrainState(ie, eo, ie, eo)
+        program, touched = we._block_scan_fn(state, make_train_step(True))
+        lanes = 1 + negatives
+        hlo = program.lower(
+            state, s((nb, batch, 1), jnp.int32), s((nb, batch, 1), jnp.float32),
+            s((nb, batch, lanes), jnp.int32),
+            s((nb, batch, lanes), jnp.float32),
+            s((nb, batch, lanes), jnp.float32),
+            s((), jnp.float32)).compile().as_text()
+        aliased = len(re.findall(r"\{\d+\}: \(\d+, \{\}",
+                                 hlo.split("\n", 1)[0]))
+        kernels = len(re.findall(r"custom_call_target=.tpu_custom_call", hlo))
+        passes = table_sized_passes(hlo, ie.shape[0] * 128)
+        print(f"SCAN {name} block_scan touched={touched} kernels={kernels} "
+              f"aliased={aliased}/4 passes={len(passes)}", flush=True)
+        for ln in passes:
+            print("  PASS", ln, flush=True)
+
+
 if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--dump")
@@ -366,6 +415,7 @@ if __name__ == "__main__":
     ap.add_argument("--tiny", action="store_true")
     ap.add_argument("--read", action="store_true")
     ap.add_argument("--pairs", action="store_true")
+    ap.add_argument("--scan", action="store_true")
     a = ap.parse_args()
     try:
         _devices(1)
@@ -382,3 +432,5 @@ if __name__ == "__main__":
         read(READ)
     if a.pairs:
         pairs(PAIRS)
+    if a.scan:
+        scan(SCAN)

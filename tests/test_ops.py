@@ -168,7 +168,7 @@ class TestStatefulRowProgramsAliasOnTpu:
         env = dict(os.environ, JAX_PLATFORMS="cpu")
         res = subprocess.run(
             [sys.executable, os.path.join(here, "aot_table_programs.py"),
-             "--alias", "--tiny", "--read", "--pairs"], env=env,
+             "--alias", "--tiny", "--read", "--pairs", "--scan"], env=env,
             capture_output=True,
             text=True,
             timeout=900)
@@ -178,7 +178,8 @@ class TestStatefulRowProgramsAliasOnTpu:
             pytest.skip(lines[-1])
         found = {}
         for ln in lines:
-            m = re.match(r"(?:ALIAS|TINY|READ|PAIRS) (\S+) (\S+) (.*)", ln)
+            m = re.match(r"(?:ALIAS|TINY|READ|PAIRS|SCAN) (\S+) (\S+) (.*)",
+                         ln)
             if m:
                 found[m.group(1), m.group(2)] = m.group(3)
         return found, res.stdout
@@ -206,6 +207,18 @@ class TestStatefulRowProgramsAliasOnTpu:
         assert int(got.pop("all_reduce")) >= 1, out[-3000:]
         assert got == {"all_gather": "0", "kernels": "4",
                        "whole_table": "0", "passes": "0"}, out[-3000:]
+
+    def test_block_round_scan_updates_touched_rows_in_place(self, compiled):
+        """``we_rows`` (PERF.md section 6, PR 41): the block round's scan
+        program over a state of 1,048,576 x 128 output rows takes the
+        touched-rows AdaGrad step, compiles for a v5e with its four row
+        writes on the kernel, updates all four state matrices in place and
+        passes over none of them (the dense step streamed every fetched
+        row through two gradient matrices a batch)."""
+        found, out = compiled
+        assert ("we_rows", "block_scan") in found, out[-2000:]
+        assert found["we_rows", "block_scan"] == (
+            "touched=True kernels=4 aliased=4/4 passes=0"), out[-3000:]
 
     @pytest.mark.parametrize("rows", [1, 2, 4, 5])
     @pytest.mark.parametrize("program,kernels", [

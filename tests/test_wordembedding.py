@@ -541,6 +541,235 @@ class TestEndToEnd:
             opt.negative_num == 10 and opt.use_adagrad and opt.epoch == 3
 
 
+def _wide_vocab(path, words=64):
+    """A vocabulary file of ``words`` words whose first 20 are
+    _make_corpus's: the others are in the tables and in no sentence."""
+    with open(path, "w") as f:
+        for i in range(words):
+            f.write(f"w{i} {1000 - i}\n")
+
+
+class TestBlockScanStep:
+    """The block round's scanned AdaGrad step is chosen from the state it
+    is given: model.make_train_step over every fetched row, or, once the
+    state passes device_pairs._SPARSE_BYTES and is whole on one device,
+    -device_pairs' touched-rows step over the rows a batch names, its pad
+    lanes in the trash row of the communicator's rung-long training copy."""
+
+    TABLES = ("ie", "eo", "ie_g2", "eo_g2")
+
+    def _world(self, monkeypatch, threshold, one_device=True):
+        import jax
+        import multiverso_tpu as mv
+        from multiverso_tpu.models.wordembedding import device_pairs
+        monkeypatch.setattr(device_pairs, "_SPARSE_BYTES", threshold)
+        mv.MV_Init([], devices=jax.devices()[:1] if one_device else None)
+        return mv
+
+    def _trainer(self, tmp_path, **kw):
+        from multiverso_tpu.models.wordembedding.distributed import (
+            DistributedWordEmbedding)
+        corpus, vocab = tmp_path / "corpus.txt", tmp_path / "vocab.txt"
+        _make_corpus(str(corpus), n_sentences=120)
+        _wide_vocab(str(vocab))
+        opt = Option(train_file=str(corpus), read_vocab_file=str(vocab),
+                     output_file=str(tmp_path / "vec.txt"),
+                     embedding_size=16, window_size=2, negative_num=3,
+                     min_count=1, epoch=1, data_block_size=4000,
+                     pair_batch_size=256, use_adagrad=True,
+                     init_learning_rate=0.1, is_pipeline=False)
+        for k, v in kw.items():
+            setattr(opt, k, v)
+        we = DistributedWordEmbedding(opt)
+        we.prepare()
+        return we
+
+    def _tables(self, we):
+        comm = we.comm
+        every = np.arange(comm.vocab_size, dtype=np.int32)
+        tables = (comm.input_table, comm.output_table, comm.ie_g2_table,
+                  comm.eo_g2_table)
+        return {name: np.array(t.GetRows(every))
+                for name, t in zip(self.TABLES, tables)}
+
+    @pytest.mark.parametrize("mode", ["skipgram_neg", "cbow"])
+    @pytest.mark.parametrize("device_plane", [0, 1])
+    def test_touched_rows_step_matches_dense(self, tmp_path, monkeypatch,
+                                             device_plane, mode):
+        """Same seed and corpus, two thresholds: the pulled embeddings and
+        both accumulator tables agree (the bound of
+        test_device_pairs_sparse_adagrad_matches_dense)."""
+        got = {}
+        for kind, threshold in (("dense", 1 << 60), ("touched", 0)):
+            mv = self._world(monkeypatch, threshold)
+            try:
+                (tmp_path / kind).mkdir()
+                we = self._trainer(tmp_path / kind, epoch=2,
+                                   device_plane=bool(device_plane),
+                                   cbow=mode == "cbow")
+                blocks = _counter("we.blocks")
+                touched = _counter("we.block_scan.touched_rows_blocks")
+                we.train()
+                blocks = _counter("we.blocks") - blocks
+                assert blocks >= 4
+                assert (_counter("we.block_scan.touched_rows_blocks")
+                        - touched) == (blocks if kind == "touched" else 0)
+                got[kind] = dict(self._tables(we),
+                                 pulled=we.comm.pull_embeddings())
+            finally:
+                mv.MV_ShutDown()
+        assert np.array_equal(got["dense"]["pulled"], got["dense"]["ie"])
+        for name in ("pulled", "ie_g2", "eo_g2", "eo"):
+            assert got["dense"][name].any()
+            np.testing.assert_allclose(got["touched"][name],
+                                       got["dense"][name],
+                                       rtol=2e-5, atol=2e-6, err_msg=name)
+
+    def test_spare_rows_of_the_training_copy(self, tmp_path, monkeypatch):
+        """One block at threshold 0. The state is a rung long and its last
+        row takes the step's pad lanes, so the last FETCHED row, a live
+        word, trains as in the dense run; a row no lane names keeps its
+        first value and a zero accumulator; what is pushed back is as long
+        as the ids."""
+        from multiverso_tpu.models.wordembedding.communicator import (
+            training_rows)
+        from multiverso_tpu.models.wordembedding.model import init_embedding
+        from multiverso_tpu.tables.matrix_table import MatrixServerTable
+        got, named, pushed, states = {}, {}, [], []
+        apply = MatrixServerTable.device_apply_rows
+
+        def applying(self, ids, delta, *a, **kw):
+            pushed.append((len(ids), delta.shape[0]))
+            return apply(self, ids, delta, *a, **kw)
+        monkeypatch.setattr(MatrixServerTable, "device_apply_rows", applying)
+        for kind, threshold in (("dense", 1 << 60), ("touched", 0)):
+            mv = self._world(monkeypatch, threshold)
+            try:
+                (tmp_path / kind).mkdir()
+                we = self._trainer(tmp_path / kind, device_plane=True,
+                                   data_block_size=1 << 20)
+                inner, fetch = we._train_block, \
+                    we.comm.request_parameter_device
+
+                def keeping(block, step):
+                    st = block.stacked
+                    named["ie"] = block.input_rows[np.unique(st["inputs"])]
+                    named["eo"] = block.output_rows[np.unique(st["outputs"])]
+                    named["last"] = (block.input_rows[-1],
+                                     block.output_rows[-1])
+                    return inner(block, step)
+
+                def fetching(input_rows, output_rows):
+                    state, rows = fetch(input_rows, output_rows)
+                    states.append(({k: getattr(state, k).shape[0]
+                                    for k in self.TABLES},
+                                   {k: v.shape[0] for k, v in rows.items()}))
+                    return state, rows
+                we._train_block = keeping
+                we.comm.request_parameter_device = fetching
+                blocks = _counter("we.blocks")
+                we.train()
+                assert _counter("we.blocks") == blocks + 1
+                got[kind] = self._tables(we)
+            finally:
+                mv.MV_ShutDown()
+        # a rung with a row to spare, whichever step reads it; the push is
+        # as long as the ids
+        for trained, fetched in states:
+            assert trained == {k: training_rows(n)
+                               for k, n in fetched.items()}
+            assert all(trained[k] > fetched[k] for k in fetched)
+        assert len(pushed) == 8 and all(n == rows for n, rows in pushed)
+        vocab = got["touched"]["ie"].shape[0]
+        first = {"ie": init_embedding(vocab, 16, 1),
+                 "eo": np.zeros((vocab, 16), np.float32)}
+        for side in ("ie", "eo"):
+            rest = np.setdiff1d(np.arange(vocab), named[side])
+            if side == "ie":
+                assert len(rest) >= vocab - 20      # no sentence holds them
+            for kind in got:
+                assert np.array_equal(got[kind][side][rest],
+                                      first[side][rest])
+                assert not got[kind][side + "_g2"][rest].any()
+        # the last fetched rows are named (the input set is the sorted set
+        # of the words named; the output set, over half the vocabulary, is
+        # every row): without a trash row the pad lanes would land there
+        last_in, last_out = named["last"]
+        assert last_in in named["ie"] and last_in < vocab - 1
+        for side, row in (("ie", last_in), ("eo", last_out)):
+            for name in (side, side + "_g2"):
+                np.testing.assert_allclose(got["touched"][name][row],
+                                           got["dense"][name][row],
+                                           rtol=2e-5, atol=2e-6)
+        assert got["dense"]["ie_g2"][last_in].any()
+        for name in self.TABLES:
+            np.testing.assert_allclose(got["touched"][name],
+                                       got["dense"][name],
+                                       rtol=2e-5, atol=2e-6, err_msg=name)
+
+    @pytest.mark.parametrize("case, threshold, kw, one_device, touched", [
+        ("default_constant_tiny_vocabulary", None, {}, True, False),
+        ("threshold_0", 0, {}, True, True),
+        ("threshold_0_host_plane", 0, {"device_plane": False}, False, True),
+        ("threshold_0_plain_sgd", 0, {"use_adagrad": False}, True, False),
+        ("threshold_0_state_over_8_devices", 0, {}, False, False),
+    ])
+    def test_the_choice(self, tmp_path, monkeypatch, case, threshold, kw,
+                        one_device, touched):
+        from multiverso_tpu.models.wordembedding import device_pairs
+        if threshold is None:
+            threshold = device_pairs._SPARSE_BYTES
+            assert threshold == 64 << 20
+        mv = self._world(monkeypatch, threshold, one_device)
+        try:
+            we = self._trainer(tmp_path, **dict({"device_plane": True}, **kw))
+            before = (_counter("we.blocks"),
+                      _counter("we.block_scan.touched_rows_blocks"))
+            assert np.isfinite(we.train())
+            blocks = _counter("we.blocks") - before[0]
+            assert blocks >= 2
+            assert (_counter("we.block_scan.touched_rows_blocks")
+                    - before[1]) == (blocks if touched else 0)
+            assert list(we._block_scan_cache) == [
+                (we.opt.use_adagrad, touched)]
+        finally:
+            mv.MV_ShutDown()
+
+    def test_row_counts_inside_a_rung_share_one_scan_program(
+            self, tmp_path, monkeypatch):
+        """The scan program is compiled for the training copy's rung, not
+        for the block's row count: the guard on the cell's set-up time (a
+        program with four Mosaic calls and two sorts a distinct count)."""
+        from multiverso_tpu.models.wordembedding.communicator import (
+            training_rows)
+        from multiverso_tpu.models.wordembedding.data import PairGenerator
+        mv = self._world(monkeypatch, 0)
+        try:
+            we = self._trainer(tmp_path, device_plane=True)
+            generator = PairGenerator(we.opt, we.dictionary, we.sampler,
+                                      we.huffman)
+            rng = np.random.default_rng(5)
+            blocks = [generator.make_block(
+                [rng.permutation(words)[:12].astype(np.int32)
+                 for _ in range(8)], 96) for words in (17, 23)]
+            counts = [len(b.input_rows) for b in blocks]
+            assert counts[0] != counts[1] and max(counts) < 32
+            assert len({training_rows(n) for n in counts}) == 1
+            assert len({b.stacked["inputs"].shape for b in blocks}) == 1
+            builds = _counter("we.block_program.builds")
+            touched = _counter("we.block_scan.touched_rows_blocks")
+            for block in blocks:
+                loss, pairs = we._train_block(block, we._step)
+                assert np.isfinite(float(loss)) and pairs == block.pair_count
+            assert _counter("we.block_program.builds") == builds + 1
+            assert (_counter("we.block_scan.touched_rows_blocks")
+                    == touched + 2)
+            (program,) = we._block_scan_cache.values()
+            assert program._cache_size() == 1
+        finally:
+            mv.MV_ShutDown()
+
+
 class TestDevicePairsStats:
     def test_stats_lanes_exact_and_flush_proof(self):
         """The block stats ride ONE int32 array: loss as bitcast f32 bits
