@@ -10,10 +10,8 @@ Four laws, each consuming :mod:`threads`' domain closures:
   scopes match by NAME (``with self._lock:``), so two same-named locks
   on different objects can mask a true race (false-negative direction;
   the honesty limits are documented in DESIGN.md §18).
-* ``device-work-domain`` — jax/jnp calls, the jit'd row-op kernels and
-  the mirror-syncing table ``state`` property must be unreachable from
-  sampling/handler/fan-out threads: PR 10's probe-never-syncs-mirror
-  regression test generalized to the whole package.
+* ``device-work-domain`` — jax/jnp calls and the jit'd row-op kernels
+  must be unreachable from sampling/handler/fan-out threads.
 * ``lock-order`` — per-function ``with``-nesting composed through the
   call graph into a lock acquisition-order graph; a cycle is a
   potential deadlock, and re-acquiring a non-reentrant ``Lock`` under
@@ -440,7 +438,7 @@ class CrossDomainStateChecker(Checker):
 @register
 class DeviceWorkDomainChecker(Checker):
     """No static path from a sampling/handler/fan-out domain to
-    jax/device work — the probe-never-syncs-mirror law generalized."""
+    jax/device work."""
 
     name = "device-work-domain"
     description = ("jax/device-work sinks must be unreachable from "
@@ -455,8 +453,6 @@ class DeviceWorkDomainChecker(Checker):
     DEVICE_ZONES: List[Tuple[str, str, str]] = [
         (r"^ops/rows\.py$", r".*", "jit'd row-op kernels"),
         (r"^ops/pallas_rows\.py$", r".*", "pallas kernels"),
-        (r"^tables/matrix_table\.py$", r"^MatrixServerTable\.state$",
-         "mirror-syncing state property getter"),
     ]
 
     def check(self, pkg: PackageIndex) -> List[Finding]:

@@ -6,7 +6,7 @@ shard actors, the pipelined exchange stage, a parallel apply pool, the
 replica fan-out thread, the watchdog/reporter samplers, ops HTTP
 handlers, the serving dispatcher, elastic coordinator RPC threads and a
 jax-free reader process all share state. Every cross-thread law the
-repo enforces (probe-never-syncs-mirror, handler-never-RPC, bounded
+repo enforces (sampler-never-launches, handler-never-RPC, bounded
 blocking) needs ONE ground truth for "which code runs on which
 thread" — this module is that inventory, and the checkers in
 :mod:`concurrency` consume it.

@@ -32,7 +32,7 @@ _PKG_LIB_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
 _REPO_LIB_PATH = os.path.join(_NATIVE_DIR, "libmultiverso_tpu.so")
 
 _WITHOUT = ("running without it: python libsvm parser and tokenizer, no "
-            "native host store, kv index or crc32c")
+            "native kv index or crc32c")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -106,16 +106,7 @@ def _configure_signatures(h: ctypes.CDLL) -> None:
         np.ctypeslib.ndpointer(np.int32), i64]
     h.MV_TokenizeLinesToIds.restype = i64
     h.MV_TokenizeLinesToIds.argtypes = h.MV_TokenizeToIds.argtypes
-    f32p = np.ctypeslib.ndpointer(np.float32, flags="C_CONTIGUOUS")
     i32p = np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS")
-    h.MV_HostStoreNew.restype = ctypes.c_void_p
-    h.MV_HostStoreNew.argtypes = [i64, i64, ctypes.c_float]
-    h.MV_HostStoreFree.argtypes = [ctypes.c_void_p]
-    h.MV_HostStoreLoad.argtypes = [ctypes.c_void_p, f32p]
-    h.MV_HostStoreGetAll.argtypes = [ctypes.c_void_p, f32p]
-    h.MV_HostStoreAddAll.argtypes = [ctypes.c_void_p, f32p]
-    h.MV_HostStoreAddRows.argtypes = [ctypes.c_void_p, i32p, i64, f32p]
-    h.MV_HostStoreGetRows.argtypes = [ctypes.c_void_p, i32p, i64, f32p]
     h.MV_HostStorePoolStats.argtypes = [
         np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")]
     # the versioned seal's hardware CRC32C (crc32c.cc)
@@ -229,79 +220,6 @@ class VocabTokenizer:
                                           self._n, self._table, self._cap,
                                           out, len(out))
         return out[:n]
-
-
-class NativeHostStore:
-    """Threaded f32 LOGICAL row store (native/src/host_store.cc): the
-    CPU-backend matrix host plane's apply/gather substrate for linear
-    aux-free updaters (data += sign*delta). Single-writer (the engine
-    thread); the parallelism is inside one call — the reference's
-    OpenMP-parallel server loop (updater.cpp:21-29), GIL-free via
-    ctypes."""
-
-    def __init__(self, handle: ctypes.CDLL, rows: int, cols: int,
-                 sign: float):
-        self._h = handle
-        self.rows, self.cols = rows, cols
-        self._ptr = handle.MV_HostStoreNew(rows, cols, ctypes.c_float(sign))
-        if not self._ptr:
-            raise MemoryError("MV_HostStoreNew failed")
-
-    @classmethod
-    def create(cls, rows: int, cols: int,
-               sign: float) -> Optional["NativeHostStore"]:
-        handle = lib()
-        if handle is None:
-            return None
-        return cls(handle, rows, cols, sign)
-
-    def __del__(self):
-        ptr, self._ptr = getattr(self, "_ptr", None), None
-        if ptr:
-            self._h.MV_HostStoreFree(ptr)
-
-    def _check_full(self, arr: np.ndarray) -> np.ndarray:
-        # the C++ side memcpys/applies rows*cols floats blindly — an
-        # undersized buffer would be an out-of-bounds heap read
-        arr = np.ascontiguousarray(arr, np.float32)
-        if arr.size != self.rows * self.cols:
-            raise ValueError(f"expected {self.rows}x{self.cols} floats, "
-                             f"got shape {arr.shape}")
-        return arr
-
-    def load(self, full: np.ndarray) -> None:
-        self._h.MV_HostStoreLoad(self._ptr, self._check_full(full))
-
-    def get_all(self) -> np.ndarray:
-        out = np.empty((self.rows, self.cols), np.float32)
-        self._h.MV_HostStoreGetAll(self._ptr, out)
-        return out
-
-    def add_all(self, delta: np.ndarray) -> None:
-        self._h.MV_HostStoreAddAll(self._ptr, self._check_full(delta))
-
-    def _check_ids(self, ids: np.ndarray) -> np.ndarray:
-        # the C++ side indexes data + id*cols blindly — an out-of-range
-        # id would be silent heap corruption, not an exception
-        ids = np.ascontiguousarray(ids, np.int32)
-        if len(ids) and (int(ids.min()) < 0 or int(ids.max()) >= self.rows):
-            raise ValueError(f"row id out of range [0, {self.rows})")
-        return ids
-
-    def add_rows(self, ids: np.ndarray, deltas: np.ndarray) -> None:
-        """ids must be UNIQUE and in-range (caller pre-combines)."""
-        ids = self._check_ids(ids)
-        deltas = np.ascontiguousarray(deltas, np.float32)
-        if deltas.size != len(ids) * self.cols:
-            raise ValueError(f"expected {len(ids)}x{self.cols} delta "
-                             f"floats, got shape {deltas.shape}")
-        self._h.MV_HostStoreAddRows(self._ptr, ids, len(ids), deltas)
-
-    def get_rows(self, ids: np.ndarray) -> np.ndarray:
-        ids = self._check_ids(ids)
-        out = np.empty((len(ids), self.cols), np.float32)
-        self._h.MV_HostStoreGetRows(self._ptr, ids, len(ids), out)
-        return out
 
 
 class KvIndex:

@@ -26,10 +26,10 @@ way the snapshot is immutable after install, which is what makes
 concurrent lock-free reads sound.
 
 **Values match training Gets.** Captures go through the same read paths
-a training Get uses — the native mirror, or ``_full_logical`` /
-``_gather_rows``, both of which apply the updater's ``access()``
-transform — so a served row is bit-identical to what ``GetRows`` at the
-cut position would have returned.
+a training Get uses — ``_full_logical`` / ``_gather_rows``, both of
+which apply the updater's ``access()`` transform — so a served row is
+bit-identical to what ``GetRows`` at the cut position would have
+returned.
 
 Residence is picked per table by ``-mv_serving_residence``:
 

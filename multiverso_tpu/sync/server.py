@@ -55,8 +55,6 @@ from multiverso_tpu.utils.mt_queue import MtQueue
 
 
 MV_DEFINE_bool("sync", False, "sync or async")
-# Declared-but-dead in the reference (server.cpp:21); kept for flag parity.
-MV_DEFINE_int("backup_worker_ratio", 0, "ratio% of backup workers (dead flag, parity)")
 # Windowed-engine transport selection (the reference picks its allreduce
 # wire adaptively by payload size, allreduce_engine.cpp:31-55). "host":
 # every window payload rides the staging allgather (capped_exchange).
@@ -102,6 +100,8 @@ _window_device_min_bytes_flag = cached_int_flag(
 # mh_apply_is_local() — both decided from EXCHANGED bytes, so all ranks
 # gate identically and an apply-side device collective can never race
 # the exchange thread's allgather into a rank-divergent order).
+# A Matrix / SparseMatrix table's apply is a device program on every
+# backend, so the overlap serves KV tables alone (ROADMAP.md D5).
 # -mv_pipeline=false restores the serial engine exactly.
 MV_DEFINE_bool("mv_pipeline", True,
                "pipelined windowed engine: overlap window N's apply "
@@ -2915,10 +2915,10 @@ class ShardedServer(Server):
     def RegisterTable(self, server_table) -> int:
         table_id = super().RegisterTable(server_table)
         if multihost.world_size() > 1:
-            # pre-warm the table's host mirror at THIS lockstep
+            # pre-warm a KV table's host values at THIS lockstep
             # position: a multi-stream engine cannot order collective
-            # applies, so the mirror bootstrap the single engine did
-            # in the first fenced window must happen here instead
+            # applies, so the bootstrap the single engine did in the
+            # first fenced window must happen here instead
             # (tables/base.py mh_prepare_local_apply contract)
             try:
                 server_table.mh_prepare_local_apply()

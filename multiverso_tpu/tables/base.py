@@ -203,17 +203,18 @@ class ServerTable:
         """Round 12 — called at table REGISTRATION in sharded
         multi-process worlds (sync/server.py ShardedServer), a
         lockstep program position BEFORE any verb reaches the table's
-        shard stream: eagerly create whatever host mirror makes
-        :meth:`mh_apply_is_local` true, so the table's very first
-        window is already host-local. A multi-stream engine cannot
-        order collective applies across its live streams, so a
-        nonlocal window there CHECK-fails loudly (_mh_fence_cause) —
-        without this hook the mirror-bootstrap window itself (the
-        single-engine design lets the FIRST fenced window create the
-        mirror) would be that nonlocal window. Collective reads are
+        shard stream: eagerly create whatever host copy makes
+        :meth:`mh_apply_is_local` true (a KV table's host values), so
+        the table's very first window is already host-local. A
+        multi-stream engine cannot order collective applies across its
+        live streams, so a nonlocal window there CHECK-fails loudly
+        (_mh_fence_cause) — without this hook the window that creates
+        the copy (the single-engine design lets the FIRST fenced window
+        create it) would be that nonlocal window. Collective reads are
         safe here: every rank registers the table at the same program
         position. Default no-op: the table then stays nonlocal and
-        the CHECK's advice applies."""
+        the CHECK's advice applies (every Matrix / SparseMatrix table,
+        on every backend)."""
 
     def mh_apply_is_local(self) -> bool:
         """True when EVERY windowed-engine apply/serve path of this
@@ -227,8 +228,8 @@ class ServerTable:
 
         CONTRACT: the answer must be rank-agreed — derive it only from
         creation-time-agreed configuration and state that evolves at
-        lockstep verb positions (e.g. the replicated host mirrors,
-        created by the first host verb on every rank), never from
+        lockstep verb positions (e.g. a KV table's replicated host
+        values, created by the first host verb on every rank), never from
         per-rank racy conditions. False is always safe (the engine then
         fences the window, exactly the serial schedule)."""
         return False
@@ -304,8 +305,8 @@ class ServerTable:
     #   (``.nbytes`` — shape math, no sync); on a multi-device process
     #   the per-device share is that divided by the mesh's local device
     #   count — a documented bound, not a measured allocation.
-    # * ``host_mirror_bytes`` — replicated host mirrors (the native f32
-    #   store, numpy kv mirrors). Exact: these are real host buffers.
+    # * ``host_mirror_bytes`` — replicated host mirrors (a KV table's
+    #   numpy values). Exact: these are real host buffers.
     # * ``host_bytes``        — host-authoritative state (host-backed
     #   values, freshness bitmaps, index structures). Exact.
 
@@ -313,9 +314,8 @@ class ServerTable:
         """Byte placement of this table's live state (see above).
         Default: the generic ``state`` pytree's leaf bytes count as
         device residence; families with mirrors/host planes override.
-        ``vars()`` deliberately bypasses properties — a family whose
-        ``state`` getter syncs mirrors (matrix) must never be synced by
-        a sampling probe; such families override this method."""
+        ``vars()`` deliberately bypasses properties: a sampling probe
+        runs no getter."""
         out = {"device_bytes": 0, "host_mirror_bytes": 0, "host_bytes": 0}
         state = vars(self).get("state")
         if isinstance(state, dict):

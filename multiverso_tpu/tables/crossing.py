@@ -72,9 +72,9 @@ def place(host, put: Callable = jnp.asarray):
 def call(program: str):
     """One launch of the program so named: ``with crossing.call(name):``
     around the call alone, its operands made before. A context manager
-    and not a wrapper of the call: the jitted program's call site stays
-    where it was, with no frame of ours between the verb and the program
-    when it is traced."""
+    and not a wrapper of the call: a frame of ours between a verb and a
+    jitted row program while it is traced cost ``rec_bag_steps`` 5 to 7 s
+    of set-up (PERF.md section 6, PR 35)."""
     tmetrics.counter("table.device.calls").inc()
     if not ttrace.enabled():
         return ttrace.NULL_SPAN

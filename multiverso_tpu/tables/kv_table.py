@@ -113,8 +113,7 @@ class KVServerTable(ServerTable):
             return
         self._values = ctx.place(jnp.zeros((self.capacity,), self.dtype),
                                  self._sharding)
-        # CPU-backend host mirror for the f32 values (same coherence
-        # pattern as the matrix table's native mirror): host verbs apply
+        # CPU-backend host mirror for the f32 values: host verbs apply
         # with numpy at vector speed instead of per-op jit dispatches
         # (~6ms/pair measured); device-plane reads sync pending host
         # writes back, ANY assignment to ``_values`` (the property
@@ -144,10 +143,9 @@ class KVServerTable(ServerTable):
 
     @_values.setter
     def _values(self, arr) -> None:
-        # safety by construction (the matrix-table state-setter pattern):
-        # ANY assignment makes the new array authoritative, so a code
-        # path that replaces the values can never leave a stale mirror
-        # serving host Gets
+        # safety by construction: ANY assignment makes the new array
+        # authoritative, so a code path that replaces the values can
+        # never leave a stale mirror serving host Gets
         self._values_arr = arr
         self._values_np = None
         self._np_dirty = False
@@ -454,10 +452,10 @@ class KVServerTable(ServerTable):
         """Pipelined-engine overlap gate (tables/base.py contract):
         host-backed (64-bit) values ARE host state, and a live
         replicated f32 mirror serves every exchanged-parts Add/Get with
-        numpy — no device collectives. Rank-agreed for the same reason
-        as the matrix mirror: eligibility is backend config, creation
-        happens at the first host verb's lockstep position, and only
-        fenced (non-local) windows or device-plane callers drop it."""
+        numpy — no device collectives. Rank-agreed: eligibility is
+        backend config, creation happens at the first host verb's
+        lockstep position, and only fenced (non-local) windows or
+        device-plane callers drop it."""
         return self._host_backed or (self._host_values_ok
                                      and self._values_np is not None)
 

@@ -282,33 +282,12 @@ class MatrixServerTable(ServerTable):
         # gauge, the Dashboard [RowSkew] line + /perf carry the rows.
         self._row_sketch = None
         self._row_sketch_notes = 0
-        # CPU-backend native host mirror (native/src/host_store.cc): the
-        # GIL-free threaded C++ store applies/serves the HOST-plane verbs
-        # for linear aux-free updaters; exactly one side is authoritative
-        # at a time — the ``state`` property/setter below keeps the two
-        # coherent (any device-path write drops the mirror; any state
-        # read syncs pending native writes back). Eligibility is static;
-        # the store itself is created lazily on the first host verb.
-        self._nat_store = None
-        self._nat_dirty = False
-        # Multi-process (round 5): the mirror is REPLICATED per rank —
-        # every host-plane verb reaches it as identically merged data
-        # (the windowed engine's parts paths, and merge_collective_add
-        # on the BSP/direct paths), so the replicas evolve in lockstep
-        # and Gets serve locally with zero host collectives. Any
-        # device-path read syncs the mirror back collectively (the
-        # `state` property runs at lockstep verb positions).
-        self._native_host_ok = (
-            self.updater.fusable and self.updater.combine_scale is not None
-            and not jax.tree.leaves(aux) and self.dtype == np.float32
-            and compress is None
-            and jax.default_backend() == "cpu")
         self.state = {
             "data": ctx.place(data, self._sharding),
             "aux": jax.tree.map(
                 lambda a: ctx.place(a, self._sharding), aux),
         }
-        jax.block_until_ready(self._state)
+        jax.block_until_ready(self.state)
         tmetrics.histogram("table.create_s").observe(
             time.perf_counter() - t_create)
         # every state leaf is row-shaped 2-D storage on the data's axis
@@ -626,106 +605,16 @@ class MatrixServerTable(ServerTable):
                                                   : self.num_cols]
         return blocks.reshape(-1, self.num_cols)[: self.num_rows]
 
-    # -- native host mirror (CPU backend) -----------------------------------
-
     @property
-    def state(self):
-        """The jax {'data','aux'} pytree. Reading it syncs any pending
-        native-mirror writes back into sharded device storage first, so
-        every device-path consumer (device planes, checkpoint, raw(),
-        engine jit programs) always sees the authoritative data."""
-        if self._nat_dirty:
-            ctx = self._zoo.mesh_ctx
-            st = dict(self._state)
-            st["data"] = ctx.place(self._to_storage(self._nat_store.get_all()),
-                                   self._sharding)
-            # mv-lint: ok(cross-domain-state): one plane per table — the worker-domain writer is the device-plane collective verb path (lockstep app-thread calls), and a device-plane table never takes engine window applies concurrently
-            self._state = st
-            # cleared only after the sync landed: a placement failure must
-            # leave the dirty flag set so retries/later reads still sync
-            # mv-lint: ok(cross-domain-state): same one-plane-per-table argument as _state above
-            self._nat_dirty = False
-        return self._state
-
-    @state.setter
-    def state(self, value) -> None:
-        self._state = value
-        if self._nat_store is not None:
-            # a device-path write made the jax state authoritative; the
-            # mirror is stale — drop it (rebuilt on the next host verb)
-            # mv-lint: ok(cross-domain-state): same one-plane-per-table argument as the state getter above
-            self._nat_store = None
-            self._nat_dirty = False
-
-    def _host_store(self):
-        """The live native mirror, or None when this table cannot ride it
-        (aux updater, compressed wire, multihost, non-CPU backend, or no
-        native toolchain)."""
-        if not self._native_host_ok:
-            return None
-        if self._nat_store is None:
-            from multiverso_tpu import native as native_mod
-            store = native_mod.NativeHostStore.create(
-                self.num_rows, self.num_cols,
-                float(self.updater.combine_scale))
-            if store is None:
-                self._native_host_ok = False   # no toolchain: stay python
-                return None
-            store.load(self.raw())
-            self._nat_store = store
-        return self._nat_store
-
-    def mh_prepare_local_apply(self) -> None:
-        """Sharded-engine pre-warm (tables/base.py contract): force the
-        native mirror live at registration — the collective ``raw()``
-        read inside ``_host_store()`` is lockstep there, exactly like
-        the first fenced window's would have been."""
-        if self._native_host_ok:
-            self._host_store()
-
-    def ledger_bytes(self):
-        """Accounting-ledger probe (tables/base.py contract): shape
-        arithmetic only — ``_state`` is read directly (the ``state``
-        property syncs a dirty mirror back to the device, which a
-        sampling thread must never trigger), and the native mirror's
-        footprint is its logical rows*cols floats."""
-        import jax
-        out = {"device_bytes": 0, "host_mirror_bytes": 0,
-               "host_bytes": 0}
-        st = self._state
-        if isinstance(st, dict):
-            out["device_bytes"] = int(sum(
-                int(getattr(leaf, "nbytes", 0))
-                for leaf in jax.tree.leaves(st)))
-        nat = self._nat_store
-        if nat is not None:
-            out["host_mirror_bytes"] = int(nat.rows) * int(nat.cols) * 4
-        return out
-
-    def mh_apply_is_local(self) -> bool:
-        """Pipelined-engine overlap gate (tables/base.py contract): with
-        the replicated native mirror LIVE, every exchanged-parts apply
-        and serve path above runs numpy/C++ on the host — no device
-        collectives, so window N's apply may overlap window N+1's host
-        exchange. Rank-agreed: mirror ELIGIBILITY is creation-time
-        config and mirror CREATION happens at the first host verb's
-        lockstep position on every rank. Before creation (or after a
-        device-path write drops the mirror) the conservative answer is
-        False — the engine fences that window, whose apply then
-        (re)creates the mirror at its lockstep position, and later
-        windows overlap. Deliberately does NOT force creation here:
-        ``_host_store()`` loads ``raw()``, a collective read, which
-        must never run from the exchange thread."""
-        return self._native_host_ok and self._nat_store is not None
+    def _state(self):
+        # read-only alias of ``state`` for benchmark/tools/aot_rounds_4c.py
+        return self.state
 
     def _read_rows_union(self, union_ids: np.ndarray) -> np.ndarray:
         """Rows for an already-validated (and, multi-process, already
-        cross-rank-agreed) id vector in ONE read: the native mirror
-        when live, else one padded gather — the merged read that batched
-        window Gets (SparseMatrixTable.ProcessGetWindowParts) slice."""
-        nat = self._host_store()
-        if nat is not None:
-            return nat.get_rows(np.asarray(union_ids, np.int32))
+        cross-rank-agreed) id vector in ONE read: one padded gather —
+        the merged read that batched window Gets
+        (SparseMatrixTable.ProcessGetWindowParts) slice."""
         device_ids = self._device_ids(np.asarray(union_ids, np.int32))
         with crossing.call("_gather_rows"):
             rows = self._gather_rows(self.state["data"], self.state["aux"],
@@ -854,76 +743,45 @@ class MatrixServerTable(ServerTable):
                     return False
                 ids_list.append(ids)
                 deltas_list.append(values.reshape(len(ids), self.num_cols))
-            nat = self._host_store()
-            if nat is None:
-                if len({a.shape for a in deltas_list}) != 1:
-                    # mixed batch shapes would mint a fresh compile per
-                    # window composition — the per-message path is
-                    # cheaper than that
-                    return False
-                # option scalars are irrelevant to linear updaters
-                # (default/sgd ignore them), so runs merge regardless of
-                # per-message options. The batch count quantizes to a
-                # power of two and the unique-id count to the bucket
-                # ladder, so the jit cache holds a bounded shape set
-                # however the engine's windows race the producers.
-                n, k = len(ids_list), ids_list[0].size
-                nb = 1 << (n - 1).bit_length()
-                if nb * k * 4 > ops.rows.SMEM_IDS_BYTES:
-                    # the merged id vector must fit the Pallas SMEM
-                    # prefetch budget (shared constant, ops/rows.py) —
-                    # huge windows process per-message so they keep the
-                    # row-DMA fast path
-                    return False
-                ids = np.full((nb, k), -1, np.int32)
-                deltas = np.zeros((nb, k, self.num_cols), self.dtype)
-                for i, (a, d) in enumerate(zip(ids_list, deltas_list)):
-                    ids[i] = a
-                    deltas[i] = d
-                uniq, inv = np.unique(ids.reshape(-1), return_inverse=True)
-                # POWER-OF-TWO bucket (coarser than the ladder): the
-                # unique count varies continuously with window overlap,
-                # and every distinct bucket is a compile of this table's
-                # merged program — pow2 caps the shape set at log2(window)
-                # sizes, all warmable up front
-                uniq_p = self._pad_ids(
-                    uniq, max(8, 1 << (len(uniq) - 1).bit_length()))
+            if len({a.shape for a in deltas_list}) != 1:
+                # mixed batch shapes would mint a fresh compile per
+                # window composition — the per-message path is
+                # cheaper than that
+                return False
+            # option scalars are irrelevant to linear updaters
+            # (default/sgd ignore them), so runs merge regardless of
+            # per-message options. The batch count quantizes to a
+            # power of two and the unique-id count to the bucket
+            # ladder, so the jit cache holds a bounded shape set
+            # however the engine's windows race the producers.
+            n, k = len(ids_list), ids_list[0].size
+            nb = 1 << (n - 1).bit_length()
+            if nb * k * 4 > ops.rows.SMEM_IDS_BYTES:
+                # the merged id vector must fit the Pallas SMEM
+                # prefetch budget (shared constant, ops/rows.py) —
+                # huge windows process per-message so they keep the
+                # row-DMA fast path
+                return False
+            ids = np.full((nb, k), -1, np.int32)
+            deltas = np.zeros((nb, k, self.num_cols), self.dtype)
+            for i, (a, d) in enumerate(zip(ids_list, deltas_list)):
+                ids[i] = a
+                deltas[i] = d
+            uniq, inv = np.unique(ids.reshape(-1), return_inverse=True)
+            # POWER-OF-TWO bucket (coarser than the ladder): the
+            # unique count varies continuously with window overlap,
+            # and every distinct bucket is a compile of this table's
+            # merged program — pow2 caps the shape set at log2(window)
+            # sizes, all warmable up front
+            uniq_p = self._pad_ids(
+                uniq, max(8, 1 << (len(uniq) - 1).bit_length()))
         with self._verb_span("server.table.add_run.dispatch",
                              adds=len(payloads)):
-            if nat is not None:
-                # native merged apply. Same-id-set payloads (one worker
-                # hammering, or replicated pushes) collapse to
-                # vector-summed deltas + ONE C++ add; otherwise
-                # per-payload pre-combine + one GIL-free add each
-                # (uniqueness is only needed WITHIN one threaded apply —
-                # linear updaters sum across applies). A cross-window
-                # np.add.at combine measured ~3x slower than the applies
-                # it saved.
-                first = ids_list[0]
-                if len(ids_list) > 1 and all(
-                        a.shape == first.shape and np.array_equal(a, first)
-                        for a in ids_list[1:]):
-                    total = deltas_list[0].astype(self.dtype, copy=True)
-                    for d in deltas_list[1:]:
-                        total += d
-                    ua, ud = _combine_duplicate_rows(
-                        first, total, self.num_cols, self.dtype)
-                    nat.add_rows(ua, ud)
-                else:
-                    for a, d in zip(ids_list, deltas_list):
-                        ua, ud = _combine_duplicate_rows(
-                            a, d, self.num_cols, self.dtype)
-                        nat.add_rows(ua, ud)
-                self._nat_dirty = True
-                for p, a in zip(payloads, ids_list):
-                    self._note_add_parts(p.get("option") or AddOption(),
-                                         [a])
-                return True
             operands = (crossing.place(uniq_p), crossing.place(deltas),
                         crossing.place(inv.astype(np.int32)),
                         self._device_opt())
             with crossing.call("_merged_add_rows"):
-                # mv-lint: ok(cross-domain-state): same one-plane-per-table argument as the state getter — engine window applies and device-plane collective verbs never drive one table concurrently
+                # mv-lint: ok(cross-domain-state): one plane per table — the worker-domain writer is the device-plane collective verb path (lockstep app-thread calls), and a device-plane table never takes engine window applies concurrently
                 self.state = self._merged_add_rows(self.state, *operands)
         # subclass bookkeeping fires per payload in message order, exactly
         # like the per-message path (SparseMatrixTable's freshness bits
@@ -1048,12 +906,6 @@ class MatrixServerTable(ServerTable):
     def _apply_summed_full(self, values: np.ndarray, option: AddOption,
                            parts) -> None:
         """Apply an (already cross-rank summed) whole-table delta."""
-        nat = self._host_store()
-        if nat is not None:
-            nat.add_all(values)
-            self._nat_dirty = True
-            self._note_add_parts(option, parts)
-            return
         delta = self._zoo.mesh_ctx.place(self._to_storage(values),
                                          self._sharding)
         self.state = self._update_full(self.state, delta,
@@ -1066,20 +918,14 @@ class MatrixServerTable(ServerTable):
         with ttrace.span("server.table.add_run.merge", cat="server"):
             ids, deltas = self._combine_duplicates(ids, deltas)
         with ttrace.span("server.table.add_run.dispatch", cat="server"):
-            nat = self._host_store()
-            if nat is not None:
-                # unique validated ids: the threaded C++ apply is race-free
-                nat.add_rows(ids, deltas)
-                self._nat_dirty = True
-            else:
-                # ship exact-size deltas; pad them to the bucket on device
-                padded_ids = self._device_ids(ids)
-                deltas = _pad_rows(crossing.place(deltas),
-                                   padded_ids.shape[0])
-                opt = self._device_opt(option)
-                with crossing.call("_update_rows"):
-                    self.state = self._update_rows(self.state, padded_ids,
-                                                   deltas, opt)
+            # ship exact-size deltas; pad them to the bucket on device
+            padded_ids = self._device_ids(ids)
+            deltas = _pad_rows(crossing.place(deltas),
+                               padded_ids.shape[0])
+            opt = self._device_opt(option)
+            with crossing.call("_update_rows"):
+                self.state = self._update_rows(self.state, padded_ids,
+                                               deltas, opt)
         self._note_add_parts(option, parts)
 
     # -- windowed-engine parts hooks (round 5; tables/base.py contract) -----
@@ -1279,16 +1125,11 @@ class MatrixServerTable(ServerTable):
         ids = np.concatenate(all_ids)
         deltas = np.concatenate(all_deltas)
         ids, deltas = self._combine_duplicates(ids, deltas)
-        nat = self._host_store()
-        if nat is not None:
-            nat.add_rows(ids, deltas)
-            self._nat_dirty = True
-        else:
-            padded_ids = self._device_ids(ids)
-            self.state = self._update_rows(
-                self.state, padded_ids,
-                _pad_rows(jnp.asarray(deltas), padded_ids.shape[0]),
-                self._device_opt())
+        padded_ids = self._device_ids(ids)
+        self.state = self._update_rows(
+            self.state, padded_ids,
+            _pad_rows(jnp.asarray(deltas), padded_ids.shape[0]),
+            self._device_opt())
         # subclass bookkeeping fires per position in window order with
         # per-rank id sets (SparseMatrixTable freshness needs each add's
         # attribution), exactly like the per-position path
@@ -1321,10 +1162,7 @@ class MatrixServerTable(ServerTable):
         Every rank validates every rank's metadata (ids + declared
         value shapes) so failures raise identically everywhere; the
         shared bucket derives from the exchanged shapes — no extra host
-        round. NOTE: on the CPU backend this drops the native host
-        mirror (any device-path write does) — the transport config owns
-        that trade; this host's measured crossover keeps auto mode on
-        the host wire (sync/server.py -window_transport)."""
+        round."""
         opts = self._check_parts_options(parts)
         rank_ids = []
         for p in parts:
@@ -1444,25 +1282,9 @@ class MatrixServerTable(ServerTable):
 
     def ProcessGetWindowParts(self, positions, my_rank: int):
         """Cross-rank get-dedup: serve a window segment's Gets from ONE
-        merged read. Mirror-backed tables serve locally; otherwise one
-        union gather (or one replicated full read when any request is
-        whole-table) serves every position."""
-        nat = self._host_store()
+        merged read: one union gather (or one replicated full read when
+        any request is whole-table) serves every position."""
         results: list = []
-        if nat is not None:
-            for parts in positions:
-                p = parts[my_rank]
-                try:
-                    if p.get("row_ids") is None:
-                        results.append(nat.get_all())
-                    else:
-                        ids = np.asarray(p["row_ids"], np.int32).ravel()
-                        self._check_ids(ids)
-                        self._note_row_access(ids)
-                        results.append(nat.get_rows(ids))
-                except Exception as exc:
-                    results.append(exc)
-            return results
         # validate EVERY rank's ids per position; a bad position fails
         # deterministically everywhere and drops out of the union
         pos_ids: list = []
@@ -1515,15 +1337,7 @@ class MatrixServerTable(ServerTable):
     def ProcessGetParts(self, parts, my_rank: int):
         """One collective Get from exchanged parts: the union is known
         locally — no union collective."""
-        nat = self._host_store()
         p = parts[my_rank]
-        if nat is not None:
-            if p.get("row_ids") is None:
-                return nat.get_all()
-            ids = np.asarray(p["row_ids"], np.int32).ravel()
-            self._check_ids(ids)
-            self._note_row_access(ids)
-            return nat.get_rows(ids)
         if any(q.get("row_ids") is None for q in parts):
             full = self._full_logical()
             if p.get("row_ids") is None:
@@ -1547,19 +1361,12 @@ class MatrixServerTable(ServerTable):
         of this collective Get (SparseMatrixTable computes all ranks' stale
         sets for its lockstep bits) passes the precomputed union so the
         id sets don't ride a second host collective."""
-        nat = self._host_store()
         if row_ids is None:
-            if nat is not None:
-                return nat.get_all()
             # multihost: XLA-replicated read (no host reassembly round)
             return self._full_logical()
         ids = np.asarray(row_ids, np.int32).ravel()
         self._check_ids(ids)
         self._note_row_access(ids)
-        if nat is not None:
-            # the store serves locally (multi-process: it is REPLICATED
-            # per rank since round 5) — no union round needed
-            return nat.get_rows(ids)
         union = (_union if _union is not None
                  else multihost.union_collective_ids(ids))
         if union is not None:
@@ -1591,20 +1398,6 @@ class MatrixServerTable(ServerTable):
         later in the window that donates the state cannot reach it."""
         if multihost.world_size() > 1:
             return None  # collective fetch/union — keep the sync path
-        nat = self._host_store()
-        if nat is not None:
-            # the native gather is synchronous and cheap (no device->host
-            # copy to overlap); serve it eagerly under the window
-            if row_ids is None:
-                out = nat.get_all()
-            else:
-                with ttrace.span("server.table.get.prepare", cat="server"):
-                    ids = np.asarray(row_ids, np.int32).ravel()
-                    self._check_ids(ids)
-                    self._note_row_access(ids)
-                with ttrace.span("server.table.get.dispatch", cat="server"):
-                    out = nat.get_rows(ids)
-            return lambda: out
         if row_ids is None:
             data = self.updater.access(self.state["data"], self.state["aux"],
                                        None)
@@ -1843,9 +1636,6 @@ class MatrixServerTable(ServerTable):
         shapes only): ``table.device_apply.pallas_verbs``, ``.xla_verbs``
         or ``.small_table_verbs``. The name is kept a bucket, as the jit
         cache keeps the program: a verb pays one dictionary lookup."""
-        # made here and not in __init__: a line added above the row
-        # programs' call sites recompiles every kernel-holding program
-        # once a checkout (ROADMAP.md D13)
         names = self.__dict__.setdefault("_apply_write_counter", {})
         name = names.get(bucket)
         if name is None:
@@ -1894,8 +1684,7 @@ class MatrixServerTable(ServerTable):
 
     #: the dense read (``ops.slice_rows``) as a program; not donating: the
     #: table is live. ``bucket`` and ``num_cols`` are its static shape,
-    #: ``count=None`` a run that is its bucket. Made here and not in
-    #: ``__init__``, as ``_count_apply_write``'s names are (ROADMAP.md D13)
+    #: ``count=None`` a run that is its bucket.
     _slice_rows = staticmethod(jax.jit(
         jax.named_scope("table.slice_rows")(ops.slice_rows),
         static_argnames=("bucket", "num_cols")))
@@ -1929,9 +1718,6 @@ class MatrixServerTable(ServerTable):
         """Immutable row snapshot for the serving plane. Residence per
         ``-mv_serving_residence``:
 
-        * mirror live -> copy-on-publish of the native host store (one
-          memcpy; the mirror exists only for linear aux-free updaters,
-          whose access() is identity, so the copy IS the training view);
         * device (single-process, aux-free) -> ONE on-device jnp.copy of
           the padded storage — a bare reference would dangle after the
           next donated update (donate_argnums) — served through the
@@ -1945,10 +1731,6 @@ class MatrixServerTable(ServerTable):
           collectives that could interleave with engine ones)."""
         from multiverso_tpu.serving import snapshot as ssnap
         mode = ssnap.residence_mode()
-        nat = self._host_store()
-        if nat is not None and mode != "device":
-            # get_all() fills a FRESH buffer — it IS the copy-on-publish
-            return ssnap.MatrixSnapshot.host(nat.get_all())
         device_legal = (multihost.world_size() <= 1
                         and not jax.tree.leaves(self.state["aux"]))
         want_device = mode == "device" or (
@@ -2053,12 +1835,16 @@ class MatrixWorkerTable(WorkerTable):
     # -- sync verbs ---------------------------------------------------------
 
     def Get(self, option: Optional[GetOption] = None) -> np.ndarray:
-        """Whole-table get (reference matrix_table.h:30-36)."""
+        """Whole-table get (reference matrix_table.h:30-36). On every
+        backend the reply may be a read-only view of the table's host
+        copy (it is on one shard): copy it before writing into it."""
         return self.Wait(self.GetAsync({"row_ids": None}, option))
 
     def GetRows(self, row_ids, option: Optional[GetOption] = None) -> np.ndarray:
         """Row-set get; rows returned in the requested order
-        (reference ProcessReplyGet scatter, matrix_table.cpp:317)."""
+        (reference ProcessReplyGet scatter, matrix_table.cpp:317). On
+        every backend the reply is a read-only view of the host copy of
+        the gather's bucket: copy it before writing into it."""
         ids = np.asarray(row_ids, np.int32)
         return self.Wait(self.GetAsync({"row_ids": ids}, option))
 

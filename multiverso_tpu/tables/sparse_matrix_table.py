@@ -342,9 +342,6 @@ class SparseMatrixServerTable(MatrixServerTable):
         is cut off on the host. More than ``READ_ROWS_CAP`` ids are read
         in pieces, each padded to the cap: one more shape, whatever the
         count."""
-        nat = self._host_store()
-        if nat is not None:
-            return nat.get_rows(ids)
         data, aux = self.state["data"], self.state["aux"]
         fetch = self._zoo.mesh_ctx.fetch
         cap = self.READ_ROWS_CAP
@@ -371,10 +368,10 @@ class SparseMatrixServerTable(MatrixServerTable):
         the host. The device gets ``raw`` as it is, at the bucket of its
         length, and sorts, dedups and gathers in one program; the host
         sorts its own copy (``np.unique``) while that runs and the rows
-        come back, so the sort is on nobody's critical path. With the CPU
-        backend's mirror, or past ``READ_ROWS_CAP`` ids, the host sorts
-        first and ``read_rows`` reads."""
-        if len(raw) > self.READ_ROWS_CAP or self._host_store() is not None:
+        come back, so the sort is on nobody's critical path. Past
+        ``READ_ROWS_CAP`` ids the host sorts first and ``read_rows``
+        reads."""
+        if len(raw) > self.READ_ROWS_CAP:
             ids = np.unique(raw)
             return ids, self.read_rows(ids)
         device_ids = self._device_ids(raw)
@@ -510,7 +507,7 @@ class SparseMatrixServerTable(MatrixServerTable):
             return per_pos      # every position failed validation
         # one merged read over the cross-position cross-rank union —
         # identical on every rank (computed from exchanged parts), so
-        # the non-mirror gather traces one identical program everywhere
+        # the gather traces one identical program everywhere
         with ttrace.span("server.table.sparse.get.read", cat="server"):
             union = np.unique(np.concatenate(unions)).astype(np.int32)
             rows_u = self._read_rows_union(union)

@@ -30,7 +30,7 @@ component                 what is counted
 tables.device_bytes       per-table jax store leaves (LOGICAL array
                           bytes — a documented bound for sharded
                           multi-device processes, exact on one device)
-tables.host_mirror_bytes  native f32 mirrors + numpy kv mirrors (exact)
+tables.host_mirror_bytes  numpy kv mirrors (exact)
 tables.host_bytes         host-authoritative values, freshness bitmaps,
                           key indexes at ALLOCATED capacity — probing-
                           table load-factor headroom included (exact)

@@ -345,7 +345,7 @@ class _OpsHandler(BaseHTTPRequestHandler):
         self.end_headers()
         self.wfile.write(data)
 
-    # mv-lint: ok(device-work-domain): the ledger probes this handler reaches walk jax.tree leaves and read .nbytes/process_count on the HOST — no device program launches; the probe-never-syncs-mirror regression test (test_watchdog) pins the matrix path
+    # mv-lint: ok(device-work-domain): the ledger probes this handler reaches walk jax.tree leaves and read .nbytes/process_count on the HOST — no device program launches
     def do_GET(self):           # noqa: N802 - stdlib handler API
         path = self.path.split("?", 1)[0]
         try:

@@ -821,7 +821,7 @@ class Watchdog:
                                         daemon=True)
         self._thread.start()
 
-    # mv-lint: ok(device-work-domain): the tick's ledger refresh walks jax.tree leaves and reads .nbytes on the HOST — no device program launches; the probe-never-syncs-mirror regression test below pins the matrix path
+    # mv-lint: ok(device-work-domain): the tick's ledger refresh walks jax.tree leaves and reads .nbytes on the HOST — no device program launches
     def _run(self) -> None:
         while not self._stop.wait(self.interval_s):
             try:

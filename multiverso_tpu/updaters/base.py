@@ -41,10 +41,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from multiverso_tpu.utils.configure import MV_DEFINE_int, MV_DEFINE_string
+from multiverso_tpu.utils.configure import MV_DEFINE_string
 
 MV_DEFINE_string("updater_type", "default", "server updater rule")
-MV_DEFINE_int("omp_threads", 4, "kept for flag parity; XLA owns threading")
 
 
 @dataclass
@@ -281,7 +280,7 @@ class DCASGDUpdater(Updater):
         bak = aux["backup"]
         # lr rides in traced (no retrace on change), so a zero can't raise
         # here — degrade the compensation to plain SGD instead of poisoning
-        # the table with inf/NaN (the native mirror applies the same
+        # the table with inf/NaN (the native runtime applies the same
         # degrade, store.cc DcasgdUpdaterC)
         lam_over_lr = jnp.where(lr > 0, lam / jnp.maximum(lr, 1e-30), 0.0)
         new = data - (delta + lam_over_lr * delta * delta * (data - bak))

@@ -29,4 +29,8 @@ def enable() -> str:
     # JAX keeps only compiles that took over 1 s; the row-verb programs
     # compile in less and there are dozens of them
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    # a Mosaic kernel's body keeps its debug locations inside the cache's
+    # key; with call stacks in them the key follows the line numbers of
+    # whoever called the row program
+    jax.config.update("jax_include_full_tracebacks_in_locations", False)
     return path

@@ -32,9 +32,9 @@ import numpy as np
 
 from multiverso_tpu.message import Message, MsgType
 from multiverso_tpu.node import ROLE_NAMES, Node, Role
-# Imported for their flag registrations (sync, backup_worker_ratio,
-# updater_type, omp_threads, telemetry/trace/stats_interval_s,
-# mv_deadline_s/chaos_spec/chaos_seed) — they MUST be registered before
+# Imported for their flag registrations (sync, updater_type,
+# telemetry/trace/stats_interval_s, mv_deadline_s/chaos_spec/chaos_seed)
+# — they MUST be registered before
 # Start()'s ParseCMDFlags runs, or a first-call "-sync=true" would be
 # silently dropped.
 import multiverso_tpu.elastic  # noqa: F401

@@ -35,6 +35,13 @@ touched-rows AdaGrad step, and prints its Mosaic kernels, how many of the
 state's four matrices are aliased input to output and its passes over a
 whole state matrix outside the in-place writes.
 
+``--locations`` calls ``compile_cache.enable()`` as an entry point does
+and lowers ``FENCE``'s programs: a Mosaic kernel's body travels inside the
+program as bytecode WITH its debug locations, which JAX's persistent
+cache therefore keys on. It prints, a program that holds a kernel, the
+files its bodies name, and whether ``SHIFT``'s program lowered through a
+caller 300 lines further down has the same bodies byte for byte.
+
 Prints ``SKIP ...`` and exits 0 where the topology description is missing.
 """
 
@@ -140,7 +147,7 @@ def programs(srv, ctx, buckets, merged):
     opt = {"worker_id": s((), jnp.int32)}
     for k in ("momentum", "learning_rate", "rho", "lambda_"):
         opt[k] = s((), jnp.float32)
-    state = srv._state
+    state = srv.state
     update_gather = jax.jit(srv.device_update_gather_rows,
                             donate_argnums=(0,))
     for b in buckets:
@@ -161,9 +168,10 @@ _LOC = re.compile(r'loc\((?:[^()]|\([^()]*\))*\)|#loc\d*( = .*)?')
 _BODY = re.compile(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22')
 
 
-def _mosaic_text(match):
+def _mosaic_text(match, locations=False):
     """A Mosaic kernel's body (base64 MLIR bytecode, file paths and line
-    numbers of ops/pallas_rows.py inside) as text without locations."""
+    numbers of ops/pallas_rows.py inside) as text, without its locations
+    unless asked for them."""
     import base64
 
     from jax._src.interpreters import mlir
@@ -172,25 +180,26 @@ def _mosaic_text(match):
     ctx.allow_unregistered_dialects = True
     with ctx:
         body = ir.Module.parse(base64.b64decode(match.group(1)))
-        return "body: " + body.operation.get_asm(enable_debug_info=False)
+        return "body: " + body.operation.get_asm(
+            enable_debug_info=locations)
 
 
 def dump(directory):
     os.makedirs(directory, exist_ok=True)
     for name, rows, cols, chips, updater, workers, buckets, merged in FENCE:
         srv, ctx = build(rows, cols, chips, updater, workers)
-        assert type(srv._state["aux"]) is dict
+        assert type(srv.state["aux"]) is dict
         progs = list(programs(srv, ctx, buckets, merged))
         if chips == 1:   # the whole-table Add, where a test can place one
             progs.append(("update_full", srv._update_full,
-                          (srv._state, srv._state["data"], progs[0][2][3])))
+                          (srv.state, srv.state["data"], progs[0][2][3])))
         lowered = [(prog, fn.lower(*args)) for prog, fn, args in progs]
         if chips == 1:   # the dense read, at each bucket the table holds
             scalar = jax.ShapeDtypeStruct(
                 (), jnp.int32,
                 sharding=SingleDeviceSharding(ctx.mesh.devices.flat[0]))
             lowered += [(f"slice_rows.{b}", srv._slice_rows.lower(
-                srv._state["data"], scalar, scalar, bucket=b,
+                srv.state["data"], scalar, scalar, bucket=b,
                 num_cols=cols)) for b in buckets if b <= srv.block_rows]
         for prog, low in lowered:
             text = low.as_text(debug_info=True)
@@ -201,8 +210,54 @@ def dump(directory):
                       "w") as f:
                 f.write(text)
             print("LOWERED", name, prog, len(text))
-        print("STATE", name, jax.tree.structure(srv._state),
+        print("STATE", name, jax.tree.structure(srv.state),
               sorted(vars(srv)))
+
+
+_FILE = re.compile(r'"([^"]+\.py)"')
+SHIFT = ("we_pairs", "update_rows.8192")
+
+
+def _bodies(lowered):
+    return list(_BODY.finditer(lowered.as_text(debug_info=True)))
+
+
+def locations():
+    """LOC <table> <program> kernels=<n> files=<the files its bodies name>
+    SHIFT <table> <program> kernels=<n> identical=<bool>"""
+    from multiverso_tpu.utils import compile_cache
+    compile_cache.enable()
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for name, rows, cols, chips, updater, workers, buckets, merged in FENCE:
+        srv, ctx = build(rows, cols, chips, updater, workers)
+        for prog, fn, args in programs(srv, ctx, buckets, merged):
+            bodies = _bodies(fn.lower(*args))
+            if not bodies:
+                continue
+            files = {os.path.relpath(f, repo) if os.path.isabs(f) else f
+                     for m in bodies
+                     for f in _FILE.findall(_mosaic_text(m, locations=True))}
+            print("LOC", name, prog, f"kernels={len(bodies)}",
+                  "files=" + ",".join(sorted(files)), flush=True)
+    # the traceable verb behind two callers of the same text, the second
+    # defined 300 lines further down its file
+    name, rows, cols, chips, updater, workers, buckets, merged = next(
+        spec for spec in FENCE if spec[0] == SHIFT[0])
+    srv, ctx = build(rows, cols, chips, updater, workers)
+    args = next(args for prog, _, args in programs(srv, ctx, buckets, merged)
+                if prog == SHIFT[1])
+    shifted = []
+    for down in (0, 300):
+        scope = {}
+        exec(compile("\n" * down + "def caller(fn, *args):\n"
+                     "    return fn(*args)\n", "caller.py", "exec"), scope)
+        jax.clear_caches()     # or the first trace answers both
+        via = jax.jit(
+            lambda *a, c=scope["caller"]: c(srv.device_update_rows, *a),
+            donate_argnums=(0,))
+        shifted.append([m.group(1) for m in _bodies(via.lower(*args))])
+    print("SHIFT", *SHIFT, f"kernels={len(shifted[0])}",
+          f"identical={shifted[0] == shifted[1]}", flush=True)
 
 
 #: what may produce a table-sized array in a row program: the state
@@ -254,7 +309,7 @@ def alias(specs):
     """ALIAS <table> <program> aliased=<n>/<state leaves> passes=<n>"""
     for name, rows, cols, chips, updater, workers, buckets, merged in specs:
         srv, ctx = build(rows, cols, chips, updater, workers)
-        leaves = jax.tree.leaves(srv._state)
+        leaves = jax.tree.leaves(srv.state)
         leaf_elems = min(int(np.prod(leaf.shape)) for leaf in leaves) \
             // srv.num_servers
         for prog, fn, args in programs(srv, ctx, buckets, merged):
@@ -295,7 +350,7 @@ def read(specs):
     rows themselves), and ``gather`` instructions."""
     for name, rows, cols, chips, updater, workers, buckets, _ in specs:
         srv, ctx = build(rows, cols, chips, updater, workers)
-        data, aux = srv._state["data"], srv._state["aux"]
+        data, aux = srv.state["data"], srv.state["aux"]
         one = SingleDeviceSharding(ctx.mesh.devices.flat[0])
         scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one)
         for b in buckets:
@@ -349,7 +404,7 @@ def pairs(specs):
             shape, dtype, sharding=whole)
         key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
         hlo = trainer._program(t_pad, nb).lower(
-            tuple(srv._state["data"] for srv in srvs),
+            tuple(srv.state["data"] for srv in srvs),
             (s((1 << 24,), jnp.int32),), s((t_pad,), jnp.int32),
             s((t_pad,), jnp.int32), s(key.shape, key.dtype),
             s((), jnp.float32)).compile().as_text()
@@ -416,6 +471,7 @@ if __name__ == "__main__":
     ap.add_argument("--read", action="store_true")
     ap.add_argument("--pairs", action="store_true")
     ap.add_argument("--scan", action="store_true")
+    ap.add_argument("--locations", action="store_true")
     a = ap.parse_args()
     try:
         _devices(1)
@@ -434,3 +490,5 @@ if __name__ == "__main__":
         pairs(PAIRS)
     if a.scan:
         scan(SCAN)
+    if a.locations:
+        locations()

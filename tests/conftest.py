@@ -48,21 +48,6 @@ def _hang_guard():
 
 
 @pytest.fixture()
-def off_host_mirror():
-    """``off(table) -> table`` with its server taken off the CPU
-    backend's native host mirror, so that host verbs run the row programs
-    the chip runs (the chip has no mirror; ROADMAP.md D8). Eligibility is
-    read at the first host verb, when the store is created: call it right
-    after ``MV_CreateTable``."""
-    def off(table):
-        srv = table.server()
-        assert srv._nat_store is None
-        srv._native_host_ok = False
-        return table
-    return off
-
-
-@pytest.fixture()
 def mv_env():
     """MultiversoEnv: MV_Init'd 1-host world, torn down after the test
     (reference Test/unittests/multiverso_env.h:10-21)."""

@@ -1,17 +1,10 @@
-"""The host-plane verbs of a default-updater float32 MatrixTable on the
-branch the chip runs.
-
-On the CPU backend such a table serves every host verb from the native
-host mirror (``MatrixServerTable._host_store``), and the chip has no
-mirror (``native_host_mirror=False``): the cell ``mt_host_verbs`` spends
-its window in the OTHER branch of each ``nat = self._host_store()`` fork
-(``_merged_add_rows`` under ``ProcessAddRun``, ``_device_ids`` /
-``_device_opt``, the gather of ``ProcessGetAsync`` and the cut of its
-bucket's pad, ``_update_full``). Eligibility is one attribute read lazily
-at the first host verb, so a test turns the mirror off right after
-creation (the ``off_host_mirror`` fixture of conftest.py) and tier-1 runs
-the chip's branch; every case then runs once more with the mirror left on. Deltas are whole numbers, so both must equal a numpy replay bit
-for bit, in any order of summation.
+"""The host-plane verbs of a float32 MatrixTable under the two linear
+updaters (``default`` adds, ``sgd`` subtracts), through the row programs
+the cell ``mt_host_verbs`` runs: ``_merged_add_rows`` under
+``ProcessAddRun``, ``_device_ids`` / ``_device_opt``, the gather of
+``ProcessGetAsync`` and the cut of its bucket's pad, ``_update_full``.
+Deltas are whole numbers, so a table must equal a numpy replay bit for
+bit, in any order of summation.
 """
 
 import threading
@@ -69,7 +62,7 @@ def _batch(rng, n: int):
     return ids, rng.integers(-3, 4, (n, COLS)).astype(np.float32)
 
 
-def _merged_window(table, replay, rng, mirror):
+def _merged_window(table, replay, rng, sign):
     first = _batch(rng, 24)
     # the same id set again (repeats across payloads), then another
     batches = [first, (first[0], _batch(rng, 24)[1]), _batch(rng, 24)]
@@ -77,23 +70,23 @@ def _merged_window(table, replay, rng, mirror):
     _one_window(table, batches)
     assert _counter("server.add.run_merged") - merged == 1
     for ids, delta in batches:
-        np.add.at(replay, ids, delta)
+        np.add.at(replay, ids, sign * delta)
 
 
-def _mixed_shapes(table, replay, rng, mirror):
+def _mixed_shapes(table, replay, rng, sign):
     batches = [_batch(rng, 8), _batch(rng, 12), _batch(rng, 8)]
     merged = _counter("server.add.run_merged")
     _one_window(table, batches)
-    if mirror == "off":    # a compile a window shape: the run declines
-        assert _counter("server.add.run_merged") == merged
+    # a compile a window shape: the run declines
+    assert _counter("server.add.run_merged") == merged
     for ids, delta in batches:
-        np.add.at(replay, ids, delta)
+        np.add.at(replay, ids, sign * delta)
 
 
-def _get_rows(table, replay, rng, mirror):
+def _get_rows(table, replay, rng, sign):
     ids, delta = _batch(rng, 40)
     table.AddRows(ids, delta)
-    np.add.at(replay, ids, delta)
+    np.add.at(replay, ids, sign * delta)
     for n in (64, 40):      # at its bucket (no slice to cut) and under it
         ask = rng.choice(ROWS, n).astype(np.int32)
         got = table.GetRows(ask)
@@ -102,14 +95,14 @@ def _get_rows(table, replay, rng, mirror):
     np.testing.assert_array_equal(table.Get(), replay)
 
 
-def _whole_add(table, replay, rng, mirror):
+def _whole_add(table, replay, rng, sign):
     for _ in range(2):
         full = rng.integers(-3, 4, (ROWS, COLS)).astype(np.float32)
         table.Add(full)
-        replay += full
+        replay += sign * full
 
 
-def _multiget(table, replay, rng, mirror):
+def _multiget(table, replay, rng, sign):
     ids = np.arange(3, dtype=np.int32)
     other = np.array([7, 5], np.int32)
     got = table.MultiGet([{"row_ids": ids}, {"row_ids": ids},
@@ -123,40 +116,33 @@ def _multiget(table, replay, rng, mirror):
     np.testing.assert_array_equal(table.GetRows(other), replay[other])
 
 
-@pytest.mark.parametrize("mirror", ["off", "on"])
+@pytest.mark.parametrize("updater_type", ["default", "sgd"])
 @pytest.mark.parametrize("case", [_merged_window, _mixed_shapes, _get_rows,
                                   _whole_add, _multiget],
                          ids=lambda f: f.__name__.lstrip("_"))
-def test_host_verb_equals_replay(world, off_host_mirror, case, mirror):
+def test_host_verb_equals_replay(world, case, updater_type):
     rng = np.random.default_rng(5)
     replay = rng.integers(-8, 9, (ROWS, COLS)).astype(np.float32)
     init = replay.copy()
     table = world.MV_CreateTable(MatrixTableOption(
-        num_rows=ROWS, num_cols=COLS, initializer=lambda shape: init))
-    srv = table.server()
-    assert srv._nat_store is None   # created at the first host verb
-    if mirror == "off":
-        off_host_mirror(table)
-    case(table, replay, rng, mirror)
+        num_rows=ROWS, num_cols=COLS, updater_type=updater_type,
+        initializer=lambda shape: init))
+    case(table, replay, rng, -1.0 if updater_type == "sgd" else 1.0)
     np.testing.assert_array_equal(table.GetRows(np.arange(ROWS)), replay)
-    if mirror == "off":
-        assert srv._nat_store is None and not srv._native_host_ok
-    elif srv._native_host_ok:       # False only without a native toolchain
-        assert srv._nat_store is not None
-    np.testing.assert_array_equal(srv.raw(), replay)
+    np.testing.assert_array_equal(table.server().raw(), replay)
 
 
-# -- where the pad of a Get's bucket is dropped (the chip's branch) ---------
+# -- where the pad of a Get's bucket is dropped -----------------------------
 # A gather returns its bucket; the first n rows are the reply. The bucket
 # crosses whole and a host view drops the pad (one launch a Get) unless the
 # pad is over ``matrix_table._HOST_CUT_PAD_BYTES``, when a slice program
 # drops it first (two launches).
 
-def _chip_table(world, off_host_mirror, rng):
-    """A table of whole numbers off the mirror, and its initial rows."""
+def _table(world, rng):
+    """A table of whole numbers, and its initial rows."""
     init = rng.integers(-8, 9, (ROWS, COLS)).astype(np.float32)
-    table = off_host_mirror(world.MV_CreateTable(MatrixTableOption(
-        num_rows=ROWS, num_cols=COLS, initializer=lambda shape: init)))
+    table = world.MV_CreateTable(MatrixTableOption(
+        num_rows=ROWS, num_cols=COLS, initializer=lambda shape: init))
     return table, init
 
 
@@ -179,13 +165,12 @@ PAD_CASES = {
 
 @pytest.mark.parametrize("path", GET_PATHS)
 @pytest.mark.parametrize("pad_case", PAD_CASES)
-def test_get_drops_its_buckets_pad(world, off_host_mirror, monkeypatch,
-                                   pad_case, path):
+def test_get_drops_its_buckets_pad(world, monkeypatch, pad_case, path):
     n, limit, side = PAD_CASES[pad_case]
     if limit is not None:
         monkeypatch.setattr(matrix_table, "_HOST_CUT_PAD_BYTES", limit)
     rng = np.random.default_rng(11)
-    table, init = _chip_table(world, off_host_mirror, rng)
+    table, init = _table(world, rng)
     srv = table.server()
     ids = rng.choice(ROWS, n).astype(np.int32)
     names = ("table.device.calls", "table.get.host_cuts",
@@ -200,15 +185,14 @@ def test_get_drops_its_buckets_pad(world, off_host_mirror, monkeypatch,
     if side is not None:    # the first cut registers both counters
         assert {"table.get.host_cuts", "table.get.device_cuts"} <= set(
             metrics.snapshot())
-    assert srv._nat_store is None
 
 
-def test_add_between_two_gets_of_a_window(world, off_host_mirror):
+def test_add_between_two_gets_of_a_window(world):
     """Get, Add, Get of the same rows in ONE window: the first reply is
     the bucket gathered before the Add, copied back after the Add's
     program donated the state it was gathered from."""
     rng = np.random.default_rng(13)
-    table, init = _chip_table(world, off_host_mirror, rng)
+    table, init = _table(world, rng)
     ids = rng.choice(ROWS, 40, replace=False).astype(np.int32)
     delta = rng.integers(1, 4, (40, COLS)).astype(np.float32)
 

@@ -137,6 +137,57 @@ class TestKernelsCompileForTpu:
         assert last.startswith("ADMITTED [128"), res.stdout[-2000:]
 
 
+def _aot_table_programs(*modes):
+    """tests/aot_table_programs.py as a child (it makes
+    ``jax.default_backend()`` answer "tpu"): its output's lines and the
+    whole of it; skips where no v5e topology can be described."""
+    import os
+    import subprocess
+    import sys
+    here = os.path.dirname(os.path.abspath(__file__))
+    res = subprocess.run(
+        [sys.executable, os.path.join(here, "aot_table_programs.py"),
+         *modes], env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=900)
+    assert res.returncode == 0, res.stderr[-3000:]
+    lines = res.stdout.strip().splitlines()
+    if lines and lines[-1].startswith("SKIP"):
+        pytest.skip(lines[-1])
+    return lines, res.stdout
+
+
+class TestKernelBodiesNameNoCaller:
+    """What identifies a compiled program (``utils/compile_cache.py``): a
+    Mosaic kernel's body travels inside its program as bytecode with its
+    debug locations, so JAX's persistent cache keys on them. After
+    ``compile_cache.enable()`` a location is the line that made the
+    operation (``ops/pallas_rows.py``) and not the stack of callers above
+    it: an edit of ``tables/matrix_table.py``, or of an app above it,
+    moves no program's key (PERF.md section 6, PR 44). The tool lowers
+    only, so this child loads no TPU library and writes no cache."""
+
+    @pytest.fixture(scope="class")
+    def lowered(self):
+        lines, out = _aot_table_programs("--locations")
+        return [ln.split() for ln in lines], out
+
+    def test_bodies_name_the_kernels_file_alone(self, lowered):
+        lines, out = lowered
+        named = {(ln[1], ln[2]): ln[4] for ln in lines if ln[0] == "LOC"}
+        # the cells' Pallas programs are all there (20 at PR 44)
+        assert len(named) >= 20, out[-3000:]
+        assert ("mt_host_verbs", "merged_add_rows.4x10000.65536") in named
+        assert ("tables_rounds_4c", "update_gather_rows.65536") in named
+        assert set(named.values()) == {
+            "files=multiverso_tpu/ops/pallas_rows.py"}, out[-3000:]
+
+    def test_a_caller_300_lines_down_lowers_the_same_body(self, lowered):
+        lines, out = lowered
+        shift = [ln for ln in lines if ln[0] == "SHIFT"]
+        assert shift == [["SHIFT", "we_pairs", "update_rows.8192",
+                          "kernels=1", "identical=True"]], out[-3000:]
+
+
 class TestStatefulRowProgramsAliasOnTpu:
     """The row programs of a table WITH per-worker updater state, compiled
     for a described v5e by tests/aot_table_programs.py (a child: it makes
@@ -160,29 +211,16 @@ class TestStatefulRowProgramsAliasOnTpu:
 
     @pytest.fixture(scope="class")
     def compiled(self):
-        import os
         import re
-        import subprocess
-        import sys
-        here = os.path.dirname(os.path.abspath(__file__))
-        env = dict(os.environ, JAX_PLATFORMS="cpu")
-        res = subprocess.run(
-            [sys.executable, os.path.join(here, "aot_table_programs.py"),
-             "--alias", "--tiny", "--read", "--pairs", "--scan"], env=env,
-            capture_output=True,
-            text=True,
-            timeout=900)
-        assert res.returncode == 0, res.stderr[-3000:]
-        lines = res.stdout.strip().splitlines()
-        if lines and lines[-1].startswith("SKIP"):
-            pytest.skip(lines[-1])
+        lines, out = _aot_table_programs("--alias", "--tiny", "--read",
+                                         "--pairs", "--scan")
         found = {}
         for ln in lines:
             m = re.match(r"(?:ALIAS|TINY|READ|PAIRS|SCAN) (\S+) (\S+) (.*)",
                          ln)
             if m:
                 found[m.group(1), m.group(2)] = m.group(3)
-        return found, res.stdout
+        return found, out
 
     @pytest.mark.parametrize("table,program", PROGRAMS)
     def test_state_aliases_through_the_row_program(self, compiled, table,
@@ -265,11 +303,11 @@ class TestMatrixTableWithPallas:
         yield mv_env
         SetCMDFlag("use_pallas", "auto")
 
-    def test_row_add_get(self, pallas_env, off_host_mirror):
+    def test_row_add_get(self, pallas_env):
         from multiverso_tpu import ops
         from multiverso_tpu.tables.matrix_table import MatrixTableOption
-        table = off_host_mirror(pallas_env.MV_CreateTable(
-            MatrixTableOption(num_rows=33, num_cols=7)))
+        table = pallas_env.MV_CreateTable(
+            MatrixTableOption(num_rows=33, num_cols=7))
         assert ops.use_pallas(table.server().state["data"])
         ids = np.array([0, 4, 17, 32], np.int32)
         deltas = np.arange(4 * 7, dtype=np.float32).reshape(4, 7)
@@ -280,14 +318,14 @@ class TestMatrixTableWithPallas:
         # untouched rows stay zero
         np.testing.assert_allclose(table.GetRows([1, 16, 31]), 0.0)
 
-    def test_wider_than_one_tile_takes_the_xla_path(self, pallas_env, off_host_mirror):
+    def test_wider_than_one_tile_takes_the_xla_path(self, pallas_env):
         """256 f32 columns: Mosaic refuses the row kernels there, so even
         ``-use_pallas=on`` must route the table to XLA (on the chip the
         old gate crashed this table's first Add) and stay exact."""
         from multiverso_tpu import ops
         from multiverso_tpu.tables.matrix_table import MatrixTableOption
-        table = off_host_mirror(pallas_env.MV_CreateTable(
-            MatrixTableOption(num_rows=300, num_cols=256)))
+        table = pallas_env.MV_CreateTable(
+            MatrixTableOption(num_rows=300, num_cols=256))
         assert not ops.use_pallas(table.server().state["data"])
         rng = np.random.default_rng(5)
         ids = rng.choice(300, 40, replace=False).astype(np.int32)
@@ -298,11 +336,11 @@ class TestMatrixTableWithPallas:
         untouched = np.setdiff1d(np.arange(300), ids).astype(np.int32)
         assert not table.GetRows(untouched).any()
 
-    def test_full_table_roundtrip(self, pallas_env, off_host_mirror):
+    def test_full_table_roundtrip(self, pallas_env):
         from multiverso_tpu.tables.matrix_table import MatrixTableOption
         rng = np.random.default_rng(3)
-        table = off_host_mirror(pallas_env.MV_CreateTable(
-            MatrixTableOption(num_rows=19, num_cols=4)))
+        table = pallas_env.MV_CreateTable(
+            MatrixTableOption(num_rows=19, num_cols=4))
         full = rng.standard_normal((19, 4)).astype(np.float32)
         table.Add(full)
         np.testing.assert_allclose(table.Get(), full, rtol=1e-6)

@@ -94,19 +94,17 @@ class TestMatrixOracle:
 
 
 class TestMatrixOraclePallas:
-    def test_random_walk_through_pallas_kernels(self, mv_env,
-                                                off_host_mirror):
+    def test_random_walk_through_pallas_kernels(self, mv_env):
         """Same oracle walk with -use_pallas=on, on the chip's own split
         inside the PS path: XLA reads, the interpreter running the Pallas
-        write kernel. The table is taken off the CPU backend's native
-        host mirror, which would answer without a row program."""
+        write kernel."""
         from multiverso_tpu.utils.configure import SetCMDFlag
         SetCMDFlag("use_pallas", "on")
         try:
             rng = np.random.default_rng(12)
             R, C = 24, 8
-            table = off_host_mirror(mv_env.MV_CreateTable(
-                MatrixTableOption(num_rows=R, num_cols=C)))
+            table = mv_env.MV_CreateTable(
+                MatrixTableOption(num_rows=R, num_cols=C))
             oracle = np.zeros((R, C), np.float32)
             for _ in range(12):
                 k = int(rng.integers(1, R + 1))
@@ -116,7 +114,6 @@ class TestMatrixOraclePallas:
                 np.add.at(oracle, ids, deltas)
                 np.testing.assert_allclose(table.GetRows(ids), oracle[ids],
                                            rtol=1e-5, atol=1e-5)
-            assert table.server()._nat_store is None
         finally:
             SetCMDFlag("use_pallas", "auto")
 
@@ -251,16 +248,15 @@ class TestRound3Oracle:
 
 
 class TestRound4Oracle:
-    """Random walks over the round-4 surfaces: the native host mirror
+    """Random walks over the round-4 surfaces: the host verbs
     interleaved with every other plane, and the LR device-plane window
     programs — all against numpy models."""
 
     @pytest.mark.parametrize("seed", [0, 1])
-    def test_mirror_interleaved_walk_matches_numpy(self, mv_env, seed):
-        """Host verbs (native mirror), device verbs (jax state), engine
-        bursts, and Store/Load interleave randomly; every read and the
-        final state must match the numpy oracle exactly — the coherence
-        protocol has no step where the two sides may disagree."""
+    def test_planes_interleaved_walk_matches_numpy(self, mv_env, seed):
+        """Host verbs, device verbs, engine bursts, and Store/Load
+        interleave randomly over the one ``state``; every read and the
+        final state must match the numpy oracle."""
         import io as _io
         from multiverso_tpu.utils.io import Stream
         from multiverso_tpu.zoo import Zoo
@@ -275,14 +271,14 @@ class TestRound4Oracle:
             op = rng.integers(0, 6)
             k = int(rng.integers(1, R + 1))
             ids = np.unique(rng.integers(0, R, k)).astype(np.int32)
-            if op == 0:     # host add (mirror)
+            if op == 0:     # host add
                 d = rng.standard_normal((len(ids), C)).astype(np.float32)
                 table.AddRows(ids, d)
                 np.add.at(oracle, ids, d)
-            elif op == 1:   # host get (mirror)
+            elif op == 1:   # host get
                 np.testing.assert_allclose(table.GetRows(ids), oracle[ids],
                                            rtol=1e-4, atol=1e-5)
-            elif op == 2:   # device write (drops mirror)
+            elif op == 2:   # device write
                 # direct server calls bypass the engine: drain queued
                 # fire-and-forget adds first (the checkpoint.py:139 /
                 # device-plane ownership convention)
@@ -290,7 +286,7 @@ class TestRound4Oracle:
                 d = rng.standard_normal((len(ids), C)).astype(np.float32)
                 srv.device_apply_rows(ids, d)
                 np.add.at(oracle, ids, d)
-            elif op == 3:   # device read (syncs mirror back)
+            elif op == 3:   # device read
                 Zoo.Get().DrainServer()
                 rows = np.asarray(srv.device_fetch_rows(ids))
                 np.testing.assert_allclose(rows, oracle[ids], rtol=1e-4,
@@ -303,8 +299,7 @@ class TestRound4Oracle:
                     np.add.at(oracle, ids, d)
             elif snapshot is not None and rng.random() < 0.5:
                 # restore an OLDER snapshot (mutations happened since):
-                # Load must discard everything after it, incl. any
-                # native-mirror state
+                # Load must discard everything after it
                 Zoo.Get().DrainServer()
                 blob, osnap = snapshot
                 srv.Load(Stream(_io.BytesIO(blob)))

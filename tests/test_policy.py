@@ -474,12 +474,19 @@ import jax
 jax.config.update("jax_platforms", "cpu")
 import numpy as np
 import multiverso_tpu as mv
-from multiverso_tpu.tables import MatrixTableOption
+from multiverso_tpu.tables import KVTableOption
 from multiverso_tpu.telemetry import flight, ops
 from multiverso_tpu.zoo import Zoo
 
 mode = sys.argv[3]
-R, C, ITERS = 512, 32, 48
+# KV tables: the drill is about the engine's streams, and two streams in
+# a multi-process world need tables whose apply is host-local
+# (tests/test_sharded.py test_matrix_table_over_two_streams_is_refused).
+# The watchdog's rule reads apply seconds a tick and holds under 0.05 s:
+# Adds of N keys keep a balanced stream's tick over that after the
+# correction has halved it, and ITERS leaves the loop the three healthy
+# ticks that clear the alert
+N, ITERS = 16384, 96
 base = int(port)
 
 def alerts():
@@ -499,7 +506,7 @@ def world(policy_on, coord_port, policy_port):
     args = [f"-dist_coordinator=127.0.0.1:{coord_port}",
             f"-dist_rank={rank}", "-dist_size=2",
             "-mv_engine_shards=2", "-mv_deadline_s=90",
-            "-mv_watchdog_s=0.15", "-mv_ops_port=0"]
+            "-mv_watchdog_s=0.3", "-mv_ops_port=0"]
     if policy_on:
         # skew: only the routing loop may act (parity stays about the
         # one correction under test); clean: EVERY loop armed — the
@@ -510,13 +517,18 @@ def world(policy_on, coord_port, policy_port):
                  f"-mv_policy_rules={rules}",
                  "-mv_policy_sustain=2", "-mv_policy_cooldown_s=2.0",
                  "-mv_policy_window_s=30", "-mv_policy_max_actions=2"]
+        if mode == "skew":
+            # the alert clears after three healthy ticks, and a tick
+            # under 0.05 s of applies is no evidence either way: on a
+            # loaded box that can outlast the default six evaluations,
+            # and the revert would undo the correction under test
+            args += ["-mv_policy_revert_after=40"]
     flight._reset_for_tests()   # the ring is process-global: scope it
     mv.MV_Init(args)            # to THIS world's events
     eng = Zoo.Get().server_engine
     assert type(eng).__name__ == "ShardedServer", type(eng)
-    tabs = [mv.MV_CreateTable(MatrixTableOption(num_rows=R, num_cols=C))
-            for _ in range(4)]
-    ids = np.arange(R, dtype=np.int32)
+    tabs = [mv.MV_CreateTable(KVTableOption()) for _ in range(4)]
+    ids = np.arange(N, dtype=np.int64)
     # THE SKEW (mode=skew): tables 0 and 2 are both HOT and both hash
     # to engine shard 0 (table_id % 2) — the modulo-routing pathology
     # the routing map exists to fix. mode=clean spreads the same load
@@ -525,16 +537,18 @@ def world(policy_on, coord_port, policy_port):
     hot = [tabs[0], tabs[2]] if mode == "skew" else tabs
     burst = 16 if mode == "skew" else 8
     for i in range(ITERS):
-        d = rng.integers(-3, 4, (R, C)).astype(np.float32)
+        d = rng.integers(-3, 4, N).astype(np.float32)
         for _ in range(burst):
             for t in hot:
-                t.AddFireForget(d, row_ids=ids)
+                t.AddFireForget(ids, d)
         if i % 7 == 3:
-            tabs[1].AddFireForget(np.ones((4, C), np.float32),
-                                  row_ids=ids[:4])
-            tabs[3].AddFireForget(np.ones((4, C), np.float32),
-                                  row_ids=ids[:4])
-        tabs[0].Wait(tabs[0].GetAsyncHandle(row_ids=ids[:8]))  # pace
+            tabs[1].AddFireForget(ids[:128], np.ones(128, np.float32))
+            tabs[3].AddFireForget(ids[:128], np.ones(128, np.float32))
+        for t in hot:           # pace: every hot stream drained
+            t.Get(ids[:8])
+        # and table 0 strictly the hottest, by verbs, on every rank:
+        # a tie would let two ranks each propose a move of their own
+        tabs[0].Get(ids[:8])
         if policy_on and i % 4 == 3:
             # the app-paced LOCKSTEP actuation point (both ranks, same
             # loop position — the MV_SaveCheckpoint discipline)
@@ -545,18 +559,18 @@ def world(policy_on, coord_port, policy_port):
     # the PARITY capture happens BEFORE the post-action probe: the
     # probe's extra verbs are policy-world-only traffic the oracle
     # world never issues
-    final = [t.GetRows(ids) for t in tabs]
+    final = [t.Get(ids) for t in tabs]
     post, cleared = None, None
     if policy_on and mode == "skew":
         # post-action probe: a fixed hot burst must now land BALANCED
         # across the two streams (each hosts one hot table)
-        d = np.ones((R, C), np.float32)
+        d = np.ones(N, np.float32)
         s0 = stream_verbs(eng)
         for _ in range(30):
-            tabs[0].AddFireForget(d, row_ids=ids)
-            tabs[2].AddFireForget(d, row_ids=ids)
-        tabs[0].GetRows(ids)            # tracked: t0 stream drained
-        tabs[2].GetRows(ids)            # tracked: t2 stream drained
+            tabs[0].AddFireForget(ids, d)
+            tabs[2].AddFireForget(ids, d)
+        tabs[0].Get(ids)                # tracked: t0 stream drained
+        tabs[2].Get(ids)                # tracked: t2 stream drained
         s1 = stream_verbs(eng)
         post = {k: s1[k] - s0.get(k, 0) for k in s1}
         # ...and the watchdog agrees the imbalance is GONE: the alert

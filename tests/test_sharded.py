@@ -245,11 +245,15 @@ import jax
 jax.config.update("jax_platforms", "cpu")
 import numpy as np
 import multiverso_tpu as mv
-from multiverso_tpu.tables import MatrixTableOption, KVTableOption
+from multiverso_tpu.tables import KVTableOption
 from multiverso_tpu.parallel import multihost
 from multiverso_tpu.zoo import Zoo
 
-R, C, K, ROUNDS = 200, 8, 20, 10
+# two KV tables, one a shard stream (table_id % shards): a KV table's
+# apply is host-local, which is what N streams in a multi-process world
+# need (a MatrixTable's is a device collective and is refused there:
+# TestShardedTwoProc.test_matrix_table_over_two_streams_is_refused)
+R, K, ROUNDS = 200, 20, 10
 
 def world(shards, coord_port):
     mv.MV_Init([f"-dist_coordinator=127.0.0.1:{coord_port}",
@@ -259,23 +263,23 @@ def world(shards, coord_port):
     if shards > 1:
         assert type(eng).__name__ == "ShardedServer", type(eng)
         assert multihost.wire_name() == "shm", multihost.wire_name()
-    mat = mv.MV_CreateTable(MatrixTableOption(num_rows=R, num_cols=C))
+    wide = mv.MV_CreateTable(KVTableOption())
     kv = mv.MV_CreateTable(KVTableOption())
     rng = np.random.default_rng(31 + rank)
     for i in range(ROUNDS):
-        ids = np.sort(rng.choice(R, K, replace=False)).astype(np.int32)
+        ids = np.sort(rng.choice(R, K, replace=False)).astype(np.int64)
         # integer-valued deltas: float32 sums of small integers are
         # exact under ANY grouping, so "bit-exact" tests the PROTOCOL
         # (no verb lost/duplicated/misrouted), not summation order —
         # window boundaries legitimately differ between 1 and N shards
-        deltas = rng.integers(-4, 5, (K, C)).astype(np.float32)
-        mat.AddFireForget(deltas, row_ids=ids)
+        deltas = rng.integers(-4, 5, K).astype(np.float32)
+        wide.AddFireForget(ids, deltas)
         kv.AddFireForget(np.array([i, 900 + rank], np.int64),
                          np.ones(2, np.float32))
     if shards > 1:
         # a cross-stream cut mid-stream, on BOTH ranks (lockstep)
         v = mv.MV_PublishSnapshot()
-    final = mat.GetRows(np.arange(R, dtype=np.int32))
+    final = wide.Get(np.arange(R, dtype=np.int64))
     keys = np.array(sorted(set(list(range(ROUNDS)) + [900, 901])),
                     np.int64)
     kvv = kv.Get(keys)
@@ -306,7 +310,7 @@ import jax
 jax.config.update("jax_platforms", "cpu")
 import numpy as np
 import multiverso_tpu as mv
-from multiverso_tpu.tables import MatrixTableOption
+from multiverso_tpu.tables import KVTableOption
 from multiverso_tpu.zoo import Zoo
 
 # full chaos on BOTH ranks (same seed: lockstep schedules) + an
@@ -321,33 +325,34 @@ mv.MV_Init([f"-dist_coordinator=127.0.0.1:{port}", f"-dist_rank={rank}",
             f"-chaos_spec={SPEC}", "-chaos_seed=4242"])
 eng = Zoo.Get().server_engine
 assert type(eng).__name__ == "ShardedServer", type(eng)
-R, C = 48, 4
-t0 = mv.MV_CreateTable(MatrixTableOption(num_rows=R, num_cols=C))
-t1 = mv.MV_CreateTable(MatrixTableOption(num_rows=R, num_cols=C))
+# KV tables: their applies are host-local, as two streams in a
+# multi-process world need (see _PARITY_CHILD)
+R = 48
+t0 = mv.MV_CreateTable(KVTableOption())
+t1 = mv.MV_CreateTable(KVTableOption())
 rng = np.random.default_rng(77 + rank)
 for i in range(14):
     for t in (t0, t1):
-        ids = np.sort(rng.choice(R, 5, replace=False)).astype(np.int32)
-        deltas = rng.integers(-4, 5, (5, C)).astype(np.float32)
+        ids = np.sort(rng.choice(R, 5, replace=False)).astype(np.int64)
+        deltas = rng.integers(-4, 5, 5).astype(np.float32)
         if i % 4 == 0:
-            t.AddRows(ids, deltas)
+            t.Add(ids, deltas)
         else:
-            t.AddFireForget(deltas, row_ids=ids)
+            t.AddFireForget(ids, deltas)
 from multiverso_tpu.failsafe import chaos
 chaos.quiesce()
 mv.MV_SetFlag("chaos_spec", "")
 chaos.quiesce()
-got0 = t0.GetRows(np.arange(R, dtype=np.int32))
-got1 = t1.GetRows(np.arange(R, dtype=np.int32))
-oracle0 = np.zeros((R, C), np.float32)
-oracle1 = np.zeros((R, C), np.float32)
+got0 = t0.Get(np.arange(R, dtype=np.int64))
+got1 = t1.Get(np.arange(R, dtype=np.int64))
+oracle0 = np.zeros(R, np.float32)
+oracle1 = np.zeros(R, np.float32)
 for r in range(2):
     orng = np.random.default_rng(77 + r)
     for i in range(14):
         for oracle in (oracle0, oracle1):
-            ids = np.sort(orng.choice(R, 5, replace=False)).astype(
-                np.int32)
-            deltas = orng.integers(-4, 5, (5, C)).astype(np.float32)
+            ids = np.sort(orng.choice(R, 5, replace=False))
+            deltas = orng.integers(-4, 5, 5).astype(np.float32)
             np.add.at(oracle, ids, deltas)
 np.testing.assert_array_equal(got0, oracle0)
 np.testing.assert_array_equal(got1, oracle1)
@@ -362,7 +367,48 @@ print(f"child {rank} SHARD-CHAOS OK", flush=True)
 '''
 
 
+_REFUSED_CHILD = r'''
+import os, sys
+rank, port, kind = int(sys.argv[1]), sys.argv[2], sys.argv[3]
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+import jax
+jax.config.update("jax_platforms", "cpu")
+import numpy as np
+import multiverso_tpu as mv
+from multiverso_tpu import tables
+
+mv.MV_Init([f"-dist_coordinator=127.0.0.1:{port}", f"-dist_rank={rank}",
+            "-dist_size=2", "-mv_engine_shards=2", "-mv_deadline_s=60"])
+option = getattr(tables, kind)
+t0 = mv.MV_CreateTable(option(num_rows=16, num_cols=4))
+t1 = mv.MV_CreateTable(option(num_rows=16, num_cols=4))
+try:
+    t1.AddRows(np.array([1, 2], np.int32), np.ones((2, 4), np.float32))
+    print(f"child {rank} NOT REFUSED", flush=True)
+except Exception as e:
+    print(f"child {rank} RAISED {type(e).__name__}: {e}", flush=True)
+os._exit(0)     # the engine is dead: no shutdown handshake to wait for
+'''
+
+
 class TestShardedTwoProc:
+    @pytest.mark.parametrize("kind", ["MatrixTableOption",
+                                      "SparseMatrixTableOption"])
+    def test_matrix_table_over_two_streams_is_refused(self, tmp_path, kind):
+        """A table whose apply is a device collective cannot ride N
+        shard streams in a multi-process world: the first window says so
+        at the caller's Wait, on every rank, on the CPU backend as on the
+        chip (sync/server.py ``_mh_fence_cause``)."""
+        outs = run_two_process(_REFUSED_CHILD, tmp_path, kind,
+                               expect="RAISED FatalError")
+        for out in outs:
+            assert "NOT REFUSED" not in out
+            assert ("window requires a collective apply "
+                    "(nonlocal_table)") in out
+            assert "shard streams in a multi-process world" in out
+            assert "run -mv_engine_shards=1" in out
+
     def test_sharded_vs_serial_bit_exact_parity_2proc(self, tmp_path):
         run_two_process(_PARITY_CHILD, tmp_path,
                         expect="SHARD-PARITY OK")

@@ -7,9 +7,8 @@ Every case drives the table through the normal path (``MV_Init`` ->
 interleavings of Adds and Gets and holds it to the reference verb by verb:
 no Get may return a row that is fresh for its worker, skip one that is
 stale, or return one twice, and the rows returned are the table's, bit for
-bit (whole-number deltas). Each case runs on the branch the chip runs (the
-CPU backend's native host mirror off, ``conftest.off_host_mirror``) and
-with the mirror on.
+bit (whole-number deltas). Each case runs under both linear updaters:
+``default`` adds a delta, ``sgd`` subtracts it, and so does the reference.
 """
 
 import threading
@@ -47,8 +46,10 @@ def _assert_get(got, want, ref, what):
     np.testing.assert_array_equal(rows, ref.data[ids], err_msg=what)
 
 
-def _interleave(table, ref, rng, workers: int, steps: int, sparse: bool):
-    """``steps`` random verbs, each checked against the reference."""
+def _interleave(table, ref, rng, workers: int, steps: int, sparse: bool,
+                sign: float):
+    """``steps`` random verbs, each checked against the reference, which
+    adds ``sign`` times what the table is handed."""
     for step in range(steps):
         kind = rng.choice(["add", "add", "get", "get", "get_rows",
                            "add_all", "add_nobody", "get_everything"],
@@ -62,11 +63,11 @@ def _interleave(table, ref, rng, workers: int, steps: int, sparse: bool):
             if kind == "add_nobody":
                 w = -1          # no keeper: stale for every worker
             table.AddRows(ids, d, AddOption(worker_id=w))
-            ref.add(w, ids, d)
+            ref.add(w, ids, sign * d)
         elif kind == "add_all":
             d = _deltas(rng, ROWS, False)
             table.Add(d, AddOption(worker_id=w))
-            ref.add(w, None, d)
+            ref.add(w, None, sign * d)
         elif kind == "get":
             _assert_get(table.Get(GetOption(worker_id=w)), ref.get(w),
                         ref, what)
@@ -85,21 +86,20 @@ def _interleave(table, ref, rng, workers: int, steps: int, sparse: bool):
 
 
 @pytest.mark.parametrize("compress", [None, "sparse"])
-@pytest.mark.parametrize("mirror", ["off", "on"])
+@pytest.mark.parametrize("updater_type", ["default", "sgd"])
 @pytest.mark.parametrize("workers", [1, 2, 3, 4])
-def test_interleavings_match_reference(workers, mirror, compress,
-                                       off_host_mirror):
+def test_interleavings_match_reference(workers, updater_type, compress):
     mv = _world(workers)
     try:
         table = mv.MV_CreateTable(SparseMatrixTableOption(
-            num_rows=ROWS, num_cols=COLS, compress=compress))
-        if mirror == "off":
-            off_host_mirror(table)
+            num_rows=ROWS, num_cols=COLS, compress=compress,
+            updater_type=updater_type))
         ref = SparseReference(ROWS, COLS, workers)
-        rng = np.random.default_rng(1000 * workers + (mirror == "on")
+        rng = np.random.default_rng(1000 * workers + (updater_type == "sgd")
                                     + 2 * (compress is not None))
         _interleave(table, ref, rng, workers, steps=120,
-                    sparse=compress is not None)
+                    sparse=compress is not None,
+                    sign=-1.0 if updater_type == "sgd" else 1.0)
         # and at the end every worker drains to the same table
         for w in range(workers):
             _assert_get(table.Get(GetOption(worker_id=w)), ref.get(w), ref,
@@ -109,8 +109,8 @@ def test_interleavings_match_reference(workers, mirror, compress,
         mv.MV_ShutDown()
 
 
-@pytest.mark.parametrize("mirror", ["off", "on"])
-def test_threads_under_worker_context(mirror, off_host_mirror):
+@pytest.mark.parametrize("updater_type", ["default", "sgd"])
+def test_threads_under_worker_context(updater_type):
     """Four worker threads in rounds of Get-all / AddRows / Get-all (the
     cell ``mt_sparse_rounds`` in small). Threads interleave, so the check
     is the order-free one: what a worker's Gets returned is covered by
@@ -121,9 +121,7 @@ def test_threads_under_worker_context(mirror, off_host_mirror):
     mv = _world(workers)
     try:
         table = mv.MV_CreateTable(SparseMatrixTableOption(
-            num_rows=ROWS, num_cols=COLS))
-        if mirror == "off":
-            off_host_mirror(table)
+            num_rows=ROWS, num_cols=COLS, updater_type=updater_type))
         rng = np.random.default_rng(7)
         # row 0 is never added, so a Get that answers [0] found nothing
         ids = [[1 + rng.choice(ROWS - 1, k, replace=False).astype(np.int32)
@@ -152,7 +150,7 @@ def test_threads_under_worker_context(mirror, off_host_mirror):
         replay = np.zeros((ROWS, COLS), np.float32)
         for w in range(workers):
             for a in ids[w]:
-                replay[a] += 1.0
+                replay[a] += -1.0 if updater_type == "sgd" else 1.0
         for w in range(workers):
             with mv.MV_WorkerContext(w):
                 last_ids, last_rows = table.Get()
@@ -217,12 +215,12 @@ def test_dirty_rows_set_algebra():
     assert d.drain().tolist() == [0, 1, 3, 5]
 
 
-def test_an_adds_id_array_is_copied(off_host_mirror):
+def test_an_adds_id_array_is_copied():
     """The caller may write its id array again after a blocking Add."""
     mv = _world(2)
     try:
-        table = off_host_mirror(mv.MV_CreateTable(SparseMatrixTableOption(
-            num_rows=ROWS, num_cols=COLS)))
+        table = mv.MV_CreateTable(SparseMatrixTableOption(
+            num_rows=ROWS, num_cols=COLS))
         ids = np.array([4, 8], np.int32)
         table.AddRows(ids, np.ones((2, COLS), np.float32),
                       AddOption(worker_id=0))
@@ -232,7 +230,7 @@ def test_an_adds_id_array_is_copied(off_host_mirror):
         mv.MV_ShutDown()
 
 
-def test_read_rows_in_pieces_and_buckets(off_host_mirror, monkeypatch):
+def test_read_rows_in_pieces_and_buckets(monkeypatch):
     """``read_rows`` hands the gather only ladder rungs up to the cap and
     reads a larger set in pieces; worker -1 (the whole table) takes that
     path at a small size here and stays out of the chip run."""
@@ -243,8 +241,8 @@ def test_read_rows_in_pieces_and_buckets(off_host_mirror, monkeypatch):
     assert SparseMatrixServerTable.read_buckets() == (8, 16)
     mv = _world(2)
     try:
-        table = off_host_mirror(mv.MV_CreateTable(SparseMatrixTableOption(
-            num_rows=ROWS, num_cols=COLS)))
+        table = mv.MV_CreateTable(SparseMatrixTableOption(
+            num_rows=ROWS, num_cols=COLS))
         srv = table.server()
         full = np.arange(ROWS * COLS, dtype=np.float32).reshape(ROWS, COLS)
         table.Add(full, AddOption(worker_id=0))
@@ -264,14 +262,14 @@ def test_read_rows_in_pieces_and_buckets(off_host_mirror, monkeypatch):
         mv.MV_ShutDown()
 
 
-def test_spans_and_counters(off_host_mirror):
+def test_spans_and_counters():
     """The names PERF.md's tables and the cell's readers rely on."""
     import multiverso_tpu as mv
     from multiverso_tpu.telemetry import trace as ttrace
     mv.MV_Init(["-num_workers=3", "-trace=true"])
     try:
-        table = off_host_mirror(mv.MV_CreateTable(SparseMatrixTableOption(
-            num_rows=ROWS, num_cols=COLS)))
+        table = mv.MV_CreateTable(SparseMatrixTableOption(
+            num_rows=ROWS, num_cols=COLS))
 
         def moved(name, before):
             return (metrics.snapshot().get(name, {}).get("value", 0)

@@ -1005,74 +1005,37 @@ class TestWindowBarrier:
         np.testing.assert_allclose(table.GetRows(ids), base + d2, rtol=1e-6)
 
 
-class TestNativeHostMirror:
-    """CPU-backend native host store (native/src/host_store.cc): the
-    matrix host plane's linear-updater applies ride GIL-free C++; the
-    state property keeps the mirror and the jax state coherent."""
+class TestHostVerbsAroundOtherPlanes:
+    """A MatrixTable's host verbs beside the table's other planes: the
+    updater's sign, a device-plane write between them, Store / Load."""
 
-    def _native_or_skip(self):
-        from multiverso_tpu import native
-        if native.lib() is None:
-            pytest.skip("native toolchain unavailable")
-
-    def test_mirror_engages_and_matches_oracle(self, mv_env):
-        self._native_or_skip()
-        rng = np.random.default_rng(11)
-        table = mv_env.MV_CreateTable(MatrixTableOption(num_rows=128,
-                                                        num_cols=8))
-        srv = table.server()
-        oracle = np.zeros((128, 8), np.float32)
-        for _ in range(5):
-            ids = rng.choice(128, 32, replace=False).astype(np.int32)
-            deltas = rng.standard_normal((32, 8)).astype(np.float32)
-            table.AddRows(ids, deltas)
-            np.add.at(oracle, ids, deltas)
-        assert srv._nat_store is not None          # the mirror engaged
-        np.testing.assert_allclose(table.Get(), oracle, rtol=1e-6)
-        # device-path read (raw) syncs pending native writes back
-        np.testing.assert_allclose(srv.raw(), oracle, rtol=1e-6)
-
-    def test_device_write_drops_mirror_and_stays_consistent(self, mv_env):
-        self._native_or_skip()
-        import jax.numpy as jnp
-        from multiverso_tpu.updaters import AddOption
-        table = mv_env.MV_CreateTable(MatrixTableOption(num_rows=64,
-                                                        num_cols=4))
-        srv = table.server()
-        ids = np.arange(64, dtype=np.int32)
-        table.AddRows(ids, np.full((64, 4), 2.0, np.float32))  # via native
-        assert srv._nat_store is not None
-        # device-plane write: must drop the mirror (jax state authoritative)
-        srv.device_apply_rows(np.array([0, 1], np.int32),
-                              np.ones((2, 4), np.float32))
-        assert srv._nat_store is None
-        got = table.GetRows(ids)                   # rebuilds the mirror
-        expect = np.full((64, 4), 2.0, np.float32)
-        expect[:2] += 1.0
-        np.testing.assert_allclose(got, expect, rtol=1e-6)
-
-    def test_sgd_sign_through_native(self, mv_env):
-        self._native_or_skip()
+    def test_sgd_sign_through_host_adds(self, mv_env):
         table = mv_env.MV_CreateTable(MatrixTableOption(
             num_rows=32, num_cols=4, updater_type="sgd"))
         ids = np.arange(32, dtype=np.int32)
         table.AddRows(ids, np.full((32, 4), 3.0, np.float32))
-        np.testing.assert_allclose(table.GetRows(ids), -3.0, rtol=1e-6)
-        assert table.server()._nat_store is not None
+        np.testing.assert_array_equal(table.GetRows(ids), -3.0)
+        table.Add(np.full((32, 4), 2.0, np.float32))
+        np.testing.assert_array_equal(table.Get(), -5.0)
 
-    def test_aux_updaters_and_compress_stay_on_jax_path(self, mv_env):
-        self._native_or_skip()
-        t1 = mv_env.MV_CreateTable(MatrixTableOption(
-            num_rows=16, num_cols=4, updater_type="adagrad"))
-        t2 = mv_env.MV_CreateTable(MatrixTableOption(
-            num_rows=16, num_cols=4, compress="sparse"))
-        for t in (t1, t2):
-            t.AddRows(np.array([1], np.int32), np.ones((1, 4), np.float32))
-            assert t.server()._nat_store is None
-            assert not t.server()._native_host_ok
+    def test_device_write_between_host_verbs_reads_back(self, mv_env):
+        table = mv_env.MV_CreateTable(MatrixTableOption(num_rows=64,
+                                                        num_cols=4))
+        srv = table.server()
+        ids = np.arange(64, dtype=np.int32)
+        table.AddRows(ids, np.full((64, 4), 2.0, np.float32))
+        srv.device_apply_rows(np.array([0, 1], np.int32),
+                              np.ones((2, 4), np.float32))
+        expect = np.full((64, 4), 2.0, np.float32)
+        expect[:2] += 1.0
+        np.testing.assert_array_equal(table.GetRows(ids), expect)
+        table.AddRows(ids[:2], np.ones((2, 4), np.float32))
+        expect[:2] += 1.0
+        np.testing.assert_array_equal(
+            np.asarray(srv.device_fetch_rows(ids)), expect)
+        np.testing.assert_array_equal(srv.raw(), expect)
 
-    def test_store_load_roundtrip_with_dirty_mirror(self, mv_env):
-        self._native_or_skip()
+    def test_store_load_roundtrip_after_host_adds(self, mv_env):
         import io as _io
         from multiverso_tpu.utils.io import Stream
         table = mv_env.MV_CreateTable(MatrixTableOption(num_rows=16,
@@ -1080,12 +1043,11 @@ class TestNativeHostMirror:
         srv = table.server()
         ids = np.arange(16, dtype=np.int32)
         table.AddRows(ids, np.full((16, 4), 5.0, np.float32))
-        assert srv._nat_dirty or srv._nat_store is not None
         buf = _io.BytesIO()
-        srv.Store(Stream(buf))                      # reads synced state
+        srv.Store(Stream(buf))
         table.AddRows(ids, np.full((16, 4), 9.0, np.float32))
         srv.Load(Stream(_io.BytesIO(buf.getvalue())))
-        np.testing.assert_allclose(table.GetRows(ids), 5.0, rtol=1e-6)
+        np.testing.assert_array_equal(table.GetRows(ids), 5.0)
 
 
 class TestKVHostMirror:

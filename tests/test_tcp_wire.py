@@ -430,10 +430,12 @@ import jax
 jax.config.update("jax_platforms", "cpu")
 import numpy as np
 import multiverso_tpu as mv
-from multiverso_tpu.tables import MatrixTableOption, KVTableOption
+from multiverso_tpu.tables import KVTableOption
 from multiverso_tpu.parallel import multihost
 
-R, C, K, ROUNDS = 200, 8, 20, 10
+# two KV tables, one a shard stream: their applies are host-local, as
+# two streams in a multi-process world need (tests/test_sharded.py)
+R, K, ROUNDS = 200, 20, 10
 
 def world(shards, coord_port, want_wire):
     # loopback cross-host: the hostname override fakes distinct hosts
@@ -447,20 +449,20 @@ def world(shards, coord_port, want_wire):
     assert multihost.wire_name() == want_wire, \
         (multihost.wire_name(), want_wire)
     assert multihost.host_label() == "node" + "AB"[rank]
-    mat = mv.MV_CreateTable(MatrixTableOption(num_rows=R, num_cols=C))
+    wide = mv.MV_CreateTable(KVTableOption())
     kv = mv.MV_CreateTable(KVTableOption())
     rng = np.random.default_rng(31 + rank)
     for i in range(ROUNDS):
-        ids = np.sort(rng.choice(R, K, replace=False)).astype(np.int32)
+        ids = np.sort(rng.choice(R, K, replace=False)).astype(np.int64)
         # integer-valued deltas: float32 sums of small integers are
         # exact under ANY grouping, so "bit-exact" tests the PROTOCOL
         # (no verb lost/duplicated/misrouted over tcp), not summation
         # order
-        deltas = rng.integers(-4, 5, (K, C)).astype(np.float32)
-        mat.AddFireForget(deltas, row_ids=ids)
+        deltas = rng.integers(-4, 5, K).astype(np.float32)
+        wide.AddFireForget(ids, deltas)
         kv.AddFireForget(np.array([i, 900 + rank], np.int64),
                          np.ones(2, np.float32))
-    final = mat.GetRows(np.arange(R, dtype=np.int32))
+    final = wide.Get(np.arange(R, dtype=np.int64))
     keys = np.array(sorted(set(list(range(ROUNDS)) + [900, 901])),
                     np.int64)
     kvv = kv.Get(keys)

@@ -555,7 +555,6 @@ class TestHostSpans:
         make = SparseMatrixTableOption if sparse else MatrixTableOption
         t = mv.MV_CreateTable(make(num_rows=256, num_cols=cols))
         srv = t.server()
-        srv._native_host_ok = False     # the chip's branch (verify skill)
         ids = np.arange(0, 2 * n, 2, dtype=np.int32)
         at_bucket = np.arange(b, dtype=np.int32)
         repeats = np.concatenate([ids[:24], ids[:24], ids[:16]])  # 64 / 24

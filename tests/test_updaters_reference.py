@@ -378,7 +378,7 @@ def test_stateful_row_program_has_one_cond_and_a_slice_a_table(
     general, dense = (
         collections.Counter(e.primitive.name for e in _equations(b.jaxpr))
         for b in conds[0].params["branches"])
-    tables = 1 + len(jax.tree.leaves(srv._state["aux"]))
+    tables = 1 + len(jax.tree.leaves(srv.state["aux"]))
     assert tables == 2
     assert dense["gather"] == 0 and dense["scatter"] == 0
     assert dense["dynamic_slice"] == tables

@@ -432,10 +432,9 @@ class TestMemoryLedger:
                    for rec in comps["tables"]["per_table"]}
             srv0 = eng.store_[0]
             dev0 = sum(int(leaf.nbytes)
-                       for leaf in jax.tree.leaves(srv0._state))
+                       for leaf in jax.tree.leaves(srv0.state))
             assert per[0]["device_bytes"] == dev0
-            if srv0._nat_store is not None:     # exact host bytes
-                assert per[0]["host_mirror_bytes"] == 128 * 16 * 4
+            assert per[0]["host_mirror_bytes"] == 0
             srv1 = eng.store_[1]
             vals1 = srv1._values_arr
             assert per[1]["device_bytes"] == int(vals1.nbytes)
@@ -467,30 +466,6 @@ class TestMemoryLedger:
                     == t["device_bytes"])
             assert (snap["mem.snapshots.bytes"]["value"]
                     == comps["snapshots"]["bytes"])
-        finally:
-            mv.MV_ShutDown()
-
-    def test_ledger_probe_never_syncs_the_mirror(self):
-        """The matrix ``state`` property syncs a dirty native mirror
-        back to the device on read — the ledger must NOT trigger that
-        (a sampling thread issuing device placements would race the
-        engine)."""
-        from multiverso_tpu.tables import MatrixTableOption
-        from multiverso_tpu.zoo import Zoo
-        mv.MV_Init([])
-        try:
-            mt = mv.MV_CreateTable(MatrixTableOption(num_rows=64,
-                                                     num_cols=8))
-            ids = np.arange(8, dtype=np.int32)
-            mt.AddRows(ids, np.ones((8, 8), np.float32))
-            mt.GetRows(ids)
-            srv = Zoo.Get().server_engine.store_[0]
-            if srv._nat_store is None:
-                pytest.skip("no native mirror on this build")
-            mt.AddRows(ids, np.ones((8, 8), np.float32))
-            assert srv._nat_dirty           # mirror ahead of device
-            accounting.memory_report()
-            assert srv._nat_dirty           # probe did NOT sync it
         finally:
             mv.MV_ShutDown()
 
@@ -632,7 +607,7 @@ import jax
 jax.config.update("jax_platforms", "cpu")
 import numpy as np
 import multiverso_tpu as mv
-from multiverso_tpu.tables import MatrixTableOption
+from multiverso_tpu.tables import KVTableOption
 from multiverso_tpu.telemetry import flight, ops
 
 mode = sys.argv[3]
@@ -645,12 +620,15 @@ if mode == "straggle" and rank == 0:
     # watchdog's straggler proxy must trip HERE and only here.
     args.append("-chaos_spec=apply.delay:1.0@0.04")
 mv.MV_Init(args)
-tab0 = mv.MV_CreateTable(MatrixTableOption(num_rows=512, num_cols=8))
-tab1 = mv.MV_CreateTable(MatrixTableOption(num_rows=512, num_cols=8))
-ids = np.arange(512, dtype=np.int32)
-d = np.ones((512, 8), np.float32)          # ~16KB per add
-tab0.AddRows(ids, d)                                    # warm
-tab1.AddRows(ids, d)
+# KV tables: the drill is about the watchdog's reading of the engine, and
+# a KV table's apply is numpy on the host, cheap and steady (a
+# MatrixTable's is a device program, a cross-process collective here)
+tab0 = mv.MV_CreateTable(KVTableOption())
+tab1 = mv.MV_CreateTable(KVTableOption())
+ids = np.arange(2048, dtype=np.int64)
+d = np.ones(2048, np.float32)               # ~24KB per add
+tab0.Add(ids, d)                                        # warm
+tab1.Add(ids, d)
 mv.MV_Barrier()
 # sustained lockstep windows: a FIXED iteration count, never a wall-
 # time bound — with the chaos delay rank 0 runs ~10x slower per
@@ -666,9 +644,9 @@ mv.MV_Barrier()
 # delay pushes rank 0 past 40ms/window — margin on BOTH sides
 for _ in range(24):
     for _ in range(8):
-        tab0.AddFireForget(d, row_ids=ids)
-        tab1.AddFireForget(d, row_ids=ids)
-    tab0.Wait(tab0.GetAsyncHandle(row_ids=ids[:16]))
+        tab0.AddFireForget(ids, d)
+        tab1.AddFireForget(ids, d)
+    tab0.Get(ids[:16])
 mv.MV_Barrier()
 
 def alerts_body():

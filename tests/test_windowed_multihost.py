@@ -3,7 +3,7 @@
 The r4 engine took the strict path for any ``nproc > 1`` world: every
 table verb ran its own host collective (~2 allgather rounds per verb)
 and every single-process window optimization (add-coalescing, get-dedup,
-merged runs, native mirror) was disabled. The windowed protocol
+merged runs) was disabled. The windowed protocol
 exchanges a whole engine window in ONE allgather and re-enables all of
 them across ranks. These tests drive the new surface with 2-process
 jax.distributed worlds (tests/test_multihost.py run_two_process
@@ -14,8 +14,6 @@ pattern):
 * the collective-count contract itself — host collective rounds per
   verb must sit far below the r4 cost of ~2/verb (the round-5 VERDICT
   metric);
-* the replicated native mirror — CPU-backend matrix tables ride the
-  GIL-free host store in 2-process worlds now;
 * compressed wire across processes — a 2-proc sparse-compressed Add
   stream applies bit-identically to an uncompressed twin (VERDICT #3);
 * deterministic failure — an invalid payload at one rank fails that
@@ -100,45 +98,6 @@ assert srv.mh_window_exchanges < srv.mh_window_verbs, (
 mv.MV_Barrier()
 mv.MV_ShutDown()
 print(f"child {rank} BURST OK per_verb={per_verb:.3f}", flush=True)
-'''
-
-
-_MIRROR_CHILD = r'''
-import os, sys
-rank, port = int(sys.argv[1]), sys.argv[2]
-os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
-import jax
-jax.config.update("jax_platforms", "cpu")
-import numpy as np
-import multiverso_tpu as mv
-from multiverso_tpu.tables import MatrixTableOption
-from multiverso_tpu.native import NativeHostStore
-
-mv.MV_Init([f"-dist_coordinator=127.0.0.1:{port}", f"-dist_rank={rank}",
-            "-dist_size=2"])
-mat = mv.MV_CreateTable(MatrixTableOption(num_rows=64, num_cols=4))
-srv = mat.server()
-ids = np.array([rank, 10 + rank, 30], np.int32)
-mat.AddRows(ids, np.full((3, 4), float(rank + 1), np.float32))
-if NativeHostStore.create(4, 4, 1.0) is not None:
-    # toolchain present: the replicated mirror must actually be serving
-    assert srv._nat_store is not None, "mirror did not engage 2-proc"
-rows = mat.GetRows(np.array([0, 1, 10, 11, 30], np.int32))
-assert np.allclose(rows[[0, 2]], 1.0), rows
-assert np.allclose(rows[[1, 3]], 2.0), rows
-assert np.allclose(rows[4], 3.0), rows          # both ranks on row 30
-# device plane after mirror writes: state property syncs collectively
-dev = np.asarray(srv.device_fetch_rows(np.array([30], np.int32)))
-assert np.allclose(dev[0, :4], 3.0), dev
-# ...and a device-path write drops the mirror, host Get still right
-srv.device_apply_rows(np.array([30], np.int32),
-                      np.ones((1, 4), np.float32))
-rows = mat.GetRows(np.array([30], np.int32))
-assert np.allclose(rows, 3.0 + 2.0), rows       # +1 from each rank
-mv.MV_Barrier()
-mv.MV_ShutDown()
-print(f"child {rank} MIRROR OK", flush=True)
 '''
 
 
@@ -319,11 +278,6 @@ class TestWindowedProtocol:
         host-collective cost per verb sits far below r4's ~2/verb."""
         run_two_process(_BURST_CHILD, tmp_path, expect="BURST OK",
                         timeout=280)
-
-    def test_native_mirror_rides_two_process_worlds(self, tmp_path):
-        """The CPU-backend native host store is replicated per rank and
-        serves 2-proc host verbs; device-plane reads sync it back."""
-        run_two_process(_MIRROR_CHILD, tmp_path, expect="MIRROR OK")
 
     def test_compressed_wire_across_processes(self, tmp_path):
         """compress='sparse' Adds from two ranks (mixed with per-rank
