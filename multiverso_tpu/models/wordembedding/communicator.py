@@ -24,6 +24,8 @@ from multiverso_tpu.models.wordembedding.model import TrainState, init_embedding
 from multiverso_tpu.parallel.mesh import next_bucket
 from multiverso_tpu.tables import KVTableOption, MatrixTableOption
 from multiverso_tpu.tables.matrix_table import _pad_row_batch
+from multiverso_tpu.telemetry import metrics as tmetrics
+from multiverso_tpu.telemetry import trace as ttrace
 
 WORD_COUNT_KEY = 0
 
@@ -92,8 +94,9 @@ class Communicator:
         on the wire. The reference's sequential blocking fetch
         (communicator.cpp:117-155) was the WE app's 2-proc
         anti-scaling hot spot (BENCH_r05)."""
-        return self.wait_parameter(
+        fetched = self.wait_rows(
             self.request_parameter_async(input_rows, output_rows))
+        return self.training_state(fetched), fetched
 
     def request_parameter_async(self, input_rows: np.ndarray,
                                 output_rows: np.ndarray) -> dict:
@@ -116,30 +119,50 @@ class Communicator:
         from multiverso_tpu import api as mv_api
         return {"call": mv_api.MV_MultiGetAsync(ops), "names": names}
 
-    def wait_parameter(self, handles: dict) -> Tuple[TrainState, dict]:
+    def wait_rows(self, handles: dict) -> dict:
+        """The reply of ``request_parameter_async``: every table's rows on
+        the host, by state field. The row bytes a block's Gets return are
+        counted here, once."""
         # unbounded-ok: MultiCall.Wait honors -mv_deadline_s internally
         fetched = dict(zip(handles["names"], handles["call"].Wait()))
+        tmetrics.counter("we.host_plane.fetched_bytes").inc(
+            sum(rows.nbytes for rows in fetched.values()))
+        return fetched
 
+    def training_state(self, fetched: dict) -> TrainState:
+        """The device state a block trains: each table's fetched rows in
+        the same training copy as the device plane's, one state shape a
+        rung, whichever plane fetched it."""
         def train(rows: np.ndarray) -> jax.Array:
-            # the same training copy as the device plane's: one state
-            # shape a rung, whichever plane fetched it
             spare = training_rows(len(rows)) - len(rows)
             return jnp.asarray(np.pad(rows, ((0, spare), (0, 0))))
 
-        state = TrainState(
+        return TrainState(
             ie=train(fetched["ie"]), eo=train(fetched["eo"]),
             ie_g2=train(fetched["ie_g2"]) if self.opt.use_adagrad else None,
             eo_g2=train(fetched["eo_g2"]) if self.opt.use_adagrad else None)
-        return state, fetched
 
     def add_delta_parameter(self, state: TrainState, fetched: dict,
                             input_rows: np.ndarray,
                             output_rows: np.ndarray) -> None:
         """Push trained - fetched (reference AddDeltaParameter,
-        communicator.cpp:157-206)."""
+        communicator.cpp:157-206), table after table, so that the server
+        applies one table's delta while the next is made. Three children
+        of the caller's span (``worker.we.push``) a table: ``.take`` the
+        copy of the trained rows back (the first waits for the block's
+        program), ``.delta`` the subtraction, ``.add`` the
+        ``AddFireForget``. The row bytes a block's Adds send are counted
+        here, once."""
+        pushed = 0
         for name, table, ids in self._row_specs(input_rows, output_rows):
-            trained = np.asarray(getattr(state, name))[: len(ids)]
-            table.AddFireForget(trained - fetched[name], row_ids=ids)
+            with ttrace.child(".take"):
+                trained = np.asarray(getattr(state, name))[: len(ids)]
+            with ttrace.child(".delta"):
+                delta = trained - fetched[name]
+            with ttrace.child(".add"):
+                table.AddFireForget(delta, row_ids=ids)
+            pushed += delta.nbytes
+        tmetrics.counter("we.host_plane.pushed_bytes").inc(pushed)
 
     # -- device plane (rows never leave HBM) --------------------------------
 

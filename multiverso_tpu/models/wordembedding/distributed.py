@@ -142,6 +142,8 @@ class DistributedWordEmbedding:
         # touched-rows step (_block_scan_fn)
         tmetrics.counter("we.block_scan.touched_rows_blocks")
         m_steps_run = tmetrics.counter("we.block.steps.run")
+        # registered at 0 too: the blocks that trained on prefetched rows
+        tmetrics.counter("we.pipeline.prefetched_blocks")
 
         def harvest(force: bool = False) -> None:
             while pending and (force or len(pending) >= 2):
@@ -213,6 +215,17 @@ class DistributedWordEmbedding:
                                     max(p[2] for p in parts))
             return block
 
+        # The block pipeline (-is_pipeline 1, host plane; reference
+        # distributed_wordembedding.cpp:203-215), ONE worker, prefetch
+        # depth one. The order holds by the order of this thread's sends
+        # into the server's first-in-first-out mailbox, whatever windows
+        # the engine cuts them into: block b+1's MV_MultiGetAsync is sent
+        # before block b's Adds and after block b-1's, so its reply holds
+        # block b-1's deltas and none of block b's. Blocks 0 and 1 of a
+        # train() train on the tables as the call found them and block
+        # b >= 2 on the tables with the deltas of blocks 0..b-2 and no
+        # other; every delta is added exactly once
+        # (tests/test_we_pipeline.py holds this under a slow server).
         current = pop_block()
         prefetch = None
         next_block: Optional[DataBlock] = None
@@ -223,8 +236,11 @@ class DistributedWordEmbedding:
                 # async dispatch already (nothing to overlap by hand)
                 if (next_block is not None and next_block.pair_count
                         and not opt.device_plane):
-                    prefetch = self.comm.request_parameter_async(
-                        next_block.input_rows, next_block.output_rows)
+                    with ttrace.span("worker.we.prefetch.issue",
+                                     cat="worker",
+                                     parent=next_block.trace_ctx):
+                        prefetch = self.comm.request_parameter_async(
+                            next_block.input_rows, next_block.output_rows)
             loss, pairs = self._train_block(current, step)
             m_blocks.inc()
             pending.append((loss, pairs, current.trace_ctx))
@@ -239,10 +255,15 @@ class DistributedWordEmbedding:
             if opt.is_pipeline:
                 if next_block is not None and next_block.pair_count \
                         and prefetch is not None:
+                    # after block b's push, as the order above has it:
+                    # the wait, then the next block's training copy
                     with ttrace.span("worker.we.fetch", cat="worker",
                                      parent=next_block.trace_ctx):
-                        next_block._prefetched = self.comm.wait_parameter(
-                            prefetch)
+                        fetched = self.comm.wait_rows(prefetch)
+                    with ttrace.span("worker.we.upload", cat="worker",
+                                     parent=next_block.trace_ctx):
+                        next_block._prefetched = (
+                            self.comm.training_state(fetched), fetched)
                 current, prefetch = next_block, None
             else:
                 current = pop_block()
@@ -346,9 +367,11 @@ class DistributedWordEmbedding:
         with ttrace.span("worker.we.upload", cat="worker"):
             tensors = [jnp.asarray(st[k]) for k in (
                 "inputs", "input_mask", "outputs", "labels", "output_mask")]
-        pre = getattr(block, "_prefetched", None)
-        if pre is not None and not self.opt.device_plane:
+        # taken off the block: whoever keeps the block keeps no rows
+        pre = block.__dict__.pop("_prefetched", None)
+        if pre is not None:
             state, fetched = pre    # train() waited for it, under its span
+            tmetrics.counter("we.pipeline.prefetched_blocks").inc()
         else:
             with ttrace.span("worker.we.fetch", cat="worker"):
                 if self.opt.device_plane:

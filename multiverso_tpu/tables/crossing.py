@@ -33,10 +33,21 @@ copied back whole and the caller takes ``[:n]`` of the host array, a view:
 no slice program, so a Get is ONE ``.call`` and the pad's bytes (48 KB of
 2 MB for 10,000 ids under the rung 10,240) are counted in ``d2h_bytes``.
 A launch costs the host more than those bytes do; only a pad over
-``matrix_table._HOST_CUT_PAD_BYTES`` is cut on the device first (a second
-``.call``, program ``slice``). ``table.get.host_cuts`` and
-``table.get.device_cuts`` count the Gets of either kind. Rows that stay in
-HBM (``device_fetch_rows``) are always cut on the device.
+``matrix_table._HOST_CUT_PAD_BYTES`` and over a quarter of the rows asked
+for is cut on the device first (a second ``.call``, program ``slice``,
+to one of the bucket's eighths: never a program a row count).
+``table.get.host_cuts`` and ``table.get.device_cuts`` count the Gets of
+either kind. Rows that stay in HBM (``device_fetch_rows``) are always cut
+on the device.
+
+How a host delta's pad is made. A row Add's delta crosses exact-size and
+the device pads it to its bucket (program ``_pad_row_batch``, one a
+distinct batch size) while the pad is under the same constant. Over it
+the delta crosses in pieces of an eighth of the bucket, views of the
+sender's array (``matrix_table._place_rows``: one ``.place`` of that
+many host arrays, no copy on the host), and ``_join_row_pieces`` lays
+them out at the bucket: one program a piece count.
+``table.add.host_pieces`` counts the pieces.
 
 With ``-trace`` off a helper adds one flag read a span and one counter
 step a crossing to what the verb did before: no ``block_until_ready``, no

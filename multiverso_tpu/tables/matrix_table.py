@@ -94,10 +94,10 @@ def _pad_rows(deltas: jax.Array, bucket: int) -> jax.Array:
 def _cut_rows(rows: jax.Array, n: int) -> jax.Array:
     """The first ``n`` of a gather's bucket of rows, ON THE DEVICE: the
     bucket itself when it is ``n`` long (no program, as ``_pad_rows``),
-    else a slice program, a launch of its own. For rows that stay in HBM
-    (``device_fetch_rows``: exactly ``len(row_ids)`` of them) and for a
-    host-bound bucket whose pad is too large to carry
-    (``_leaving_rows``)."""
+    else a slice program, a launch of its own and a program a distinct
+    ``n``. For rows that stay in HBM (``device_fetch_rows``: exactly
+    ``len(row_ids)`` of them); a host-bound bucket is cut to one of its
+    eighths at most (``_leaving_rows``)."""
     if n == rows.shape[0]:
         return rows
     with crossing.call("slice"):
@@ -113,7 +113,8 @@ def _cut_rows(rows: jax.Array, n: int) -> jax.Array:
 #: (PR 38's chip run, medians of 40): 0.05 MB 0.78 ms against 1.77 ms
 #: with the slice program, 2 MB 1.03 / 1.82, 4 MB 1.26 / 1.77, 8 MB
 #: 1.66 / 1.78. A quarter-octave rung leaves a pad under a quarter of the
-#: rows asked for, so only a Get of more than 16 MB reaches this.
+#: rows asked for, so only a Get of more than 16 MB reaches this. The
+#: same bytes decide how a host delta crosses (``_place_rows``).
 _HOST_CUT_PAD_BYTES = 4 << 20
 
 
@@ -121,22 +122,71 @@ def _leaving_rows(rows: jax.Array, n: int) -> jax.Array:
     """What of a gather's bucket is copied back when its first ``n`` rows
     are wanted ON THE HOST; the caller takes ``[:n]`` of the host array,
     a view, whichever this returns. The pad (``bucket - n`` rows of the
-    trash row) is dropped where it is cheaper: carried back and cut by
-    that view while it is under ``_HOST_CUT_PAD_BYTES`` (no slice
-    program, one launch a Get and not two), cut on the device by
-    ``_cut_rows`` over it. One step of ``table.get.host_cuts`` or
-    ``table.get.device_cuts`` a bucket longer than ``n`` (both
-    registered at 0 by the first)."""
+    trash row) is carried back and cut by that view while it is under
+    ``_HOST_CUT_PAD_BYTES`` (no slice program, one launch a Get and not
+    two) and, whatever its bytes, while it is at most a quarter of the
+    rows asked for, which the bucket ladder's rungs over 256 rows keep
+    it: a block of the WordEmbedding app asks for a million rows, a
+    different count every block, and a program cut to that count would
+    be compiled while the worker waits. A larger pad over the constant
+    (a short bucket of very wide rows; a caller's own bucket) is cut on
+    the device to the shortest of the bucket's EIGHTHS that holds the
+    rows: at most eight programs a bucket, never one a row count. One
+    step of ``table.get.host_cuts`` or ``table.get.device_cuts`` a
+    bucket longer than ``n`` (both registered at 0 by the first)."""
     bucket = rows.shape[0]
     if n == bucket:
         return rows
     host_cuts = tmetrics.counter("table.get.host_cuts")
     device_cuts = tmetrics.counter("table.get.device_cuts")
-    if (bucket - n) * (rows.nbytes // bucket) <= _HOST_CUT_PAD_BYTES:
+    pad = bucket - n
+    if (pad * (rows.nbytes // bucket) <= _HOST_CUT_PAD_BYTES
+            or 4 * pad <= n):
         host_cuts.inc()
         return rows
     device_cuts.inc()
-    return _cut_rows(rows, n)
+    eighth = -(-bucket // 8)
+    return _cut_rows(rows, min(bucket, -(-n // eighth) * eighth))
+
+
+@functools.partial(jax.jit, static_argnames=("bucket",))
+def _join_row_pieces(pieces, last_at, bucket: int):
+    """``_pad_row_batch``'s result from a batch that crossed in pieces of
+    one shape (``_place_rows``): every piece but the last laid end to
+    end, zeros up to ``bucket``, and the last piece, which ENDS at the
+    batch's last row and so overlaps the one before it, written at row
+    ``last_at`` (a traced scalar). One program a piece count, whatever
+    the row count."""
+    body, last = pieces[:-1], pieces[-1]
+    rest = bucket - len(body) * last.shape[0]
+    joined = jnp.concatenate(
+        [*body, jnp.zeros((rest, last.shape[1]), last.dtype)])
+    return lax.dynamic_update_slice(joined, last, (last_at, 0))
+
+
+def _place_rows(deltas: np.ndarray, bucket: int) -> jax.Array:
+    """A host delta batch of a row Add on the device, at ``bucket`` rows
+    (pad delta = 0). While the pad is under ``_HOST_CUT_PAD_BYTES`` the
+    batch crosses exact-size and ``_pad_row_batch`` pads it there: fewer
+    bytes over the boundary, one program a distinct batch size, and the
+    sizes of small verbs repeat. Over it (a batch of more than 16 MB: a
+    block of the WordEmbedding app sends a million rows, a different
+    count every block, and a pad program compiled for each is the
+    sender's wait) it crosses in pieces of an eighth of the bucket,
+    views of the sender's array: no copy and no zeroing on the host. The
+    last piece is the batch's last eighth-of-a-bucket rows, so every
+    piece has one shape, and ``_join_row_pieces`` is one program a piece
+    count. ``table.add.host_pieces`` counts the pieces crossed."""
+    n, piece = deltas.shape[0], bucket // 8
+    pieces = tmetrics.counter("table.add.host_pieces")
+    if ((bucket - n) * deltas[:1].nbytes <= _HOST_CUT_PAD_BYTES
+            or bucket % 8 or n < piece):
+        return _pad_rows(crossing.place(deltas), bucket)
+    body = [deltas[at: at + piece] for at in range(0, n - piece, piece)]
+    placed = crossing.place([*body, deltas[n - piece:]], jax.device_put)
+    pieces.inc(len(placed))
+    with crossing.call("_join_row_pieces"):
+        return _join_row_pieces(placed, np.int32(n - piece), bucket=bucket)
 
 
 def _combine_duplicate_rows(ids: np.ndarray, deltas: np.ndarray,
@@ -920,8 +970,7 @@ class MatrixServerTable(ServerTable):
         with ttrace.span("server.table.add_run.dispatch", cat="server"):
             # ship exact-size deltas; pad them to the bucket on device
             padded_ids = self._device_ids(ids)
-            deltas = _pad_rows(crossing.place(deltas),
-                               padded_ids.shape[0])
+            deltas = _place_rows(deltas, padded_ids.shape[0])
             opt = self._device_opt(option)
             with crossing.call("_update_rows"):
                 self.state = self._update_rows(self.state, padded_ids,

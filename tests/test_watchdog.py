@@ -630,6 +630,24 @@ d = np.ones(2048, np.float32)               # ~24KB per add
 tab0.Add(ids, d)                                        # warm
 tab1.Add(ids, d)
 mv.MV_Barrier()
+
+def alerts_body():
+    url = f"http://127.0.0.1:{ops.port()}/alerts"
+    return json.loads(urllib.request.urlopen(url, timeout=10).read())
+
+# The drill's subject is the burst below. A fresh world's tables being
+# made and first filled IS ledger growth (4.2 -> 5.8 MB here), and under
+# six test workers' load it spans four watchdog ticks, which is the
+# growth rule's whole window: wait, in TICKS and not in seconds, until
+# that window holds the finished tables alone, and judge the flight
+# ring from there on.
+quiet_from = alerts_body()["ticks"]
+deadline = time.time() + 30
+while alerts_body()["ticks"] < quiet_from + 5 and time.time() < deadline:
+    time.sleep(0.05)
+assert alerts_body()["ticks"] >= quiet_from + 5
+burst_from = time.time()
+mv.MV_Barrier()
 # sustained lockstep windows: a FIXED iteration count, never a wall-
 # time bound — with the chaos delay rank 0 runs ~10x slower per
 # window, so a timed loop would let rank 1 admit verbs rank 0 never
@@ -649,10 +667,6 @@ for _ in range(24):
     tab0.Get(ids[:16])
 mv.MV_Barrier()
 
-def alerts_body():
-    url = f"http://127.0.0.1:{ops.port()}/alerts"
-    return json.loads(urllib.request.urlopen(url, timeout=10).read())
-
 # on a fast, idle host the burst ends inside two ticks: the verdict is
 # about at least three, so wait for the third (idle ticks hold the state)
 deadline = time.time() + 5
@@ -663,7 +677,7 @@ assert body["enabled"] and body["ticks"] >= 3, body
 active = sorted(a["rule"] for a in body["alerts"])
 hz = json.loads(urllib.request.urlopen(
     f"http://127.0.0.1:{ops.port()}/healthz", timeout=10).read())
-ring_kinds = {e["kind"] for e in flight.events()}
+ring_kinds = {e["kind"] for e in flight.events() if e["t"] >= burst_from}
 if mode == "straggle" and rank == 0:
     assert "straggler" in active, body
     assert hz["status"] == "warn" and "straggler" in hz["alerts"], hz
