@@ -34,6 +34,21 @@ from multiverso_tpu.utils.log import Log
 from multiverso_tpu.utils.timer import Timer
 
 
+def _steps_to_run(state, pair_count: int, batch: int, nb: int) -> int:
+    """The trip count a block round's program is handed: the batches that
+    hold a pair, ``ceil(pair_count / batch)`` of the ``nb`` laid out
+    (``PairGenerator._finalize_block`` fills the pair axis in order; a last
+    batch with one live lane counts). A state laid over devices of more
+    than one process would make the program a collective one, which every
+    process must run alike whatever its own block holds: there ``nb``
+    (both planes hand over a state of this process's own devices today:
+    a multi-process fetch cuts its rows out of an addressable copy)."""
+    if not all(rows.is_fully_addressable
+               for rows in state if rows is not None):
+        return nb
+    return -(-pair_count // batch)
+
+
 class DistributedWordEmbedding:
     def __init__(self, option: Option):
         self.opt = option
@@ -279,10 +294,22 @@ class DistributedWordEmbedding:
                           opt.total_words, opt.epoch)
 
     def _block_scan_fn(self, state, step):
-        """-> (program, touched): one jit'd program scanning a train step
+        """-> (program, touched): one jit'd program looping a train step
         over a whole block's stacked batches, so a block pays ONE upload +
         ONE dispatch instead of one per batch, and whether that step is
         the touched-rows one.
+
+        The loop runs ``n_live`` steps, the program's last operand (an
+        int32 scalar), and not the ``nb`` its inputs are laid out in:
+        ``make_block`` fills the pair axis in order, so the batches that
+        hold a pair are the first ``ceil(pair_count / pair_batch_size)``
+        and ``nb`` is that count rounded up to a bucket. A batch past them
+        is all zero masks: zero gradients, zero loss, every row it names
+        rewritten with what was read from it, so leaving it out leaves the
+        state and the loss (the sum over all ``nb`` lanes, a batch not run
+        keeping the zero it would return) equal to the bit. The count is
+        an operand and ``nb`` a shape: blocks inside one bucket share one
+        compiled program (``_steps_to_run`` has the host's side).
 
         The step is chosen from the ``state`` the program is handed. Under
         AdaGrad ``step`` (model.make_train_step) streams every row of the
@@ -297,7 +324,7 @@ class DistributedWordEmbedding:
         ``step``, as plain SGD does at any size (its update is the
         scatter-add already).
 
-        A program retraces per distinct batch count and state rung, which
+        A program retraces per distinct ``nb`` and state rung, which
         block sizing and ``communicator.training_rows`` keep to a handful.
         Kept for the trainer's life under what it depends on,
         ``use_adagrad`` (which fixes ``step``) and the choice, so a later
@@ -318,14 +345,20 @@ class DistributedWordEmbedding:
             if touched:
                 step = device_pairs._make_sparse_adagrad_step()
 
-            def run(state, inputs, imask, outputs, labels, omask, lr):
-                def body(st, x):
-                    return step(st, *x, lr)
+            def run(state, inputs, imask, outputs, labels, omask, lr,
+                    n_live):
+                stacked = (inputs, imask, outputs, labels, omask)
+
+                def body(i, carry):
+                    st, losses = carry
+                    st, loss = step(st, *(lax.dynamic_index_in_dim(
+                        a, i, keepdims=False) for a in stacked), lr)
+                    return st, losses.at[i].set(loss)
                 # a stable name in the device trace's op metadata
                 with jax.named_scope("we.block_scan"):
-                    st, losses = lax.scan(body, state,
-                                          (inputs, imask, outputs, labels,
-                                           omask))
+                    st, losses = lax.fori_loop(
+                        0, n_live, body,
+                        (state, jnp.zeros((inputs.shape[0],), jnp.float32)))
                     return st, jnp.sum(losses)
 
             # donate the block state: the fetch path hands this jit its own
@@ -386,8 +419,16 @@ class DistributedWordEmbedding:
                         block.input_rows, block.output_rows)
         with ttrace.span("worker.we.dispatch", cat="worker"):
             program, touched = self._block_scan_fn(state, step)
+            nb = tensors[0].shape[0]
+            n_live = _steps_to_run(state, block.pair_count,
+                                   self.opt.pair_batch_size, nb)
             state, loss_dev = program(
-                state, *tensors, jnp.float32(self._current_lr()))
+                state, *tensors, jnp.float32(self._current_lr()),
+                np.int32(n_live))
+            # host integers both: no device read (-device_pairs steps the
+            # same pair, .run at the harvest from its program's count)
+            tmetrics.counter("we.block.steps.laid_out").inc(nb)
+            tmetrics.counter("we.block.steps.run").inc(n_live)
             if touched:
                 tmetrics.counter("we.block_scan.touched_rows_blocks").inc()
         with ttrace.span("worker.we.push", cat="worker"):

@@ -451,7 +451,7 @@ def scan(specs):
             s((nb, batch, lanes), jnp.int32),
             s((nb, batch, lanes), jnp.float32),
             s((nb, batch, lanes), jnp.float32),
-            s((), jnp.float32)).compile().as_text()
+            s((), jnp.float32), s((), jnp.int32)).compile().as_text()
         aliased = len(re.findall(r"\{\d+\}: \(\d+, \{\}",
                                  hlo.split("\n", 1)[0]))
         kernels = len(re.findall(r"custom_call_target=.tpu_custom_call", hlo))

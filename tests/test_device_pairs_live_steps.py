@@ -163,27 +163,42 @@ def _corpus(path, sentences=160):
                     + "\n")
 
 
-def test_the_harvest_counts_the_steps_run_of_those_laid_out(tmp_path):
-    """``we.block.steps.laid_out`` steps by ``nb`` at a block's dispatch,
-    ``we.block.steps.run`` at its harvest by the third lane of the copy
-    that brings the loss: a CBOW pass, where numpy knows the live
-    batches exactly."""
+#: plane -> the app's options: ``-device_pairs`` (a CBOW pass, where numpy
+#: knows the live batches from the token stream) and the block rounds of
+#: the device plane, the host plane and the host plane's block pipeline
+PLANES = {
+    "device_pairs": dict(negative_num=0, cbow=True, hs=True,
+                         device_pairs=True, is_pipeline=False),
+    "device_plane": dict(negative_num=3, device_plane=True,
+                         is_pipeline=False),
+    "host_plane": dict(negative_num=3, is_pipeline=False),
+    "host_pipeline": dict(negative_num=3, is_pipeline=True),
+}
+
+
+@pytest.mark.parametrize("plane", list(PLANES))
+def test_the_harvest_counts_the_steps_run_of_those_laid_out(tmp_path, plane):
+    """``we.block.steps.laid_out`` steps by ``nb`` at a block's dispatch.
+    ``we.block.steps.run`` steps, a ``-device_pairs`` block, at its
+    harvest by the third lane of the copy that brings the loss and, a
+    block round's, at its dispatch by the host's own count of the
+    batches that hold a pair, ``ceil(pair_count / batch)``: over a
+    ``train()`` both read what numpy counts from the blocks."""
     from multiverso_tpu.models.wordembedding.distributed import (
         DistributedWordEmbedding)
     corpus = tmp_path / "corpus.txt"
     _corpus(str(corpus))
     batch = 64
     opt = Option(train_file=str(corpus), output_file=str(tmp_path / "v.txt"),
-                 embedding_size=16, window_size=2, negative_num=0,
-                 min_count=1, epoch=1, data_block_size=2400,
-                 pair_batch_size=batch, use_adagrad=True, cbow=True, hs=True,
-                 device_pairs=True, is_pipeline=False, seed=11)
+                 embedding_size=16, window_size=2, min_count=1, epoch=1,
+                 data_block_size=2400, pair_batch_size=batch,
+                 use_adagrad=True, seed=11, **PLANES[plane])
     we = DistributedWordEmbedding(opt)
     we.prepare()
     blocks, inner = [], we._train_block
 
     def keeping(block, step):
-        blocks.append(block.token_sent)
+        blocks.append(block)
         return inner(block, step)
     we._train_block = keeping
     names = ("we.block.steps.run", "we.block.steps.laid_out")
@@ -196,13 +211,20 @@ def test_the_harvest_counts_the_steps_run_of_those_laid_out(tmp_path):
         we.close()
     assert len(blocks) >= 3
     want_run = want_laid_out = 0
-    for sent in blocks:
-        t_pad = next_bucket(len(sent), min_bucket=1024)
-        want_laid_out += next_bucket(t_pad // batch, min_bucket=4)
-        pair = sent[1:] == sent[:-1]
-        centre = np.zeros(t_pad, bool)
-        centre[:len(sent)] = np.append(pair, False) | np.append(False, pair)
-        want_run += int(centre.reshape(-1, batch).any(axis=1).sum())
+    for block in blocks:
+        if plane == "device_pairs":
+            sent = block.token_sent
+            t_pad = next_bucket(len(sent), min_bucket=1024)
+            want_laid_out += next_bucket(t_pad // batch, min_bucket=4)
+            pair = sent[1:] == sent[:-1]
+            live = np.zeros(t_pad, bool)
+            live[:len(sent)] = (np.append(pair, False)
+                                | np.append(False, pair))
+        else:
+            want_laid_out += next_bucket(-(-block.pair_count // batch),
+                                         min_bucket=4)
+            live = block.stacked["output_mask"].any(axis=2)
+        want_run += int(live.reshape(-1, batch).any(axis=1).sum())
     assert (run, laid_out) == (want_run, want_laid_out)
     assert 0 < run < laid_out
 

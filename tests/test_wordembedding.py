@@ -769,6 +769,122 @@ class TestBlockScanStep:
         finally:
             mv.MV_ShutDown()
 
+    #: step -> (device_pairs._SPARSE_BYTES, use_adagrad); plain SGD has no
+    #: touched-rows step (its update is the scatter-add at any size)
+    STEPS = {"dense_adagrad": (1 << 60, True), "touched_adagrad": (0, True),
+             "dense_sgd": (0, False)}
+    #: pairs a block holds, at 256 a batch -> (batches that hold one, nb)
+    PAIRS = {"one_pair": (1, 1, 4),
+             "whole_batches": (5 * 256, 5, 8),
+             "one_pair_over_a_bucket": (4 * 256 + 1, 5, 8),
+             "a_full_bucket": (4 * 256, 4, 4)}
+
+    def _cut_blocks(self, we, pair_counts):
+        """Blocks of the same sentences cut to ``pair_counts`` pairs."""
+        from multiverso_tpu.models.wordembedding.data import PairGenerator
+        generator = PairGenerator(we.opt, we.dictionary, we.sampler,
+                                  we.huffman)
+        rng = np.random.default_rng(5)
+        arrays = generator._skipgram_neg_arrays(
+            [rng.integers(0, 20, 12).astype(np.int32) for _ in range(60)])
+        assert len(arrays[0]) > max(pair_counts)
+        return [generator._finalize_block(*(a[:n] for a in arrays),
+                                          word_count=720)
+                for n in pair_counts]
+
+    @pytest.mark.parametrize("pairs", list(PAIRS))
+    @pytest.mark.parametrize("step", list(STEPS))
+    def test_the_live_steps_leave_what_every_laid_out_step_leaves(
+            self, tmp_path, monkeypatch, step, pairs):
+        """The program handed ``ceil(pair_count / batch)`` leaves the state
+        and the loss that the program handed ``nb`` (every laid-out step,
+        what ``lax.scan`` ran) leaves, entry for entry; one step fewer does
+        not: the last batch that holds a pair, be it one, is trained."""
+        import jax.numpy as jnp
+        from multiverso_tpu.models.wordembedding.distributed import (
+            _steps_to_run)
+        threshold, use_adagrad = self.STEPS[step]
+        pair_count, n_live, nb = self.PAIRS[pairs]
+        mv = self._world(monkeypatch, threshold)
+        try:
+            we = self._trainer(tmp_path, device_plane=True,
+                               use_adagrad=use_adagrad)
+            (block,) = self._cut_blocks(we, [pair_count])
+            assert block.pair_count == pair_count
+            st = block.stacked
+            assert st["inputs"].shape[0] == nb
+            # the pairs fill the laid-out lanes in order: what the count
+            # the host hands over rests on
+            live = st["output_mask"].reshape(nb, -1).any(axis=1)
+            assert live.tolist() == [True] * n_live + [False] * (nb - n_live)
+            tensors = [jnp.asarray(st[k]) for k in (
+                "inputs", "input_mask", "outputs", "labels", "output_mask")]
+
+            def run(steps):
+                state, _ = we.comm.request_parameter_device(
+                    block.input_rows, block.output_rows)
+                assert _steps_to_run(state, block.pair_count,
+                                     we.opt.pair_batch_size, nb) == n_live
+                program, touched = we._block_scan_fn(state, we._step)
+                assert touched == (step == "touched_adagrad")
+                state, loss = program(state, *tensors, jnp.float32(0.1),
+                                      np.int32(steps))
+                return ([np.asarray(rows) for rows in state
+                         if rows is not None], np.asarray(loss))
+
+            (live_state, live_loss), (full_state, full_loss), \
+                (short_state, short_loss) = run(n_live), run(nb), \
+                run(n_live - 1)
+            assert len(live_state) == (4 if use_adagrad else 2)
+            for got, want in zip(live_state, full_state):
+                assert np.array_equal(got, want)
+            assert live_loss.tobytes() == full_loss.tobytes()
+            assert live_loss > 0
+            assert short_loss < live_loss
+            assert not all(np.array_equal(got, short)
+                           for got, short in zip(live_state, short_state))
+            (program,) = we._block_scan_cache.values()
+            assert program._cache_size() == 1
+        finally:
+            mv.MV_ShutDown()
+
+    def test_a_state_over_more_than_one_process_runs_every_laid_out_step(
+            self):
+        """The trip count is the host's own block's: a program that is one
+        collective program over processes gets ``nb`` on each of them."""
+        import collections
+        from multiverso_tpu.models.wordembedding.distributed import (
+            _steps_to_run)
+        Rows = collections.namedtuple("Rows", "is_fully_addressable")
+        here, spread = Rows(True), Rows(False)
+        assert _steps_to_run((here, here, None, None), 257, 256, 4) == 2
+        assert _steps_to_run((here, here, here, here), 256, 256, 4) == 1
+        assert _steps_to_run((here, spread, here, spread), 257, 256, 4) == 4
+
+    def test_pair_counts_inside_a_bucket_share_one_scan_program(
+            self, tmp_path, monkeypatch):
+        """The trip count is an operand and never a shape: blocks of 300
+        and 700 pairs (2 and 3 batches of the 4 laid out) dispatch ONE
+        compiled program, and the two counters read the steps run of
+        those laid out."""
+        mv = self._world(monkeypatch, 0)
+        try:
+            we = self._trainer(tmp_path, device_plane=True)
+            blocks = self._cut_blocks(we, [300, 700])
+            assert len({b.stacked["inputs"].shape for b in blocks}) == 1
+            names = ("we.block_program.builds", "we.block.steps.run",
+                     "we.block.steps.laid_out")
+            before = [_counter(n) for n in names]
+            for block in blocks:
+                loss, pairs = we._train_block(block, we._step)
+                assert float(loss) > 0 and pairs == block.pair_count
+            assert [_counter(n) - b for n, b in zip(names, before)] == [
+                1, 2 + 3, 4 + 4]
+            (program,) = we._block_scan_cache.values()
+            assert program._cache_size() == 1
+        finally:
+            mv.MV_ShutDown()
+
 
 class TestDevicePairsStats:
     def test_stats_lanes_exact_and_flush_proof(self):
