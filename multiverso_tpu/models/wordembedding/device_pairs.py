@@ -64,8 +64,8 @@ from multiverso_tpu.telemetry import trace as ttrace
 
 
 class _LazyStats:
-    """One element of a shared (3,) INT32 device stats array (loss
-    bits, pair count, steps the loop ran);
+    """One element of a shared (4,) INT32 device stats array (loss
+    bits, pair count, steps the loop ran, row lanes their updates ran);
     float()/int() fetch the WHOLE array once (cached on the array handle
     by jax), so a block's loss+pairs harvest costs one transfer. The
     array is integer-typed with the f32 loss BITCAST into lane 0: the
@@ -129,7 +129,11 @@ def _make_sparse_adagrad_step(eps: float = 1e-10, lanes=None):
     rows are summed (all but one are zero: the sum is exact); the update
     needs nothing from another shard — every shard holds the batch's
     deduplicated gradients and writes the rows it owns, the others' lanes
-    and the pad lanes going to its own trash row."""
+    and the pad lanes going to its own trash row.
+
+    Returns ``(state, loss, lanes_run)``: the row lanes the two tables'
+    updates ran, of the ``inputs.size + outputs.size`` the batch laid
+    out (all of them per shard, where ``lanes`` is given)."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -165,10 +169,7 @@ def _make_sparse_adagrad_step(eps: float = 1e-10, lanes=None):
         eo_contrib = err[:, :, None] * h[:, None, :]
         ie_contrib = hid_err[:, None, :] * imask[:, :, None]
 
-        @jax.named_scope("we.device_pairs_step.update")
-        def row_update(tab, g2tab, ids, contrib):
-            uids, grads = ops.dedup_rows(ids.reshape(-1),
-                                         contrib.reshape(-1, D))
+        def update(tab, g2tab, uids, grads):
             if lanes is None:
                 trash = tab.shape[0] - 1
                 uids = jnp.where(uids < 0, trash, uids)
@@ -184,9 +185,40 @@ def _make_sparse_adagrad_step(eps: float = 1e-10, lanes=None):
             return (ops.scatter_set_rows(tab, uids, rows, dense=dense),
                     ops.scatter_set_rows(g2tab, uids, g2_rows, dense=dense))
 
-        eo, eo_g2 = row_update(eo, state.eo_g2, outputs, eo_contrib)
-        ie, ie_g2 = row_update(ie, state.ie_g2, inputs, ie_contrib)
-        return TrainState(ie, eo, ie_g2, eo_g2), loss
+        @jax.named_scope("we.device_pairs_step.update")
+        def row_update(tab, g2tab, ids, contrib):
+            """-> (tab, g2tab, the lanes the update ran)."""
+            uids, grads = ops.dedup_rows(ids.reshape(-1),
+                                         contrib.reshape(-1, D))
+            n, chunk = uids.shape[0], ids.shape[0]
+            if lanes is not None or n == chunk:
+                return (*update(tab, g2tab, uids, grads), n)
+            # dedup_rows leaves the batch's distinct ids in its first
+            # lanes, ascending, and -1 in every lane after them (an eighth
+            # of a CBOW + HS batch's lanes hold a distinct row): the update
+            # walks those first lanes in chunks of one batch's pairs and
+            # stops after the last that holds one, a trip count the device
+            # reads. A row's arithmetic is its own lane's, so the live
+            # rows come out as the update of all n lanes leaves them; the
+            # trash row gets fewer writes. The chunk divides n (a whole
+            # number of lanes a pair), so no two chunks overlap, and it is
+            # one shape for every table and lane count: the row kernel is
+            # traced once, and writes every chunk (an id vector over
+            # ops.SMEM_IDS_BYTES whole would be XLA's scatter)
+            live = jnp.max(jnp.where(uids >= 0, jnp.arange(1, n + 1), 0))
+            trips = (live + chunk - 1) // chunk
+
+            def body(i, tables):
+                at = i * chunk
+                return update(*tables,
+                              lax.dynamic_slice(uids, (at,), (chunk,)),
+                              lax.dynamic_slice(grads, (at, 0), (chunk, D)))
+            tab, g2tab = lax.fori_loop(0, trips, body, (tab, g2tab))
+            return tab, g2tab, trips * chunk
+
+        eo, eo_g2, eo_run = row_update(eo, state.eo_g2, outputs, eo_contrib)
+        ie, ie_g2, ie_run = row_update(ie, state.ie_g2, inputs, ie_contrib)
+        return TrainState(ie, eo, ie_g2, eo_g2), loss, eo_run + ie_run
 
     return step
 
@@ -277,11 +309,28 @@ class DevicePairsTrainer:
 
     # -- the block program --------------------------------------------------
 
+    def _sparse(self) -> bool:
+        """Whether the block program's step is the touched-rows one."""
+        data = self.comm.input_table.server().state["data"]
+        return bool(self.opt.use_adagrad
+                    and data.size * data.dtype.itemsize > _SPARSE_BYTES)
+
+    @property
+    def step_update_lanes(self) -> int:
+        """The row lanes the touched-rows step's two updates lay out a
+        batch, a lane each input and output of a pair (0 under the dense
+        steps): what ``we.update.lanes.laid_out`` counts a step run."""
+        opt = self.opt
+        if not self._sparse():
+            return 0
+        return opt.pair_batch_size * (
+            (2 * opt.window_size if opt.cbow else 1)
+            + (self._max_code if opt.hs else 1 + opt.negative_num))
+
     def _program(self, t_pad: int, nb: int):
         opt = self.opt
         srv = self.comm.input_table.server()
-        table_bytes = srv.state["data"].size * srv.state["data"].dtype.itemsize
-        sparse = opt.use_adagrad and table_bytes > _SPARSE_BYTES
+        sparse = self._sparse()
         # storage of more than one shard: the touched-rows step runs per
         # shard (its row kernel cannot be partitioned by the compiler, and
         # no chip may hold a table whole); one shard compiles what it
@@ -422,27 +471,33 @@ class DevicePairsTrainer:
             order = jnp.argsort(~live, stable=True)
 
             def body(i, carry):
-                st, losses = carry
+                st, losses, lanes_run = carry
                 at = order[i]
-                st, loss = step(st, *(lax.dynamic_index_in_dim(
+                # the touched-rows step says how many row lanes its
+                # updates ran; the dense steps have no lanes to say
+                st, loss, *ran = step(st, *(lax.dynamic_index_in_dim(
                     a, at, keepdims=False) for a in stacked), lr)
-                return st, losses.at[at].set(loss)
+                return st, losses.at[at].set(loss), lanes_run + sum(ran)
 
             # a dead batch's loss is the zero its step would return: the
             # block's sum is over all nb, and keeps its bits
-            state, losses = lax.fori_loop(
-                0, n_live, body, (state, jnp.zeros((nb,), jnp.float32)))
+            state, losses, lanes_run = lax.fori_loop(
+                0, n_live, body,
+                (state, jnp.zeros((nb,), jnp.float32), jnp.int32(0)))
             out = ((state.ie, state.eo, state.ie_g2, state.eo_g2)
                    if use_adagrad else (state.ie, state.eo))
-            # ONE (3,) INT32 stats array: the caller's lazy harvest pays
-            # a single host fetch per block instead of three.
+            # ONE (4,) INT32 stats array: the caller's lazy harvest pays
+            # a single host fetch per block instead of four.
             # The f32 loss rides as raw BITS in lane 0 (see _LazyStats —
             # an f32-typed array would flush the bitcast count lane as a
-            # denormal on TPU); lane 2 is the steps the loop ran.
+            # denormal on TPU); lane 2 is the steps the loop ran, lane 3
+            # the row lanes the touched-rows step ran its updates at (0
+            # under the dense steps; int32 holds a 100MB block's 22,000
+            # skip-gram steps of 57,344 lanes).
             loss_bits = lax.bitcast_convert_type(
                 jnp.sum(losses).astype(jnp.float32), jnp.int32)
-            stats = jnp.stack([loss_bits,
-                               jnp.sum(pmask).astype(jnp.int32), n_live])
+            stats = jnp.stack([loss_bits, jnp.sum(pmask).astype(jnp.int32),
+                               n_live, lanes_run])
             return out, stats
 
         if sharded:
@@ -573,7 +628,8 @@ class DevicePairsTrainer:
                 self._take_states(), aux, ids_g, sent_g, key,
                 jnp.float32(lr))
         self._put_states(states)
-        # stats is a (3,) int32 device array; one np.asarray in the
-        # harvest fetches its scalars (lane 0 is the bitcast f32 loss,
-        # lane 2, the steps run, is reached from the pair count's handle)
+        # stats is a (4,) int32 device array; one np.asarray in the
+        # harvest fetches its scalars (lane 0 is the bitcast f32 loss;
+        # lanes 2 and 3, the steps run and the row lanes their updates
+        # ran, are reached from the pair count's handle)
         return _LazyStats(stats, 0, bits=True), _LazyStats(stats, 1)

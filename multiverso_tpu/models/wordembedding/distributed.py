@@ -157,6 +157,10 @@ class DistributedWordEmbedding:
         # touched-rows step (_block_scan_fn)
         tmetrics.counter("we.block_scan.touched_rows_blocks")
         m_steps_run = tmetrics.counter("we.block.steps.run")
+        # a -device_pairs block's touched-rows updates: the row lanes the
+        # device ran them at, of those the steps run laid out
+        m_lanes_run = tmetrics.counter("we.update.lanes.run")
+        m_lanes_laid_out = tmetrics.counter("we.update.lanes.laid_out")
         # registered at 0 too: the blocks that trained on prefetched rows
         tmetrics.counter("we.pipeline.prefetched_blocks")
 
@@ -175,8 +179,13 @@ class DistributedWordEmbedding:
                     if hasattr(pairs, "lane"):
                         # a -device_pairs block: the steps its program's
                         # loop ran, of the nb laid out (third lane of the
-                        # copy just made)
-                        m_steps_run.inc(int(pairs.lane(2)))
+                        # copy just made), and the row lanes their
+                        # updates ran (the fourth) of those they laid out
+                        steps = int(pairs.lane(2))
+                        m_steps_run.inc(steps)
+                        m_lanes_run.inc(int(pairs.lane(3)))
+                        m_lanes_laid_out.inc(
+                            steps * self._dp_trainer.step_update_lanes)
 
         from multiverso_tpu.parallel import multihost
         from multiverso_tpu.utils.log import CHECK
@@ -343,7 +352,10 @@ class DistributedWordEmbedding:
             from jax import lax
 
             if touched:
-                step = device_pairs._make_sparse_adagrad_step()
+                # (state, loss) like ``step``: the lanes its updates ran
+                # are the -device_pairs harvest's to count
+                sparse = device_pairs._make_sparse_adagrad_step()
+                step = lambda *args: sparse(*args)[:2]  # noqa: E731
 
             def run(state, inputs, imask, outputs, labels, omask, lr,
                     n_live):

@@ -28,6 +28,13 @@ program over four shards at ``we_pairs_4c``'s shapes (``PAIRS``) and
 prints its collectives, its Mosaic kernels, how many instructions hold a
 table's whole rows and its table-sized passes.
 
+``--block`` compiles the same block program on ONE chip at ``we_cbow_hs``'
+and ``we_pairs``' shapes (``BLOCK``) and prints its Mosaic kernels, its
+``while`` loops (the block's, and one a table whose update walks its
+distinct rows in chunks), how many of the four tables are aliased input
+to output, its passes over a whole table and the MiB of temporaries the
+compiler gives it beside its operands.
+
 ``--scan`` compiles the WordEmbedding app's block-round scan program
 (``models/wordembedding/distributed.py`` ``_block_scan_fn``) on one chip
 at ``we_rows``' shapes (``SCAN``): a state over ``_SPARSE_BYTES``, so the
@@ -371,8 +378,50 @@ def read(specs):
                     print("  PASS", ln, flush=True)
 
 
-# (name, vocabulary, chips, tokens a block padded, batches a block)
-PAIRS = [("we_pairs_4c", 8_388_600, 4, 163_840, 256)]
+# (name, vocabulary, chips, tokens a block padded, batches a block, the
+# app's objective, the longest Huffman code)
+PAIRS = [("we_pairs_4c", 8_388_600, 4, 163_840, 256, {}, 0)]
+# one chip: we_cbow_hs' blocks of 131,080 tokens (a lane a token, 20 batches
+# laid out in 32) and we_pairs' (ten lanes a token, 200 batches in 256)
+BLOCK = [("we_cbow_hs", 2_097_100, 1, 163_840, 32,
+          dict(cbow=True, hs=True, negative_num=0), 27),
+         ("we_pairs", 2_097_100, 1, 163_840, 256, {}, 0)]
+_KERNEL = r"custom_call_target=.tpu_custom_call"
+
+
+def _block_program(vocab, chips, t_pad, nb, objective, max_code):
+    """-> (``device_pairs``' block program over ``chips`` shards of four
+    ``vocab`` x 128 tables, compiled; a table's server)."""
+    from multiverso_tpu.models.wordembedding import device_pairs as dp
+    from multiverso_tpu.models.wordembedding.option import Option
+    ctx = MeshContext.create(_devices(chips))
+    zoo = types.SimpleNamespace(mesh_ctx=ctx, num_workers=1)
+    with _no_allocation():
+        srvs = [MatrixServerTable(vocab, 128, np.float32, zoo, "default")
+                for _ in range(4)]
+    tables = [types.SimpleNamespace(server=lambda s=s: s) for s in srvs]
+    trainer = dp.DevicePairsTrainer.__new__(dp.DevicePairsTrainer)
+    trainer.opt = Option(**{**dict(
+        embedding_size=128, window_size=5, negative_num=5, use_adagrad=True,
+        device_pairs=True, pair_batch_size=8192), **objective})
+    trainer.comm = types.SimpleNamespace(
+        input_table=tables[0], output_table=tables[1],
+        ie_g2_table=tables[2], eo_g2_table=tables[3])
+    trainer._max_code = max_code
+    whole = (NamedSharding(ctx.mesh, P()) if chips > 1
+             else SingleDeviceSharding(ctx.mesh.devices.flat[0]))
+    s = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=whole)
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    aux = ((s((vocab, max_code), jnp.int32),
+            s((vocab, -(-max_code // 32)), jnp.uint32),
+            s((vocab,), jnp.int32)) if trainer.opt.hs
+           else (s((1 << 24,), jnp.int32),))
+    compiled = trainer._program(t_pad, nb).lower(
+        tuple(srv.state["data"] for srv in srvs), aux,
+        s((t_pad,), jnp.int32), s((t_pad,), jnp.int32),
+        s(key.shape, key.dtype), s((), jnp.float32)).compile()
+    return compiled, srvs[0]
 
 
 def pairs(specs):
@@ -383,39 +432,37 @@ def pairs(specs):
     kernels (the four row writes of the touched-rows step), instructions
     that hold an array of a table's WHOLE stored rows (a table gathered
     onto one chip) and passes over a shard outside the in-place writes."""
-    from multiverso_tpu.models.wordembedding import device_pairs as dp
-    from multiverso_tpu.models.wordembedding.option import Option
-    for name, vocab, chips, t_pad, nb in specs:
-        ctx = MeshContext.create(_devices(chips))
-        zoo = types.SimpleNamespace(mesh_ctx=ctx, num_workers=1)
-        with _no_allocation():
-            srvs = [MatrixServerTable(vocab, 128, np.float32, zoo, "default")
-                    for _ in range(4)]
-        tables = [types.SimpleNamespace(server=lambda s=s: s) for s in srvs]
-        trainer = dp.DevicePairsTrainer.__new__(dp.DevicePairsTrainer)
-        trainer.opt = Option(embedding_size=128, window_size=5,
-                             negative_num=5, use_adagrad=True,
-                             device_pairs=True, pair_batch_size=8192)
-        trainer.comm = types.SimpleNamespace(
-            input_table=tables[0], output_table=tables[1],
-            ie_g2_table=tables[2], eo_g2_table=tables[3])
-        whole = NamedSharding(ctx.mesh, P())
-        s = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
-            shape, dtype, sharding=whole)
-        key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
-        hlo = trainer._program(t_pad, nb).lower(
-            tuple(srv.state["data"] for srv in srvs),
-            (s((1 << 24,), jnp.int32),), s((t_pad,), jnp.int32),
-            s((t_pad,), jnp.int32), s(key.shape, key.dtype),
-            s((), jnp.float32)).compile().as_text()
+    for name, *spec in specs:
+        compiled, srv = _block_program(*spec)
+        hlo = compiled.as_text()
         count = lambda pat: len(re.findall(pat, hlo))  # noqa: E731
-        passes = table_sized_passes(hlo, srvs[0].shard_rows * 128)
+        passes = table_sized_passes(hlo, srv.shard_rows * 128)
         print(f"PAIRS {name} block_program "
               f"all_gather={count(r' all-gather(-start)?[(]')} "
               f"all_reduce={count(r' all-reduce(-start)?[(]')} "
-              f"kernels={count(r'custom_call_target=.tpu_custom_call')} "
-              f"whole_table={count(rf'[[]{srvs[0].padded_rows},128[]]')} "
+              f"kernels={count(_KERNEL)} "
+              f"whole_table={count(rf'[[]{srv.padded_rows},128[]]')} "
               f"passes={len(passes)}", flush=True)
+        for ln in passes:
+            print("  PASS", ln, flush=True)
+
+
+def block(specs):
+    """BLOCK <cell> block_program kernels=<n> whiles=<n> aliased=<n>/4
+    passes=<n> temp_mb=<n>: the one-chip block program compiled for the
+    chip."""
+    for name, *spec in specs:
+        compiled, srv = _block_program(*spec)
+        hlo = compiled.as_text()
+        aliased = len(re.findall(r"\{\d+\}: \(\d+, \{\}",
+                                 hlo.split("\n", 1)[0]))
+        passes = table_sized_passes(hlo, srv.shard_rows * 128)
+        print(f"BLOCK {name} block_program "
+              f"kernels={len(re.findall(_KERNEL, hlo))} "
+              f"whiles={len(re.findall(r' while[(]', hlo))} "
+              f"aliased={aliased}/4 passes={len(passes)} temp_mb="
+              f"{compiled.memory_analysis().temp_size_in_bytes >> 20}",
+              flush=True)
         for ln in passes:
             print("  PASS", ln, flush=True)
 
@@ -454,7 +501,7 @@ def scan(specs):
             s((), jnp.float32), s((), jnp.int32)).compile().as_text()
         aliased = len(re.findall(r"\{\d+\}: \(\d+, \{\}",
                                  hlo.split("\n", 1)[0]))
-        kernels = len(re.findall(r"custom_call_target=.tpu_custom_call", hlo))
+        kernels = len(re.findall(_KERNEL, hlo))
         passes = table_sized_passes(hlo, ie.shape[0] * 128)
         print(f"SCAN {name} block_scan touched={touched} kernels={kernels} "
               f"aliased={aliased}/4 passes={len(passes)}", flush=True)
@@ -470,6 +517,7 @@ if __name__ == "__main__":
     ap.add_argument("--tiny", action="store_true")
     ap.add_argument("--read", action="store_true")
     ap.add_argument("--pairs", action="store_true")
+    ap.add_argument("--block", action="store_true")
     ap.add_argument("--scan", action="store_true")
     ap.add_argument("--locations", action="store_true")
     a = ap.parse_args()
@@ -488,6 +536,8 @@ if __name__ == "__main__":
         read(READ)
     if a.pairs:
         pairs(PAIRS)
+    if a.block:
+        block(BLOCK)
     if a.scan:
         scan(SCAN)
     if a.locations:

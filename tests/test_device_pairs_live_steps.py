@@ -80,8 +80,8 @@ class _World:
             states, aux, *tr._put((pad(ids), pad(sent))),
             jax.random.PRNGKey(5), jnp.float32(LR))
         stats = np.asarray(stats)
-        assert stats.shape == (3,) and stats.dtype == np.int32
-        return [np.asarray(t) for t in out], *(int(v) for v in stats)
+        assert stats.shape == (4,) and stats.dtype == np.int32
+        return [np.asarray(t) for t in out], *(int(v) for v in stats[:3])
 
 
 def _tokens(sentences=40, length=12):

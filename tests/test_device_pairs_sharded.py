@@ -120,8 +120,8 @@ def _sharded_step(world):
     step = dp._make_sparse_adagrad_step(lanes=srv.device_local_lanes)
 
     def run(states, inputs, imask, outputs, labels, omask, lr):
-        state, loss = step(TrainState(*states), inputs, imask, outputs,
-                           labels, omask, lr)
+        state, loss, _ = step(TrainState(*states), inputs, imask, outputs,
+                              labels, omask, lr)
         return tuple(state), loss
     rows, whole = P(SERVER_AXIS, None), P()
     return jax.jit(jax.shard_map(

@@ -213,11 +213,11 @@ class TestStatefulRowProgramsAliasOnTpu:
     def compiled(self):
         import re
         lines, out = _aot_table_programs("--alias", "--tiny", "--read",
-                                         "--pairs", "--scan")
+                                         "--pairs", "--scan", "--block")
         found = {}
         for ln in lines:
-            m = re.match(r"(?:ALIAS|TINY|READ|PAIRS|SCAN) (\S+) (\S+) (.*)",
-                         ln)
+            m = re.match(
+                r"(?:ALIAS|TINY|READ|PAIRS|SCAN|BLOCK) (\S+) (\S+) (.*)", ln)
             if m:
                 found[m.group(1), m.group(2)] = m.group(3)
         return found, out
@@ -257,6 +257,28 @@ class TestStatefulRowProgramsAliasOnTpu:
         assert ("we_rows", "block_scan") in found, out[-2000:]
         assert found["we_rows", "block_scan"] == (
             "touched=True kernels=4 aliased=4/4 passes=0"), out[-3000:]
+
+    @pytest.mark.parametrize("cell,whiles", [
+        # the block's loop and one a table whose update walks its distinct
+        # rows in chunks: both tables under CBOW + HS (81,920 and 221,184
+        # lanes a step), the output table under skip-gram (49,152; its
+        # input update is a lane a pair, no loop)
+        ("we_cbow_hs", 3), ("we_pairs", 2)])
+    def test_block_program_updates_in_place_inside_its_loops(
+            self, compiled, cell, whiles):
+        """``we_cbow_hs`` and ``we_pairs`` (PERF.md section 6, PR 49): the
+        one-chip ``-device_pairs`` block program compiles for a v5e with
+        every row write on the kernel (four calls: a chunk of one batch's
+        pairs is within ``ops.SMEM_IDS_BYTES`` whatever the lanes a step,
+        where CBOW + HS's 221,184 output lanes whole were XLA's scatter),
+        all four tables aliased input to output and no pass over a table
+        inside the update's loop (its dense-run ``cond`` included)."""
+        found, out = compiled
+        assert (cell, "block_program") in found, out[-2000:]
+        got = found[cell, "block_program"].split()
+        assert got[:4] == ["kernels=4", f"whiles={whiles}", "aliased=4/4",
+                           "passes=0"], out[-3000:]
+        assert got[4].startswith("temp_mb=")
 
     @pytest.mark.parametrize("rows", [1, 2, 4, 5])
     @pytest.mark.parametrize("program,kernels", [

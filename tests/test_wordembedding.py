@@ -707,6 +707,42 @@ class TestBlockScanStep:
                                        got["dense"][name],
                                        rtol=2e-5, atol=2e-6, err_msg=name)
 
+    @pytest.mark.parametrize("mode", ["skipgram_neg", "cbow"])
+    @pytest.mark.parametrize("device_plane", [0, 1])
+    def test_the_looped_update_is_the_update_of_every_lane(
+            self, tmp_path, monkeypatch, device_plane, mode):
+        """Same seed and corpus, the scan's touched-rows step as it is
+        (an update walks the batch's distinct rows in chunks of 64 pairs:
+        four lanes a pair out, four in under CBOW) and with the update of
+        every lane in its place: the four tables after two passes equal
+        to the bit, and the loss the same float."""
+        from multiverso_tpu.models.wordembedding import device_pairs
+        from tests.test_we_cbow_hs import full_lane_step
+        looped = device_pairs._make_sparse_adagrad_step
+        got = {}
+        for kind, make in (("looped", looped), ("full_lane", lambda: (
+                lambda *args: (*full_lane_step()(*args), 0)))):
+            monkeypatch.setattr(device_pairs, "_make_sparse_adagrad_step",
+                                make)
+            mv = self._world(monkeypatch, 0)
+            try:
+                (tmp_path / kind).mkdir()
+                we = self._trainer(tmp_path / kind, epoch=2,
+                                   pair_batch_size=64,
+                                   device_plane=bool(device_plane),
+                                   cbow=mode == "cbow")
+                touched = _counter("we.block_scan.touched_rows_blocks")
+                loss = we.train()
+                assert _counter("we.block_scan.touched_rows_blocks") > touched
+                got[kind] = dict(self._tables(we), loss=loss)
+            finally:
+                mv.MV_ShutDown()
+        assert got["looped"]["loss"] == got["full_lane"]["loss"]
+        for name in self.TABLES:
+            assert got["looped"][name].any()
+            assert np.array_equal(got["looped"][name],
+                                  got["full_lane"][name]), name
+
     @pytest.mark.parametrize("case, threshold, kw, one_device, touched", [
         ("default_constant_tiny_vocabulary", None, {}, True, False),
         ("threshold_0", 0, {}, True, True),
