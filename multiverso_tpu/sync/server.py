@@ -3040,7 +3040,30 @@ class ShardedServer(Server):
 
 
 class SyncServer(Server):
-    """BSP server (reference server.cpp:60-222). See module docstring."""
+    """BSP server (reference server.cpp:60-222). See module docstring.
+
+    What on its path differs from the windowed engine (``Server``), all
+    of it because the vector clocks count single Get and Add messages
+    and decide to defer or drain one message at a time:
+
+    * no window: ``_get_entry`` / ``_add_entry`` process one admitted
+      verb (a "window" of 1), so several workers' Adds are never merged
+      into one ``ProcessAddRun`` and queued Gets never share a dispatch;
+    * one engine shard whatever ``-mv_engine_shards`` says
+      (``engine_shard_cap``): the clocks span all tables;
+    * no worker-side write combining, no Get cache, no batched
+      envelopes (the three class flags below);
+    * the table verbs are the blocking ones: every Add a lone
+      ``ProcessAdd``, every Get a ``ProcessGet`` that copies its rows
+      back on this thread before the next message is looked at.
+
+    A Get that arrives before its round's last Add waits in
+    ``_get_cache`` and is served by the drain that Add sets off; an Add
+    of a worker whose Get ran ahead of the get round waits in
+    ``_add_cache``. The benchmark's cell ``mt_bsp_rounds``
+    (``benchmark/runners/table_bsp_rounds.py``, PERF.md sections 4 to 6)
+    measures this path on the chip; its per-layer metrics read the
+    ``server.bsp.*`` spans and instruments below."""
 
     #: the vector-clock protocol counts Get/Add MESSAGES per worker:
     #: worker-side write combining / get caching would break the round
@@ -3050,6 +3073,11 @@ class SyncServer(Server):
     #: ...and batched envelopes would hide N clock ticks inside one
     #: message — Zoo.SendToServerMulti delivers members individually
     MULTI_VERB_OK = False
+    #: rounds whose first Add has been admitted and whose last Get has
+    #: not been answered, at most (``server.bsp.round_s``): workers that
+    #: alternate Add and Get keep one or two open; a world that only
+    #: Adds would otherwise grow the record for ever
+    _ROUNDS_OPEN_MAX = 64
 
     def __init__(self, num_workers: int):
         super().__init__()
@@ -3075,29 +3103,81 @@ class SyncServer(Server):
         #: MAX-merge gauge: the job-wide number is the worst rank's
         #: skew, not a sum over ranks
         self._t_staleness = tmetrics.max_gauge("server.bsp.staleness")
+        #: telemetry of the rounds, all on this thread: add rounds
+        #: completed; Gets and Adds the clocks saw, and those of them a
+        #: cache held first; a round's length, from the admission of its
+        #: first Add to the reply of the Get that completes the next get
+        #: round (sum and count exact, as server.window.latency_s)
+        self._t_rounds = tmetrics.counter("server.bsp.rounds")
+        self._t_gets = tmetrics.counter("server.bsp.gets")
+        self._t_gets_cached = tmetrics.counter("server.bsp.gets_cached")
+        self._t_adds = tmetrics.counter("server.bsp.adds")
+        self._t_adds_cached = tmetrics.counter("server.bsp.adds_cached")
+        self._t_round_s = tmetrics.histogram("server.bsp.round_s")
+        #: Adds each worker has sent: the round its next Add belongs to
+        self._adds_seen = [0] * num_workers
+        self._rounds_begun = 0
+        #: when the first Add of each open round was admitted, oldest
+        #: first; the get round that completes closes the oldest
+        self._round_t0: Deque[float] = collections.deque(
+            maxlen=self._ROUNDS_OPEN_MAX)
+        #: the open ``server.bsp.get_hold`` span of each cached Get, in
+        #: the get cache's order (the shared no-op while -trace is off)
+        self._get_holds: Deque = collections.deque()
 
     def _note_staleness(self) -> None:
         self._t_staleness.set(max(self._get_clocks.staleness(),
                                   self._add_clocks.staleness()))
 
+    def _drain_gets(self) -> None:
+        """Serve the cached Gets, one blocking gather and copy back
+        after another: the round's last Add has landed (or the last
+        worker still adding has finished training)."""
+        if not self._get_cache:
+            return
+        with ttrace.span("server.bsp.drain", cat="server"):
+            while self._get_cache:
+                get_msg = self._get_cache.popleft()
+                self._get_holds.popleft().end()
+                super().ProcessGet(get_msg)
+                CHECK(not self._get_clocks.Update(get_msg.src),
+                      "drained Get must not complete a round")
+
+    def _drain_adds(self) -> None:
+        """Apply the cached Adds: the get round they ran ahead of is
+        complete."""
+        if not self._add_cache:
+            return
+        with ttrace.span("server.bsp.drain", cat="server"):
+            while self._add_cache:
+                add_msg = self._add_cache.popleft()
+                super().ProcessAdd(add_msg)
+                CHECK(not self._add_clocks.Update(add_msg.src),
+                      "drained Add must not complete a round")
+                self._num_waited_add[add_msg.src] -= 1
+
     def ProcessAdd(self, msg: Message) -> None:
         worker = msg.src
+        self._t_adds.inc()
+        sent = self._adds_seen[worker]
+        self._adds_seen[worker] = sent + 1
+        if sent >= self._rounds_begun:      # the first Add of a round
+            self._rounds_begun = sent + 1
+            self._round_t0.append(_time.perf_counter())
         # 1. Before add: cache faster worker (server.cpp:141-147)
         if self._get_clocks.local_clock(worker) > self._get_clocks.global_clock():
             self._add_cache.append(msg)
             self._num_waited_add[worker] += 1
+            self._t_adds_cached.inc()
             self._note_staleness()
             return
         # 2. Process add
         super().ProcessAdd(msg)
         # 3. After add: drain cached gets when the add round completes
         if self._add_clocks.Update(worker):
+            self._t_rounds.inc()
             CHECK(not self._add_cache, "add cache must be empty at round end")
-            while self._get_cache:
-                get_msg = self._get_cache.popleft()
-                super().ProcessGet(get_msg)
-                CHECK(not self._get_clocks.Update(get_msg.src),
-                      "drained Get must not complete a round")
+            self._drain_gets()
         self._note_staleness()
 
     def _multi_entry_bsp(self, msg: Message) -> None:
@@ -3130,22 +3210,24 @@ class SyncServer(Server):
 
     def ProcessGet(self, msg: Message) -> None:
         worker = msg.src
+        self._t_gets.inc()
         # 1. Before get: wait for other workers' adds (server.cpp:164-171)
         if (self._add_clocks.local_clock(worker) > self._add_clocks.global_clock()
                 or self._num_waited_add[worker] > 0):
             self._get_cache.append(msg)
+            self._get_holds.append(ttrace.begin("server.bsp.get_hold",
+                                                cat="server"))
+            self._t_gets_cached.inc()
             self._note_staleness()
             return
         # 2. Process get
         super().ProcessGet(msg)
         # 3. After get: drain cached adds when the get round completes
         if self._get_clocks.Update(worker):
-            while self._add_cache:
-                add_msg = self._add_cache.popleft()
-                super().ProcessAdd(add_msg)
-                CHECK(not self._add_clocks.Update(add_msg.src),
-                      "drained Add must not complete a round")
-                self._num_waited_add[add_msg.src] -= 1
+            if self._round_t0:
+                self._t_round_s.observe(_time.perf_counter()
+                                        - self._round_t0.popleft())
+            self._drain_adds()
         self._note_staleness()
 
     def ProcessFinishTrain(self, msg: Message) -> None:
@@ -3153,15 +3235,8 @@ class SyncServer(Server):
         worker = msg.src
         if self._add_clocks.FinishTrain(worker):
             CHECK(not self._add_cache, "add cache must be empty")
-            while self._get_cache:
-                get_msg = self._get_cache.popleft()
-                super().ProcessGet(get_msg)
-                CHECK(not self._get_clocks.Update(get_msg.src), "")
+            self._drain_gets()
         if self._get_clocks.FinishTrain(worker):
             CHECK(not self._get_cache, "get cache must be empty")
-            while self._add_cache:
-                add_msg = self._add_cache.popleft()
-                super().ProcessAdd(add_msg)
-                CHECK(not self._add_clocks.Update(add_msg.src), "")
-                self._num_waited_add[add_msg.src] -= 1
+            self._drain_adds()
         msg.reply(None)

@@ -1413,27 +1413,33 @@ class MatrixServerTable(ServerTable):
         if row_ids is None:
             # multihost: XLA-replicated read (no host reassembly round)
             return self._full_logical()
-        ids = np.asarray(row_ids, np.int32).ravel()
-        self._check_ids(ids)
-        self._note_row_access(ids)
-        union = (_union if _union is not None
-                 else multihost.union_collective_ids(ids))
-        if union is not None:
-            # each process may request different rows of this collective
-            # Get: gather the union with one identical program everywhere,
-            # then slice this process's rows out of the union result
-            union = union.astype(np.int32)
-            device_ids = self._device_ids(union)
+        # the blocking Get's own span (the BSP server's every Get; the
+        # windowed engine's Gets are ProcessGetAsync's): its copy in,
+        # launch, wait and copy back show as server.table.get.place /
+        # .call / .wait / .take
+        with ttrace.span("server.table.get", cat="server"):
+            ids = np.asarray(row_ids, np.int32).ravel()
+            self._check_ids(ids)
+            self._note_row_access(ids)
+            union = (_union if _union is not None
+                     else multihost.union_collective_ids(ids))
+            if union is not None:
+                # each process may request different rows of this
+                # collective Get: gather the union with one identical
+                # program everywhere, then slice this process's rows out
+                # of the union result
+                union = union.astype(np.int32)
+                device_ids = self._device_ids(union)
+                with crossing.call("_gather_rows"):
+                    rows = self._gather_rows(self.state["data"],
+                                             self.state["aux"], device_ids)
+                host_rows = self._take_rows(rows, len(union))
+                return host_rows[np.searchsorted(union, ids)]
+            device_ids = self._device_ids(ids)
             with crossing.call("_gather_rows"):
                 rows = self._gather_rows(self.state["data"],
                                          self.state["aux"], device_ids)
-            host_rows = self._take_rows(rows, len(union))
-            return host_rows[np.searchsorted(union, ids)]
-        device_ids = self._device_ids(ids)
-        with crossing.call("_gather_rows"):
-            rows = self._gather_rows(self.state["data"], self.state["aux"],
-                                     device_ids)
-        return self._take_rows(rows, len(ids))
+            return self._take_rows(rows, len(ids))
 
     def ProcessGetAsync(self, option: GetOption = None, row_ids=None):
         """Two-phase Get (base-class contract, tables/base.py): dispatch

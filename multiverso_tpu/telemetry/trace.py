@@ -110,6 +110,9 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def end(self) -> None:
+        """A held span (:func:`begin`) that tracing-off never opened."""
+
 
 NULL_SPAN = _NULL_SPAN = _NullSpan()
 
@@ -167,6 +170,37 @@ class _Span:
                 "tid": self._tid, "args": ev_args}
 
 
+class _HeldSpan(_Span):
+    """A span whose two ends are not one ``with`` block: it begins in
+    :func:`begin` and ends at its ``end()``, on the same thread, whatever
+    opened and closed there in between. It is no part of the thread's
+    nesting: no span becomes its child and it has no parent. The
+    profiler's annotation records a start and an end of its own, so held
+    spans may overlap each other and end in any order."""
+
+    __slots__ = ()
+
+    def begin(self):
+        sid = (_pid << 24) | (next(_id_counter) & 0xFFFFFF)
+        self._ctx = SpanContext(sid, sid)
+        self._prev = None
+        ann = _annotation
+        if ann is not None:
+            ann = ann(self.name)
+            ann.__enter__()
+        self._ann = ann
+        self._t0 = _now_us()
+        return self
+
+    def end(self) -> None:
+        self._dur = _now_us() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        self._tid = threading.get_ident()
+        _events.append(self)
+
+
 def span(name: str, parent: Optional[SpanContext] = None, cat: str = "mv",
          args: Optional[dict] = None):
     """Context manager opening a span for the ``with`` block. ``parent``
@@ -191,6 +225,15 @@ def child(suffix: str, args: Optional[dict] = None):
     if top is None:
         return _Span(ORPHAN + suffix, None, "server", args)
     return _Span(top.name + suffix, None, top.cat, args)
+
+
+def begin(name: str, cat: str = "mv", args: Optional[dict] = None):
+    """Open a span that a later line of this thread ends with ``.end()``
+    (a wait in a queue: the entry and the exit are different calls).
+    Tracing off: one flag read, the shared no-op."""
+    if not enabled():
+        return _NULL_SPAN
+    return _HeldSpan(name, None, cat, args).begin()
 
 
 def flow_start(ctx: Optional[SpanContext], name: str = "mv.msg") -> None:

@@ -4,6 +4,10 @@ model-average allreduce.
 Counterparts of reference Test/unittests/test_sync.cpp,
 Test/test_array_table.cpp (sync multi-worker accumulation invariant) and
 Test/test_allreduce.cpp.
+
+The BSP guarantee is held here on an ArrayTable against a closed form;
+``tests/test_bsp_rounds.py`` holds it on a MatrixTable, every Get of
+every round against the plain reference ``tables/bsp_reference.py``.
 """
 
 import threading
