@@ -148,7 +148,8 @@ class HotPathFlagCacheChecker(Checker):
     HOT_ZONES: List[Tuple[str, str, str]] = [
         (r"^sync/server\.py$",
          r"^(?:Server|ShardedServer|SyncServer|_EngineShard)\."
-         r"(?:_mh_|_pl_|_local_window|_admit|_get_entry|_add_entry|"
+         r"(?:_mh_|_pl_|_local_window|_take_|_run_window|_cut|"
+         r"_serve|_finalize|_judge_|_drain_|_admit|_get_entry|_add_entry|"
          r"_process_add_run|Process|Receive|_fence_entry|_fs_wrap_reply|"
          r"_flight_exchanged|_note_|_ph_)",
          "engine verb/window/apply machinery"),

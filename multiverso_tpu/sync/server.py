@@ -15,6 +15,12 @@ Behavioral equivalent of reference src/server.cpp:
   clocks to infinity and drains (server.cpp:188-211). Guarantee preserved
   (comment at server.cpp:60-67): all workers' i-th Get returns identical
   parameters, assuming all workers issue the same number of Gets/Adds.
+  The clocks judge every message by itself, in mailbox order; the device
+  work of what they admit is served through the same window as
+  ``Server``'s, cut so that no Add passes a Get and no Get an Add
+  (``SyncServer._cut``): consecutive admitted Adds of a table are one
+  stretch, consecutive admitted Gets one dispatch-then-finalize in
+  which Gets of the same rows share one gather.
 
 Selection by the ``sync`` flag mirrors ``Server::GetServer``
 (server.cpp:224-232).
@@ -23,6 +29,7 @@ Selection by the ``sync`` flag mirrors ``Server::GetServer``
 from __future__ import annotations
 
 import collections
+import functools
 import threading
 import time as _time
 from typing import Deque, Dict, List, Optional
@@ -625,6 +632,23 @@ class _ExchangeStage:
                        t0, win_ctx, ph, fence_cause))
 
 
+#: the two kinds of stretch a window's verbs are cut into
+#: (``Server._cut``): one table's Adds applied as ONE run, and Gets
+#: dispatched one behind the other and finalized together
+_ADDS, _GETS = "adds", "gets"
+
+
+def _extend_stretch(out: list, kind: str, msg: Message) -> None:
+    """``msg`` joins the stretch that ends ``out`` if that is one of its
+    kind (and, for Adds, of its table); a new stretch begins otherwise."""
+    last = out[-1] if out else None
+    if (isinstance(last, tuple) and last[0] is kind
+            and (kind is _GETS or last[1][0].table_id == msg.table_id)):
+        last[1].append(msg)
+    else:
+        out.append((kind, [msg]))
+
+
 class Server(Actor):
     """Async server engine (reference server.cpp:23-58)."""
 
@@ -680,6 +704,8 @@ class Server(Actor):
         self._t_splits = tmetrics.counter("server.window.barrier_splits")
         self._t_dispatch = tmetrics.counter("server.add.dispatches")
         self._t_merged = tmetrics.counter("server.add.run_merged")
+        #: Gets answered from an identical Get's gather and copy back
+        self._t_shared = tmetrics.counter("server.get.shared")
         self._t_defer = tmetrics.counter("server.add.device_deferrals")
         #: host-vs-device transport byte accounting: what this rank
         #: actually shipped on the host staging wire vs what it kept
@@ -823,11 +849,11 @@ class Server(Actor):
     #: round accounting — SyncServer overrides both to False.
     GET_CACHE_OK = True
     WRITE_COMBINE_OK = True
-    #: round 19 — whether this engine flattens Request_MultiVerb
-    #: envelopes. The async window engine does (members become ordinary
-    #: window verbs); the BSP SyncServer processes messages strictly
-    #: one at a time, so Zoo.SendToServerMulti falls back to delivering
-    #: the members individually there (same stream order, unbatched).
+    #: round 19 — whether the zoo may batch verbs into Request_MultiVerb
+    #: envelopes for this engine. The async window engine takes them
+    #: (members become ordinary window verbs); under the BSP SyncServer,
+    #: whose clocks count messages, Zoo.SendToServerMulti falls back to
+    #: delivering the members individually (same stream order).
     MULTI_VERB_OK = True
 
     def receive_multi(self, members) -> None:
@@ -1290,12 +1316,28 @@ class Server(Actor):
         * GET PIPELINING — distinct Gets overlap their device->host
           copies (dispatch all, finalize after), as before.
 
-        SyncServer overrides both entries with its unbatched clocked
-        path: the BSP defer/drain protocol must see messages strictly
-        one at a time."""
+        SyncServer takes its window the same way and serves it through
+        the same ``_local_window``; what it overrides is ``_cut``, how
+        a window's verbs are cut into stretches: under BSP no Add may
+        pass a Get and no Get an Add."""
+        batch = self._take_window(msg, self.GET_PIPELINE_WINDOW)
+        if not batch:
+            return
+        if multihost.world_size() > 1:
+            # multi-process WINDOWED protocol (round 5): one host
+            # collective exchanges the whole window; verbs then apply
+            # from the exchanged parts with cross-rank coalescing/dedup.
+            self._mh_windows(batch)
+            return
+        self._run_window(batch)
+
+    def _take_window(self, msg: Optional[Message], cap: int) -> list:
+        """``msg`` (None: nothing in hand) and what the mailbox holds
+        behind it now, envelopes flattened: those the failsafe gate
+        admits, in mailbox order. ``cap`` messages at most."""
         with ttrace.span("server.window.admit", cat="server"):
-            batch = [msg]
-            while len(batch) < self.GET_PIPELINE_WINDOW:
+            batch = [] if msg is None else [msg]
+            while len(batch) < cap:
                 ok, nxt = self.mailbox.TryPop()
                 if not ok:
                     break
@@ -1314,15 +1356,17 @@ class Server(Actor):
             # failsafe admission (dedup + chaos) BEFORE windowing: a
             # duplicate or chaos-rejected verb must never become a stream
             # position (divergent descriptors across ranks otherwise)
-            batch = [m for m in batch if self._admit(m)]
-        if not batch:
-            return
-        if multihost.world_size() > 1:
-            # multi-process WINDOWED protocol (round 5): one host
-            # collective exchanges the whole window; verbs then apply
-            # from the exchanged parts with cross-rank coalescing/dedup.
-            self._mh_windows(batch)
-            return
+            return [m for m in batch if self._admit(m)]
+
+    def _take_late(self, taken: int) -> list:
+        """What a window that has served ``taken`` messages and not yet
+        copied its Gets back takes on top: nothing here (SyncServer
+        overrides)."""
+        return []
+
+    def _run_window(self, batch) -> None:
+        """One single-process window, with its span and instruments
+        (``batch`` grows by what the window took late)."""
         _t0 = _time.perf_counter()
         phases = self._phases_on()
         if phases:
@@ -1361,109 +1405,147 @@ class Server(Actor):
                               (MsgType.Request_Add, MsgType.Request_Get)))
 
     def _local_window(self, batch) -> None:
-        """Apply one drained single-process window (see _get_entry)."""
-        # Any non-Get/Add message (e.g. Request_StoreLoad's Load) mutates
-        # table state outside the Add/Get algebra: it BARRIERS the window.
-        # Adds must not coalesce across it (a Load between two Adds would
-        # apply the later Add before the restore and silently wipe it),
-        # and a Get queued after it must not join a gather dispatched
-        # before it.
-        with ttrace.span("server.window.form", cat="server"):
-            segments: list = [[]]
-            for m in batch:
-                if m.msg_type in (MsgType.Request_Add, MsgType.Request_Get):
-                    segments[-1].append(m)
-                    # round 20 — policy routing input (actor thread only)
-                    if m.table_id >= 0:
-                        self.table_verbs[m.table_id] = (
-                            self.table_verbs.get(m.table_id, 0) + 1)
-                else:
-                    segments.append(m)       # barrier marker
-                    segments.append([])
-            # a verb segment's Adds grouped into per-table runs, and its
-            # Gets' dedup keys (key cost — tobytes of the payload arrays
-            # — only when the segment could hold a duplicate)
-            formed = []     # barrier message | (verbs, add runs, keys)
-            for seg in segments:
-                if not isinstance(seg, list):
-                    formed.append(seg)
-                    continue
-                add_runs: Dict[int, list] = {}
-                for m in seg:
-                    if m.msg_type is MsgType.Request_Add:
-                        add_runs.setdefault(m.table_id, []).append(m)
-                n_gets = len(seg) - sum(map(len, add_runs.values()))
-                formed.append((seg, add_runs, [
-                    self._get_dedup_key(m) if n_gets > 1
-                    and m.msg_type is MsgType.Request_Get else None
-                    for m in seg]))
-        pending = []   # (finalize, [msgs]) in dispatch order
+        """Serve one drained single-process window (see _get_entry): the
+        one window loop of both engines. Any non-Get/Add message (e.g.
+        Request_StoreLoad's Load, FinishTrain) mutates state outside the
+        Add/Get algebra: it BARRIERS the window, runs through its own
+        handler at its position, and nothing is formed, merged or
+        shared across it. The verbs between two barriers are cut into
+        stretches by the class (``_cut``) and served in that order
+        (``_serve``); every dispatched Get is copied back and answered
+        when all of the window has been dispatched (``_finalize``). A
+        segment is cut only when everything before it has run: what a
+        barrier's handler changes (the BSP clocks, at FinishTrain) is
+        seen by the cut behind it. Before the copies back the class may
+        take what has landed since (``_take_late``): it is appended to
+        ``batch`` and served as the window's next messages."""
+        pending: list = []   # (finalize, [msgs]) in dispatch order
         seen: Dict[tuple, int] = {}
         # perf forensics: per-(table, verb) apply seconds — only on the
-        # 1-in-N sampled windows (_get_entry decides; the elastic
+        # 1-in-N sampled windows (_run_window decides; the elastic
         # post-transition drain path leaves the flag wherever the last
         # window set it, which is fine for a sampled surface)
         tbl = {} if self._ph_stamp_this else None
-        for seg in formed:
-            if not isinstance(seg, tuple):
-                # barrier: runs its normal handler in order, with
-                # standard error routing; no dedup survives it
-                self.window_barrier_splits += 1
-                self._t_splits.inc()
-                tflight.record("barrier", epoch=self.window_epoch,
-                               stream=self.mh_stream,
-                               detail=MsgType(seg.msg_type).name)
-                self._dispatch(seg)
-                seen.clear()
-                continue
-            seg, add_runs, keys = seg
-            applied = set()
-            for m, key in zip(seg, keys):
-                if m.msg_type is MsgType.Request_Add:
-                    if m.table_id not in applied:
-                        applied.add(m.table_id)
-                        _tt = (_time.perf_counter() if tbl is not None
-                               else 0.0)
-                        self._process_add_run(add_runs[m.table_id])
-                        if tbl is not None:
-                            k = (m.table_id, "A")
-                            tbl[k] = (tbl.get(k, 0.0)
-                                      + _time.perf_counter() - _tt)
-                        # a Get queued after this Add must not join a
-                        # gather dispatched before it (it would observe
-                        # LESS progress than was enqueued ahead of it) —
-                        # drop the table's dedup entries
-                        seen = {k: v for k, v in seen.items()
-                                if k[0] != m.table_id}
+        at = 0
+        while at < len(batch):
+            segments: list = [[]]
+            for m in batch[at:]:
+                if m.msg_type in (MsgType.Request_Add, MsgType.Request_Get):
+                    segments[-1].append(m)
                 else:
-                    if key is not None and key in seen:
-                        pending[seen[key]][1].append(m)
-                        continue
-                    _tt = (_time.perf_counter() if tbl is not None
-                           else 0.0)
-                    with monitor_region("SERVER_PROCESS_GET"):
-                        try:
-                            table = self.store_[m.table_id]
-                            finalize = table.ProcessGetAsync(**m.payload)
-                            if finalize is None:
-                                self.ProcessGet(m)
-                            else:
-                                if key is not None:
-                                    seen[key] = len(pending)
-                                pending.append((finalize, [m]))
-                        except Exception as exc:
-                            # failures (bad table id included) reply to
-                            # THIS message only — an escape here would
-                            # abandon every pending finalize and hang
-                            # their waiters
-                            Log.Error("table ProcessGet dispatch failed: "
-                                      "%r", exc)
-                            m.reply(exc)
-                    if tbl is not None:
-                        k = (m.table_id, "G")
-                        tbl[k] = (tbl.get(k, 0.0)
-                                  + _time.perf_counter() - _tt)
-        # the blocking device->host fetch of each Get, and the replies
+                    segments += [m, []]         # barrier marker
+            at = len(batch)
+            for seg in segments:
+                if not isinstance(seg, list):
+                    # barrier: runs its normal handler in order, with
+                    # standard error routing; no dedup survives it
+                    self.window_barrier_splits += 1
+                    self._t_splits.inc()
+                    tflight.record("barrier", epoch=self.window_epoch,
+                                   stream=self.mh_stream,
+                                   detail=MsgType(seg.msg_type).name)
+                    self._dispatch(seg)
+                    seen.clear()
+                    continue
+                if not seg:
+                    continue
+                with ttrace.span("server.window.form", cat="server"):
+                    for m in seg:
+                        # round 20 — policy routing input (actor thread
+                        # only)
+                        if m.table_id >= 0:
+                            self.table_verbs[m.table_id] = (
+                                self.table_verbs.get(m.table_id, 0) + 1)
+                    stretches, share = self._cut(seg)
+                self._serve(stretches, share, pending, seen, tbl)
+            batch += self._take_late(len(batch))
+        self._finalize(pending, tbl)
+        if tbl:
+            self._ph_tables(tbl, -1, 0)
+
+    def _cut(self, verbs):
+        """The Adds and Gets between two barriers, cut into the
+        stretches ``_serve`` runs in order: ``(_ADDS, msgs)``, one
+        table's Adds as ONE run, and ``(_GETS, msgs)``, Gets dispatched
+        one behind the other. -> (stretches, whether the Gets are keyed
+        for sharing a gather: a key costs tobytes of the payload arrays,
+        so only where the verbs could hold a duplicate). The
+        asynchronous cut: all of a table's Adds at the position of its
+        FIRST (see _get_entry)."""
+        add_runs: Dict[int, list] = {}
+        for m in verbs:
+            if m.msg_type is MsgType.Request_Add:
+                add_runs.setdefault(m.table_id, []).append(m)
+        n_gets = len(verbs) - sum(map(len, add_runs.values()))
+        out: list = []
+        for m in verbs:
+            if m.msg_type is MsgType.Request_Get:
+                _extend_stretch(out, _GETS, m)
+            elif m.table_id in add_runs:
+                out.append((_ADDS, add_runs.pop(m.table_id)))
+        return out, n_gets > 1
+
+    def _serve(self, stretches, share: bool, pending: list, seen: dict,
+               tbl) -> None:
+        """Run a cut's stretches in order. An Adds stretch is one
+        ``_process_add_run``. A Get is dispatched (``ProcessGetAsync``)
+        and joins ``pending`` for the caller's ``_finalize``; with
+        ``share``, one whose request equals that of a Get dispatched
+        since the table's last Add (``seen``) rides that Get's gather
+        and copy back. A table without a two-phase Get answers on the
+        spot. A callable (the BSP cut's: a drain) runs at its position and
+        dispatches into the same ``pending`` and ``seen``."""
+        for st in stretches:
+            if callable(st):
+                st(pending, seen)
+                continue
+            kind, msgs = st
+            if kind is _ADDS:
+                tid = msgs[0].table_id
+                _tt = _time.perf_counter() if tbl is not None else 0.0
+                self._process_add_run(msgs)
+                if tbl is not None:
+                    k = (tid, "A")
+                    tbl[k] = tbl.get(k, 0.0) + _time.perf_counter() - _tt
+                # a Get queued after this Add must not join a gather
+                # dispatched before it (it would observe LESS progress
+                # than was enqueued ahead of it) — drop the table's
+                # dedup entries
+                for k in [k for k in seen if k[0] == tid]:
+                    del seen[k]
+                continue
+            for m in msgs:
+                key = self._get_dedup_key(m) if share else None
+                if key is not None and key in seen:
+                    pending[seen[key]][1].append(m)
+                    self._t_shared.inc()
+                    continue
+                _tt = _time.perf_counter() if tbl is not None else 0.0
+                with monitor_region("SERVER_PROCESS_GET"):
+                    try:
+                        table = self.store_[m.table_id]
+                        finalize = table.ProcessGetAsync(**m.payload)
+                        if finalize is None:
+                            self.ProcessGet(m)
+                        else:
+                            if key is not None:
+                                seen[key] = len(pending)
+                            pending.append((finalize, [m]))
+                    except Exception as exc:
+                        # failures (bad table id included) reply to
+                        # THIS message only — an escape here would
+                        # abandon every pending finalize and hang
+                        # their waiters
+                        Log.Error("table ProcessGet dispatch failed: "
+                                  "%r", exc)
+                        m.reply(exc)
+                if tbl is not None:
+                    k = (m.table_id, "G")
+                    tbl[k] = tbl.get(k, 0.0) + _time.perf_counter() - _tt
+
+    def _finalize(self, pending, tbl) -> None:
+        """The blocking device->host fetch of each dispatched Get, and
+        the replies."""
         with ttrace.span("server.window.finalize", cat="server"):
             for finalize, msgs in pending:
                 _tt = _time.perf_counter() if tbl is not None else 0.0
@@ -1483,10 +1565,14 @@ class Server(Actor):
                     continue
                 msgs[0].reply(result)
                 for m in msgs[1:]:
-                    # each deduped caller owns its result arrays
-                    m.reply(copy_result(result))
-        if tbl:
-            self._ph_tables(tbl, -1, 0)
+                    # each deduped caller owns its result arrays: a copy
+                    # of the copy before it, not of the first answer,
+                    # which is a view of the buffer the device wrote and
+                    # reads at a fraction of the host's own memory
+                    # (1.7 ms against 0.2 ms for 2 MB on a v5e's host:
+                    # PERF.md section 6, PR 51)
+                    result = copy_result(result)
+                    m.reply(result)
 
     # -- multi-process WINDOWED protocol (round 5) --------------------------
     # The r4 design took the strict path: every table verb ran its own
@@ -2460,12 +2546,15 @@ class Server(Actor):
             self.ProcessAdd(m)
 
     @staticmethod
-    def _get_dedup_key(m: Message):
+    def _get_dedup_key(m: Message, skip=()):
         """Hashable identity of a Get's request, or None when any payload
-        part can't be keyed (those never dedup)."""
+        part can't be keyed (those never dedup). Payload parts of a type
+        in ``skip`` are no part of it."""
         parts = [m.table_id]
         for k in sorted(m.payload):
             v = m.payload[k]
+            if isinstance(v, skip):
+                continue
             if isinstance(v, np.ndarray):
                 parts.append((k, v.dtype.str, v.shape, v.tobytes()))
             elif v is None or isinstance(v, (bool, int, float, str, bytes)):
@@ -2495,7 +2584,7 @@ class Server(Actor):
 
     def _add_entry(self, msg: Message) -> None:
         """Request_Add enters the same window as Gets (coalescing — see
-        _get_entry). SyncServer re-binds this to its strict ProcessAdd."""
+        _get_entry), in both engines."""
         self._get_entry(msg)
 
     def ProcessAdd(self, msg: Message) -> None:
@@ -3042,20 +3131,57 @@ class ShardedServer(Server):
 class SyncServer(Server):
     """BSP server (reference server.cpp:60-222). See module docstring.
 
-    What on its path differs from the windowed engine (``Server``), all
-    of it because the vector clocks count single Get and Add messages
-    and decide to defer or drain one message at a time:
+    The vector clocks count single Get and Add messages, and they
+    judge every message by itself and in mailbox order: defer it into
+    ``_add_cache`` / ``_get_cache``, or admit it and tick, and where a
+    tick completes a round, drain the cache at that position. The
+    device work of what they admit is not done on the spot. In a
+    single process the engine takes what the mailbox holds as
+    ``Server`` does (``_take_window``) and serves it through the same
+    window loop (``_local_window``); what the class decides is how the
+    verbs are cut into stretches (``_cut``) and what a window takes on
+    top (``_take_late``):
 
-    * no window: ``_get_entry`` / ``_add_entry`` process one admitted
-      verb (a "window" of 1), so several workers' Adds are never merged
-      into one ``ProcessAddRun`` and queued Gets never share a dispatch;
-    * one engine shard whatever ``-mv_engine_shards`` says
-      (``engine_shard_cap``): the clocks span all tables;
-    * no worker-side write combining, no Get cache, no batched
-      envelopes (the three class flags below);
-    * the table verbs are the blocking ones: every Add a lone
-      ``ProcessAdd``, every Get a ``ProcessGet`` that copies its rows
-      back on this thread before the next message is looked at.
+    * a maximal stretch of consecutive admitted Adds of one table is ONE
+      ``_process_add_run``, which here applies them verb by verb (see
+      there why no run is merged under BSP);
+    * a maximal stretch of consecutive admitted Gets is dispatched
+      together (``ProcessGetAsync``) and finalized together, identical
+      Gets sharing one gather and one copy back, whoever sent them;
+    * THE ORDERING RULE, which is not ``Server``'s (that one moves every
+      Add of a table to the position of the table's first: "a Get may
+      observe more progress, never less"; under BSP worker 0's Add of
+      round r+1 can sit in one batch behind the others' Gets of round
+      r): no Add is applied ahead of a Get that the clocks placed
+      before it, and no Get is dispatched ahead of an Add placed before
+      it. A stretch never reaches across a verb of the other kind, a
+      verb of another table ends an Add stretch, and a non-verb message
+      (FinishTrain, StoreLoad, ...) ends every stretch and runs at its
+      own position. A Get dispatched before a later Add stretch and
+      finalized after it reads the earlier state (the gather's output
+      is a fresh buffer), and sharing one gather among the Gets of a
+      stretch is exact: no Add lies between them;
+    * a drain serves its cached verbs as stretches at the tick's
+      position, under its ``server.bsp.drain`` span: its Adds applied,
+      its Gets dispatched among the window's own and answered with them
+      (a held Get shares the gather of the round's Gets behind it);
+    * before a window copies its Gets back it takes what has landed in
+      the mailbox since (``_take_late``), and the clocks judge and the
+      window serves that as its next messages: the Get of a worker that
+      was answered a moment later than the others joins their gather
+      while it is in flight. Nothing is waited for: what decides is
+      what the mailbox holds when the engine looks. The answers of a
+      shared gather go out together, so the workers' next sends land
+      together: a world in lock step settles at one gather a round.
+
+    What still differs from the windowed engine: one engine shard
+    whatever ``-mv_engine_shards`` says (``engine_shard_cap``: the
+    clocks span all tables); no worker-side write combining, no Get
+    cache, no batched envelopes from the zoo (the three class flags
+    below); and with more than one process a window of ONE verb, as
+    every verb there is a host collective that all ranks must issue in
+    one order, which a window cut by each rank's own mailbox race is
+    not.
 
     A Get that arrives before its round's last Add waits in
     ``_get_cache`` and is served by the drain that Add sets off; an Add
@@ -3071,7 +3197,10 @@ class SyncServer(Server):
     GET_CACHE_OK = False
     WRITE_COMBINE_OK = False
     #: ...and batched envelopes would hide N clock ticks inside one
-    #: message — Zoo.SendToServerMulti delivers members individually
+    #: message — Zoo.SendToServerMulti delivers members individually.
+    #: (An envelope a direct caller lands all the same is flattened at
+    #: its mailbox position by the inherited window entry: each member
+    #: is judged by the clocks as a message of its own.)
     MULTI_VERB_OK = False
     #: rounds whose first Add has been admitted and whose last Get has
     #: not been answered, at most (``server.bsp.round_s``): workers that
@@ -3081,17 +3210,6 @@ class SyncServer(Server):
 
     def __init__(self, num_workers: int):
         super().__init__()
-        # Zoo.SendToServerMulti honors MULTI_VERB_OK and delivers
-        # members individually, but direct callers (Server.receive_multi
-        # is inherited; ShardedServer.Receive documents pre-wrapped
-        # envelopes) could still land one — the inherited registration
-        # points at _get_entry, whose BSP override would feed the
-        # envelope to ProcessGet (table_id -1 → a bogus store_[-1]
-        # dispatch AND a spurious get-clock tick). Re-register a
-        # handler that flattens members strictly one at a time through
-        # the clocked entries instead (review catch, round 19).
-        self.RegisterHandler(MsgType.Request_MultiVerb,
-                             self._multi_entry_bsp)
         self._num_workers = num_workers
         self._get_clocks = VectorClock(num_workers)
         self._add_clocks = VectorClock(num_workers)
@@ -3129,34 +3247,57 @@ class SyncServer(Server):
         self._t_staleness.set(max(self._get_clocks.staleness(),
                                   self._add_clocks.staleness()))
 
-    def _drain_gets(self) -> None:
-        """Serve the cached Gets, one blocking gather and copy back
-        after another: the round's last Add has landed (or the last
-        worker still adding has finished training)."""
-        if not self._get_cache:
-            return
-        with ttrace.span("server.bsp.drain", cat="server"):
-            while self._get_cache:
-                get_msg = self._get_cache.popleft()
-                self._get_holds.popleft().end()
-                super().ProcessGet(get_msg)
-                CHECK(not self._get_clocks.Update(get_msg.src),
-                      "drained Get must not complete a round")
+    def _get_entry(self, msg: Message) -> None:
+        """Window handler of Gets and Adds (and of an envelope a direct
+        caller landed). The failsafe admission gate (dedup + chaos)
+        applies BEFORE the clocks see a verb: a duplicate Add must not
+        tick a vector clock twice. Multi-process: a window of one (see
+        the class docstring)."""
+        cap = 1 if multihost.world_size() > 1 else self.GET_PIPELINE_WINDOW
+        batch = self._take_window(msg, cap)
+        if batch:
+            self._run_window(batch)
 
-    def _drain_adds(self) -> None:
-        """Apply the cached Adds: the get round they ran ahead of is
-        complete."""
-        if not self._add_cache:
-            return
-        with ttrace.span("server.bsp.drain", cat="server"):
-            while self._add_cache:
-                add_msg = self._add_cache.popleft()
-                super().ProcessAdd(add_msg)
-                CHECK(not self._add_clocks.Update(add_msg.src),
-                      "drained Add must not complete a round")
-                self._num_waited_add[add_msg.src] -= 1
+    def _take_late(self, taken: int) -> list:
+        """What has landed since the window was taken (single process;
+        the window's cap holds for the whole of it). No waiting: a
+        worker whose verb is not there yet opens the next window."""
+        if multihost.world_size() > 1:
+            return []
+        return self._take_window(None, self.GET_PIPELINE_WINDOW - taken)
 
-    def ProcessAdd(self, msg: Message) -> None:
+    def _cut(self, verbs):
+        """The BSP cut: the clocks judge every verb in mailbox order
+        (``_judge_add`` / ``_judge_get``, reference server.cpp:139-186)
+        and what they admit is appended to the stretches in the order
+        they admit it: a deferred verb appears where its drain puts it.
+        So stretches hold the ordering rule of the class docstring by
+        construction: an admitted verb joins the stretch that ends the
+        list only if that holds verbs of its kind (and, for Adds, of its
+        table). Every Get is keyed: one that lands late may equal one in
+        flight."""
+        out: list = []
+        for m in verbs:
+            if m.msg_type is MsgType.Request_Add:
+                self._judge_add(m, out)
+            else:
+                self._judge_get(m, out)
+        return out, True
+
+    @staticmethod
+    def _get_dedup_key(m: Message):
+        """All workers' Gets of a round are equal (the guarantee), so
+        who asks, which is all a ``GetOption`` holds, is no part of a
+        Get's identity: Gets that name the same rows share one gather
+        whoever sent them. (A table whose answer does depend on the
+        asker, SparseMatrixTable, has no two-phase Get and shares
+        nothing.) A Get answered from another's gather does not pass
+        through the table: what the table notes a Get (the row-access
+        sketch, ``_note_row_access``) counts a shared gather once, as
+        under ``Server``'s dedup."""
+        return Server._get_dedup_key(m, skip=GetOption)
+
+    def _judge_add(self, msg: Message, out: list) -> None:
         worker = msg.src
         self._t_adds.inc()
         sent = self._adds_seen[worker]
@@ -3171,44 +3312,16 @@ class SyncServer(Server):
             self._t_adds_cached.inc()
             self._note_staleness()
             return
-        # 2. Process add
-        super().ProcessAdd(msg)
+        # 2. Process add: its place among the stretches
+        _extend_stretch(out, _ADDS, msg)
         # 3. After add: drain cached gets when the add round completes
         if self._add_clocks.Update(worker):
             self._t_rounds.inc()
             CHECK(not self._add_cache, "add cache must be empty at round end")
-            self._drain_gets()
+            out += self._drain_gets()
         self._note_staleness()
 
-    def _multi_entry_bsp(self, msg: Message) -> None:
-        """A batched envelope on the BSP engine: process the members
-        inline, strictly one at a time, through the clocked entries —
-        at the envelope's mailbox position, so member order (and the
-        round accounting, which counts individual messages) is exactly
-        what member-by-member delivery would have produced."""
-        for m in msg.payload["members"]:
-            if m.msg_type is MsgType.Request_Add:
-                self._add_entry(m)
-            else:
-                self._get_entry(m)
-
-    def _get_entry(self, msg: Message) -> None:
-        # no pipelining window under BSP: the vector-clock protocol's
-        # defer/drain decisions depend on strict one-at-a-time
-        # processing. The failsafe admission gate (dedup + chaos) still
-        # applies BEFORE the clocks see the verb — a duplicate Add must
-        # not tick a vector clock twice.
-        if not self._admit(msg):
-            return
-        self.ProcessGet(msg)
-
-    def _add_entry(self, msg: Message) -> None:
-        # no add-coalescing under BSP either (same strictness)
-        if not self._admit(msg):
-            return
-        self.ProcessAdd(msg)
-
-    def ProcessGet(self, msg: Message) -> None:
+    def _judge_get(self, msg: Message, out: list) -> None:
         worker = msg.src
         self._t_gets.inc()
         # 1. Before get: wait for other workers' adds (server.cpp:164-171)
@@ -3220,23 +3333,90 @@ class SyncServer(Server):
             self._t_gets_cached.inc()
             self._note_staleness()
             return
-        # 2. Process get
-        super().ProcessGet(msg)
+        # 2. Process get: its place among the stretches
+        _extend_stretch(out, _GETS, msg)
         # 3. After get: drain cached adds when the get round completes
         if self._get_clocks.Update(worker):
             if self._round_t0:
-                self._t_round_s.observe(_time.perf_counter()
-                                        - self._round_t0.popleft())
-            self._drain_adds()
+                self._observe_round_at_reply(msg, self._round_t0.popleft())
+            out += self._drain_adds()
         self._note_staleness()
+
+    def _observe_round_at_reply(self, msg: Message, t0: float) -> None:
+        """``server.bsp.round_s`` ends where the worker is answered, not
+        where the clock ticked: shadow ``msg.reply`` (as
+        ``_fs_wrap_reply`` does) so the round is observed behind the
+        finalize that replies."""
+        orig = msg.reply
+
+        def _reply(result=None):
+            orig(result)
+            self._t_round_s.observe(_time.perf_counter() - t0)
+
+        msg.reply = _reply
+
+    def _process_add_run(self, msgs) -> None:
+        """A stretch of Adds goes verb by verb. A merged run is a
+        program a count of Adds and of distinct rows, and which a world
+        meets races its workers' sends: none can be brought up before a
+        timed stretch of rounds. Nor would a run shorten a round: a
+        round's Adds are bound by their bytes into the device, merged
+        or lone (PERF.md section 6, PR 51)."""
+        for m in msgs:
+            self.ProcessAdd(m)
+
+    def _drain_gets(self) -> list:
+        """The cached Gets, ticked: the round's last Add has landed (or
+        the last worker still adding has finished training). -> what
+        serves them, to run behind that Add (nothing for an empty
+        cache)."""
+        if not self._get_cache:
+            return []
+        msgs, holds = list(self._get_cache), list(self._get_holds)
+        self._get_cache.clear()
+        self._get_holds.clear()
+        for m in msgs:
+            CHECK(not self._get_clocks.Update(m.src),
+                  "drained Get must not complete a round")
+        return [functools.partial(self._serve_drain, [(_GETS, msgs)], holds)]
+
+    def _drain_adds(self) -> list:
+        """The cached Adds, ticked: the get round they ran ahead of is
+        complete. -> what applies them (nothing for an empty cache)."""
+        if not self._add_cache:
+            return []
+        stretches: list = []
+        for m in self._add_cache:
+            _extend_stretch(stretches, _ADDS, m)
+            CHECK(not self._add_clocks.Update(m.src),
+                  "drained Add must not complete a round")
+            self._num_waited_add[m.src] -= 1
+        self._add_cache.clear()
+        return [functools.partial(self._serve_drain, stretches, ())]
+
+    def _serve_drain(self, stretches, holds, pending: list,
+                     seen: dict) -> None:
+        """Serve a drain's stretches at the tick's position: its Adds
+        applied, its Gets dispatched among the window's own (``pending``),
+        to be copied back and answered with them: a held Get and the
+        round's Gets behind it share one gather."""
+        with ttrace.span("server.bsp.drain", cat="server"):
+            for hold in holds:
+                hold.end()
+            self._serve(stretches, True, pending, seen, None)
 
     def ProcessFinishTrain(self, msg: Message) -> None:
         """server.cpp:188-211: force worker clocks to infinity, drain caches."""
         worker = msg.src
+        drains: list = []
         if self._add_clocks.FinishTrain(worker):
             CHECK(not self._add_cache, "add cache must be empty")
-            self._drain_gets()
+            drains += self._drain_gets()
         if self._get_clocks.FinishTrain(worker):
             CHECK(not self._get_cache, "get cache must be empty")
-            self._drain_adds()
+            drains += self._drain_adds()
+        pending: list = []
+        for drain in drains:
+            drain(pending, {})
+        self._finalize(pending, None)
         msg.reply(None)

@@ -12,19 +12,30 @@ fail the same check, and the ``server.bsp.*`` spans and instruments are
 read. Every blocking call has a time limit of its own (``-mv_deadline_s``
 bounds a ``Wait``, every ``join`` has a timeout), so a protocol fault
 fails a test and does not hang the suite.
+
+The BSP engine serves what its mailbox holds as one window (PR 51). The
+window tests stage the mailbox from this thread with ``AddAsyncHandle``
+/ ``GetAsyncHandle`` while the engine's thread is held inside a message
+of its own (``_engine_held``), so a window's content is exact and no
+case depends on a race or reads the clock.
 """
 
+import contextlib
 import os
 import threading
 import time
 
+import jax
 import numpy as np
 import pytest
 
-from multiverso_tpu.tables import MatrixTableOption
+from multiverso_tpu.message import Message, MsgType
+from multiverso_tpu.tables import (MatrixTableOption,
+                                   SparseMatrixTableOption)
 from multiverso_tpu.tables.bsp_reference import BspRounds
 from multiverso_tpu.telemetry import metrics as tmetrics
 from multiverso_tpu.telemetry import trace as ttrace
+from multiverso_tpu.utils.waiter import Waiter
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROWS, COLS, WORKERS, ROUNDS, K, SETS = 20_000, 50, 4, 30, 200, 8
@@ -380,10 +391,623 @@ def test_the_spans_of_a_held_get_and_of_both_drains():
         end = hold["ts"] + hold["dur"]
         assert first["ts"] <= end <= first["ts"] + first["dur"]
         assert hold["ts"] < first["ts"]
-    # the drain's children are the table's blocking Gets
+    # the drain dispatches its three Gets as one stretch among the
+    # window's own: they name the same rows, so one gather is dispatched
+    # (and copied back and answered when the window finalizes)
     served = [e["name"] for e in spans
               if e["args"]["parent_id"] == first["args"]["span_id"]]
-    assert served == ["server.table.get"] * (WORKERS - 1)
+    assert served == ["server.table.get.prepare", "server.table.get.dispatch"]
+    assert _moved(before, after, "server.get.shared") == WORKERS - 2
+
+
+# -- the window of the BSP engine (PR 51) -------------------------------------
+
+_COMPILES = []
+jax.monitoring.register_event_duration_secs_listener(
+    lambda name, secs, **kw: _COMPILES.append(name)
+    if name == "/jax/core/compile/backend_compile_duration" else None)
+
+
+def _engine_message(msg_type, **fields) -> Message:
+    """A message of this thread's own, sent; its ``waiter`` tells the
+    reply."""
+    from multiverso_tpu.zoo import Zoo
+    msg = Message(msg_type=msg_type, waiter=Waiter(1), **fields)
+    Zoo.Get().SendToServer(msg)
+    return msg
+
+
+def _answer(msg: Message):
+    assert msg.waiter.Wait(JOIN_S), "the engine never answered"
+    assert not isinstance(msg.result, Exception), msg.result
+    return msg.result
+
+
+@contextlib.contextmanager
+def _engine_held():
+    """The engine's thread waits inside a StoreLoad payload of ours, so
+    all that is sent meanwhile is in its mailbox when it goes on: the
+    next window is exactly that, in that order (16 messages at most,
+    ``GET_PIPELINE_WINDOW``)."""
+    inside, go_on = threading.Event(), threading.Event()
+
+    def hold():
+        inside.set()
+        assert go_on.wait(JOIN_S), "the test never let the engine go on"
+
+    held = _engine_message(MsgType.Request_StoreLoad, payload={"fn": hold})
+    assert inside.wait(JOIN_S), "the engine never reached the hold"
+    try:
+        yield
+    finally:
+        go_on.set()
+    _answer(held)
+
+
+def _settled_snapshot() -> dict:
+    """The instruments once the engine has ended the window it is in:
+    a window counts itself when its last reply has been sent, and the
+    ping is answered behind that."""
+    _answer(_engine_message(MsgType.Request_Barrier))
+    return tmetrics.snapshot()
+
+
+class _Staged:
+    """Verbs sent from this thread under a worker's context; ``rows()``
+    waits for them all and gives the Gets' answers in the order sent."""
+
+    def __init__(self, mv):
+        self.mv, self.adds, self.gets, self.answers = mv, [], [], []
+
+    def add(self, table, w, ids, delta):
+        with self.mv.MV_WorkerContext(w):
+            self.adds.append((table, table.AddAsyncHandle(delta, ids)))
+
+    def get(self, table, w, ids=None):
+        with self.mv.MV_WorkerContext(w):
+            self.gets.append((table, table.GetAsyncHandle(ids)))
+
+    def rows(self) -> list:
+        for table, handle in self.adds:
+            table.Wait(handle)
+        for table, handle in self.gets:
+            got = table.Wait(handle)
+            self.answers.append(tuple(np.array(part) for part in got)
+                                if isinstance(got, tuple) else np.array(got))
+        self.adds, self.gets = [], []
+        return self.answers
+
+
+_WINDOW_COUNTERS = ("server.add.run_merged", "server.add.dispatches",
+                    "server.get.shared", "server.bsp.rounds",
+                    "server.bsp.gets_cached", "server.bsp.adds_cached",
+                    "server.window.verbs", "server.window.barrier_splits")
+
+
+def _window_moved(before: dict, after: dict) -> dict:
+    """What one staged window moved, and that it WAS one window."""
+    moved = {name.split(".", 1)[1]: _moved(before, after, name)
+             for name in _WINDOW_COUNTERS}
+    moved["windows"] = (after["server.window.latency_s"]["count"]
+                        - before.get("server.window.latency_s",
+                                     {"count": 0})["count"])
+    return moved
+
+
+def _total(deltas, j, workers=range(WORKERS)):
+    return sum(deltas[w][j] for w in workers)
+
+
+def test_a_whole_round_queued_is_one_window_and_one_gather():
+    """(a) Four Adds then four Gets in the mailbox are ONE window: the
+    Adds one stretch, applied verb by verb (no run is merged under BSP),
+    the Gets one gather that three of them share, and every Get is the
+    round's total."""
+    import multiverso_tpu as mv
+    ids, deltas = _traffic(60)
+    _world(mv, "-sync=true")
+    try:
+        table = mv.MV_CreateTable(MatrixTableOption(num_rows=ROWS,
+                                                    num_cols=COLS))
+        sent = _Staged(mv)
+        before = _settled_snapshot()
+        with _engine_held():
+            for w in range(WORKERS):
+                sent.add(table, w, ids[0], deltas[w][0])
+            for w in range(WORKERS):
+                sent.get(table, w, ids[0])
+        rows = sent.rows()
+        moved = _window_moved(before, _settled_snapshot())
+    finally:
+        mv.MV_ShutDown()
+    ref = BspRounds(COLS, WORKERS, np.concatenate(ids))
+    ref.round(0, ids[0], [deltas[w][0] for w in range(WORKERS)])
+    for got in rows:
+        assert np.array_equal(got, ref.expect_get(0, ids[0]))
+    assert moved == {"add.run_merged": 0, "add.dispatches": WORKERS,
+                     "get.shared": WORKERS - 1, "bsp.rounds": 1,
+                     "bsp.gets_cached": 0, "bsp.adds_cached": 0,
+                     "window.verbs": 2 * WORKERS,
+                     "window.barrier_splits": 0, "windows": 1}
+
+
+@pytest.mark.parametrize("batch", ["gets_then_the_next_add",
+                                   "an_add_between_the_gets",
+                                   "last_add_gets_then_next_adds"])
+def test_no_add_passes_a_get_the_clocks_placed_before_it(batch):
+    """(b) The ordering rule. Adds of round 1 sit in one batch behind,
+    or among, Gets of round 0: the Gets are round 0's total and hold
+    none of them. (The asynchronous engine's cut would apply every Add
+    of the batch at the first Add's position, ahead of the Gets.) The
+    Adds behind the last Get are applied after the Gets' gather was
+    dispatched and before its copy back is finalized; the Add AMONG the
+    Gets is held by the clocks and applied by the drain that the last
+    Get's tick sets off, at the same point: the Gets hold none of
+    them."""
+    import multiverso_tpu as mv
+    ids, deltas = _traffic(61)
+    last = WORKERS - 1
+    _world(mv, "-sync=true")
+    try:
+        table = mv.MV_CreateTable(MatrixTableOption(num_rows=ROWS,
+                                                    num_cols=COLS))
+
+        def add(w, j):
+            with mv.MV_WorkerContext(w):
+                table.AddRows(ids[0], deltas[w][j])
+
+        sent = _Staged(mv)
+        if batch == "last_add_gets_then_next_adds":
+            for w in range(last):
+                add(w, 0)
+            first = []
+            before = _settled_snapshot()
+            with _engine_held():
+                sent.add(table, last, ids[0], deltas[last][0])
+                for w in range(WORKERS):
+                    sent.get(table, w, ids[0])
+                sent.add(table, 0, ids[0], deltas[0][1])
+                sent.add(table, 1, ids[0], deltas[1][1])
+            # three stretches: the round's last Add; the four Gets; the
+            # next round's two Adds
+            want = {"add.run_merged": 0, "add.dispatches": 3,
+                    "get.shared": WORKERS - 1, "bsp.rounds": 1,
+                    "bsp.adds_cached": 0, "window.verbs": WORKERS + 3,
+                    "windows": 1}
+            rest = range(2, WORKERS)
+        else:
+            for w in range(WORKERS):
+                add(w, 0)
+            with mv.MV_WorkerContext(0):
+                first = [table.GetRows(ids[0]).copy()]
+            before = _settled_snapshot()
+            among = batch == "an_add_between_the_gets"
+            with _engine_held():
+                sent.get(table, 1, ids[0])
+                if among:
+                    sent.add(table, 0, ids[0], deltas[0][1])
+                for w in range(2, WORKERS):
+                    sent.get(table, w, ids[0])
+                if not among:
+                    sent.add(table, 0, ids[0], deltas[0][1])
+            want = {"add.run_merged": 0, "add.dispatches": 1,
+                    "get.shared": WORKERS - 2, "bsp.rounds": 0,
+                    "bsp.adds_cached": int(among),
+                    "window.verbs": WORKERS, "windows": 1}
+            rest = range(1, WORKERS)
+        rows = first + sent.rows()
+        moved = _window_moved(before, _settled_snapshot())
+        # the Adds behind the Gets did land: round 1, ended by hand
+        for w in rest:
+            add(w, 1)
+        then = []
+        for w in range(WORKERS):
+            with mv.MV_WorkerContext(w):
+                then.append(table.GetRows(ids[0]).copy())
+    finally:
+        mv.MV_ShutDown()
+    assert len(rows) == WORKERS
+    for got in rows:
+        assert np.array_equal(got, _total(deltas, 0))
+    for got in then:
+        assert np.array_equal(got, _total(deltas, 0) + _total(deltas, 1))
+    assert moved["bsp.gets_cached"] == 0
+    assert {k: moved[k] for k in want} == want
+
+
+def test_a_get_queued_before_the_rounds_last_add_is_held_and_holds_it():
+    """(c) Worker 0's Get sits in the batch BEFORE worker 3's Add: the
+    clocks hold it, that Add's tick drains it, and it holds that Add.
+    The Add joins the stretch of the three before it: the clocks placed
+    the Get behind it. The drain dispatches the held Get at the tick's
+    position, and the three Gets behind it share its gather."""
+    import multiverso_tpu as mv
+    ids, deltas = _traffic(62)
+    last = WORKERS - 1
+    _world(mv, "-sync=true", "-trace=true")
+    try:
+        table = mv.MV_CreateTable(MatrixTableOption(num_rows=ROWS,
+                                                    num_cols=COLS))
+        sent = _Staged(mv)
+        ttrace.clear()
+        before = _settled_snapshot()
+        with _engine_held():
+            for w in range(last):
+                sent.add(table, w, ids[0], deltas[w][0])
+            sent.get(table, 0, ids[0])
+            sent.add(table, last, ids[0], deltas[last][0])
+            for w in range(1, WORKERS):
+                sent.get(table, w, ids[0])
+        rows = sent.rows()
+        moved = _window_moved(before, _settled_snapshot())
+        spans = [e for e in ttrace.to_chrome_trace()["traceEvents"]
+                 if e.get("ph") == "X"]
+    finally:
+        mv.MV_ShutDown()
+    for got in rows:
+        assert np.array_equal(got, _total(deltas, 0))
+    assert moved == {"add.run_merged": 0, "add.dispatches": WORKERS,
+                     "get.shared": WORKERS - 1, "bsp.rounds": 1,
+                     "bsp.gets_cached": 1, "bsp.adds_cached": 0,
+                     "window.verbs": 2 * WORKERS,
+                     "window.barrier_splits": 0, "windows": 1}
+    drains = [e for e in spans if e["name"] == "server.bsp.drain"]
+    holds = [e for e in spans if e["name"] == "server.bsp.get_hold"]
+    assert len(drains) == 1 and len(holds) == 1
+    drain = drains[0]
+    assert (drain["ts"] <= holds[0]["ts"] + holds[0]["dur"]
+            <= drain["ts"] + drain["dur"])
+    # the stretch that holds the last Add ends before the drain starts,
+    # and the round's one gather is dispatched inside the drain
+    adds = sorted((e for e in spans
+                   if e["name"] == "server.table.add_run.dispatch"),
+                  key=lambda e: e["ts"])
+    assert len(adds) == WORKERS
+    assert adds[-1]["ts"] + adds[-1]["dur"] <= drain["ts"]
+    dispatched = [e["ts"] for e in spans
+                  if e["name"] == "server.table.get.dispatch"]
+    assert len(dispatched) == 1
+    assert drain["ts"] <= dispatched[0] <= drain["ts"] + drain["dur"]
+
+
+@pytest.mark.parametrize("barrier", ["finish_train", "store_load"])
+def test_a_message_that_is_no_verb_ends_the_stretches(barrier):
+    """(d) A FinishTrain, and a StoreLoad, inside a batch: the Adds in
+    front of it are applied when it runs (three, and two), the verbs
+    behind it are judged and served after it."""
+    import multiverso_tpu as mv
+    from multiverso_tpu.updaters.base import GetOption
+    ids, deltas = _traffic(63)
+    last = WORKERS - 1
+    _world(mv, "-sync=true")
+    try:
+        table = mv.MV_CreateTable(MatrixTableOption(num_rows=ROWS,
+                                                    num_cols=COLS))
+        sent = _Staged(mv)
+        before = _settled_snapshot()
+        if barrier == "finish_train":
+            # worker 3 ends training in the middle of round 0: worker
+            # 0's Get, held for worker 3's Add, is drained by it
+            with _engine_held():
+                for w in range(last):
+                    sent.add(table, w, ids[0], deltas[w][0])
+                sent.get(table, 0, ids[0])
+                ended = _engine_message(MsgType.Server_Finish_Train,
+                                        src=last)
+                for w in range(1, last):
+                    sent.get(table, w, ids[0])
+            seen_by_it = None
+            want_rows = [_total(deltas, 0, range(last))] * last
+            want = {"add.run_merged": 0, "add.dispatches": last,
+                    "bsp.gets_cached": 1, "get.shared": last - 2,
+                    "window.verbs": 2 * last, "window.barrier_splits": 1,
+                    "windows": 1}
+        else:
+            def read():
+                return np.array(table.server().ProcessGet(
+                    GetOption(), row_ids=ids[0]))
+
+            with _engine_held():
+                for w in range(2):
+                    sent.add(table, w, ids[0], deltas[w][0])
+                ended = _engine_message(MsgType.Request_StoreLoad,
+                                        payload={"fn": read})
+                for w in range(2, WORKERS):
+                    sent.add(table, w, ids[0], deltas[w][0])
+                for w in range(WORKERS):
+                    sent.get(table, w, ids[0])
+            seen_by_it = _total(deltas, 0, range(2))
+            want_rows = [_total(deltas, 0)] * WORKERS
+            want = {"add.run_merged": 0, "add.dispatches": WORKERS,
+                    "bsp.gets_cached": 0, "get.shared": WORKERS - 1,
+                    "window.verbs": 2 * WORKERS,
+                    "window.barrier_splits": 1, "windows": 1}
+        rows = sent.rows()
+        result = _answer(ended)
+        moved = _window_moved(before, _settled_snapshot())
+    finally:
+        mv.MV_ShutDown()
+    if seen_by_it is not None:
+        assert np.array_equal(result, seen_by_it)
+    assert len(rows) == len(want_rows)
+    for got, want_got in zip(rows, want_rows):
+        assert np.array_equal(got, want_got)
+    assert {k: moved[k] for k in want} == want
+
+
+def test_two_tables_interleaved_in_one_batch():
+    """(e) A verb of another table ends an Add stretch; Gets of both
+    tables are one stretch in which each table's share a gather."""
+    import multiverso_tpu as mv
+    wide = 8
+    ids, deltas = _traffic(64)
+    ids_b, deltas_b = _traffic(65, rows=3_000, cols=wide)
+    _world(mv, "-sync=true")
+    try:
+        a = mv.MV_CreateTable(MatrixTableOption(num_rows=ROWS,
+                                                num_cols=COLS))
+        b = mv.MV_CreateTable(MatrixTableOption(num_rows=3_000,
+                                                num_cols=wide))
+        sent = _Staged(mv)
+        before = _settled_snapshot()
+        with _engine_held():
+            for pair in ((0, 1), (2, 3)):
+                for w in pair:
+                    sent.add(a, w, ids[0], deltas[w][0])
+                for w in pair:
+                    sent.add(b, w, ids_b[0], deltas_b[w][0])
+            for w in range(WORKERS):
+                sent.get(a, w, ids[0])
+                sent.get(b, w, ids_b[0])
+        rows = sent.rows()
+        moved = _window_moved(before, _settled_snapshot())
+    finally:
+        mv.MV_ShutDown()
+    for got_a, got_b in zip(rows[0::2], rows[1::2]):
+        assert np.array_equal(got_a, _total(deltas, 0))
+        assert np.array_equal(got_b, _total(deltas_b, 0))
+    assert moved["windows"] == 1 and moved["window.verbs"] == 4 * WORKERS
+    # four stretches of two, where the asynchronous cut makes two runs
+    # of four
+    assert (moved["add.run_merged"], moved["add.dispatches"]) == (
+        0, 2 * WORKERS)
+    assert moved["get.shared"] == 2 * (WORKERS - 1)
+
+
+@pytest.mark.parametrize("kind", ["momentum", "adagrad", "sparse"])
+def test_the_window_answers_as_one_verb_at_a_time(kind):
+    """(f) A table whose updater is not linear (the order of its Adds
+    shows in the rows), and a SparseMatrixTable (no two-phase Get,
+    answers that depend on who asks): two rounds queued whole give, Get
+    for Get, what the same verbs give sent one after another."""
+    import multiverso_tpu as mv
+    ids, deltas = _traffic(66)
+    rounds = 2
+
+    def option():
+        if kind == "sparse":
+            return SparseMatrixTableOption(num_rows=ROWS, num_cols=COLS)
+        return MatrixTableOption(num_rows=ROWS, num_cols=COLS,
+                                 updater_type=kind)
+
+    def drive(queued: bool):
+        _world(mv, "-sync=true")
+        try:
+            table = mv.MV_CreateTable(option())
+            sent = _Staged(mv)
+            before = _settled_snapshot()
+            for r in range(rounds):
+                hold = _engine_held() if queued else contextlib.nullcontext()
+                with hold:
+                    for w in range(WORKERS):
+                        sent.add(table, w, ids[r], deltas[w][r])
+                        if not queued:
+                            sent.rows()
+                    for w in range(WORKERS):
+                        # the sparse Get: all that is new to the worker
+                        sent.get(table, w, None if kind == "sparse"
+                                 else ids[r])
+                        if not queued:
+                            sent.rows()
+            return sent.rows(), _window_moved(before, _settled_snapshot())
+        finally:
+            mv.MV_ShutDown()
+
+    one_by_one, moved_1 = drive(queued=False)
+    queued, moved_q = drive(queued=True)
+    assert moved_1["windows"] == 2 * WORKERS * rounds
+    assert moved_q["windows"] == rounds
+    assert len(queued) == len(one_by_one) == WORKERS * rounds
+    for got, want in zip(queued, one_by_one):
+        if kind == "sparse":
+            assert np.array_equal(got[0], want[0])
+            assert np.array_equal(got[1], want[1])
+        else:
+            assert np.array_equal(got, want)
+    if kind == "sparse":
+        # worker w's Get of round r: the rows the OTHERS added
+        assert sorted(queued[0][0].tolist()) == sorted(ids[0].tolist())
+        assert moved_q["get.shared"] == 0
+    else:
+        assert np.any(queued[-1])
+    assert moved_q["add.run_merged"] == 0
+    assert moved_q["add.dispatches"] == WORKERS * rounds
+
+
+@pytest.mark.parametrize("queued", ["whole_rounds", "split_rounds"])
+def test_no_program_is_compiled_after_the_first_round(queued):
+    """(g) Round 0 goes verb by verb; the rounds behind it are queued
+    whole, or so that their stretches hold 3 + 1, 2 + 2 and 1 + 3 verbs:
+    no program is compiled for them, whatever sizes the stretches take
+    (a stretch of Adds is no merged run under BSP, and a shared gather
+    is the lone Get's program)."""
+    import multiverso_tpu as mv
+    ids, deltas = _traffic(67)
+    _world(mv, "-sync=true")
+    try:
+        # a shape of its own: no other test's programs are this one's
+        table = mv.MV_CreateTable(MatrixTableOption(num_rows=ROWS + 8,
+                                                    num_cols=COLS + 1))
+        deltas = [[np.pad(d, ((0, 0), (0, 1))) for d in per]
+                  for per in deltas]
+        sent = _Staged(mv)
+
+        def round_of(r, cuts):
+            groups = list(zip((0,) + cuts, cuts + (WORKERS,)))
+            for lo, hi in groups:
+                with _engine_held():
+                    for w in range(lo, hi):
+                        sent.add(table, w, ids[r], deltas[w][r])
+                sent.rows()
+            for lo, hi in groups:
+                with _engine_held():
+                    for w in range(lo, hi):
+                        sent.get(table, w, ids[r])
+                sent.rows()
+
+        round_of(0, (1, 2, 3))
+        compiled = len(_COMPILES)
+        rounds = ([(), (), ()] if queued == "whole_rounds"
+                  else [(3,), (2,), (1,)])
+        for r, cuts in enumerate(rounds, start=1):
+            round_of(r, cuts)
+        compiled = len(_COMPILES) - compiled
+        got = sent.rows()
+    finally:
+        mv.MV_ShutDown()
+    ref = BspRounds(COLS + 1, WORKERS, np.concatenate(ids))
+    for r in range(1 + len(rounds)):
+        ref.round(r, ids[r], [deltas[w][r] for w in range(WORKERS)])
+        for w in range(WORKERS):
+            assert np.array_equal(got[WORKERS * r + w],
+                                  ref.expect_get(r, ids[r])), (r, w)
+    assert compiled == 0
+
+
+@pytest.mark.parametrize("late", ["a_get", "the_rounds_last_add"])
+def test_what_lands_while_a_window_is_served_joins_it(late):
+    """Before a window copies its Gets back it takes what has landed
+    since. A Get that lands while the others' gather is in flight
+    shares it; the round's last Add that lands while the Adds before it
+    are applied is judged next, and its tick drains the Get the clocks
+    held. Staged exactly: the late verb is sent from inside the table
+    call that serves the window's first verb."""
+    import multiverso_tpu as mv
+    ids, deltas = _traffic(68)
+    last = WORKERS - 1
+    _world(mv, "-sync=true")
+    try:
+        table = mv.MV_CreateTable(MatrixTableOption(num_rows=ROWS,
+                                                    num_cols=COLS))
+        sent = _Staged(mv)
+        srv = table.server()
+
+        def send_late_from(verb, send):
+            served = getattr(srv, verb)
+
+            def serve(*args, **kwargs):
+                delattr(srv, verb)          # once
+                send()
+                return served(*args, **kwargs)
+
+            setattr(srv, verb, serve)
+
+        if late == "a_get":
+            for w in range(WORKERS):
+                with mv.MV_WorkerContext(w):
+                    table.AddRows(ids[0], deltas[w][0])
+            before = _settled_snapshot()
+            send_late_from("ProcessGetAsync",
+                           lambda: sent.get(table, last, ids[0]))
+            with _engine_held():
+                for w in range(last):
+                    sent.get(table, w, ids[0])
+            want = {"get.shared": WORKERS - 1, "bsp.gets_cached": 0,
+                    "bsp.rounds": 0, "window.verbs": WORKERS, "windows": 1}
+        else:
+            before = _settled_snapshot()
+            send_late_from("ProcessAdd", lambda: sent.add(
+                table, last, ids[0], deltas[last][0]))
+            with _engine_held():
+                for w in range(last):
+                    sent.add(table, w, ids[0], deltas[w][0])
+                sent.get(table, 0, ids[0])
+            want = {"add.dispatches": WORKERS, "bsp.gets_cached": 1,
+                    "bsp.rounds": 1, "window.verbs": WORKERS + 1,
+                    "windows": 1}
+        rows = sent.rows()
+        moved = _window_moved(before, _settled_snapshot())
+    finally:
+        mv.MV_ShutDown()
+    assert len(rows) == (WORKERS if late == "a_get" else 1)
+    for got in rows:
+        assert np.array_equal(got, _total(deltas, 0))
+    assert {k: moved[k] for k in want} == want
+
+
+@pytest.mark.parametrize("world", ["one_worker_left", "a_late_fourth_add"])
+def test_no_window_waits_for_a_send(world):
+    """A window takes what the mailbox holds and never waits for more:
+    not for workers that have finished training, and not for a
+    straggler's Add (which then opens a window of its own). The engine
+    blocks on its mailbox between windows only."""
+    import multiverso_tpu as mv
+    from multiverso_tpu.zoo import Zoo
+    ids, deltas = _traffic(69)
+    last = WORKERS - 1
+    _world(mv, "-sync=true")
+    try:
+        table = mv.MV_CreateTable(MatrixTableOption(num_rows=ROWS,
+                                                    num_cols=COLS))
+        engine = Zoo.Get().server_engine
+        inside, waits = [], []
+        run_window, pop = engine._run_window, engine.mailbox.Pop
+
+        def watched_window(batch):
+            inside.append(batch)
+            try:
+                run_window(batch)
+            finally:
+                inside.pop()
+
+        def watched_pop(*args, **kwargs):
+            if inside:
+                waits.append((args, kwargs))
+            return pop(*args, **kwargs)
+
+        engine._run_window, engine.mailbox.Pop = watched_window, watched_pop
+        before = _settled_snapshot()
+        rows = []
+        if world == "one_worker_left":
+            for w in range(1, WORKERS):
+                _answer(_engine_message(MsgType.Server_Finish_Train, src=w))
+            for r in range(3):
+                with mv.MV_WorkerContext(0):
+                    table.AddRows(ids[0], deltas[0][r])
+                    rows.append(table.GetRows(ids[0]).copy())
+            want_rows = [sum(deltas[0][:r + 1]) for r in range(3)]
+            want = {"windows": 6, "window.verbs": 6, "bsp.gets_cached": 0}
+        else:
+            sent = _Staged(mv)
+            with _engine_held():
+                for w in range(last):
+                    sent.add(table, w, ids[0], deltas[w][0])
+            sent.rows()
+            for w in [last] + list(range(last)):
+                with mv.MV_WorkerContext(w):
+                    if w == last:
+                        table.AddRows(ids[0], deltas[w][0])
+                    rows.append(table.GetRows(ids[0]).copy())
+            want_rows = [_total(deltas, 0)] * WORKERS
+            want = {"windows": 2 + WORKERS, "window.verbs": 2 * WORKERS,
+                    "bsp.gets_cached": 0, "bsp.rounds": 1}
+        moved = _window_moved(before, _settled_snapshot())
+    finally:
+        mv.MV_ShutDown()
+    assert not waits
+    for got, want_got in zip(rows, want_rows):
+        assert np.array_equal(got, want_got)
+    assert {k: moved[k] for k in want} == want
 
 
 class TestHeldSpan:
