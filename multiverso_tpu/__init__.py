@@ -83,7 +83,11 @@ __all__ = list(_API_NAMES) + ["__version__"]
 
 def __getattr__(name: str):
     if name in _API_NAMES:
-        from multiverso_tpu import api
+        # the training plane's import (api -> zoo -> jax) is the first
+        # phase of a start: gauge mv.import_s (telemetry/startup.py)
+        from multiverso_tpu.telemetry import startup
+        with startup.phase("mv.import"):
+            from multiverso_tpu import api
         value = getattr(api, name)
         globals()[name] = value     # cache: one import per process
         return value

@@ -191,6 +191,7 @@ class Actor:
             m.reply(died)
 
     def _main(self) -> None:
+        trace.name_native_thread()
         self._started.set()
         try:
             while True:

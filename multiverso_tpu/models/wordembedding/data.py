@@ -442,6 +442,7 @@ def start_loader(option, dictionary: Dictionary, generator: PairGenerator,
                 queue.push(pending.popleft().result())
 
     def run():
+        ttrace.name_native_thread()
         error = None
         try:
             if workers == 1:
@@ -453,6 +454,6 @@ def start_loader(option, dictionary: Dictionary, generator: PairGenerator,
         finally:
             queue.close(error)
 
-    t = threading.Thread(target=run, daemon=True)
+    t = threading.Thread(target=run, daemon=True, name="mv-we-loader")
     t.start()
     return t

@@ -28,6 +28,7 @@ from multiverso_tpu.models.wordembedding.model import (decayed_lr,
 from multiverso_tpu.models.wordembedding.option import Option
 from multiverso_tpu.models.wordembedding.sampler import Sampler
 from multiverso_tpu.telemetry import metrics as tmetrics
+from multiverso_tpu.telemetry import startup
 from multiverso_tpu.telemetry import trace as ttrace
 from multiverso_tpu.utils import compile_cache
 from multiverso_tpu.utils.log import Log
@@ -71,9 +72,39 @@ class DistributedWordEmbedding:
 
     def prepare(self) -> None:
         """Dictionary, sampler, world, tables, trainer. No trace covers
-        set-up, so each part's seconds go to a ``we.prepare.*_s`` gauge
-        (set once the world is up: ``-telemetry`` is a flag of it)."""
+        set-up, so each part's seconds go to a ``we.prepare.*_s`` gauge:
+        the host's parts as phases of the start (``telemetry/startup.py``),
+        the laps from the world on once the world is up."""
         opt = self.opt
+        with startup.phase("we.prepare.dictionary"):
+            stop = set()
+            if opt.stopwords and opt.sw_file:
+                with open(opt.sw_file, encoding="utf-8") as f:
+                    stop = set(f.read().split())
+            if opt.read_vocab_file:
+                self.dictionary = Dictionary.load_vocab(opt.read_vocab_file,
+                                                        stop)
+            else:
+                self.dictionary = Dictionary(stop)
+                self.dictionary.build_from_corpus(opt.train_file)
+            self.dictionary.RemoveWordsLessThan(max(opt.min_count, 1))
+            if self.dictionary.Size() == 0:
+                raise ValueError("empty vocabulary after min_count pruning")
+            # the dictionary is final: build the loader's tokenizer here,
+            # once, and not at the start of every pass. Part of the
+            # dictionary's phase; its own seconds go to
+            # we.prepare.tokenizer_s besides
+            with startup.phase("we.prepare.tokenizer"):
+                self.dictionary.tokenizer()
+            if opt.total_words <= 0:
+                opt.total_words = self.dictionary.WordCount()
+            counts = self.dictionary.counts()
+        with startup.phase("we.prepare.sampler"):
+            self.sampler = Sampler(counts, seed=opt.seed)
+        if opt.hs:
+            with startup.phase("we.prepare.huffman"):
+                self.huffman = HuffmanEncoder()
+                self.huffman.BuildFromTermFrequency(counts)
         laps, at = {}, time.perf_counter()
 
         def lap(part: str) -> None:
@@ -81,34 +112,6 @@ class DistributedWordEmbedding:
             now = time.perf_counter()
             laps[part], at = now - at, now
 
-        stop = set()
-        if opt.stopwords and opt.sw_file:
-            with open(opt.sw_file, encoding="utf-8") as f:
-                stop = set(f.read().split())
-        if opt.read_vocab_file:
-            self.dictionary = Dictionary.load_vocab(opt.read_vocab_file, stop)
-        else:
-            self.dictionary = Dictionary(stop)
-            self.dictionary.build_from_corpus(opt.train_file)
-        self.dictionary.RemoveWordsLessThan(max(opt.min_count, 1))
-        if self.dictionary.Size() == 0:
-            raise ValueError("empty vocabulary after min_count pruning")
-        # the dictionary is final: build the loader's tokenizer here, once,
-        # and not at the start of every pass. Part of the dictionary's lap;
-        # its own seconds go to we.prepare.tokenizer_s besides
-        built = time.perf_counter()
-        self.dictionary.tokenizer()
-        laps["tokenizer"] = time.perf_counter() - built
-        if opt.total_words <= 0:
-            opt.total_words = self.dictionary.WordCount()
-        counts = self.dictionary.counts()
-        lap("dictionary")
-        self.sampler = Sampler(counts, seed=opt.seed)
-        lap("sampler")
-        if opt.hs:
-            self.huffman = HuffmanEncoder()
-            self.huffman.BuildFromTermFrequency(counts)
-            lap("huffman")
         self._world.init_if_needed()
         lap("world")
         # exception-safe: anything raising after MV_Init (table creation,

@@ -288,6 +288,7 @@ class _ApplyPool:
         return box
 
     def _loop(self) -> None:
+        ttrace.name_native_thread()
         while True:
             ok, item = self._q.Pop()
             if not ok:

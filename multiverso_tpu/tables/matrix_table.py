@@ -37,7 +37,6 @@ from __future__ import annotations
 
 import functools
 import threading
-import time
 from dataclasses import dataclass
 from typing import Callable, Dict, Optional, Tuple
 
@@ -57,6 +56,7 @@ from multiverso_tpu.tables import crossing
 from multiverso_tpu.tables.base import ServerTable, TableOption, WorkerTable
 from multiverso_tpu.telemetry import metrics as tmetrics
 from multiverso_tpu.telemetry import sketch as tsketch
+from multiverso_tpu.telemetry import startup
 from multiverso_tpu.telemetry import trace as ttrace
 from multiverso_tpu.updaters.base import (AddOption, CreateUpdater, GetOption,
                                           stack_workers, unstack_workers)
@@ -314,16 +314,6 @@ class MatrixServerTable(ServerTable):
                             else None)
         self._opt_cache: Dict[tuple, Dict[str, jax.Array]] = {}
         self._opt_lock = threading.Lock()
-        # table.create_s: the initialiser, _to_storage and placement below
-        # (which materialises the whole table on one device first)
-        t_create = time.perf_counter()
-        if initializer is not None:
-            init = np.asarray(initializer((num_rows, num_cols)), self.dtype)
-            data = self._to_storage(init)  # host numpy; place() shards it
-        else:
-            data = jnp.zeros((self.padded_rows, self.store_cols), self.dtype)
-        aux = self.updater.init_aux((self.padded_rows, self.store_cols),
-                                    self.dtype, zoo.num_workers)
         # round 11 — access-skew measurement (-mv_row_sketch): a
         # bounded Space-Saving top-K over Get row ids, created lazily
         # when the flag arms (telemetry/sketch.py; the off path is one
@@ -332,14 +322,23 @@ class MatrixServerTable(ServerTable):
         # gauge, the Dashboard [RowSkew] line + /perf carry the rows.
         self._row_sketch = None
         self._row_sketch_notes = 0
-        self.state = {
-            "data": ctx.place(data, self._sharding),
-            "aux": jax.tree.map(
-                lambda a: ctx.place(a, self._sharding), aux),
-        }
-        jax.block_until_ready(self.state)
-        tmetrics.histogram("table.create_s").observe(
-            time.perf_counter() - t_create)
+        # table.create_s: the initialiser, _to_storage and placement below
+        # (which materialises the whole table on one device first)
+        with startup.phase("table.create", histogram=True):
+            storage = (self.padded_rows, self.store_cols)
+            if initializer is not None:
+                init = np.asarray(initializer((num_rows, num_cols)),
+                                  self.dtype)
+                data = self._to_storage(init)  # host numpy; place() shards it
+            else:
+                data = jnp.zeros(storage, self.dtype)
+            aux = self.updater.init_aux(storage, self.dtype, zoo.num_workers)
+            self.state = {
+                "data": ctx.place(data, self._sharding),
+                "aux": jax.tree.map(
+                    lambda a: ctx.place(a, self._sharding), aux),
+            }
+            jax.block_until_ready(self.state)
         # every state leaf is row-shaped 2-D storage on the data's axis
         self._aux_specs = jax.tree.map(lambda a: P(SERVER_AXIS, None), aux)
 

@@ -60,6 +60,14 @@ The fleet plane (round 22) adds one more:
   ``python -m multiverso_tpu.telemetry.fleet --trace`` multi-dump
   trace merge CLI. Zero new connections, zero collectives.
 
+The start-up ledger (PR 52) adds one more, on the same registry and
+spans and with no flag of its own:
+
+* ``startup`` — set-up by phase (``mv.import_s``, ``mv.init_s``, ...)
+  and every program JAX traced, lowered, compiled or loaded, by name
+  (``jit.*``), from JAX's own monitoring events. Imported where it is
+  used (the package's lazy import is its first phase).
+
 Importing this package registers every telemetry flag (``-telemetry``,
 ``-trace``, ``-stats_interval_s``, ``-mv_flight_events``,
 ``-mv_diag_dir``, ``-mv_ops_port``, ``-mv_watchdog_s``,

@@ -530,9 +530,14 @@ class MetricsRegistry:
             pos += width
         return out
 
-    def _reset_for_tests(self) -> None:
+    def clear(self) -> None:
+        """Forget every instrument (handles held elsewhere go on counting,
+        unseen): a world that starts with ``-telemetry=false`` drops what
+        was counted before its flags could be read."""
         with self._lock:
             self._instruments.clear()
+
+    _reset_for_tests = clear
 
 
 REGISTRY = MetricsRegistry()
@@ -566,5 +571,8 @@ def merged_snapshot() -> Dict[str, dict]:
     return REGISTRY.merged_snapshot()
 
 
-def _reset_for_tests() -> None:
-    REGISTRY._reset_for_tests()
+def clear() -> None:
+    REGISTRY.clear()
+
+
+_reset_for_tests = clear

@@ -50,6 +50,7 @@ from multiverso_tpu.failsafe import deadline as fdeadline
 from multiverso_tpu.failsafe.errors import (ActorDied, DeadlineExceeded,
                                             MembershipChanged)
 from multiverso_tpu.telemetry import metrics as tmetrics
+from multiverso_tpu.telemetry import startup
 from multiverso_tpu.parallel import multihost
 from multiverso_tpu.parallel.allreduce import RendezvousAllreduce
 from multiverso_tpu.parallel.mesh import MeshContext
@@ -97,15 +98,29 @@ class Zoo:
     def Start(self, argv: Optional[List[str]] = None,
               devices=None) -> List[str]:
         CHECK(not self.started, "Zoo already started")
+        # all of a start is the phase mv.init (gauge mv.init_s), with
+        # mv.init.mesh and mv.init.planes inside it
+        with startup.phase("mv.init"):
+            return self._start(argv, devices)
+
+    def _start(self, argv: Optional[List[str]], devices) -> List[str]:
         rest = ParseCMDFlags(argv or [])
+        if not tmetrics.enabled():
+            # the import's seconds and any compile before this line were
+            # counted under the flag's default
+            tmetrics.clear()
+        startup.listen()
         self._ma_mode = bool(GetFlag("ma"))
         role = ROLE_NAMES.get(str(GetFlag("ps_role")).lower(), Role.ALL)
         self.num_workers = max(1, int(GetFlag("num_workers")))
         # multi-process bring-up BEFORE mesh construction: a multi-controller
         # job's mesh must span the global device set (SURVEY.md §2c — the
-        # MPI/ZMQ transport's TPU equivalent is the cross-host mesh itself)
-        self._multihost = multihost.maybe_initialize()
-        self.mesh_ctx = MeshContext.create(devices)
+        # MPI/ZMQ transport's TPU equivalent is the cross-host mesh itself).
+        # mv.init.mesh_s: where the backend answers in an app that had not
+        # touched JAX
+        with startup.phase("mv.init.mesh"):
+            self._multihost = multihost.maybe_initialize()
+            self.mesh_ctx = MeshContext.create(devices)
         if self._multihost:
             # host-wire selection BEFORE the engine exists (round 12):
             # same-host worlds ride the shared-memory wire, cross-host
@@ -134,31 +149,33 @@ class Zoo:
             from multiverso_tpu.sync.server import Server
             self.server_engine = Server.GetServer(self.num_workers)
             self.server_engine.Start()
-        from multiverso_tpu.telemetry.export import start_reporter
-        start_reporter()        # -stats_interval_s periodic reports
-        from multiverso_tpu.telemetry.ops import start_ops
-        start_ops()             # -mv_ops_port /metrics·/healthz·/flight
-        # watchdog plane (round 13): the byte ledger's mem.* gauges
-        # register eagerly every world; the typed-rule tick thread only
-        # arms when -mv_watchdog_s > 0 (off by default, like the
-        # reporter). Both are LOCAL-only — no collectives ever.
-        from multiverso_tpu.telemetry.accounting import start_ledger
-        start_ledger()
-        from multiverso_tpu.telemetry.watchdog import start_watchdog
-        start_watchdog()
-        # elastic membership plane LAST (needs the engine up): rank 0
-        # hosts the coordinator, every rank registers + heartbeats
-        elastic.start_plane(self)
-        # replica fan-out AFTER elastic so its subscription registry
-        # can ride the membership coordinator (round 17); rank 0 owns
-        # the fan-out thread, every rank reads one cached flag
-        from multiverso_tpu import replica as _replica
-        _replica.start_plane(self)
-        # policy plane LAST (round 20): it needs the watchdog's tick
-        # listener hook and — multi-process — the elastic coordinator
-        # endpoint (or its own -mv_policy_addr authority) already up
-        from multiverso_tpu import policy as _policy
-        _policy.start_plane(self)
+        # what the planes cost a start: gauge mv.init.planes_s
+        with startup.phase("mv.init.planes"):
+            from multiverso_tpu.telemetry.export import start_reporter
+            start_reporter()        # -stats_interval_s periodic reports
+            from multiverso_tpu.telemetry.ops import start_ops
+            start_ops()             # -mv_ops_port /metrics·/healthz·/flight
+            # watchdog plane (round 13): the byte ledger's mem.* gauges
+            # register eagerly every world; the typed-rule tick thread only
+            # arms when -mv_watchdog_s > 0 (off by default, like the
+            # reporter). Both are LOCAL-only — no collectives ever.
+            from multiverso_tpu.telemetry.accounting import start_ledger
+            start_ledger()
+            from multiverso_tpu.telemetry.watchdog import start_watchdog
+            start_watchdog()
+            # elastic membership plane LAST (needs the engine up): rank 0
+            # hosts the coordinator, every rank registers + heartbeats
+            elastic.start_plane(self)
+            # replica fan-out AFTER elastic so its subscription registry
+            # can ride the membership coordinator (round 17); rank 0 owns
+            # the fan-out thread, every rank reads one cached flag
+            from multiverso_tpu import replica as _replica
+            _replica.start_plane(self)
+            # policy plane LAST (round 20): it needs the watchdog's tick
+            # listener hook and — multi-process — the elastic coordinator
+            # endpoint (or its own -mv_policy_addr authority) already up
+            from multiverso_tpu import policy as _policy
+            _policy.start_plane(self)
         self.started = True
         Log.Debug("Zoo started: %d servers (mesh devices), %d workers, "
                   "mode=%s", self.num_servers, self.num_workers,
