@@ -2521,14 +2521,22 @@ class Server(Actor):
                     continue
             verbs[p].reply(result)
 
+    def _offer_add_run(self, table, payloads) -> bool:
+        """What a run of two or more Adds is offered to: the table's
+        merged run (a same-rows run summed, any other stacked)."""
+        return table.ProcessAddRun(payloads)
+
     def _process_add_run(self, msgs) -> None:
-        """Apply a table's window-worth of Adds: merged when the table
-        accepts (ProcessAddRun validates BEFORE mutating and returns
-        False to decline), per-message otherwise."""
+        """Apply a table's window-worth of Adds: as ONE dispatch when the
+        table accepts the run (``_offer_add_run``; the table validates
+        BEFORE mutating and returns False to decline), per-message
+        otherwise. An accepted run acknowledges every message after the
+        apply; an exception replies to every message of the run."""
         if len(msgs) > 1:
             try:
                 table = self.store_[msgs[0].table_id]
-                merged = table.ProcessAddRun([m.payload for m in msgs])
+                merged = self._offer_add_run(table,
+                                             [m.payload for m in msgs])
             except Exception as exc:
                 # the run contract: state mutates only after validation,
                 # so a raise here means the whole merged Add failed
@@ -3144,8 +3152,14 @@ class SyncServer(Server):
     top (``_take_late``):
 
     * a maximal stretch of consecutive admitted Adds of one table is ONE
-      ``_process_add_run``, which here applies them verb by verb (see
-      there why no run is merged under BSP);
+      ``_process_add_run``. Where its two or more payloads name the
+      same rows (the workers of a synchronous round push one shared
+      set) the table sums them on the host and applies ONE lone Add
+      (``_offer_add_run``, ``ProcessAddSameRows``: one dispatch, a
+      quarter of the bytes into the device for a stretch of four, the
+      lone Add's own program); any other stretch goes verb by verb (see
+      there why the stacked run stays out of BSP). Every Add is in the
+      table when its reply goes out, summed or lone;
     * a maximal stretch of consecutive admitted Gets is dispatched
       together (``ProcessGetAsync``) and finalized together, identical
       Gets sharing one gather and one copy back, whoever sent them;
@@ -3356,15 +3370,26 @@ class SyncServer(Server):
 
         msg.reply = _reply
 
-    def _process_add_run(self, msgs) -> None:
-        """A stretch of Adds goes verb by verb. A merged run is a
-        program a count of Adds and of distinct rows, and which a world
-        meets races its workers' sends: none can be brought up before a
-        timed stretch of rounds. Nor would a run shorten a round: a
-        round's Adds are bound by their bytes into the device, merged
-        or lone (PERF.md section 6, PR 51)."""
-        for m in msgs:
-            self.ProcessAdd(m)
+    def _offer_add_run(self, table, payloads) -> bool:
+        """A stretch of two or more Adds is offered to the table's
+        same-rows run and to nothing else (``ProcessAddSameRows``):
+        where every payload names the same id array, as the workers of
+        a synchronous round do, the deltas are summed on the host in
+        message order and applied as ONE lone Add, one dispatch and one
+        acknowledgement of every message after it. A round's Adds are
+        bound by their bytes into the device, and those fall with the
+        count of Adds summed; the program is the lone Add's, so there
+        is nothing to warm and no count that races the workers' sends;
+        and a stretch of two is worth as much an Add as one of four, so
+        nothing is waited for. All or nothing a stretch: one that mixes
+        id sets, a non-linear updater, a compressed or whole-table Add
+        decline and the stretch goes verb by verb. Never the STACKED
+        run of ``ProcessAddRun``: that is a program a count of Adds and
+        of distinct rows, which a world meets races its workers' sends,
+        so none can be brought up before a timed stretch of rounds, and
+        it carries every Add's bytes into the device all the same
+        (PERF.md section 6, PR 51 and PR 53)."""
+        return table.ProcessAddSameRows(payloads)
 
     def _drain_gets(self) -> list:
         """The cached Gets, ticked: the round's last Add has landed (or

@@ -141,6 +141,25 @@ class ServerTable:
         this method fails the whole run, with no per-message fallback."""
         return False
 
+    def ProcessAddSameRows(self, payloads) -> bool:
+        """The same-rows run: apply a stretch of two or more queued Adds
+        whose payloads all name the SAME rows as ONE lone Add of their
+        host-side sum, and return True; False declines (the default),
+        and the engine then goes on as if it had not asked. It is what
+        the BSP engine offers a stretch of Adds (``SyncServer``: the
+        workers of a round push the same rows, and a sum needs no
+        program of its own, where a stacked run is a program a count of
+        Adds that no world can warm before it meets it), and what a
+        table's ``ProcessAddRun`` may try first. A table accepts only
+        where summing first equals applying one by one (a linear
+        updater: ``update(update(s, a), b) == update(s, a + b)``) and
+        never writes to a payload's arrays. The CONTRACT is
+        ``ProcessAddRun``'s: all or nothing a stretch, everything
+        validated BEFORE state mutates (an exception fails every Add of
+        the stretch), and what a table notes an Add (freshness bits, the
+        publish journal) is noted once a payload, in message order."""
+        return False
+
     # -- multi-process WINDOW protocol hooks (sync/server.py windowed
     # engine, round 5): the engine exchanges a whole window of verbs in
     # ONE host collective and hands every rank's payloads down, so table

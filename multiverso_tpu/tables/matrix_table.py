@@ -748,8 +748,13 @@ class MatrixServerTable(ServerTable):
 
     def _check_ids(self, ids: np.ndarray) -> None:
         CHECK(ids.size > 0, "empty row id set")
-        CHECK(int(ids.min()) >= 0 and int(ids.max()) < self.num_rows,
-              "row id out of range")
+        CHECK(self._ids_in_range(ids), "row id out of range")
+
+    def _ids_in_range(self, ids: np.ndarray) -> bool:
+        """A non-empty id vector inside the table (the run hooks decline
+        on False where ``_check_ids`` raises)."""
+        return (ids.size > 0 and int(ids.min()) >= 0
+                and int(ids.max()) < self.num_rows)
 
     def _combine_duplicates(self, ids: np.ndarray, deltas: np.ndarray):
         """Pre-combine duplicate row ids (see module docstring)."""
@@ -758,9 +763,94 @@ class MatrixServerTable(ServerTable):
 
     # -- server verbs -------------------------------------------------------
 
+    def _shared_row_ids(self, payloads) -> Optional[np.ndarray]:
+        """The one id vector that every payload of a run names,
+        validated, or None: a payload without ``row_ids`` (a whole-table
+        Add) or ``compressed``, an id out of range, or an id array that
+        is not the first's (``a is b or np.array_equal(a, b)``, which
+        tells another length before it compares an entry; another order
+        is another array; the first mismatch ends the test). Workers that push one shared
+        set hand over one array object, so the common case compares
+        nothing; where the arrays differ the cost is one comparison of
+        a payload's ids."""
+        shared = None
+        for p in payloads:
+            row_ids = p.get("row_ids")
+            if row_ids is None or p.get("compressed") is not None:
+                return None
+            ids = np.asarray(row_ids, np.int32).ravel()
+            if shared is None:
+                if not self._ids_in_range(ids):
+                    return None
+                shared = ids
+            elif not (ids is shared or np.array_equal(ids, shared)):
+                return None
+        return shared
+
+    def ProcessAddSameRows(self, payloads) -> bool:
+        """The same-rows run (base-class contract): a stretch of two or
+        more row Adds whose payloads all name the SAME id array is
+        summed on the host and applied as ONE lone Add. Workers that
+        train synchronously push the same rows every step, so the bytes
+        into the device, which bound a round's Adds, fall with the count
+        of Adds summed. Accepts exactly when ``ProcessAddRun`` could
+        (one process; a linear, aux-free updater; every payload with
+        ``row_ids`` in range, none ``compressed``, ``values`` of the
+        right size) AND the id arrays are equal (``_shared_row_ids``);
+        everything is validated before anything is written. The deltas
+        are summed in the table's dtype in message order into a FRESH
+        array (no payload is written to): for a linear updater
+        ``update(update(s, a), b) == update(s, a + b)``, the contract
+        ``ProcessAddRun`` and ``multihost.sum_collective_add`` already
+        rest on, and only the association of the additions differs from
+        the Adds one by one, not the precision. The sum takes the lone
+        Add's own path (``_combine_duplicates`` for repeats inside the
+        id set, then ``_dispatch_rows``: ``_update_rows`` at the lone
+        Add's bucket), so no program, shape or compile is new whatever
+        the count of Adds, and one ``.merge`` and one ``.dispatch`` span
+        cover the run. Subclass bookkeeping fires once a payload in
+        message order with that payload's option, as in
+        ``ProcessAddRun``. Counters ``table.add_run.summed`` (runs) and
+        ``table.add_run.summed_adds`` (Adds in them)."""
+        if (len(payloads) < 2 or multihost.world_size() > 1
+                or not self._merge_adds):
+            return False
+        # both registered at 0 by the first run a table is offered
+        summed_runs = tmetrics.counter("table.add_run.summed")
+        summed_adds = tmetrics.counter("table.add_run.summed_adds")
+        ids = self._shared_row_ids(payloads)
+        if ids is None:
+            return False
+        with self._verb_span("server.table.add_run.merge",
+                             adds=len(payloads)):
+            deltas_list = []
+            for p in payloads:
+                values = np.asarray(p.get("values"), self.dtype)
+                if values.size != ids.size * self.num_cols:
+                    return False
+                deltas_list.append(values.reshape(ids.size, self.num_cols))
+            total = deltas_list[0] + deltas_list[1]
+            for d in deltas_list[2:]:
+                total += d
+            uniq, total = self._combine_duplicates(ids, total)
+        with self._verb_span("server.table.add_run.dispatch",
+                             adds=len(payloads)):
+            # option scalars are irrelevant to linear updaters
+            self._dispatch_rows(uniq, total, None)
+        summed_runs.inc()
+        summed_adds.inc(len(payloads))
+        for p in payloads:
+            self._note_add_parts(p.get("option") or AddOption(), [ids])
+        return True
+
     def ProcessAddRun(self, payloads) -> bool:
         """Engine add-coalescing (base-class contract): merge a window's
-        row-set Adds into ONE device dispatch — concat the batches,
+        row-set Adds into ONE device dispatch. A run whose payloads all
+        name the same id array is summed on the host and applied as one
+        lone Add (``ProcessAddSameRows``, tried first: a quarter of the
+        bytes cross for a run of four, and no program of the run's
+        own); at the first id array that differs the run is stacked
+        instead — concat the batches,
         pre-combine duplicates ACROSS the merged adds (np.add.at), one
         jit'd update. Sound exactly when the updater declares itself
         LINEAR (``combine_scale is not None``): update(data, delta) ==
@@ -773,6 +863,8 @@ class MatrixServerTable(ServerTable):
         (the per-message path then reports precise errors)."""
         if multihost.world_size() > 1 or not self._merge_adds:
             return False
+        if self.ProcessAddSameRows(payloads):
+            return True
         # a window's run of Adds in two named halves: .merge is host
         # numpy (validation, stacking, np.unique, padding), .dispatch the
         # host-to-device copies (.place) and the merged program's .call
@@ -784,8 +876,7 @@ class MatrixServerTable(ServerTable):
                 if row_ids is None or p.get("compressed") is not None:
                     return False
                 ids = np.asarray(row_ids, np.int32).ravel()
-                if (ids.size == 0 or int(ids.min()) < 0
-                        or int(ids.max()) >= self.num_rows):
+                if not self._ids_in_range(ids):
                     return False
                 values = np.asarray(p.get("values"), self.dtype)
                 if values.size != ids.size * self.num_cols:
@@ -967,14 +1058,20 @@ class MatrixServerTable(ServerTable):
         with ttrace.span("server.table.add_run.merge", cat="server"):
             ids, deltas = self._combine_duplicates(ids, deltas)
         with ttrace.span("server.table.add_run.dispatch", cat="server"):
-            # ship exact-size deltas; pad them to the bucket on device
-            padded_ids = self._device_ids(ids)
-            deltas = _place_rows(deltas, padded_ids.shape[0])
-            opt = self._device_opt(option)
-            with crossing.call("_update_rows"):
-                self.state = self._update_rows(self.state, padded_ids,
-                                               deltas, opt)
+            self._dispatch_rows(ids, deltas, option)
         self._note_add_parts(option, parts)
+
+    def _dispatch_rows(self, ids: np.ndarray, deltas: np.ndarray,
+                       option: Optional[AddOption]) -> None:
+        """A lone Add's device half, inside the caller's ``.dispatch``
+        span: distinct ids and their deltas across, the update."""
+        # ship exact-size deltas; pad them to the bucket on device
+        padded_ids = self._device_ids(ids)
+        deltas = _place_rows(deltas, padded_ids.shape[0])
+        opt = self._device_opt(option)
+        with crossing.call("_update_rows"):
+            self.state = self._update_rows(self.state, padded_ids,
+                                           deltas, opt)
 
     # -- windowed-engine parts hooks (round 5; tables/base.py contract) -----
     # One window exchange already delivered EVERY rank's payloads — these
@@ -1160,8 +1257,7 @@ class MatrixServerTable(ServerTable):
                 if row_ids is None or p.get("compressed") is not None:
                     return False
                 ids = np.asarray(row_ids, np.int32).ravel()
-                if (ids.size == 0 or int(ids.min()) < 0
-                        or int(ids.max()) >= self.num_rows):
+                if not self._ids_in_range(ids):
                     return False
                 values = np.asarray(p.get("values"), self.dtype)
                 if values.size != ids.size * self.num_cols:
@@ -1266,8 +1362,7 @@ class MatrixServerTable(ServerTable):
                 if row_ids is None or p.get("compressed") is not None:
                     return False
                 ids = np.asarray(row_ids, np.int32).ravel()
-                if (ids.size == 0 or int(ids.min()) < 0
-                        or int(ids.max()) >= self.num_rows):
+                if not self._ids_in_range(ids):
                     return False
                 v = p.get("values")
                 size = v.size if isinstance(v, wire.DeferredArray) \

@@ -18,6 +18,10 @@ window tests stage the mailbox from this thread with ``AddAsyncHandle``
 / ``GetAsyncHandle`` while the engine's thread is held inside a message
 of its own (``_engine_held``), so a window's content is exact and no
 case depends on a race or reads the clock.
+
+A stretch of two or more Adds whose payloads name the same rows is summed
+on the host and applied as ONE lone Add (PR 53, ``ProcessAddSameRows``):
+one dispatch, one merged run; any other stretch goes verb by verb.
 """
 
 import contextlib
@@ -81,7 +85,8 @@ def _run_threads(work):
 
 
 def _moved(before: dict, after: dict, name: str) -> float:
-    return (after[name].get("value", 0.0)
+    """What a counter gained (0 for one that nothing has registered)."""
+    return (after.get(name, {}).get("value", 0.0)
             - before.get(name, {}).get("value", 0.0))
 
 
@@ -338,6 +343,14 @@ def test_the_counters_after_rounds_of_workers():
     assert took["count"] - was["count"] == rounds
     assert took["sum"] - was["sum"] > 0
     assert after["server.bsp.staleness"]["value"] == 0.0
+    # every Add is a lone dispatch or one of a same-rows run, whatever
+    # stretches the workers' sends made
+    runs, in_runs = (_moved(before, after, name)
+                     for name in _SUMMED_COUNTERS)
+    dispatches = _moved(before, after, "server.add.dispatches")
+    assert _moved(before, after, "server.add.run_merged") == runs
+    assert dispatches - runs + in_runs == WORKERS * rounds
+    assert 2 * runs <= in_runs <= WORKERS * rounds
 
 
 def test_the_spans_of_a_held_get_and_of_both_drains():
@@ -402,10 +415,12 @@ def test_the_spans_of_a_held_get_and_of_both_drains():
 
 # -- the window of the BSP engine (PR 51) -------------------------------------
 
+#: every program JAX traced and every one it compiled, in order
 _COMPILES = []
 jax.monitoring.register_event_duration_secs_listener(
     lambda name, secs, **kw: _COMPILES.append(name)
-    if name == "/jax/core/compile/backend_compile_duration" else None)
+    if name in ("/jax/core/compile/jaxpr_trace_duration",
+                "/jax/core/compile/backend_compile_duration") else None)
 
 
 def _engine_message(msg_type, **fields) -> Message:
@@ -482,12 +497,15 @@ _WINDOW_COUNTERS = ("server.add.run_merged", "server.add.dispatches",
                     "server.get.shared", "server.bsp.rounds",
                     "server.bsp.gets_cached", "server.bsp.adds_cached",
                     "server.window.verbs", "server.window.barrier_splits")
+#: the table's own count of its same-rows runs and of the Adds in them
+#: (registered by the first stretch of two or more a table is offered)
+_SUMMED_COUNTERS = ("table.add_run.summed", "table.add_run.summed_adds")
 
 
 def _window_moved(before: dict, after: dict) -> dict:
     """What one staged window moved, and that it WAS one window."""
     moved = {name.split(".", 1)[1]: _moved(before, after, name)
-             for name in _WINDOW_COUNTERS}
+             for name in _WINDOW_COUNTERS + _SUMMED_COUNTERS}
     moved["windows"] = (after["server.window.latency_s"]["count"]
                         - before.get("server.window.latency_s",
                                      {"count": 0})["count"])
@@ -500,9 +518,10 @@ def _total(deltas, j, workers=range(WORKERS)):
 
 def test_a_whole_round_queued_is_one_window_and_one_gather():
     """(a) Four Adds then four Gets in the mailbox are ONE window: the
-    Adds one stretch, applied verb by verb (no run is merged under BSP),
-    the Gets one gather that three of them share, and every Get is the
-    round's total."""
+    Adds one stretch that names one id set, summed on the host and
+    applied as ONE lone Add (one dispatch, one merged run, four Adds
+    summed), the Gets one gather that three of them share, and every Get
+    is the round's total."""
     import multiverso_tpu as mv
     ids, deltas = _traffic(60)
     _world(mv, "-sync=true")
@@ -524,7 +543,8 @@ def test_a_whole_round_queued_is_one_window_and_one_gather():
     ref.round(0, ids[0], [deltas[w][0] for w in range(WORKERS)])
     for got in rows:
         assert np.array_equal(got, ref.expect_get(0, ids[0]))
-    assert moved == {"add.run_merged": 0, "add.dispatches": WORKERS,
+    assert moved == {"add.run_merged": 1, "add.dispatches": 1,
+                     "add_run.summed": 1, "add_run.summed_adds": WORKERS,
                      "get.shared": WORKERS - 1, "bsp.rounds": 1,
                      "bsp.gets_cached": 0, "bsp.adds_cached": 0,
                      "window.verbs": 2 * WORKERS,
@@ -568,9 +588,10 @@ def test_no_add_passes_a_get_the_clocks_placed_before_it(batch):
                     sent.get(table, w, ids[0])
                 sent.add(table, 0, ids[0], deltas[0][1])
                 sent.add(table, 1, ids[0], deltas[1][1])
-            # three stretches: the round's last Add; the four Gets; the
-            # next round's two Adds
-            want = {"add.run_merged": 0, "add.dispatches": 3,
+            # three stretches: the round's last Add, a lone one; the four
+            # Gets; the next round's two Adds, summed
+            want = {"add.run_merged": 1, "add.dispatches": 2,
+                    "add_run.summed_adds": 2,
                     "get.shared": WORKERS - 1, "bsp.rounds": 1,
                     "bsp.adds_cached": 0, "window.verbs": WORKERS + 3,
                     "windows": 1}
@@ -619,8 +640,9 @@ def test_a_get_queued_before_the_rounds_last_add_is_held_and_holds_it():
     """(c) Worker 0's Get sits in the batch BEFORE worker 3's Add: the
     clocks hold it, that Add's tick drains it, and it holds that Add.
     The Add joins the stretch of the three before it: the clocks placed
-    the Get behind it. The drain dispatches the held Get at the tick's
-    position, and the three Gets behind it share its gather."""
+    the Get behind it, and the four are summed and applied as one lone
+    Add. The drain dispatches the held Get at the tick's position, and
+    the three Gets behind it share its gather."""
     import multiverso_tpu as mv
     ids, deltas = _traffic(62)
     last = WORKERS - 1
@@ -646,7 +668,8 @@ def test_a_get_queued_before_the_rounds_last_add_is_held_and_holds_it():
         mv.MV_ShutDown()
     for got in rows:
         assert np.array_equal(got, _total(deltas, 0))
-    assert moved == {"add.run_merged": 0, "add.dispatches": WORKERS,
+    assert moved == {"add.run_merged": 1, "add.dispatches": 1,
+                     "add_run.summed": 1, "add_run.summed_adds": WORKERS,
                      "get.shared": WORKERS - 1, "bsp.rounds": 1,
                      "bsp.gets_cached": 1, "bsp.adds_cached": 0,
                      "window.verbs": 2 * WORKERS,
@@ -662,7 +685,10 @@ def test_a_get_queued_before_the_rounds_last_add_is_held_and_holds_it():
     adds = sorted((e for e in spans
                    if e["name"] == "server.table.add_run.dispatch"),
                   key=lambda e: e["ts"])
-    assert len(adds) == WORKERS
+    assert len(adds) == 1 and adds[0]["args"]["adds"] == WORKERS
+    merges = [e for e in spans
+              if e["name"] == "server.table.add_run.merge"]
+    assert len(merges) == 1 and merges[0]["args"]["adds"] == WORKERS
     assert adds[-1]["ts"] + adds[-1]["dur"] <= drain["ts"]
     dispatched = [e["ts"] for e in spans
                   if e["name"] == "server.table.get.dispatch"]
@@ -673,8 +699,9 @@ def test_a_get_queued_before_the_rounds_last_add_is_held_and_holds_it():
 @pytest.mark.parametrize("barrier", ["finish_train", "store_load"])
 def test_a_message_that_is_no_verb_ends_the_stretches(barrier):
     """(d) A FinishTrain, and a StoreLoad, inside a batch: the Adds in
-    front of it are applied when it runs (three, and two), the verbs
-    behind it are judged and served after it."""
+    front of it are applied when it runs (three, and two: each stretch
+    summed into one lone Add), the verbs behind it are judged and served
+    after it."""
     import multiverso_tpu as mv
     from multiverso_tpu.updaters.base import GetOption
     ids, deltas = _traffic(63)
@@ -698,7 +725,8 @@ def test_a_message_that_is_no_verb_ends_the_stretches(barrier):
                     sent.get(table, w, ids[0])
             seen_by_it = None
             want_rows = [_total(deltas, 0, range(last))] * last
-            want = {"add.run_merged": 0, "add.dispatches": last,
+            want = {"add.run_merged": 1, "add.dispatches": 1,
+                    "add_run.summed_adds": last,
                     "bsp.gets_cached": 1, "get.shared": last - 2,
                     "window.verbs": 2 * last, "window.barrier_splits": 1,
                     "windows": 1}
@@ -718,7 +746,8 @@ def test_a_message_that_is_no_verb_ends_the_stretches(barrier):
                     sent.get(table, w, ids[0])
             seen_by_it = _total(deltas, 0, range(2))
             want_rows = [_total(deltas, 0)] * WORKERS
-            want = {"add.run_merged": 0, "add.dispatches": WORKERS,
+            want = {"add.run_merged": 2, "add.dispatches": 2,
+                    "add_run.summed_adds": WORKERS,
                     "bsp.gets_cached": 0, "get.shared": WORKERS - 1,
                     "window.verbs": 2 * WORKERS,
                     "window.barrier_splits": 1, "windows": 1}
@@ -767,10 +796,10 @@ def test_two_tables_interleaved_in_one_batch():
         assert np.array_equal(got_a, _total(deltas, 0))
         assert np.array_equal(got_b, _total(deltas_b, 0))
     assert moved["windows"] == 1 and moved["window.verbs"] == 4 * WORKERS
-    # four stretches of two, where the asynchronous cut makes two runs
-    # of four
-    assert (moved["add.run_merged"], moved["add.dispatches"]) == (
-        0, 2 * WORKERS)
+    # four stretches of two, each summed, where the asynchronous cut
+    # makes two runs of four
+    assert (moved["add.run_merged"], moved["add.dispatches"]) == (4, 4)
+    assert moved["add_run.summed_adds"] == 2 * WORKERS
     assert moved["get.shared"] == 2 * (WORKERS - 1)
 
 
@@ -779,7 +808,11 @@ def test_the_window_answers_as_one_verb_at_a_time(kind):
     """(f) A table whose updater is not linear (the order of its Adds
     shows in the rows), and a SparseMatrixTable (no two-phase Get,
     answers that depend on who asks): two rounds queued whole give, Get
-    for Get, what the same verbs give sent one after another."""
+    for Get, what the same verbs give sent one after another. The
+    non-linear tables decline the same-rows run and go verb by verb; the
+    SparseMatrixTable's updater is linear, so its round's four Adds are
+    summed, and its freshness bits still see every Add and its
+    worker."""
     import multiverso_tpu as mv
     ids, deltas = _traffic(66)
     rounds = 2
@@ -830,17 +863,111 @@ def test_the_window_answers_as_one_verb_at_a_time(kind):
         assert moved_q["get.shared"] == 0
     else:
         assert np.any(queued[-1])
-    assert moved_q["add.run_merged"] == 0
-    assert moved_q["add.dispatches"] == WORKERS * rounds
+    summed = rounds if kind == "sparse" else 0
+    assert moved_q["add.run_merged"] == summed
+    assert moved_q["add_run.summed_adds"] == WORKERS * summed
+    assert moved_q["add.dispatches"] == (summed or WORKERS * rounds)
+    assert moved_1["add.run_merged"] == moved_1["add_run.summed"] == 0
+
+
+@pytest.mark.parametrize("rows", ["sets_of_their_own", "one_entry_differs",
+                                  "another_order", "another_length"])
+def test_a_stretch_that_names_different_rows_goes_verb_by_verb(rows):
+    """All or nothing a stretch: four queued Adds whose id arrays are
+    not one array are four lone Adds (never the stacked run), and every
+    Get is still the round's total."""
+    import multiverso_tpu as mv
+    ids, deltas = _traffic(70)
+    per_worker = [ids[0]] * WORKERS
+    if rows == "sets_of_their_own":
+        per_worker = ids[:WORKERS]
+    elif rows == "one_entry_differs":
+        other = ids[0].copy()
+        other[-1] = ids[1][0]
+        per_worker = [ids[0], ids[0], other, ids[0]]
+    elif rows == "another_order":
+        per_worker = [ids[0], ids[0][::-1].copy(), ids[0], ids[0]]
+    else:
+        per_worker = [ids[0], ids[0], ids[0], ids[0][:-1]]
+    asked = np.unique(np.concatenate([ids[0], *per_worker]))
+    _world(mv, "-sync=true")
+    try:
+        table = mv.MV_CreateTable(MatrixTableOption(num_rows=ROWS,
+                                                    num_cols=COLS))
+        sent = _Staged(mv)
+        before = _settled_snapshot()
+        with _engine_held():
+            for w in range(WORKERS):
+                sent.add(table, w, per_worker[w],
+                         deltas[w][0][:len(per_worker[w])])
+            for w in range(WORKERS):
+                sent.get(table, w, asked)
+        rows_got = sent.rows()
+        moved = _window_moved(before, _settled_snapshot())
+    finally:
+        mv.MV_ShutDown()
+    want = np.zeros((ROWS, COLS), np.float32)
+    for w in range(WORKERS):
+        np.add.at(want, per_worker[w], deltas[w][0][:len(per_worker[w])])
+    for got in rows_got:
+        assert np.array_equal(got, want[asked])
+    assert {k: moved[k] for k in (
+        "add.run_merged", "add.dispatches", "add_run.summed",
+        "add_run.summed_adds", "get.shared", "bsp.rounds", "windows")} == {
+        "add.run_merged": 0, "add.dispatches": WORKERS,
+        "add_run.summed": 0, "add_run.summed_adds": 0,
+        "get.shared": WORKERS - 1, "bsp.rounds": 1, "windows": 1}
+
+
+@pytest.mark.parametrize("at", [0, 2, 3])
+def test_a_stretch_that_fails_validation_answers_every_add_with_it(at):
+    """The run contract: a same-rows run validates before it writes, and
+    what it raises is the answer to EVERY Add of the stretch (there is no
+    verb-by-verb fallback behind an exception). The table is untouched:
+    the round's Gets, whose clocks the four Adds did tick, read zeros."""
+    import multiverso_tpu as mv
+    ids, deltas = _traffic(71)
+    _world(mv, "-sync=true")
+    try:
+        table = mv.MV_CreateTable(MatrixTableOption(num_rows=ROWS,
+                                                    num_cols=COLS))
+        sent = _Staged(mv)
+        before = _settled_snapshot()
+        handles = []
+        with _engine_held():
+            for w in range(WORKERS):
+                # worker ``at``'s values are no numbers: the table's
+                # conversion raises while the run is being validated
+                values = (np.array(["x"] * K) if w == at else deltas[w][0])
+                with mv.MV_WorkerContext(w):
+                    handles.append(table.AddAsync(
+                        {"row_ids": ids[0], "values": values}))
+            for w in range(WORKERS):
+                sent.get(table, w, ids[0])
+        for handle in handles:
+            with pytest.raises(ValueError, match="could not convert"):
+                table.Wait(handle)
+        rows = sent.rows()
+        moved = _window_moved(before, _settled_snapshot())
+        raw = table.server().raw()
+    finally:
+        mv.MV_ShutDown()
+    assert len(rows) == WORKERS
+    for got in rows:
+        assert not np.any(got)
+    assert not np.any(raw)
+    assert (moved["add.dispatches"], moved["add.run_merged"],
+            moved["add_run.summed_adds"], moved["bsp.rounds"]) == (0, 0, 0, 1)
 
 
 @pytest.mark.parametrize("queued", ["whole_rounds", "split_rounds"])
 def test_no_program_is_compiled_after_the_first_round(queued):
     """(g) Round 0 goes verb by verb; the rounds behind it are queued
     whole, or so that their stretches hold 3 + 1, 2 + 2 and 1 + 3 verbs:
-    no program is compiled for them, whatever sizes the stretches take
-    (a stretch of Adds is no merged run under BSP, and a shared gather
-    is the lone Get's program)."""
+    no program is traced or compiled for them, whatever sizes the
+    stretches take (a stretch of Adds summed is the lone Add's program
+    at the lone Add's bucket, and a shared gather is the lone Get's
+    program)."""
     import multiverso_tpu as mv
     ids, deltas = _traffic(67)
     _world(mv, "-sync=true")
@@ -867,12 +994,14 @@ def test_no_program_is_compiled_after_the_first_round(queued):
 
         round_of(0, (1, 2, 3))
         compiled = len(_COMPILES)
+        before = _settled_snapshot()
         rounds = ([(), (), ()] if queued == "whole_rounds"
                   else [(3,), (2,), (1,)])
         for r, cuts in enumerate(rounds, start=1):
             round_of(r, cuts)
-        compiled = len(_COMPILES) - compiled
+        built = _COMPILES[compiled:]
         got = sent.rows()
+        moved = _window_moved(before, _settled_snapshot())
     finally:
         mv.MV_ShutDown()
     ref = BspRounds(COLS + 1, WORKERS, np.concatenate(ids))
@@ -881,7 +1010,11 @@ def test_no_program_is_compiled_after_the_first_round(queued):
         for w in range(WORKERS):
             assert np.array_equal(got[WORKERS * r + w],
                                   ref.expect_get(r, ids[r])), (r, w)
-    assert compiled == 0
+    assert built == []
+    # the stretches of two or more were summed: 4 + 4 + 4, or 3 + (2 + 2) + 3
+    assert moved["add_run.summed_adds"] == (12 if queued == "whole_rounds"
+                                            else 10)
+    assert moved["add.dispatches"] == (3 if queued == "whole_rounds" else 6)
 
 
 @pytest.mark.parametrize("late", ["a_get", "the_rounds_last_add"])
@@ -926,13 +1059,15 @@ def test_what_lands_while_a_window_is_served_joins_it(late):
                     "bsp.rounds": 0, "window.verbs": WORKERS, "windows": 1}
         else:
             before = _settled_snapshot()
-            send_late_from("ProcessAdd", lambda: sent.add(
+            send_late_from("ProcessAddSameRows", lambda: sent.add(
                 table, last, ids[0], deltas[last][0]))
             with _engine_held():
                 for w in range(last):
                     sent.add(table, w, ids[0], deltas[w][0])
                 sent.get(table, 0, ids[0])
-            want = {"add.dispatches": WORKERS, "bsp.gets_cached": 1,
+            # the three queued Adds summed, the late one a lone Add
+            want = {"add.dispatches": 2, "add.run_merged": 1,
+                    "add_run.summed_adds": last, "bsp.gets_cached": 1,
                     "bsp.rounds": 1, "window.verbs": WORKERS + 1,
                     "windows": 1}
         rows = sent.rows()

@@ -215,3 +215,186 @@ def test_add_between_two_gets_of_a_window(world):
     np.testing.assert_array_equal(first, init[ids])
     np.testing.assert_array_equal(second, init[ids] + delta)
     assert first.shape == second.shape == (40, COLS)
+
+
+# -- the same-rows run ------------------------------------------------------
+# A run of Adds whose payloads name ONE id array is summed on the host and
+# applied as one lone Add (``ProcessAddSameRows``; ``ProcessAddRun`` tries
+# it first, and the BSP engine offers its stretches nothing else).
+
+K = 24
+
+
+def _shared_ids(rng):
+    """K ids, a third of them distinct: repeats inside the shared set."""
+    return _batch(rng, K)[0]
+
+
+def _payloads(rng, ids, n: int, whole: bool, worker_of=lambda i: i % 2):
+    from multiverso_tpu.updaters.base import AddOption
+    out = []
+    for i in range(n):
+        delta = (rng.integers(-3, 4, (K, COLS)) if whole
+                 else rng.standard_normal((K, COLS))).astype(np.float32)
+        delta.setflags(write=False)     # a write to a payload raises
+        out.append({"row_ids": ids, "values": delta,
+                    "option": AddOption(worker_id=worker_of(i))})
+    ids.setflags(write=False)
+    return out
+
+
+@pytest.mark.parametrize("entry", ["ProcessAddSameRows", "ProcessAddRun"])
+@pytest.mark.parametrize("deltas", ["whole_numbers", "random"])
+@pytest.mark.parametrize("n", [2, 3, 4])
+def test_same_rows_run_equals_the_adds_one_by_one(world, n, deltas, entry):
+    rng = np.random.default_rng(17 + n)
+    summed, init = _table(world, rng)
+    one_by_one, _ = _table(world, np.random.default_rng(17 + n))
+    ids = _shared_ids(rng)
+    payloads = _payloads(rng, ids, n, whole=deltas == "whole_numbers")
+    kept = [p["values"].copy() for p in payloads]
+    names = ("table.add_run.summed", "table.add_run.summed_adds",
+             "table.device.calls", "table.device.h2d_copies",
+             "table.device.h2d_bytes")
+
+    def moved_by(call):
+        before = [_counter(name) for name in names]
+        call()
+        return [_counter(name) - was for name, was in zip(names, before)]
+
+    runs, adds, *crossed = moved_by(
+        lambda: getattr(summed.server(), entry)(payloads))
+    assert (runs, adds) == (1, n)
+    # what crosses is ONE lone Add's, whatever n
+    lone = moved_by(lambda: one_by_one.server().ProcessAdd(**payloads[0]))
+    assert crossed == lone[2:] and lone[:2] == [0, 0]
+    assert crossed[2] < 2 * K * COLS * 4
+    for p in payloads[1:]:
+        one_by_one.server().ProcessAdd(**p)
+    got, want = summed.server().raw(), one_by_one.server().raw()
+    if deltas == "whole_numbers":
+        np.testing.assert_array_equal(got, want)
+        replay = init.copy()
+        for p in payloads:
+            np.add.at(replay, ids, p["values"])
+        np.testing.assert_array_equal(got, replay)
+    else:
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    for p, was in zip(payloads, kept):
+        np.testing.assert_array_equal(p["values"], was)
+
+
+def _declines(world, monkeypatch, rng, case):
+    """-> (table, its initial rows, payloads the same-rows run declines)."""
+    table, init = _table(world, rng)
+    ids = rng.choice(ROWS, K, replace=False).astype(np.int32)
+    payloads = _payloads(rng, ids, 3, whole=True)
+    other = ids.copy()
+    if case == "one_entry_differs":
+        other[-1] = (other[-1] + 1) % ROWS
+        payloads[2]["row_ids"] = other
+    elif case == "another_length":
+        payloads[1]["row_ids"] = ids[:-1]
+        payloads[1]["values"] = payloads[1]["values"][:-1]
+    elif case == "another_order":
+        payloads[1]["row_ids"] = ids[::-1].copy()
+    elif case == "compressed":
+        payloads[2]["compressed"] = {"kind": "sparse", "row_ids": ids}
+    elif case == "whole_table":
+        payloads[0] = {"row_ids": None, "option": None,
+                       "values": np.ones((ROWS, COLS), np.float32)}
+    elif case == "id_out_of_range":
+        other[0] = ROWS
+        for p in payloads:
+            p["row_ids"] = other
+    elif case == "values_of_another_size":
+        payloads[2]["values"] = payloads[2]["values"][:-1]
+    elif case == "a_lone_add":
+        payloads = payloads[:1]
+    elif case == "non_linear_updater":
+        table = world.MV_CreateTable(MatrixTableOption(
+            num_rows=ROWS, num_cols=COLS, updater_type="adagrad",
+            initializer=lambda shape: init))
+    elif case == "two_processes":
+        monkeypatch.setattr(matrix_table.multihost, "world_size", lambda: 2)
+    else:
+        raise AssertionError(case)
+    return table, init, payloads
+
+
+@pytest.mark.parametrize("case", [
+    "one_entry_differs", "another_length", "another_order", "compressed",
+    "whole_table", "id_out_of_range", "values_of_another_size",
+    "a_lone_add", "non_linear_updater", "two_processes"])
+def test_same_rows_run_declines_and_leaves_the_table(world, monkeypatch,
+                                                     case):
+    table, init, payloads = _declines(world, monkeypatch,
+                                      np.random.default_rng(23), case)
+    srv = table.server()
+    names = ("table.device.calls", "table.device.h2d_copies",
+             "table.add_run.summed", "table.add_run.summed_adds")
+    before = [_counter(name) for name in names]
+    assert srv.ProcessAddSameRows(payloads) is False
+    monkeypatch.undo()
+    assert [_counter(name) for name in names] == before
+    np.testing.assert_array_equal(srv.raw(), init)
+
+
+def test_a_run_of_mixed_id_sets_is_stacked_as_before(world):
+    """``ProcessAddRun`` falls through at the first id array that
+    differs: the stacked run's program, every payload's bytes across."""
+    rng = np.random.default_rng(29)
+    table, init = _table(world, rng)
+    ids = rng.choice(ROWS, K, replace=False).astype(np.int32)
+    payloads = _payloads(rng, ids, 3, whole=True)
+    payloads[2]["row_ids"] = rng.choice(ROWS, K, replace=False).astype(
+        np.int32)
+    h2d = _counter("table.device.h2d_bytes")
+    summed = _counter("table.add_run.summed")
+    assert table.server().ProcessAddRun(payloads) is True
+    assert _counter("table.add_run.summed") == summed
+    # the stack is a power of two of batches: four for three
+    assert _counter("table.device.h2d_bytes") - h2d >= 4 * K * COLS * 4
+    for p in payloads:
+        np.add.at(init, p["row_ids"], p["values"])
+    np.testing.assert_array_equal(table.server().raw(), init)
+
+
+@pytest.mark.parametrize("entry", ["ProcessAddSameRows", "ProcessAddRun"])
+def test_sparse_table_notes_every_payload_of_a_same_rows_run(world, entry):
+    """SparseMatrixTable's freshness bits (and the publish journal behind
+    the same hook) see every Add of a summed run in message order with
+    its own option, as they do verb by verb."""
+    from multiverso_tpu.tables import SparseMatrixTableOption
+    rng = np.random.default_rng(31)
+    tables = [world.MV_CreateTable(SparseMatrixTableOption(
+        num_rows=ROWS, num_cols=COLS)) for _ in range(2)]
+    summed, one_by_one = (t.server() for t in tables)
+    ids = rng.choice(ROWS, K, replace=False).astype(np.int32)
+    payloads = _payloads(rng, ids, 3, whole=True,
+                         worker_of=lambda i: (1, 0, 1)[i])
+    for srv in (summed, one_by_one):    # every worker fresh on every row
+        for w in range(2):
+            srv.ProcessGet(GetOption(worker_id=w))
+    noted = []
+    note = summed._note_add_parts
+
+    def recorded(option, parts):
+        noted.append((option.worker_id, [np.array(p) for p in parts]))
+        note(option, parts)
+
+    summed._note_add_parts = recorded
+    assert getattr(summed, entry)(payloads) is True
+    for p in payloads:
+        one_by_one.ProcessAdd(**p)
+    assert [w for w, _ in noted] == [1, 0, 1]
+    for _, parts in noted:
+        assert len(parts) == 1
+        np.testing.assert_array_equal(parts[0], ids)
+    np.testing.assert_array_equal(summed.up_to_date, one_by_one.up_to_date)
+    np.testing.assert_array_equal(summed.raw(), one_by_one.raw())
+    # the last Add was worker 1's: worker 0 is stale on the rows, and
+    # worker 1 too (worker 0 added between its Adds)
+    for w in range(2):
+        got_ids, rows = summed.ProcessGet(GetOption(worker_id=w))
+        assert sorted(got_ids.tolist()) == sorted(ids.tolist())
