@@ -8,6 +8,20 @@ sum-of-squared-gradient tables — plus the int64 KV word-count table
 fetches the block's touched rows (communicator.cpp:117); ``AddDeltaParameter``
 pushes back ``trained - fetched`` (communicator.cpp:157-206) so concurrent
 workers' progress merges additively on the default (+=) server updater.
+
+Where a block's rows live between its fetch and its push. On both planes
+a fetch leaves two things on the device: the block's ORIGINALS by state
+field and the training copy the block's scan is handed and donates
+(``training_rows`` long); the push makes ``trained - original`` there, in
+the originals' buffers, which it consumes. The device plane gathers the
+rows out of the sharded stores and scatters the delta back: nothing
+crosses. The host plane gets them through the server as numpy arrays
+(``MV_MultiGetAsync``) and sends the delta as one (``AddFireForget``): its
+rows cross the boundary ONCE each way (``training_state`` in,
+``add_delta_parameter`` back) and no line of the host passes over them in
+between. Every program of that path is keyed by the training RUNG (with
+the width and the dtype), or by a piece count (``_join_row_pieces``):
+never by a block's row count, which differs every block and every seed.
 """
 
 from __future__ import annotations
@@ -23,6 +37,7 @@ import multiverso_tpu as mv
 from multiverso_tpu.models.wordembedding.model import TrainState, init_embedding
 from multiverso_tpu.parallel.mesh import next_bucket
 from multiverso_tpu.tables import KVTableOption, MatrixTableOption
+from multiverso_tpu.tables import matrix_table
 from multiverso_tpu.tables.matrix_table import _pad_row_batch
 from multiverso_tpu.telemetry import metrics as tmetrics
 from multiverso_tpu.telemetry import trace as ttrace
@@ -47,6 +62,41 @@ def _trained_delta(trained: jax.Array, fetched: jax.Array) -> jax.Array:
     rows were: the spare rows of the training copy are cut in the same
     program and the delta is no row set more in HBM."""
     return trained[: fetched.shape[0]] - fetched
+
+
+def _place_at_rung(rows: np.ndarray, rung: int) -> jax.Array:
+    """A reply's host rows on the device at ``rung`` rows, zeros below
+    them, the way a host delta crosses (``matrix_table._place_rows``):
+    views of the reply's array in eighths of the rung and one
+    ``_join_row_pieces`` program a piece count; no pad, no zeroing and no
+    copy on the host. The table layer sends a batch whose pad is under
+    its ``_HOST_CUT_PAD_BYTES`` exact-size and pads it by a program a
+    distinct count; a block's counts never repeat, so such a batch (a
+    block's input rows: tens of MB) is padded here on the host,
+    milliseconds, and crosses at the rung."""
+    spare = rung - len(rows)
+    if spare * rows[:1].nbytes <= matrix_table._HOST_CUT_PAD_BYTES:
+        return jnp.asarray(np.pad(rows, ((0, spare), (0, 0))))
+    return matrix_table._place_rows(rows, rung)
+
+
+@jax.jit
+def _training_copy(original: jax.Array) -> jax.Array:
+    """The buffer a host-plane block's scan is handed and donates, made
+    on the device of the original, which stays for the delta. One program
+    a rung."""
+    return jnp.copy(original)
+
+
+@functools.partial(jax.jit, donate_argnums=(1,))
+def _rung_delta(trained: jax.Array, original: jax.Array) -> jax.Array:
+    """trained - original over the WHOLE training rung, written where the
+    original was: one program a rung (``_trained_delta``'s shape is the
+    row count, which the device plane's fetch fixes). Below the fetched
+    rows it gives 0 - 0 and, in the last row, what the scan's pad lanes
+    wrote to the trash row: the caller's ``[:n]`` of the host copy cuts
+    both."""
+    return trained - original
 
 
 class Communicator:
@@ -84,7 +134,11 @@ class Communicator:
 
     def request_parameter(self, input_rows: np.ndarray,
                           output_rows: np.ndarray) -> Tuple[TrainState, dict]:
-        """Fetch the block's rows; returns (device state, fetched host copy).
+        """Fetch the block's rows; returns (device state, fetched): the
+        pair ``request_parameter_device`` returns. ``fetched`` holds the
+        block's originals ON THE DEVICE by state field
+        (``training_state`` put them where the reply's host rows were);
+        ``add_delta_parameter`` consumes them.
 
         Issues every table's Get asynchronously BEFORE waiting any
         (round 7): the engine drains the burst into one window — one
@@ -130,38 +184,57 @@ class Communicator:
         return fetched
 
     def training_state(self, fetched: dict) -> TrainState:
-        """The device state a block trains: each table's fetched rows in
-        the same training copy as the device plane's, one state shape a
-        rung, whichever plane fetched it."""
-        def train(rows: np.ndarray) -> jax.Array:
-            spare = training_rows(len(rows)) - len(rows)
-            return jnp.asarray(np.pad(rows, ((0, spare), (0, 0))))
-
-        return TrainState(
-            ie=train(fetched["ie"]), eo=train(fetched["eo"]),
-            ie_g2=train(fetched["ie_g2"]) if self.opt.use_adagrad else None,
-            eo_g2=train(fetched["eo_g2"]) if self.opt.use_adagrad else None)
+        """The device state a block trains, of a reply's host rows; ON
+        RETURN ``fetched`` HOLDS THE BLOCK'S ORIGINALS ON THE DEVICE in
+        their place, by state field, and the host keeps no copy of the
+        reply. Each table's rows cross once, as the reply holds them, and
+        lie at ``training_rows`` rows there (``_place_at_rung``: zeros
+        below the rows, the last the trash row). That array is the
+        original, kept in HBM for the delta; the state is a copy of it
+        made on the device (``_training_copy``), a buffer of its own
+        because the scan donates it. The same training copy as the device
+        plane's: one state shape a rung, whichever plane fetched it."""
+        train = {}
+        for name, rows in fetched.items():
+            fetched[name] = _place_at_rung(rows, training_rows(len(rows)))
+            train[name] = _training_copy(fetched[name])
+        return TrainState(ie=train["ie"], eo=train["eo"],
+                          ie_g2=train.get("ie_g2"), eo_g2=train.get("eo_g2"))
 
     def add_delta_parameter(self, state: TrainState, fetched: dict,
                             input_rows: np.ndarray,
                             output_rows: np.ndarray) -> None:
         """Push trained - fetched (reference AddDeltaParameter,
-        communicator.cpp:157-206), table after table, so that the server
-        applies one table's delta while the next is made. Three children
-        of the caller's span (``worker.we.push``) a table: ``.take`` the
-        copy of the trained rows back (the first waits for the block's
-        program), ``.delta`` the subtraction, ``.add`` the
-        ``AddFireForget``. The row bytes a block's Adds send are counted
-        here, once."""
-        pushed = 0
-        for name, table, ids in self._row_specs(input_rows, output_rows):
-            with ttrace.child(".take"):
-                trained = np.asarray(getattr(state, name))[: len(ids)]
+        communicator.cpp:157-206). ``fetched`` holds the block's
+        originals on the device (``training_state``) and is consumed: the
+        subtraction runs there over the whole rung, in the originals'
+        buffers (``_rung_delta``: float32, the host's ``trained -
+        fetched`` to the bit). Every table's program is dispatched and
+        its copy back started before the first is taken; then table after
+        table, in the order ``ie``, ``eo``, ``ie_g2``, ``eo_g2``, the
+        rung's host array is taken and ``AddFireForget`` is handed the
+        view of its first ``len(ids)`` rows, so the server applies one
+        table's delta while the next crosses. That array is READ-ONLY (a
+        device array's host copy): nothing on an Add's way writes to a
+        payload. Children of the caller's span (``worker.we.push``), one
+        of each a table: ``.delta`` the dispatches, ``.take`` the wait
+        for the copy back (the first waits for the block's program),
+        ``.add`` the ``AddFireForget``. The row bytes a block's Adds send
+        are counted here, once."""
+        specs = self._row_specs(input_rows, output_rows)
+        deltas = []
+        for name, _, _ in specs:
             with ttrace.child(".delta"):
-                delta = trained - fetched[name]
+                delta = _rung_delta(getattr(state, name), fetched.pop(name))
+                delta.copy_to_host_async()
+            deltas.append(delta)
+        pushed = 0
+        for (_, table, ids), delta in zip(specs, deltas):
+            with ttrace.child(".take"):
+                rows = np.asarray(delta)[: len(ids)]
             with ttrace.child(".add"):
-                table.AddFireForget(delta, row_ids=ids)
-            pushed += delta.nbytes
+                table.AddFireForget(rows, row_ids=ids)
+            pushed += rows.nbytes
         tmetrics.counter("we.host_plane.pushed_bytes").inc(pushed)
 
     # -- device plane (rows never leave HBM) --------------------------------

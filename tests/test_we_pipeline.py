@@ -2,9 +2,12 @@
 plane (``-is_pipeline 1``: rows through the server by Get and Add, the next
 block's rows prefetched), against its plain reference
 (``models/wordembedding/pipeline_reference.py``); the order it keeps; the
-counters of what crosses the boundary; and that a Get or an Add of a
+counters of what crosses the boundary; that a Get or an Add of a
 block's row set compiles no program for its row count and copies nothing
-on the host.
+on the host; and that between a reply and a push a block's rows live on
+the device alone: the training copy and the delta are made there, bit for
+bit what ``np.pad`` and the host's ``trained - fetched`` made, by programs
+keyed by the training rung.
 """
 
 import os
@@ -13,6 +16,7 @@ import jax
 import numpy as np
 import pytest
 
+from multiverso_tpu.models.wordembedding import communicator
 from multiverso_tpu.models.wordembedding import pipeline_reference as ref
 from multiverso_tpu.models.wordembedding.option import Option
 from multiverso_tpu.tables import matrix_table
@@ -284,8 +288,10 @@ def test_the_order_holds_under_a_slow_server(tmp_path, monkeypatch):
 def test_second_pass_compiles_no_program_a_row_count(tmp_path, monkeypatch):
     """After a pass that warms the shapes up, a pass whose blocks name
     other row counts (another draw of negatives) compiles no slice and no
-    pad program, with every Get's pad and every Add's pad over the
-    constant: at most a join program a piece count it had not seen."""
+    pad program, with every Get's pad, every Add's pad and every training
+    copy's pad over the constant: at most a join program a piece count it
+    had not seen. The worker's own two programs, the training copy and
+    the delta, have the rung's shape and are not built again."""
     monkeypatch.setattr(matrix_table, "_HOST_CUT_PAD_BYTES", 0)
     names = ("table.get.host_cuts", "table.get.device_cuts",
              "table.add.host_pieces")
@@ -298,6 +304,8 @@ def test_second_pass_compiles_no_program_a_row_count(tmp_path, monkeypatch):
         compiled = len(_COMPILES)
         pads = matrix_table._pad_row_batch._cache_size()
         joins = matrix_table._join_row_pieces._cache_size()
+        copies = communicator._training_copy._cache_size()
+        deltas = communicator._rung_delta._cache_size()
         app.train()
         second = {(len(b["input_rows"]), len(b["output_rows"]))
                   for b in app.blocks}
@@ -305,13 +313,238 @@ def test_second_pass_compiles_no_program_a_row_count(tmp_path, monkeypatch):
         assert matrix_table._pad_row_batch._cache_size() == pads
         new_joins = matrix_table._join_row_pieces._cache_size() - joins
         assert len(_COMPILES) - compiled == new_joins <= 1
+        assert communicator._training_copy._cache_size() == copies
+        assert communicator._rung_delta._cache_size() == deltas
         host, device, pieces = (_counter(name) - was
                                 for name, was in zip(names, before))
         # a bucket over 256 rows keeps its pad under a quarter of the rows
         # and is carried; a shorter one is cut to an eighth of the bucket
         assert host > 0 and host + device > 0
-        # four Adds a block, seven or eight pieces each
-        assert 4 * 7 * len(app.blocks) <= pieces <= 4 * 8 * len(app.blocks)
+        # four training copies and four Adds a block, seven or eight
+        # pieces each
+        assert 8 * 7 * len(app.blocks) <= pieces <= 8 * 8 * len(app.blocks)
+
+
+# -- a block's rows between its reply and its push ---------------------------
+
+def _record_a_blocks_rows(monkeypatch):
+    """-> (replies, trained, sent): a block each, in the worker's order,
+    the host rows a reply held (copies), the trained state as the push
+    was handed it (host copies) and the (table, payload, a copy of the
+    payload as it was handed over, ids) of every ``AddFireForget``."""
+    Communicator = communicator.Communicator
+    replies, trained, sent = [], [], []
+    wait, push, add = (Communicator.wait_rows,
+                       Communicator.add_delta_parameter,
+                       matrix_table.MatrixWorkerTable.AddFireForget)
+
+    def waiting(self, handles):
+        got = wait(self, handles)
+        replies.append({name: np.array(rows) for name, rows in got.items()})
+        return got
+
+    def pushing(self, state, fetched, *ids):
+        # the originals, on the device: no host row rides on a block
+        assert all(isinstance(rows, jax.Array) for rows in fetched.values())
+        trained.append({name: np.array(getattr(state, name))
+                        for name in fetched})
+        return push(self, state, fetched, *ids)
+
+    def adding(self, deltas, row_ids=None, option=None):
+        sent.append((self, deltas, np.array(deltas), np.array(row_ids)))
+        return add(self, deltas, row_ids=row_ids, option=option)
+    monkeypatch.setattr(Communicator, "wait_rows", waiting)
+    monkeypatch.setattr(Communicator, "add_delta_parameter", pushing)
+    monkeypatch.setattr(matrix_table.MatrixWorkerTable, "AddFireForget",
+                        adding)
+    return replies, trained, sent
+
+
+def _tables_of(comm):
+    """(state field, table) in the order a push sends."""
+    return [(name, table) for name, table, _ in comm._row_specs(None, None)]
+
+
+@pytest.mark.parametrize("use_adagrad", [True, False],
+                         ids=["adagrad", "sgd"])
+@pytest.mark.parametrize("is_pipeline", [True, False],
+                         ids=["pipeline", "sequential"])
+def test_every_delta_is_the_hosts_subtraction_to_the_bit(
+        tmp_path, monkeypatch, is_pipeline, use_adagrad):
+    """The payload each ``AddFireForget`` receives is ``trained[:n] -
+    fetched`` of the same float32 operands, ``n`` rows long, for every
+    table of every block in the order ie, eo, ie_g2, eo_g2; it is
+    read-only and, once every delta has landed, holds what it held when
+    it was handed over."""
+    replies, trained, sent = _record_a_blocks_rows(monkeypatch)
+    with _App(tmp_path, is_pipeline=is_pipeline, use_adagrad=use_adagrad,
+              epoch=1) as app:
+        pushed = _counter("we.host_plane.pushed_bytes")
+        app.we.train()
+        pushed = _counter("we.host_plane.pushed_bytes") - pushed
+        tables = _tables_of(app.we.comm)
+        for _, table in tables:     # a Get queues behind its table's Adds
+            table.GetRows(np.arange(8, dtype=np.int32))
+        blocks = app.blocks
+    per_block = 4 if use_adagrad else 2
+    assert len(tables) == per_block
+    assert len(replies) == len(trained) == len(blocks) >= 5
+    assert len(sent) == per_block * len(blocks)
+    assert pushed == sum(payload.nbytes for _, payload, _, _ in sent)
+    for b, block in enumerate(blocks):
+        for t, (name, table) in enumerate(tables):
+            got_table, payload, delta, ids = sent[b * per_block + t]
+            want_ids = block["input_rows" if name.startswith("ie")
+                             else "output_rows"]
+            assert got_table is table
+            np.testing.assert_array_equal(ids, want_ids)
+            want = trained[b][name][: len(ids)] - replies[b][name]
+            assert delta.dtype == np.float32 and delta.shape == want.shape
+            np.testing.assert_array_equal(delta, want)
+            # a rung with a row to spare: the view cut the rest
+            assert trained[b][name].shape[0] \
+                == communicator.training_rows(len(ids)) > len(ids)
+            assert not payload.flags.writeable
+            np.testing.assert_array_equal(payload, delta)
+
+
+#: a block's row count against the ladder: the foot of the rung 320 (256
+#: is a rung itself, so its training rung is the next), its middle and
+#: its top less one
+COUNTS = {"a_rungs_foot": 256, "its_middle": 288, "its_top_less_one": 319}
+RUNG = 320
+
+
+@pytest.fixture
+def comm(request):
+    """The communicator alone in a one-worker world, AdaGrad by the
+    parameter."""
+    import multiverso_tpu as mv
+    mv.MV_Init([])
+    try:
+        yield communicator.Communicator(
+            Option(embedding_size=DIM, use_adagrad=request.param,
+                   seed=SEED), VOCAB)
+    finally:
+        mv.MV_ShutDown()
+
+
+@pytest.mark.parametrize("comm", [True, False], ids=["adagrad", "sgd"],
+                         indirect=True)
+@pytest.mark.parametrize("crossing", ["whole", "in_pieces"])
+@pytest.mark.parametrize("case", COUNTS)
+def test_training_copy_and_delta_by_row_count(monkeypatch, comm, crossing,
+                                              case):
+    """A reply of ``n`` rows: the training copy is ``np.pad``'s to the
+    bit and a buffer of its own beside the original, which takes the
+    reply's place in ``fetched``; after a "training" that also writes the
+    spare rows and the trash row, the delta sent is the host's
+    ``trained[:n] - fetched`` to the bit and ``n`` rows long. Whether the
+    rows cross whole, padded by the host, or in eighths of the rung; and
+    no count builds a program the rung's first count did not."""
+    n = COUNTS[case]
+    assert communicator.training_rows(n) == RUNG
+    monkeypatch.setattr(matrix_table, "_HOST_CUT_PAD_BYTES",
+                        0 if crossing == "in_pieces" else 4 << 20)
+    tables = _tables_of(comm)
+    rng = np.random.default_rng(n)
+    # each a view of a longer array, as a reply is of its bucket
+    fetched = {name: rng.standard_normal((RUNG, DIM)).astype(np.float32)[:n]
+               for name, _ in tables}
+    replies = dict(fetched)
+    kept = {name: rows.copy() for name, rows in fetched.items()}
+    names = ("table.add.host_pieces", "we.host_plane.fetched_bytes")
+    before = [_counter(name) for name in names]
+    pads = matrix_table._pad_row_batch._cache_size()
+    state = comm.training_state(fetched)
+    pieces, counted = (_counter(name) - was
+                       for name, was in zip(names, before))
+    assert pieces == (0 if crossing == "whole"
+                      else len(tables) * -(-8 * n // RUNG))
+    assert counted == 0             # wait_rows counts a reply, once
+    assert matrix_table._pad_row_batch._cache_size() == pads
+    assert set(fetched) == {name for name, _ in tables}
+    for name, _ in tables:
+        want = np.pad(kept[name], ((0, RUNG - n), (0, 0)))
+        copy, original = getattr(state, name), fetched[name]
+        assert isinstance(original, jax.Array)
+        np.testing.assert_array_equal(np.asarray(copy), want)
+        np.testing.assert_array_equal(np.asarray(original), want)
+        assert copy is not original
+        assert (copy.unsafe_buffer_pointer()
+                != original.unsafe_buffer_pointer())
+        # nor the reply's memory, which nothing wrote to
+        np.testing.assert_array_equal(replies[name], kept[name])
+    assert (state.ie_g2 is None) == (not comm.opt.use_adagrad)
+    # "training": every row moves, the spare rows and the trash row too
+    trained = jax.tree.map(lambda rows: rows * 1.5 + 0.25, state)
+    sent = []
+    monkeypatch.setattr(
+        matrix_table.MatrixWorkerTable, "AddFireForget",
+        lambda self, deltas, row_ids=None, option=None:
+        sent.append((self, deltas, row_ids)))
+    ids = np.arange(n, dtype=np.int32)
+    originals = dict(fetched)
+    pushed = _counter("we.host_plane.pushed_bytes")
+    comm.add_delta_parameter(trained, fetched, ids, ids)
+    assert _counter("we.host_plane.pushed_bytes") - pushed \
+        == len(tables) * n * DIM * 4
+    assert [table for table, _, _ in sent] == [table for _, table in tables]
+    for (name, _), (_, delta, got_ids) in zip(tables, sent):
+        assert got_ids is ids
+        assert delta.shape == (n, DIM) and delta.dtype == np.float32
+        assert not delta.flags.writeable
+        np.testing.assert_array_equal(
+            delta, np.asarray(getattr(trained, name))[:n] - kept[name])
+        # consumed: the delta was written where the original was
+        assert originals[name].is_deleted()
+    assert not fetched
+    # one program a rung, whatever the count: another count of the rung
+    # builds neither program again
+    programs = (communicator._training_copy, communicator._rung_delta)
+    built = [program._cache_size() for program in programs]
+    other = 256 + (n + 17) % 64
+    assert other != n and communicator.training_rows(other) == RUNG
+    fetched = {name: np.ones((other, DIM), np.float32) for name, _ in tables}
+    ids = np.arange(other, dtype=np.int32)
+    comm.add_delta_parameter(comm.training_state(fetched), fetched, ids, ids)
+    assert [program._cache_size() for program in programs] == built
+    assert all(delta.shape == (other, DIM) and not delta.any()
+               for _, delta, _ in sent[len(tables):])
+
+
+@pytest.mark.parametrize("crossing", ["whole", "in_pieces"])
+@pytest.mark.parametrize("ids", ["distinct", "repeated"])
+def test_an_add_never_writes_to_its_payload(monkeypatch, crossing, ids):
+    """What the push hands ``AddFireForget`` is a view of a device
+    array's host copy, which numpy holds read-only: an Add of such a
+    payload lands as a writable one's does, whether it crosses whole or
+    in pieces and whether ``_combine_duplicate_rows`` sums repeated ids
+    (a block's never repeat; a caller's may)."""
+    import multiverso_tpu as mv
+    from multiverso_tpu.tables import MatrixTableOption
+    monkeypatch.setattr(matrix_table, "_HOST_CUT_PAD_BYTES",
+                        0 if crossing == "in_pieces" else 4 << 20)
+    n = 300
+    rng = np.random.default_rng(n)
+    row_ids = (rng.permutation(VOCAB)[:n] if ids == "distinct"
+               else rng.integers(0, 40, n)).astype(np.int32)
+    payload = np.asarray(jax.numpy.asarray(
+        rng.standard_normal((RUNG, DIM)).astype(np.float32)))[:n]
+    assert not payload.flags.writeable
+    kept = payload.copy()
+    mv.MV_Init([])
+    try:
+        table = mv.MV_CreateTable(MatrixTableOption(num_rows=VOCAB,
+                                                    num_cols=DIM))
+        table.AddFireForget(payload, row_ids=row_ids)
+        got = np.array(table.GetRows(np.arange(VOCAB, dtype=np.int32)))
+    finally:
+        mv.MV_ShutDown()
+    want = np.zeros((VOCAB, DIM), np.float32)
+    np.add.at(want, row_ids, kept)
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(payload, kept)
 
 
 class _Rows:
