@@ -390,3 +390,25 @@ def row_write(shard_rows: int, cols: int, dtype, bucket: int) -> str:
     data = jax.ShapeDtypeStruct((shard_rows, cols), dtype)
     ids = jax.ShapeDtypeStruct((bucket,), jnp.int32)
     return "pallas" if use_pallas(data, ids) else "xla"
+
+
+def pool_rows(rows: jax.Array, bag_of: jax.Array, num_bags: int) -> jax.Array:
+    """Sum pooling of gathered rows: ``(num_bags, cols)`` whose row ``b``
+    is the sum of the ``rows`` whose ``bag_of`` is ``b``, a bag no
+    position names a row of zeros. ``bag_of`` is the position-to-bag map
+    of bags laid end to end, so it is SORTED, and its pad lanes carry
+    ``num_bags``: a segment past the last, which the sum drops, and the
+    map stays sorted with them. Both sizes are static, the positions' rung
+    and the bags' rung; the table layer pads to them
+    (``tables/pooled.py``). One XLA scatter-add on every backend: a bag of
+    a row-sharded table holds a position or two, no shape for a kernel."""
+    return jax.ops.segment_sum(rows, bag_of, num_segments=num_bags,
+                               indices_are_sorted=True)
+
+
+def spread_rows(bag_rows: jax.Array, bag_of: jax.Array) -> jax.Array:
+    """``pool_rows``' transpose, the backward of a sum: every position
+    takes its bag's row, ``bag_rows[bag_of]``. A pad lane (``bag_of`` past
+    the last bag) reads the last bag's row; the caller drops it by the map
+    it sums the positions by (a segment of -1)."""
+    return jnp.take(bag_rows, bag_of, axis=0, mode="clip")

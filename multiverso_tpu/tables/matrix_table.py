@@ -1930,6 +1930,61 @@ class MatrixServerTable(ServerTable):
         self.state["data"] = ctx.place(self._to_storage(values),
                                        self._sharding)
 
+    # -- device plane, pooled: a bag's rows summed where they live ----------
+    # Two thin methods below every other of the class (no line above
+    # moves); the programs and the host's handling of jagged bags are
+    # tables/pooled.py's, imported and built by a table's first pooled verb.
+
+    def device_fetch_pooled(self, row_ids, lengths, *,
+                            padded: bool = False) -> jax.Array:
+        """The sums of bags of rows as a DEVICE array (never leaves HBM),
+        ``(len(lengths), num_cols)`` float32. ``row_ids`` holds the
+        positions bag after bag, ``lengths[b]`` how many belong to bag
+        ``b`` (torch's ``EmbeddingBag`` offsets as lengths, torchrec's
+        ``KeyedJaggedTensor``; ``sum(lengths) == len(row_ids)``, checked).
+        Row ``b`` is the float32 sum of the table's rows at bag ``b``'s
+        positions, a repeated id counted as often as it stands; an empty
+        bag gives a row of zeros. One copy in (the ids and the
+        position-to-bag map, padded on the host) and ONE program: the
+        gather ends in a segment sum by bag, and no row a position exists
+        outside it. Positions pad to the row verbs' id ladder and bags to
+        a ladder of their own (``pooled.program_key``); like
+        ``device_fetch_rows`` the result is cut to ``len(lengths)`` rows
+        by a slice program, one a distinct count, unless the count is its
+        rung. **``padded=True`` returns the bags' rung as it is**
+        (``pooled.bag_bucket(len(lengths))`` rows, those past
+        ``len(lengths)`` zero) and runs no cut: for a caller whose bag
+        count changes from verb to verb, as a row-sharded server's does;
+        ``device_apply_pooled`` takes the gradients back at either length.
+        A table over several devices of one process pools after the
+        ``shard_map`` gather, as ``device_fetch_rows`` gathers.
+        Multi-process: REFUSED by a ``CHECK`` (no silent wrong answer)."""
+        from multiverso_tpu.tables import pooled
+        return pooled.fetch_pooled(self, row_ids, lengths, padded)
+
+    def device_apply_pooled(self, row_ids, lengths, bag_deltas,
+                            option: Optional[AddOption] = None) -> None:
+        """Apply one (device or host) gradient row a BAG to the rows the
+        bags name, in place: every position of bag ``b`` carries
+        ``bag_deltas[b]`` (the backward of a sum). The guarantee is
+        ``device_apply_rows``' own: the deltas of all positions that name
+        one row, within a bag and across bags, are summed before the
+        updater runs once on that row; rows no position names keep their
+        values bit for bit; the delta never leaves the device and is not
+        donated. In its effect on rows and updater state it is
+        ``device_apply_rows(row_ids, bag_deltas[bag of each position],
+        option)``, but the delta a position exists inside the program
+        only: spread, combine and row update are ONE program (a gather by
+        the bag map feeding the segment sum by the host's inverse map,
+        then the row update at the distinct count's power-of-two bucket,
+        as ``_merged_add_rows``), keyed by ``pooled.program_key``.
+        ``bag_deltas`` is ``(len(lengths), num_cols)`` or the bags' rung's
+        rows (what a ``padded`` fetch returned; rows past ``len(lengths)``
+        are never read). Several devices of one process: through the
+        ``shard_map`` row update. Multi-process: REFUSED by a ``CHECK``."""
+        from multiverso_tpu.tables import pooled
+        pooled.apply_pooled(self, row_ids, lengths, bag_deltas, option)
+
 
 class MatrixWorkerTable(WorkerTable):
     """Worker half (reference matrix_table.h:26-77)."""

@@ -42,6 +42,13 @@ touched-rows AdaGrad step, and prints its Mosaic kernels, how many of the
 state's four matrices are aliased input to output and its passes over a
 whole state matrix outside the in-place writes.
 
+``--pooled`` compiles the pooled verbs' two programs
+(``tables/pooled.py``: ``_fetch_pooled`` / ``_apply_pooled``, built over
+the table's own row programs) at ``POOLED``'s shapes, one chip and four,
+and prints their Mosaic kernels, the state leaves the apply aliases, its
+table-sized passes and, sharded, the shape of every all-reduce (the
+gather's ``psum``, a row a position: the segment sum runs after it).
+
 ``--locations`` calls ``compile_cache.enable()`` as an entry point does
 and lowers ``FENCE``'s programs: a Mosaic kernel's body travels inside the
 program as bytecode WITH its debug locations, which JAX's persistent
@@ -350,6 +357,59 @@ def tiny(specs):
             print(f"TINY {name} {prog} kernels={kernels}", flush=True)
 
 
+POOLED = [  # --pooled: (name, rows, chips, position rung, bag rung,
+    # distinct class): rec_pooled_steps' table 20, its largest verb, and
+    # its table 10 on one chip; a one-row table under 2,048 positions (its
+    # buckets are over the table: passes say nothing there); a table over
+    # four shards whose bags are a quarter of its positions
+    ("adagrad_128_pooled_t20", 1_250_000, 1, 229_376, 65_536, 262_144),
+    ("adagrad_128_pooled", 95_874, 1, 6_144, 6_144, 8_192),
+    ("adagrad_128_pooled_r1", 1, 1, 2_048, 2_048, 8),
+    ("adagrad_128_pooled_4c", 1_048_500, 4, 8_192, 2_048, 8_192),
+]
+
+
+def pooled(specs):
+    """POOLED <table> fetch_pooled.<positions>x<bags> kernels=<n>
+    all_reduce=<shapes>
+    POOLED <table> apply_pooled.<positions>x<bags>.<distinct> kernels=<n>
+    aliased=<n>/<state leaves> passes=<n>"""
+    from multiverso_tpu.tables import pooled as pooled_verbs
+    for name, rows, chips, positions, bags, distinct in specs:
+        srv, ctx = build(rows, 128, chips, "adagrad", 1)
+        programs = pooled_verbs._programs(srv)
+        rep = (NamedSharding(ctx.mesh, P()) if chips > 1
+               else SingleDeviceSharding(ctx.mesh.devices.flat[0]))
+        s = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+            shape, dtype, sharding=rep)
+        opt = {k: s((), jnp.float32)
+               for k in ("momentum", "learning_rate", "rho", "lambda_")}
+        opt["worker_id"] = s((), jnp.int32)
+        state, lanes = srv.state, s((positions,), jnp.int32)
+        leaves = jax.tree.leaves(state)
+        kernels = lambda hlo: len(re.findall(  # noqa: E731
+            r"custom_call_target=\"tpu_custom_call\"", hlo))
+        hlo = programs.fetch.lower(state["data"], state["aux"], lanes,
+                                   lanes, bags=bags).compile().as_text()
+        reduces = sorted(set(re.findall(
+            r"= (f32\[[0-9,]+\])\S* all-reduce(?:-start)?\(", hlo)))
+        print(f"POOLED {name} fetch_pooled.{positions}x{bags} "
+              f"kernels={kernels(hlo)} all_reduce={','.join(reduces)}",
+              flush=True)
+        hlo = programs.apply.lower(
+            state, s((distinct,), jnp.int32), s((bags, 128), jnp.float32),
+            lanes, lanes, opt).compile().as_text()
+        aliased = len(re.findall(r"\{\d+\}: \(\d+, \{\}",
+                                 hlo.split("\n", 1)[0]))
+        passes = table_sized_passes(hlo, min(
+            int(np.prod(leaf.shape)) for leaf in leaves) // srv.num_servers)
+        print(f"POOLED {name} apply_pooled.{positions}x{bags}.{distinct} "
+              f"kernels={kernels(hlo)} aliased={aliased}/{len(leaves)} "
+              f"passes={len(passes)}", flush=True)
+        for ln in passes:
+            print("  PASS", ln, flush=True)
+
+
 def read(specs):
     """READ <table> <program> passes=<n> gathers=<n>: instructions of the
     compiled read that write an array as large as the bucket of rows it
@@ -519,6 +579,7 @@ if __name__ == "__main__":
     ap.add_argument("--pairs", action="store_true")
     ap.add_argument("--block", action="store_true")
     ap.add_argument("--scan", action="store_true")
+    ap.add_argument("--pooled", action="store_true")
     ap.add_argument("--locations", action="store_true")
     a = ap.parse_args()
     try:
@@ -540,5 +601,7 @@ if __name__ == "__main__":
         block(BLOCK)
     if a.scan:
         scan(SCAN)
+    if a.pooled:
+        pooled(POOLED)
     if a.locations:
         locations()

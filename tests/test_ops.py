@@ -213,11 +213,13 @@ class TestStatefulRowProgramsAliasOnTpu:
     def compiled(self):
         import re
         lines, out = _aot_table_programs("--alias", "--tiny", "--read",
-                                         "--pairs", "--scan", "--block")
+                                         "--pairs", "--scan", "--block",
+                                         "--pooled")
         found = {}
         for ln in lines:
             m = re.match(
-                r"(?:ALIAS|TINY|READ|PAIRS|SCAN|BLOCK) (\S+) (\S+) (.*)", ln)
+                r"(?:ALIAS|TINY|READ|PAIRS|SCAN|BLOCK|POOLED) (\S+) (\S+) "
+                r"(.*)", ln)
             if m:
                 found[m.group(1), m.group(2)] = m.group(3)
         return found, out
@@ -294,6 +296,36 @@ class TestStatefulRowProgramsAliasOnTpu:
         table = f"adagrad_128_r{rows}"
         assert (table, program) in found, out[-2000:]
         assert found[table, program] == f"kernels={kernels}", out[-3000:]
+
+    @pytest.mark.parametrize("table,program,want", [
+        # rec_pooled_steps' largest verb (table 20): XLA's scatter, the id
+        # vector is over the kernel's budget; rows and history in place
+        ("adagrad_128_pooled_t20", "fetch_pooled.229376x65536",
+         "kernels=0 all_reduce="),
+        ("adagrad_128_pooled_t20", "apply_pooled.229376x65536.262144",
+         "kernels=0 aliased=2/2 passes=0"),
+        ("adagrad_128_pooled", "fetch_pooled.6144x6144",
+         "kernels=0 all_reduce="),
+        ("adagrad_128_pooled", "apply_pooled.6144x6144.8192",
+         "kernels=2 aliased=2/2"),
+        ("adagrad_128_pooled_r1", "fetch_pooled.2048x2048",
+         "kernels=0 all_reduce="),
+        ("adagrad_128_pooled_r1", "apply_pooled.2048x2048.8",
+         "kernels=2 aliased=2/2"),
+        # over four shards the gather's psum carries a row a position,
+        # as device_fetch_rows': the segment sum runs after it
+        ("adagrad_128_pooled_4c", "fetch_pooled.8192x2048",
+         "kernels=0 all_reduce=f32[8192,128]"),
+        ("adagrad_128_pooled_4c", "apply_pooled.8192x2048.8192",
+         "kernels=2 aliased=2/2 passes=0")])
+    def test_pooled_programs_compile_for_v5e(self, compiled, table, program,
+                                             want):
+        """``device_fetch_pooled`` / ``device_apply_pooled``'s programs
+        (``tables/pooled.py``): the apply writes rows and history in
+        place, by the kernel where the row update takes it."""
+        found, out = compiled
+        assert (table, program) in found, out[-2000:]
+        assert found[table, program].startswith(want), out[-3000:]
 
     @pytest.mark.parametrize("program", [
         "slice_rows.163840",            # lm_head's fetch: the whole table
